@@ -17,7 +17,6 @@ expresses it TPU-first:
 Public API is re-exported here for convenience.
 """
 
-from .utils import compat as _compat  # noqa: F401 — jax API shims, first
 from .config import TrainConfig, MeshConfig, DataConfig, ModelConfig
 from .parallel.mesh import make_mesh, world_setup, local_mesh
 from .parallel.sharding import (

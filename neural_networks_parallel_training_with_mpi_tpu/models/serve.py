@@ -232,7 +232,10 @@ class DecodeServer:
         is active)."""
         if not self.active.any():
             return
-        active_dev = jnp.asarray(self.active)
+        # a HOST-side copy: on the CPU backend asarray may alias the numpy
+        # buffer (and jnp.array's own copy is an async device op), while
+        # the host mutates self.active before the dispatched step has run
+        active_dev = jnp.asarray(self.active.copy())
         self.caches, self.tokens, self.pos, self.key = self._step(
             self.params, self.caches, self.tokens, self.pos, active_dev,
             self.key)

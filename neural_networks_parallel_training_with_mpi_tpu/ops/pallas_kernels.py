@@ -34,12 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports fail on some non-TPU builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -184,7 +179,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
                                block_k=block_k, seq_len=t,
                                mask=_resolve_mask(causal, mask_mode),
                                scale=scale)
-    mem = {} if not _HAS_PLTPU else {"memory_space": pltpu.VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, t // block_q),
@@ -370,7 +365,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, block_q: int,
     lse3 = lse.reshape(b * h, 1, t)
     delta3 = delta.reshape(b * h, 1, t)
 
-    mem = {} if not _HAS_PLTPU else {"memory_space": pltpu.VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     row = dict(block_q=block_q, block_k=block_k, seq_len=t,
                mask=_resolve_mask(causal, mask_mode), scale=scale)
     full = lambda spec_t: pl.BlockSpec((1, spec_t, d),
@@ -499,21 +494,23 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     Refs: ``tables (S, MB)`` / ``lens (S,)`` / ``starts (S,)`` ride
     scalar prefetch (SMEM) — runtime VALUES, not compile-time constants,
     so table churn and length growth re-run the same compiled kernel.
-    ``q (1, KV, W·G, hd)`` in VMEM; ``k``/``v`` pools (and int8 scale
-    pools when ``quant``) stay UNBLOCKED in ANY/HBM — only the blocks a
-    stream actually owns ever cross into VMEM, which is the bandwidth
-    half of the win (the FLOPs half is the loop bound).  Scratch: 2-slot
-    VMEM landing buffers per pool operand + a (2, n_operands) DMA
-    semaphore array.
+    ``q (1, KV, W·G, hd)`` in VMEM; ``k``/``v`` pools ``(NB, bs, KV·hd)``
+    (and int8 scale pools ``(NB, 1, KV·bs)`` when ``quant``) stay
+    UNBLOCKED in HBM — only the blocks a stream actually owns ever cross
+    into VMEM, which is the bandwidth half of the win (the FLOPs half is
+    the loop bound).  Every pool operand's last dim is lane-dense: Mosaic
+    refuses to DMA-slice a block out of an array whose last dim is below
+    the 128-lane tile, so heads are folded into it and head ``h`` is a
+    static lane slice.  Scratch: 2-slot VMEM landing buffers per pool
+    operand + a (2, n_operands) DMA semaphore array.
 
     Blocks past a stream's true length (and every block of an inactive
     ``len=0`` lane, whose loop never runs) contribute NOTHING.  Within
     the last live block the tail positions ``>= len`` are masked, so the
-    sink block's frozen garbage is never attended.  int8 pools
-    dequantize ON LOAD (``k·k_scale`` per (position, head) — the same
-    per-position scheme the gathered path applies to its logits/probs,
-    reassociated).  A ``len=0`` lane exits with output 0, the flash
-    kernels' "no contribution" convention."""
+    sink block's frozen garbage is never attended.  int8 pools apply
+    their per-(position, head) scales to the scores and probabilities —
+    the gathered path's scheme.  A ``len=0`` lane exits with output 0,
+    the flash kernels' "no contribution" convention."""
     if quant:
         (ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, ks_buf, vs_buf, sem) = rest
@@ -523,6 +520,7 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     ln = lens_ref[s]
     nb = lax.div(ln + block_size - 1, block_size)
     rows = width * groups
+    hd = q_ref.shape[-1]
 
     def _copies(j):
         slot = lax.rem(j, 2)
@@ -558,18 +556,24 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
         for c in _copies(j):
             c.wait()
         slot = lax.rem(j, 2)
-        k = k_buf[slot].astype(jnp.float32)          # (bs, KV, hd)
+        k = k_buf[slot].astype(jnp.float32)          # (bs, KV*hd)
         v = v_buf[slot].astype(jnp.float32)
-        if quant:
-            k = k * ks_buf[slot].astype(jnp.float32)[..., None]
-            v = v * vs_buf[slot].astype(jnp.float32)[..., None]
+
+        def head_scale(buf, h):
+            # (1, bs), sliced from the REF: a value slice past lane 128
+            # of the (1, KV*bs) row does not lower
+            return buf[slot, :, h * block_size:(h + 1) * block_size]
+
         k_pos = j * block_size + k_off
         keep = (k_pos < ln) & (k_pos <= q_pos)       # (rows, bs)
+        acc, m, l = list(acc), list(m), list(l)
         for h in range(kv_heads):
             q = q_ref[0, h].astype(jnp.float32) * scale    # (rows, hd)
             sc = jax.lax.dot_general(
-                q, k[:, h, :], (((1,), (1,)), ((), ())),
+                q, k[:, h * hd:(h + 1) * hd], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)        # (rows, bs)
+            if quant:
+                sc = sc * head_scale(ks_buf, h)
             sc = jnp.where(keep, sc, NEG_INF)
             m_new = jnp.maximum(m[h], sc.max(axis=-1, keepdims=True))
             # a row with no attendable key in THIS block keeps its prior
@@ -577,17 +581,21 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
             # finite before the running exp() can ever see exp(0) garbage
             p = jnp.exp(sc - m_new)
             corr = jnp.exp(m[h] - m_new)
-            l = l.at[h].set(corr * l[h] + p.sum(axis=-1, keepdims=True))
-            acc = acc.at[h].set(corr * acc[h] + jax.lax.dot_general(
-                p, v[:, h, :], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            m = m.at[h].set(m_new)
-        return acc, m, l
+            l[h] = corr * l[h] + p.sum(axis=-1, keepdims=True)
+            if quant:
+                p = p * head_scale(vs_buf, h)
+            acc[h] = corr * acc[h] + jax.lax.dot_general(
+                p, v[:, h * hd:(h + 1) * hd], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m[h] = m_new
+        return tuple(acc), tuple(m), tuple(l)
 
-    hd = q_ref.shape[-1]
-    acc0 = jnp.zeros((kv_heads, rows, hd), jnp.float32)
-    m0 = jnp.full((kv_heads, rows, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((kv_heads, rows, 1), jnp.float32)
+    # per-head carries as tuples: the kv_heads loop is a Python unroll,
+    # and a stacked (kv_heads, rows, ...) carry updated with .at[h].set
+    # is a scatter, which Mosaic does not lower
+    acc0 = (jnp.zeros((rows, hd), jnp.float32),) * kv_heads
+    m0 = (jnp.full((rows, 1), NEG_INF, jnp.float32),) * kv_heads
+    l0 = (jnp.zeros((rows, 1), jnp.float32),) * kv_heads
 
     @pl.when(nb == 0)
     def _inactive():
@@ -598,9 +606,11 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
         for c in _copies(0):
             c.start()
         acc, m, l = lax.fori_loop(0, nb, body, (acc0, m0, l0))
-        empty = m < (NEG_INF * 0.5)
-        l_safe = jnp.where(empty, 1.0, l)
-        o_ref[0] = jnp.where(empty, 0.0, acc / l_safe).astype(o_ref.dtype)
+        for h in range(kv_heads):
+            empty = m[h] < (NEG_INF * 0.5)
+            l_safe = jnp.where(empty, 1.0, l[h])
+            o_ref[0, h] = jnp.where(empty, 0.0,
+                                    acc[h] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -623,7 +633,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       (``n_heads`` must be a multiple of the pool's ``kv_heads``).
     * ``k_pool``/``v_pool``: (num_blocks, block_size, kv_heads, head_dim)
       — f32/bf16, or int8 with ``k_scale``/``v_scale``
-      (num_blocks, block_size, kv_heads) f32 dequantized on load.
+      (num_blocks, block_size, kv_heads) f32, applied to the scores and
+      probabilities.
     * ``tables``: (streams, max_blocks) int32 pool indices; unallocated
       entries point at the sink block and are NEVER walked (the block
       loop stops at ``ceil(length/block_size)``).
@@ -650,35 +661,36 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if interpret is None:
         interpret = _interpret_default()
 
-    if not _HAS_PLTPU:  # pragma: no cover - exercised only on odd builds
-        # unlike the flash kernels (plain grids, no DMA), the paged
-        # kernel's scalar-prefetch spec, HBM refs and async copies live
-        # in pallas.tpu even in interpret mode — no pl-only fallback
-        raise RuntimeError("paged_attention needs jax.experimental."
-                           "pallas.tpu (scalar prefetch + async DMA)")
-
     # (S, W, H, hd) -> (S, KV, W·G, hd): per-kv-head query rows contiguous
     qk = q.reshape(s_n, width, kv_heads, groups, hd)
     qk = qk.transpose(0, 2, 1, 3, 4).reshape(s_n, kv_heads,
                                              width * groups, hd)
 
     row_map = lambda s, tbl, lns, sts: (s, 0, 0, 0)      # noqa: E731
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)      # stays in HBM
+    hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)      # never blocked
     in_specs = [
         pl.BlockSpec((1, kv_heads, width * groups, hd), row_map),
-        any_spec, any_spec,
+        hbm_spec, hbm_spec,
     ]
-    operands = [qk, k_pool, v_pool]
+    # pool blocks cross into VMEM as lane-dense (bs, KV*hd) rows (see
+    # the kernel's docstring); head h is the lane slice [h*hd, (h+1)*hd)
+    operands = [qk, k_pool.reshape(nb, bs, kv_heads * hd),
+                v_pool.reshape(nb, bs, kv_heads * hd)]
     n_dma = 2
     scratch = [
-        pltpu.VMEM((2, bs, kv_heads, hd), k_pool.dtype),
-        pltpu.VMEM((2, bs, kv_heads, hd), v_pool.dtype),
+        pltpu.VMEM((2, bs, kv_heads * hd), k_pool.dtype),
+        pltpu.VMEM((2, bs, kv_heads * hd), v_pool.dtype),
     ]
     if quant:
-        in_specs += [any_spec, any_spec]
-        operands += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((2, bs, kv_heads), k_scale.dtype),
-                    pltpu.VMEM((2, bs, kv_heads), v_scale.dtype)]
+        # scales ride head-major, (1, KV*bs) per block, so head h's
+        # per-position scales are a static lane slice that broadcasts
+        # over the score rows
+        in_specs += [hbm_spec, hbm_spec]
+        operands += [
+            sc.transpose(0, 2, 1).reshape(nb, 1, kv_heads * bs)
+            for sc in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, 1, kv_heads * bs), k_scale.dtype),
+                    pltpu.VMEM((2, 1, kv_heads * bs), v_scale.dtype)]
         n_dma = 4
     scratch.append(pltpu.SemaphoreType.DMA((2, n_dma)))
 
@@ -733,7 +745,7 @@ def fused_layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     block_rows = min(block_rows, rows)
     if rows % block_rows:
         block_rows = 1  # degenerate but correct fallback
-    mem = {} if not _HAS_PLTPU else {"memory_space": pltpu.VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     out = pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
         grid=(rows // block_rows,),

@@ -2103,7 +2103,8 @@ def _worker_argparser():
                          "BEFORE reporting ready (serve.loadgen."
                          "prewarm), so measured fleet TTFTs are "
                          "steady-state from the first routed request")
-    ap.add_argument("--platform", default="cpu")
+    ap.add_argument("--platform", default="cpu",
+                    choices=["auto", "cpu", "tpu"])
     return ap
 
 
@@ -2118,8 +2119,11 @@ def worker_main(argv: Optional[Sequence[str]] = None) -> int:
 
     from ..utils import platform as plat
 
-    if args.platform == "cpu":
-        plat.pin("cpu", num_devices=max(1, args.tp))
+    # this worker is the one process that touches its device: the backend
+    # comes up here, and a platform that was asked for and is not the one
+    # that came up raises (no fallback)
+    plat.select(args.platform, max(1, args.tp), log=log)
+    plat.compile_cache()
 
     import selectors
 

@@ -929,9 +929,13 @@ class PagedDecodeServer:
             "borrowed table entries")
         bucket = prefill_bucket(w)
         chunk = st.prompt[st.prefilled:st.prefilled + w] + [0] * (bucket - w)
+        # the device gets a HOST-side copy: on the CPU backend asarray may
+        # alias the numpy buffer (and jnp.array's own copy is an async
+        # device op), while the host mutates self.tables / self.active in
+        # place before the dispatched program has run
         logits, self.pools = self._prefill_fn(
             self.params, self.pools,
-            jnp.asarray(self.tables[slot:slot + 1]),
+            jnp.asarray(self.tables[slot:slot + 1].copy()),
             jnp.asarray([st.prefilled], jnp.int32),
             jnp.asarray([chunk], jnp.int32),
             jnp.asarray(w, jnp.int32))
@@ -1271,7 +1275,7 @@ class PagedDecodeServer:
         self.pools, self.tokens, self.pos, self.key = self._step_fn(
             self.params, self.pools, self.tokens,
             jnp.asarray(masked), self.pos,
-            jnp.asarray(self.active), self.key)
+            jnp.asarray(self.active.copy()), self.key)   # see prefill_step
         finished = []
         for rid, slot in list(self._slot_of.items()):
             if not self.active[slot]:

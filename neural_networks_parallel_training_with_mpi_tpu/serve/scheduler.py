@@ -525,7 +525,7 @@ class Scheduler:
         # admission capacity, not the model, is the bottleneck) vs
         # streams mid-decode (sched_bubble: the loop owns the time).
         # The next tick retro-emits that gap as a span, so the goodput
-        # taxonomy prices scheduler dead time instead of dropping it.
+        # category set prices scheduler dead time instead of dropping it.
         self._gap_wall: Optional[float] = None
         self._gap_state: Optional[str] = None
 

@@ -24,7 +24,7 @@ supervised multi-process run — including files from DIFFERENT
 incarnations after a crash-relaunch — onto ONE Chrome/Perfetto timeline
 where the relaunch gap is visible, plus a per-phase time-share summary.
 
-Span taxonomy (the fixed vocabulary the report tool groups by):
+Span vocabulary (the fixed vocabulary the report tool groups by):
 
 ==============  ========================================================
 ``load``        host batch assembly (the loader's ``next()``)

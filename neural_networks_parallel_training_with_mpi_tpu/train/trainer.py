@@ -1633,8 +1633,10 @@ class Trainer:
         if step_flops is not None:
             result["model_flops_per_sec"] = step_flops * thr.samples_per_sec
             if self.telemetry.enabled:
-                result["mfu"] = (result["model_flops_per_sec"]
-                                 / self.telemetry.peak_total)
+                # a utilization exists against a chip's peak only
+                peak = self.telemetry.peak_total
+                result["mfu"] = (None if peak is None else
+                                 result["model_flops_per_sec"] / peak)
         # peak device memory where the backend reports it (TPU HBM; {} on
         # CPU) — the observability the reference's prints never had.
         # PROCESS-lifetime high-water mark (the runtime never resets it),

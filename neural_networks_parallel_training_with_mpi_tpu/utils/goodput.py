@@ -13,19 +13,19 @@ and has two halves:
    (``train/resilience.py`` ``events_path``), and the autopilot
    decision ledger into an exact interval-sweep account of each
    process's covered wall-clock — every second lands in exactly one
-   category of a fixed, exhaustive taxonomy, gaps between spans are
+   category of a fixed, exhaustive set, gaps between spans are
    *attributed, never dropped*, and the categories provably sum to the
    covered interval (``sum_ok`` is asserted by tests and the bench);
 
 2. an **online meter** (:class:`GoodputMeter`) that subscribes to the
    span stream via ``train.trace.add_listener`` and keeps the same
-   taxonomy incrementally, cheap enough to ride every traced process
+   category set incrementally, cheap enough to ride every traced process
    (priced by ``bench.py --goodput``), feeding ``kind="goodput"``
    rollup records through the existing telemetry channel so
    ``tools/obs_agg.py`` can merge a fleet-wide goodput fraction into
    fleet.json / Prometheus / the dashboard.
 
-Taxonomy (fixed and exhaustive — the categories ROADMAP items 1 and 4
+Category set (fixed and exhaustive — the categories ROADMAP items 1 and 4
 will be priced in):
 
 ==================  =====================================================
@@ -76,7 +76,7 @@ try:  # package context (bench, telemetry, tests)
 except Exception:  # standalone file-path load: tools inject utils/jsonl
     _jsonl = None  # type: ignore[assignment]
 
-#: the fixed, exhaustive taxonomy — every accounted second lands in
+#: the fixed, exhaustive category set — every accounted second lands in
 #: exactly one of these, and consumers (obs_agg, the report tool, the
 #: bench gates) iterate THIS tuple rather than discovering keys.
 CATEGORIES = ("step", "compile", "data_stall", "ckpt", "rollback", "eval",
@@ -118,7 +118,7 @@ SUM_TOL = 1e-6  # float tolerance for the sum-to-covered invariant
 
 
 def categorize(name: str) -> str:
-    """Map a span name to its taxonomy category (unknown names are
+    """Map a span name to its category (unknown names are
     ``idle`` — 'idle/other' is the catch-all, never a dropped second)."""
     if name.startswith("compile:"):
         return "compile"
@@ -445,7 +445,7 @@ def ledger_from_dir(dirpath: str) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 class GoodputMeter:
-    """Incremental taxonomy accounting from the live span stream.
+    """Incremental category accounting from the live span stream.
 
     Subscribes via ``train.trace.add_listener(meter.on_span)``; per span
     the cost is one dict update, priced by ``bench.py --goodput``.  It

@@ -4,7 +4,7 @@ The reference has no failure handling: a lost rank hangs
 ``comm.gather`` forever (dataParallelTraining_NN_MPI.py:185) and the job
 blocks silently until the scheduler kills it.  The TPU-native equivalents of
 that failure mode — a peer host dropping out of a DCN collective, a wedged
-device tunnel — stall inside ``block_until_ready`` the same way.
+device — stall inside ``block_until_ready`` the same way.
 
 :class:`HangWatchdog` converts the silent stall into a loud, diagnosable
 failure: a daemon thread tracks a heartbeat the train loop pats every step,

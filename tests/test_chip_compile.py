@@ -1,0 +1,160 @@
+"""Described-chip compiles: the main path's kernels and serving programs
+lowered for a TPU v5e that is described, not attached.
+
+Nothing here runs — a compile says the chip's compiler accepts the
+program at ``big_lm`` head geometry (16 heads x head_dim 64, d_model
+1024, bf16), which interpret mode cannot say: the fused paged kernel
+passed every interpret-mode test while Mosaic refused it outright.  Every
+kernel is lowered with ``interpret=False`` passed explicitly (the default
+asks ``jax.default_backend()``, which is the CPU here).
+
+The topology is described inside a module-scoped fixture — never at
+import, never in conftest — so under pytest-xdist only the worker that is
+handed this file loads the TPU compiler, and every worker collects the
+same tests.  All such compiles live in this one file for the same reason.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from neural_networks_parallel_training_with_mpi_tpu.models.transformer import (
+    Transformer, TransformerConfig,
+)
+from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import (
+    flash_attention, fused_layernorm, paged_attention,
+)
+from neural_networks_parallel_training_with_mpi_tpu.serve import paged_kv
+from neural_networks_parallel_training_with_mpi_tpu.utils import prng
+
+# big_lm widths (bench.py _BIG); the serving geometry is chip_smoke's
+BATCH, SEQ, HEADS, HEAD_DIM, D_MODEL = 8, 1024, 16, 64, 1024
+SLOTS, NUM_BLOCKS, BLOCK_SIZE, PREFILL = 8, 513, 16, 128
+MAX_BLOCKS = SEQ // BLOCK_SIZE
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a described-chip executable is written to the persistent cache but
+    # cannot be read back without a chip; keep these compiles out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """``spec(shape, dtype)``: an abstract array placed on one described
+    chip (there is no device to hold a real one)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_forward(spec):
+    qkv = spec((BATCH, SEQ, HEADS, HEAD_DIM), BF16)
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, True, 128, 128, False),
+        qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_backward(spec):
+    qkv = spec((BATCH, SEQ, HEADS, HEAD_DIM), BF16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, 128, 128, False)
+        return out.astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    # forward + dq + dk/dv kernels
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
+
+
+def test_fused_layernorm(spec):
+    text = _compiled_text(
+        lambda x, s, b: fused_layernorm(x, s, b, interpret=False),
+        spec((BATCH * SEQ, D_MODEL), BF16), spec((D_MODEL,), jnp.float32),
+        spec((D_MODEL,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width,int8_kv", [
+    (1, False), (PREFILL, False), (1, True), (PREFILL, True),
+], ids=["decode", "prefill128", "decode-int8kv", "prefill128-int8kv"])
+def test_paged_attention(spec, width, int8_kv):
+    """The fused paged kernel (``attn_impl="fused"``): width 1 is the
+    batched decode step, width 128 one chunked-prefill bucket."""
+    pool = spec((NUM_BLOCKS, BLOCK_SIZE, HEADS, HEAD_DIM),
+                jnp.int8 if int8_kv else BF16)
+    args = [spec((SLOTS, width, HEADS, HEAD_DIM), BF16), pool, pool,
+            spec((SLOTS, MAX_BLOCKS), jnp.int32), spec((SLOTS,), jnp.int32),
+            spec((SLOTS,), jnp.int32)]
+    if int8_kv:
+        scale = spec((NUM_BLOCKS, BLOCK_SIZE, HEADS), jnp.float32)
+        args += [scale, scale]
+
+    def fn(q, kp, vp, tables, lens, starts, ks=None, vs=None):
+        return paged_attention(q, kp, vp, tables, lens, starts, k_scale=ks,
+                               v_scale=vs, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+@pytest.fixture(scope="module")
+def serve_programs(spec):
+    """The gathered paged server's jitted programs over a 2-layer model
+    at big_lm widths, with abstract params and pools on the described
+    chip."""
+    model = Transformer(TransformerConfig(
+        vocab_size=32768, max_seq_len=SEQ, n_layers=2, d_model=D_MODEL,
+        n_heads=HEADS, d_ff=4096, compute_dtype=BF16))
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda: model.init(prng.init_key(0))))
+    pools = abstract(jax.eval_shape(
+        lambda: paged_kv.init_paged_kv(model, NUM_BLOCKS, BLOCK_SIZE)))
+    prefill, step, _, _ = paged_kv._paged_programs(
+        model, BLOCK_SIZE, MAX_BLOCKS, 0.0, 0, 1.0, False, "gathered")
+    return params, pools, prefill, step
+
+
+def test_gathered_decode_step(spec, serve_programs):
+    params, pools, _, step = serve_programs
+    step.lower(
+        params, pools, spec((SLOTS, SEQ), jnp.int32),
+        spec((SLOTS, MAX_BLOCKS), jnp.int32), spec((SLOTS,), jnp.int32),
+        spec((SLOTS,), jnp.bool_), spec((2,), jnp.uint32)).compile()
+
+
+def test_gathered_prefill_bucket(spec, serve_programs):
+    params, pools, prefill, _ = serve_programs
+    prefill.lower(
+        params, pools, spec((1, MAX_BLOCKS), jnp.int32),
+        spec((1,), jnp.int32), spec((1, PREFILL), jnp.int32),
+        spec((), jnp.int32)).compile()

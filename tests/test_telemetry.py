@@ -72,10 +72,12 @@ def test_metrics_stream_heartbeat_and_summary(tmp_path, mesh8, capsys):
                for r in step_recs)
     assert all(r["param_norm"] > 0 for r in step_recs)
     assert all(0 <= r["update_ratio"] for r in step_recs)
-    # mfu + step_time appear once dispatch-to-dispatch time exists
+    # mfu + step_time appear once dispatch-to-dispatch time exists; off
+    # the TPU the field is present and null (no chip peak to divide by)
     timed = [r for r in step_recs if "step_time_ms" in r]
-    assert timed and all("mfu" in r and r["mfu"] >= 0 for r in timed)
-    assert "mfu" in result and result["mfu"] > 0
+    assert timed and all("mfu" in r and r["mfu"] is None for r in timed)
+    assert "mfu" in result and result["mfu"] is None
+    assert result["model_flops_per_sec"] > 0
     hb = telemetry_lib.read_heartbeat(os.path.join(d, "heartbeat.json"))
     assert hb["step"] == 8 and hb["final"] is True
     assert telemetry_lib.heartbeat_age_s(
@@ -354,8 +356,8 @@ def test_train_step_flops_mlp_and_peak_table():
     assert bench.peak_flops("TPU v5e") == 197e12
     assert bench.peak_flops("TPU v4") == 275e12
     assert bench.peak_flops("cpu") is None
-    assert telemetry_lib.telemetry_peak_flops("cpu", "cpu") == \
-        telemetry_lib.NOMINAL_CPU_PEAK_FLOPS
+    # no utilization against an invented peak: off-TPU there is none
+    assert telemetry_lib.telemetry_peak_flops("cpu", "cpu") is None
     assert telemetry_lib.telemetry_peak_flops("TPU v4", "tpu") == 275e12
 
 

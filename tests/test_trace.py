@@ -224,7 +224,7 @@ def _digest(params):
     return h.hexdigest()
 
 
-def test_trainer_span_taxonomy_and_ledger(tmp_path, mesh8):
+def test_trainer_span_categories_and_ledger(tmp_path, mesh8):
     """fit() emits load/dispatch/fetch/ckpt spans and the step's compile
     lands in the ledger with the layout-tagged name."""
     from neural_networks_parallel_training_with_mpi_tpu.train.trainer import (
@@ -298,7 +298,7 @@ def test_serve_tick_spans_and_churn_adds_no_compiles(tmp_path):
     """Acceptance: the paged-attention table-churn no-recompile
     invariant as a LEDGER assertion — after the first decode compile,
     admission/retire churn through the scheduler adds zero compile
-    events — plus the tick-phase span taxonomy."""
+    events — plus the tick-phase span vocabulary."""
     from neural_networks_parallel_training_with_mpi_tpu.models.transformer import (
         Transformer, TransformerConfig,
     )

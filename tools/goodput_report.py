@@ -4,7 +4,7 @@ Joins one or more trace directories' span streams with the supervisor
 lifecycle events (``supervisor-events*.jsonl``) and autopilot decision
 ledger (``autopilot*.jsonl``) into the exact offline goodput account
 built by ``utils/goodput.py``: every second of each process's covered
-wall-clock lands in exactly one category of the fixed taxonomy (step,
+wall-clock lands in exactly one category of the fixed set (step,
 compile, data_stall, ckpt, rollback, eval, relaunch_gap, drain,
 serve_queue_wait, serve_bubble, idle), gaps attributed rather than
 dropped, categories provably summing to the covered interval.
@@ -49,7 +49,8 @@ gp._jsonl = jz  # standalone load: inject the shared tolerant reader
 _BAR_W = 40
 # one glyph per category for the text bar, in CATEGORIES order
 _GLYPH = {"step": "#", "compile": "C", "data_stall": "d", "ckpt": "k",
-          "rollback": "R", "eval": "e", "relaunch_gap": "_", "drain": "v",
+          "rollback": "R", "eval": "e", "recovery": "r",
+          "relaunch_gap": "_", "drain": "v",
           "serve_queue_wait": "q", "serve_bubble": "b", "idle": "."}
 
 

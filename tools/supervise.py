@@ -69,7 +69,8 @@ def main(argv=None) -> int:
                         "probe reports fewer than N healthy devices, "
                         "then exit 46 (capacity abort, no-retry) when "
                         "the restart budget runs out")
-    p.add_argument("--probe-timeout", type=float, default=60.0,
+    p.add_argument("--probe-timeout", dest="world_probe_s", type=float,
+                   default=60.0,
                    help="seconds the topology probe may spend before it "
                         "counts as failed")
     p.add_argument("--telemetry-dir", default=None,
@@ -129,7 +130,7 @@ def main(argv=None) -> int:
                      ckpt_dir=args.checkpoint_dir,
                      elastic=args.elastic,
                      min_devices=args.min_devices,
-                     probe=(lambda: default_probe(args.probe_timeout))
+                     probe=(lambda: default_probe(args.world_probe_s))
                      if args.elastic else None)
 
 
