@@ -154,15 +154,17 @@ def _block_chunk(model: Transformer, params, cache, x, pos):
         out = out.reshape(b, s, c.n_heads, c.head_dim)
     out = out.reshape(b, s, c.d_model)
     x = x + mods["attn_out"].apply(params["attn_out"], out)
-    h = mods["ln2"].apply(params["ln2"], x)
-    if c.moe_experts > 0:
-        ff, _ = mods["moe"].apply(params["moe"], h)
-    else:
-        ff = model._ffn(mods, params, h)
+    with jax.named_scope("ffn"):   # as Transformer._block names it
+        h = mods["ln2"].apply(params["ln2"], x)
+        if c.moe_experts > 0:
+            ff, _ = mods["moe"].apply(params["moe"], h)
+        else:
+            ff = model._ffn(mods, params, h)
+        x = x + ff.astype(x.dtype)
     new_cache = {"k": new_k, "v": new_v}
     if quant:
         new_cache.update(k_scale=new_ks, v_scale=new_vs)
-    return x + ff.astype(x.dtype), new_cache
+    return x, new_cache
 
 
 def _forward_token_batched(model: Transformer, params, caches, ids,

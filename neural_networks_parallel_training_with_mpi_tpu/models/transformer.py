@@ -294,29 +294,36 @@ class Transformer(Module):
         qobs = {} if collect else None
         qkw = ({"qscales": qscales, "qobserved": qobs}
                if c.matmul_dtype == "fp8" else {})
-        h = mods["ln1"].apply(params["ln1"], x)
-        qkv = mods["qkv"].apply(params["qkv"], h, **qkw)
-        q, k, v = split_qkv(c, qkv)
-        # GQA training path: repeat K/V to full query heads so every
-        # attention impl (dense/flash/ring/...) sees plain MHA — same
-        # math as grouped attention; the bandwidth win is decode-side
-        # (models.generate caches the UN-repeated kv_heads)
-        k, v = repeat_kv(c, k), repeat_kv(c, v)
-        out = sequence_sharded_attention(
-            c.attention, q, k, v,
-            axis=c.seq_axis, causal=True, block_q=c.flash_block_q,
-            block_k=c.flash_block_k,
-            rope_theta=(c.rope_theta if c.pos_encoding == "rope"
-                        else None))
-        out = out.reshape(*out.shape[:2], c.d_model)
-        x = x + mods["attn_out"].apply(params["attn_out"], out, **qkw)
-        h = mods["ln2"].apply(params["ln2"], x)
-        if c.moe_experts > 0:
-            ff, aux = mods["moe"].apply(params["moe"], h)
-        else:
-            ff = self._ffn(mods, params, h, **qkw)
-            aux = jnp.zeros((), jnp.float32)
-        return x + ff.astype(x.dtype), aux, (qobs or {})
+        # the named scopes are what the device trace is read by
+        # (benchmark/reducers/scopes.py); each norm and residual add sits
+        # in the scope of the matrix product it feeds or follows
+        with jax.named_scope("attn_proj"):
+            h = mods["ln1"].apply(params["ln1"], x)
+            qkv = mods["qkv"].apply(params["qkv"], h, **qkw)
+            q, k, v = split_qkv(c, qkv)
+            # GQA training path: repeat K/V to full query heads so every
+            # attention impl (dense/flash/ring/...) sees plain MHA — same
+            # math as grouped attention; the bandwidth win is decode-side
+            # (models.generate caches the UN-repeated kv_heads)
+            k, v = repeat_kv(c, k), repeat_kv(c, v)
+        with jax.named_scope("attention"):
+            out = sequence_sharded_attention(
+                c.attention, q, k, v,
+                axis=c.seq_axis, causal=True, block_q=c.flash_block_q,
+                block_k=c.flash_block_k,
+                rope_theta=(c.rope_theta if c.pos_encoding == "rope"
+                            else None))
+        with jax.named_scope("attn_proj"):
+            out = out.reshape(*out.shape[:2], c.d_model)
+            x = x + mods["attn_out"].apply(params["attn_out"], out, **qkw)
+        with jax.named_scope("ffn"):
+            h = mods["ln2"].apply(params["ln2"], x)
+            if c.moe_experts > 0:
+                ff, aux = mods["moe"].apply(params["moe"], h)
+            else:
+                ff = self._ffn(mods, params, h, **qkw)
+                aux = jnp.zeros((), jnp.float32)
+            return x + ff.astype(x.dtype), aux, (qobs or {})
 
     def add_pos(self, params, x_tokens: jax.Array,
                 positions: jax.Array) -> jax.Array:
@@ -340,30 +347,33 @@ class Transformer(Module):
         Single definition shared by the training forward and the KV-cache
         decode path (models.generate), so they cannot drift."""
         c = self.cfg
-        x = Embedding(c.vocab_size, c.d_model, c.param_dtype).apply(
-            params["embed"], ids)
-        return self.add_pos(params, x, positions)
+        with jax.named_scope("embed"):
+            x = Embedding(c.vocab_size, c.d_model, c.param_dtype).apply(
+                params["embed"], ids)
+            return self.add_pos(params, x, positions)
 
     def final_norm(self, params, x: jax.Array) -> jax.Array:
         """The pre-head LayerNorm — the non-vocab half of
         :meth:`head_logits`, shared with the vocab-parallel head (same
         drift argument as :meth:`add_pos`)."""
         c = self.cfg
-        return LayerNorm(c.d_model, param_dtype=c.param_dtype).apply(
-            params["ln_f"], x)
+        with jax.named_scope("lm_head"):
+            return LayerNorm(c.d_model, param_dtype=c.param_dtype).apply(
+                params["ln_f"], x)
 
     def head_logits(self, params, x: jax.Array, qscales=None) -> jax.Array:
         """Final LayerNorm + untied head -> f32 logits (shared with
         models.generate, same drift argument as :meth:`embed`)."""
         c = self.cfg
         x = self.final_norm(params, x)
-        logits = Linear(c.d_model, c.vocab_size, use_bias=False,
-                        param_dtype=c.param_dtype,
-                        compute_dtype=c.compute_dtype,
-                        matmul_dtype=self._mm("head"),
-                        q_role="head").apply(params["head"], x,
-                                             qscales=qscales)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = Linear(c.d_model, c.vocab_size, use_bias=False,
+                            param_dtype=c.param_dtype,
+                            compute_dtype=c.compute_dtype,
+                            matmul_dtype=self._mm("head"),
+                            q_role="head").apply(params["head"], x,
+                                                 qscales=qscales)
+            return logits.astype(jnp.float32)
 
     def fwd_flops(self, x_shape):
         """(B, T) token batch.  qkv/out/ffn/attention matmuls + LM head;
@@ -508,17 +518,19 @@ class Transformer(Module):
                 logits, yc, mask, label_smoothing=label_smoothing)
 
         chunk_sum = jax.checkpoint(chunk_sum)
-        xs = x.reshape(B, n, k, x.shape[-1]).swapaxes(0, 1)  # (n, B, k, d)
-        ys = labels.reshape(B, n, k).swapaxes(0, 1)          # (n, B, k)
 
         def body(acc, inp):
             xc, yc = inp
             s, cnt = chunk_sum(params["head"], xc, yc)
             return (acc[0] + s, acc[1] + cnt), None
 
-        (s, cnt), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (xs, ys))
+        with jax.named_scope("chunked_ce"):
+            xs = x.reshape(B, n, k, x.shape[-1]).swapaxes(0, 1)  # (n,B,k,d)
+            ys = labels.reshape(B, n, k).swapaxes(0, 1)          # (n, B, k)
+            (s, cnt), _ = jax.lax.scan(
+                body,
+                (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+                (xs, ys))
         return s, cnt
 
     def fused_loss_sum(self, loss_name: str):

@@ -197,6 +197,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qh, kh, vh)
     return _heads_minor(out, b, h), lse.reshape(b * h, t)
 
@@ -384,6 +385,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, block_q: int,
                                **mem),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qh, kh, vh, doh, lse3, delta3)
 
     dk, dv = pl.pallas_call(
@@ -406,6 +408,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qh, kh, vh, doh, lse3, delta3)
     return (_heads_minor(dq, b, h), _heads_minor(dk, b, h),
             _heads_minor(dv, b, h))
@@ -709,6 +712,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct(
             (s_n, kv_heads, width * groups, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       starts.astype(jnp.int32), *operands)
     # (S, KV, W·G, hd) -> (S, W, H, hd)

@@ -147,8 +147,6 @@ def zero1_shard_update(optimizer: Optimizer, state: TrainState,
     from jax.flatten_util import ravel_pytree
 
     reduce_axes = DATA_AXES + tuple(extra_reduce_axes)
-    total = lax.psum(c, reduce_axes)
-    loss = lax.psum(s, reduce_axes) / total
     flat_params, unravel = ravel_pytree(state.params)
     flat_grads, _ = ravel_pytree(grads)
     n = data_axis_size(mesh)
@@ -163,33 +161,41 @@ def zero1_shard_update(optimizer: Optimizer, state: TrainState,
                 f"zero1 opt-state slot length {leaf.shape[0]} != "
                 f"derived shard length {shard_len}")
     pad = shard_len * n - flat_params.shape[0]
-    g_shard = lax.psum_scatter(
-        jnp.pad(flat_grads.astype(jnp.float32), (0, pad)),
-        DATA_AXES, scatter_dimension=0, tiled=True)
-    if extra_reduce_axes:
-        g_shard = lax.psum(g_shard, tuple(extra_reduce_axes))
-    g_shard = g_shard / total
-    gnorm = None
-    if (grad_clip > 0 or with_metrics
-            or optimizer.update_with_norm is not None):
-        # padding lanes are zero, so they contribute nothing to the norm;
-        # measured PRE-clip, matching the replicated path's guard
-        gsq = lax.psum(jnp.sum(jnp.square(g_shard)), DATA_AXES)
-        gnorm = jnp.sqrt(gsq)
-    if grad_clip > 0:
-        scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-        g_shard = g_shard * scale
-    idx = lax.axis_index(DATA_AXES)
-    p_shard = lax.dynamic_slice(
-        jnp.pad(flat_params, (0, pad)), (idx * shard_len,), (shard_len,))
-    if optimizer.update_with_norm is not None:
-        new_p_shard, new_opt = optimizer.update_with_norm(
-            g_shard, state.opt_state, p_shard, gnorm)
-    else:
-        new_p_shard, new_opt = optimizer.update(g_shard, state.opt_state,
-                                                p_shard)
-    flat_new = lax.all_gather(new_p_shard, DATA_AXES, axis=0,
-                              tiled=True)[:flat_params.shape[0]]
+    # ``grad_exchange`` names the work, whatever collectives implement
+    # it: the reduce-scatter here and the parameter all-gather below
+    with jax.named_scope("grad_exchange"):
+        total = lax.psum(c, reduce_axes)
+        loss = lax.psum(s, reduce_axes) / total
+        g_shard = lax.psum_scatter(
+            jnp.pad(flat_grads.astype(jnp.float32), (0, pad)),
+            DATA_AXES, scatter_dimension=0, tiled=True)
+        if extra_reduce_axes:
+            g_shard = lax.psum(g_shard, tuple(extra_reduce_axes))
+        g_shard = g_shard / total
+        gnorm = None
+        if (grad_clip > 0 or with_metrics
+                or optimizer.update_with_norm is not None):
+            # padding lanes are zero, so they contribute nothing to the
+            # norm; measured PRE-clip, matching the replicated path's guard
+            gsq = lax.psum(jnp.sum(jnp.square(g_shard)), DATA_AXES)
+            gnorm = jnp.sqrt(gsq)
+        if grad_clip > 0:
+            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
+            g_shard = g_shard * scale
+        idx = lax.axis_index(DATA_AXES)
+        p_shard = lax.dynamic_slice(
+            jnp.pad(flat_params, (0, pad)), (idx * shard_len,),
+            (shard_len,))
+    with jax.named_scope("optimizer_update"):
+        if optimizer.update_with_norm is not None:
+            new_p_shard, new_opt = optimizer.update_with_norm(
+                g_shard, state.opt_state, p_shard, gnorm)
+        else:
+            new_p_shard, new_opt = optimizer.update(
+                g_shard, state.opt_state, p_shard)
+    with jax.named_scope("grad_exchange"):
+        flat_new = lax.all_gather(new_p_shard, DATA_AXES, axis=0,
+                                  tiled=True)[:flat_params.shape[0]]
     new_state = TrainState(state.step + 1, unravel(flat_new), new_opt)
     if not with_metrics:
         return new_state, loss
@@ -299,20 +305,22 @@ def make_train_step(model, optimizer: Optimizer, mesh: Mesh,
 
     def shard_step(state: TrainState, batch: Batch):
         new_qstate = None
-        if fp8:
-            # delayed scaling (ops.qmm): read the per-role delayed amax
-            # from the calibration state, collect this step's observed
-            # amax from the differentiated forward, pmax it across
-            # replicas (every replica must roll the IDENTICAL history —
-            # the state is replicated) and record it after the update
-            qamax = qmm.delayed_amax(state.qstate)
-            s, c, grads, obs = _accumulated_q_sum_and_grads(
-                loss_fn, state.params, batch, accum_steps, qamax)
-            obs = {k: lax.pmax(v, DATA_AXES) for k, v in obs.items()}
-            new_qstate = qmm.update_qstate(state.qstate, obs)
-        else:
-            s, c, grads = _accumulated_sum_and_grads(
-                loss_fn, state.params, batch, accum_steps)
+        with jax.named_scope("loss_and_grad"):
+            if fp8:
+                # delayed scaling (ops.qmm): read the per-role delayed
+                # amax from the calibration state, collect this step's
+                # observed amax from the differentiated forward, pmax it
+                # across replicas (every replica must roll the IDENTICAL
+                # history — the state is replicated) and record it after
+                # the update
+                qamax = qmm.delayed_amax(state.qstate)
+                s, c, grads, obs = _accumulated_q_sum_and_grads(
+                    loss_fn, state.params, batch, accum_steps, qamax)
+                obs = {k: lax.pmax(v, DATA_AXES) for k, v in obs.items()}
+                new_qstate = qmm.update_qstate(state.qstate, obs)
+            else:
+                s, c, grads = _accumulated_sum_and_grads(
+                    loss_fn, state.params, batch, accum_steps)
         if update_sharding == "zero1":
             new_state, out = zero1_shard_update(
                 optimizer, state, s, c, grads, mesh, grad_clip=grad_clip,
@@ -329,37 +337,44 @@ def make_train_step(model, optimizer: Optimizer, mesh: Mesh,
             if fp8:
                 new_state = new_state._replace(qstate=new_qstate)
             return new_state, out
-        if grad_reduction == "global_mean":
-            total = lax.psum(c, DATA_AXES)
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.psum(g, DATA_AXES) / total, grads)
-            loss = lax.psum(s, DATA_AXES) / total
-        elif grad_reduction == "local":
-            # MEASUREMENT-ONLY ablation (bench.py --scaling): the exact
-            # same per-shard compute with ZERO cross-device collectives,
-            # so (global_mean step time) - (local step time) isolates the
-            # gradient allreduce cost at each mesh size.  Replicas apply
-            # their own shard-mean and silently diverge — never train
-            # with this; the Trainer does not expose it.
-            grads = jax.tree_util.tree_map(
-                lambda g: g / jnp.maximum(c, 1.0), grads)
-            loss = s / jnp.maximum(c, 1.0)
-        else:  # per_shard_mean: the reference's :188-197 semantics
-            local_mean = jax.tree_util.tree_map(
-                lambda g: g / jnp.maximum(c, 1.0), grads)
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.pmean(g, DATA_AXES), local_mean)
-            loss = lax.pmean(s / jnp.maximum(c, 1.0), DATA_AXES)
-        if with_metrics:
-            from ..train import telemetry
+        # ``grad_exchange`` names the work (every replica ends up with the
+        # mean gradient), whatever collective implements it
+        with jax.named_scope("grad_exchange"):
+            if grad_reduction == "global_mean":
+                total = lax.psum(c, DATA_AXES)
+                grads = jax.tree_util.tree_map(
+                    lambda g: lax.psum(g, DATA_AXES) / total, grads)
+                loss = lax.psum(s, DATA_AXES) / total
+            elif grad_reduction == "local":
+                # MEASUREMENT-ONLY ablation (bench.py --scaling): the
+                # exact same per-shard compute with ZERO cross-device
+                # collectives, so (global_mean step time) - (local step
+                # time) isolates the gradient allreduce cost at each mesh
+                # size.  Replicas apply their own shard-mean and silently
+                # diverge — never train with this; the Trainer does not
+                # expose it.
+                grads = jax.tree_util.tree_map(
+                    lambda g: g / jnp.maximum(c, 1.0), grads)
+                loss = s / jnp.maximum(c, 1.0)
+            else:  # per_shard_mean: the reference's :188-197 semantics
+                local_mean = jax.tree_util.tree_map(
+                    lambda g: g / jnp.maximum(c, 1.0), grads)
+                grads = jax.tree_util.tree_map(
+                    lambda g: lax.pmean(g, DATA_AXES), local_mean)
+                loss = lax.pmean(s / jnp.maximum(c, 1.0), DATA_AXES)
+        with jax.named_scope("optimizer_update"):
+            if with_metrics:
+                from ..train import telemetry
 
-            new_params, new_opt, metrics = telemetry.update_with_metrics(
-                optimizer, grads, state.opt_state, state.params, loss)
-            return (TrainState(state.step + 1, new_params, new_opt,
-                               new_qstate if fp8 else state.qstate),
-                    metrics)
-        new_params, new_opt = optimizer.update(grads, state.opt_state,
-                                               state.params)
+                new_params, new_opt, metrics = (
+                    telemetry.update_with_metrics(
+                        optimizer, grads, state.opt_state, state.params,
+                        loss))
+                return (TrainState(state.step + 1, new_params, new_opt,
+                                   new_qstate if fp8 else state.qstate),
+                        metrics)
+            new_params, new_opt = optimizer.update(grads, state.opt_state,
+                                                   state.params)
         return (TrainState(state.step + 1, new_params, new_opt,
                            new_qstate if fp8 else state.qstate), loss)
 

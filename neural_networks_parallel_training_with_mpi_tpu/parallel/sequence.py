@@ -499,30 +499,35 @@ def sequence_sharded_attention(impl: str, q, k, v, *, axis: str = "seq",
         positions = global_positions(impl, axis, q.shape[1])
         q = rope_rotate(q, positions, rope_theta)
         k = rope_rotate(k, positions, rope_theta)
-    if impl == "dense":
-        return attention_reference(q, k, v, causal=causal, scale=scale)
-    if impl == "dense_blockwise":
-        return attention_dense_blockwise(q, k, v, causal=causal,
-                                         scale=scale)
-    if impl == "flash":
-        from ..ops.pallas_kernels import flash_attention
+    # the inner scope names what implements the work, under the
+    # caller's ``attention`` scope (the device trace is read by both)
+    with jax.named_scope(f"attn_{impl}"):
+        if impl == "dense":
+            return attention_reference(q, k, v, causal=causal, scale=scale)
+        if impl == "dense_blockwise":
+            return attention_dense_blockwise(q, k, v, causal=causal,
+                                             scale=scale)
+        if impl == "flash":
+            from ..ops.pallas_kernels import flash_attention
 
-        return flash_attention(q, k, v, causal, block_q=block_q,
-                               block_k=block_k)
-    if impl == "ring":
-        return ring_attention(q, k, v, axis=axis, causal=causal, scale=scale)
-    if impl == "ring_flash":
-        return ring_flash_attention(q, k, v, axis=axis, causal=causal,
-                                    scale=scale, block_q=block_q,
-                                    block_k=block_k)
-    if impl == "striped":
-        return ring_attention(q, k, v, axis=axis, causal=causal, scale=scale,
-                              striped=True)
-    if impl == "striped_flash":
-        return striped_ring_flash_attention(q, k, v, axis=axis,
-                                            causal=causal, scale=scale,
-                                            block_q=block_q,
-                                            block_k=block_k)
-    if impl == "ulysses":
-        return ulysses_attention(q, k, v, axis=axis, causal=causal, scale=scale)
+            return flash_attention(q, k, v, causal, block_q=block_q,
+                                   block_k=block_k)
+        if impl == "ring":
+            return ring_attention(q, k, v, axis=axis, causal=causal,
+                                  scale=scale)
+        if impl == "ring_flash":
+            return ring_flash_attention(q, k, v, axis=axis, causal=causal,
+                                        scale=scale, block_q=block_q,
+                                        block_k=block_k)
+        if impl == "striped":
+            return ring_attention(q, k, v, axis=axis, causal=causal,
+                                  scale=scale, striped=True)
+        if impl == "striped_flash":
+            return striped_ring_flash_attention(q, k, v, axis=axis,
+                                                causal=causal, scale=scale,
+                                                block_q=block_q,
+                                                block_k=block_k)
+        if impl == "ulysses":
+            return ulysses_attention(q, k, v, axis=axis, causal=causal,
+                                     scale=scale)
     raise ValueError(f"unknown attention impl {impl!r}")

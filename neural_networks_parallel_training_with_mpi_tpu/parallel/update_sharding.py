@@ -223,71 +223,80 @@ def sharded_update(optimizer: Optimizer, state: TrainState, s, c, grads,
     commute).
     """
     reduce_axes = DATA_AXES + tuple(extra_reduce_axes)
-    total = lax.psum(c, reduce_axes)
-    loss = lax.psum(s, reduce_axes) / total
-    idx = lax.axis_index(DATA_AXES)
-
     p_leaves, treedef = jax.tree_util.tree_flatten(state.params)
     g_leaves = jax.tree_util.tree_leaves(grads)
     plans = jax.tree_util.tree_leaves(plan, is_leaf=_is_plan)
     assert len(p_leaves) == len(g_leaves) == len(plans), (
         "update plan does not mirror the param tree")
 
-    g_mixed, p_mixed = [], []
-    for p, g, pl in zip(p_leaves, g_leaves, plans):
-        g32 = g.astype(jnp.float32)
-        if pl.axis is None:
-            gr = lax.psum(g32, reduce_axes) / total
-            g_mixed.append(gr)
-            p_mixed.append(p)
-            continue
-        gs = lax.psum_scatter(pad_leaf(g32, pl), DATA_AXES,
-                              scatter_dimension=pl.axis, tiled=True)
-        if extra_reduce_axes:
-            gs = lax.psum(gs, tuple(extra_reduce_axes))
-        g_mixed.append(gs / total)
-        pp = pad_leaf(p, pl)
-        start = [0] * p.ndim
-        start[pl.axis] = idx * pl.shard
-        sizes = list(pp.shape)
-        sizes[pl.axis] = pl.shard
-        p_mixed.append(lax.dynamic_slice(pp, tuple(start), tuple(sizes)))
+    # ``grad_exchange`` names the work (gradients in, this replica's
+    # updated parameters everywhere), whatever collectives implement it:
+    # the reduce-scatter here and the parameter all-gather below
+    with jax.named_scope("grad_exchange"):
+        total = lax.psum(c, reduce_axes)
+        loss = lax.psum(s, reduce_axes) / total
+        idx = lax.axis_index(DATA_AXES)
+        g_mixed, p_mixed = [], []
+        for p, g, pl in zip(p_leaves, g_leaves, plans):
+            g32 = g.astype(jnp.float32)
+            if pl.axis is None:
+                gr = lax.psum(g32, reduce_axes) / total
+                g_mixed.append(gr)
+                p_mixed.append(p)
+                continue
+            gs = lax.psum_scatter(pad_leaf(g32, pl), DATA_AXES,
+                                  scatter_dimension=pl.axis, tiled=True)
+            if extra_reduce_axes:
+                gs = lax.psum(gs, tuple(extra_reduce_axes))
+            g_mixed.append(gs / total)
+            pp = pad_leaf(p, pl)
+            start = [0] * p.ndim
+            start[pl.axis] = idx * pl.shard
+            sizes = list(pp.shape)
+            sizes[pl.axis] = pl.shard
+            p_mixed.append(lax.dynamic_slice(pp, tuple(start),
+                                             tuple(sizes)))
 
-    # one global grad norm (pre-clip, matching the replicated path where
-    # the guard measures before optim.with_clipping): replicated-leaf
-    # squares are already identical on every replica; scattered-leaf
-    # partial squares need one scalar psum (padding lanes are zero)
-    gnorm = None
-    if grad_clip > 0 or with_metrics or optimizer.update_with_norm is not None:
-        sq_rep = _grad_sq(g for g, pl in zip(g_mixed, plans)
-                          if pl.axis is None)
-        sq_sh = _grad_sq(g for g, pl in zip(g_mixed, plans)
-                         if pl.axis is not None)
-        gnorm = jnp.sqrt(sq_rep + lax.psum(sq_sh, DATA_AXES))
-    if grad_clip > 0:
-        scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-        g_mixed = [g * scale for g in g_mixed]
+        # one global grad norm (pre-clip, matching the replicated path
+        # where the guard measures before optim.with_clipping):
+        # replicated-leaf squares are already identical on every replica;
+        # scattered-leaf partial squares need one scalar psum (padding
+        # lanes are zero)
+        gnorm = None
+        if (grad_clip > 0 or with_metrics
+                or optimizer.update_with_norm is not None):
+            sq_rep = _grad_sq(g for g, pl in zip(g_mixed, plans)
+                              if pl.axis is None)
+            sq_sh = _grad_sq(g for g, pl in zip(g_mixed, plans)
+                             if pl.axis is not None)
+            gnorm = jnp.sqrt(sq_rep + lax.psum(sq_sh, DATA_AXES))
+        if grad_clip > 0:
+            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
+            g_mixed = [g * scale for g in g_mixed]
 
     g_tree = jax.tree_util.tree_unflatten(treedef, g_mixed)
     p_tree = jax.tree_util.tree_unflatten(treedef, p_mixed)
-    if optimizer.update_with_norm is not None:
-        new_p_mixed, new_opt = optimizer.update_with_norm(
-            g_tree, state.opt_state, p_tree, gnorm)
-    else:
-        new_p_mixed, new_opt = optimizer.update(g_tree, state.opt_state,
-                                                p_tree)
+    with jax.named_scope("optimizer_update"):
+        if optimizer.update_with_norm is not None:
+            new_p_mixed, new_opt = optimizer.update_with_norm(
+                g_tree, state.opt_state, p_tree, gnorm)
+        else:
+            new_p_mixed, new_opt = optimizer.update(g_tree, state.opt_state,
+                                                    p_tree)
 
     new_full = []
-    for np_, p, pl in zip(jax.tree_util.tree_leaves(new_p_mixed),
-                          p_leaves, plans):
-        if pl.axis is None:
-            new_full.append(np_)
-            continue
-        gathered = lax.all_gather(np_, DATA_AXES, axis=pl.axis, tiled=True)
-        if gathered.shape[pl.axis] != p.shape[pl.axis]:
-            gathered = lax.slice_in_dim(gathered, 0, p.shape[pl.axis],
-                                        axis=pl.axis)
-        new_full.append(gathered)
+    with jax.named_scope("grad_exchange"):
+        for np_, p, pl in zip(jax.tree_util.tree_leaves(new_p_mixed),
+                              p_leaves, plans):
+            if pl.axis is None:
+                new_full.append(np_)
+                continue
+            gathered = lax.all_gather(np_, DATA_AXES, axis=pl.axis,
+                                      tiled=True)
+            if gathered.shape[pl.axis] != p.shape[pl.axis]:
+                gathered = lax.slice_in_dim(gathered, 0, p.shape[pl.axis],
+                                            axis=pl.axis)
+            new_full.append(gathered)
     new_params = jax.tree_util.tree_unflatten(treedef, new_full)
     new_state = TrainState(state.step + 1, new_params, new_opt)
     if not with_metrics:

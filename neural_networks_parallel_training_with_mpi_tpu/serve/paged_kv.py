@@ -98,6 +98,7 @@ import numpy as np
 from ..models.generate import _quantize_kv, _sample
 from ..models.transformer import Transformer, split_qkv
 from ..ops.pallas_kernels import paged_attention
+from ..train import trace as trace_lib
 
 Pytree = Any
 
@@ -360,6 +361,54 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
                          f"got {attn_impl!r}")
 
+    def gathered_attention(q, kp, vp, tables, positions, ksp, vsp):
+        """Attention over each row's whole table width: ``pool[table]``
+        materialised (scope ``paged_gather``), then a full-width masked
+        scores-softmax-values reduction (scope ``attn_core``).  Same
+        values, same order, as the dense cache's (B, T, kv, hd) slab.
+        ``ksp``/``vsp`` are the int8 scale pools or None."""
+        b, w = positions.shape
+        with jax.named_scope("paged_gather"):
+            # (B, MB, bs, kv, hd) -> (B, T_cap, kv, hd), positions in
+            # ascending order
+            gk = kp[tables].reshape(b, t_cap, c.kv_heads, c.head_dim)
+            gv = vp[tables].reshape(b, t_cap, c.kv_heads, c.head_dim)
+            if ksp is not None:
+                gks = ksp[tables].reshape(b, t_cap, c.kv_heads)
+                gvs = vsp[tables].reshape(b, t_cap, c.kv_heads)
+        with jax.named_scope("attn_core"):
+            scale = 1.0 / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
+            mask = (jnp.arange(t_cap)[None, None, :]
+                    <= positions[:, :, None])           # (B, W, T_cap)
+            if c.kv_heads == c.n_heads:
+                logits = jnp.einsum("bqhd,bkhd->bhqk",
+                                    q.astype(jnp.float32),
+                                    gk.astype(jnp.float32)) * scale
+                if ksp is not None:
+                    logits = logits * gks.transpose(0, 2, 1)[:, :, None, :]
+                logits = jnp.where(mask[:, None], logits, -1e30)
+                probs = jax.nn.softmax(logits, axis=-1)
+                if ksp is not None:
+                    probs = probs * gvs.transpose(0, 2, 1)[:, :, None, :]
+                return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                                  gv.astype(jnp.float32))
+            g = c.n_heads // c.kv_heads
+            q5 = q.reshape(b, w, c.kv_heads, g, c.head_dim)
+            logits = jnp.einsum("bqcgd,bkcd->bcgqk",
+                                q5.astype(jnp.float32),
+                                gk.astype(jnp.float32)) * scale
+            if ksp is not None:
+                logits = logits * gks.transpose(0, 2, 1)[:, :, None,
+                                                         None, :]
+            logits = jnp.where(mask[:, None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1)
+            if ksp is not None:
+                probs = probs * gvs.transpose(0, 2, 1)[:, :, None,
+                                                       None, :]
+            out = jnp.einsum("bcgqk,bkcd->bqcgd", probs,
+                             gv.astype(jnp.float32))
+            return out.reshape(b, w, c.n_heads, c.head_dim)
+
     def block_fwd(layer_params, pool, tables, starts, x, valid, lengths):
         """One transformer block over a chunk ``x`` (B, W, D) whose rows
         sit at per-row start positions, K/V scattered into the paged
@@ -373,92 +422,69 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         attendable-key count (0 = inactive lane), traced like the
         tables so length churn never recompiles."""
         mods = model._block_modules()
-        h = mods["ln1"].apply(layer_params["ln1"], x)
-        qkv = mods["qkv"].apply(layer_params["qkv"], h)
-        b, w, _ = qkv.shape
-        q, k, v = split_qkv(c, qkv)   # q: (B,W,H,hd); k/v: (B,W,KV,hd)
-        positions = starts[:, None] + jnp.arange(w)[None, :]    # (B, W)
-        if c.pos_encoding == "rope":
-            from ..ops.rope import rope_rotate
-
-            q = rope_rotate(q, positions, c.rope_theta)
-            k = rope_rotate(k, positions, c.rope_theta)
-        # scatter coordinates: each position resolves its own block via
-        # the row's table (chunks straddle block boundaries freely); pad
-        # columns land in the sink
-        blk = jnp.take_along_axis(tables, positions // bs, axis=1)
-        blk = jnp.where(valid[None, :], blk, SINK_BLOCK)
-        off = jnp.where(valid[None, :], positions % bs, 0)
         quant = "k_scale" in pool
-        if quant:
-            k, ks = _quantize_kv(k)
-            v, vs = _quantize_kv(v)
-            new_ksp = pool["k_scale"].at[blk, off].set(ks)
-            new_vsp = pool["v_scale"].at[blk, off].set(vs)
-        new_kp = pool["k"].at[blk, off].set(k.astype(pool["k"].dtype))
-        new_vp = pool["v"].at[blk, off].set(v.astype(pool["v"].dtype))
-        if attn_impl == "fused":
-            # the Pallas kernel reads K/V straight from the pool through
-            # the tables and reduces over each row's TRUE length — no
-            # pool[table] materialization, no max_blocks*bs reduction.
-            # int8 scale pools ride in and dequantize on load.
-            out = paged_attention(
-                q, new_kp, new_vp, tables, lengths, starts,
-                k_scale=new_ksp if quant else None,
-                v_scale=new_vsp if quant else None).astype(x.dtype)
-        else:
-            # gather each row's attended window: (B, MB, bs, kv, hd) ->
-            # (B, T_cap, kv, hd), positions in ascending order — the
-            # same values, same order, as the dense cache's
-            # (B, T, kv, hd) slab
-            gk = new_kp[tables].reshape(b, t_cap, c.kv_heads, c.head_dim)
-            gv = new_vp[tables].reshape(b, t_cap, c.kv_heads, c.head_dim)
-            scale = 1.0 / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
-            mask = (jnp.arange(t_cap)[None, None, :]
-                    <= positions[:, :, None])           # (B, W, T_cap)
-            if quant:
-                gks = new_ksp[tables].reshape(b, t_cap, c.kv_heads)
-                gvs = new_vsp[tables].reshape(b, t_cap, c.kv_heads)
-            if c.kv_heads == c.n_heads:
-                logits = jnp.einsum("bqhd,bkhd->bhqk",
-                                    q.astype(jnp.float32),
-                                    gk.astype(jnp.float32)) * scale
+        # named scopes as in ``Transformer._block`` (the device trace is
+        # read by them): ``attention`` is the work, the inner scopes say
+        # what implements it here
+        with jax.named_scope("attn_proj"):
+            h = mods["ln1"].apply(layer_params["ln1"], x)
+            qkv = mods["qkv"].apply(layer_params["qkv"], h)
+            b, w, _ = qkv.shape
+            q, k, v = split_qkv(c, qkv)  # q: (B,W,H,hd); k/v: (B,W,KV,hd)
+        with jax.named_scope("attention"):
+            positions = starts[:, None] + jnp.arange(w)[None, :]  # (B, W)
+            if c.pos_encoding == "rope":
+                from ..ops.rope import rope_rotate
+
+                q = rope_rotate(q, positions, c.rope_theta)
+                k = rope_rotate(k, positions, c.rope_theta)
+            with jax.named_scope("paged_scatter"):
+                # scatter coordinates: each position resolves its own
+                # block via the row's table (chunks straddle block
+                # boundaries freely); pad columns land in the sink
+                blk = jnp.take_along_axis(tables, positions // bs, axis=1)
+                blk = jnp.where(valid[None, :], blk, SINK_BLOCK)
+                off = jnp.where(valid[None, :], positions % bs, 0)
                 if quant:
-                    logits = logits * gks.transpose(0, 2, 1)[:, :, None, :]
-                logits = jnp.where(mask[:, None], logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                if quant:
-                    probs = probs * gvs.transpose(0, 2, 1)[:, :, None, :]
-                out = jnp.einsum("bhqk,bkhd->bqhd", probs,
-                                 gv.astype(jnp.float32)).astype(x.dtype)
+                    k, ks = _quantize_kv(k)
+                    v, vs = _quantize_kv(v)
+                    new_ksp = pool["k_scale"].at[blk, off].set(ks)
+                    new_vsp = pool["v_scale"].at[blk, off].set(vs)
+                new_kp = pool["k"].at[blk, off].set(
+                    k.astype(pool["k"].dtype))
+                new_vp = pool["v"].at[blk, off].set(
+                    v.astype(pool["v"].dtype))
+            if attn_impl == "fused":
+                # the Pallas kernel reads K/V straight from the pool
+                # through the tables and reduces over each row's TRUE
+                # length — no pool[table] materialization, no
+                # max_blocks*bs reduction.  int8 scale pools ride in and
+                # dequantize on load.
+                with jax.named_scope("attn_core"), \
+                        jax.named_scope("paged_attention_fused"):
+                    out = paged_attention(
+                        q, new_kp, new_vp, tables, lengths, starts,
+                        k_scale=new_ksp if quant else None,
+                        v_scale=new_vsp if quant else None).astype(x.dtype)
             else:
-                g = c.n_heads // c.kv_heads
-                q5 = q.reshape(b, w, c.kv_heads, g, c.head_dim)
-                logits = jnp.einsum("bqcgd,bkcd->bcgqk",
-                                    q5.astype(jnp.float32),
-                                    gk.astype(jnp.float32)) * scale
-                if quant:
-                    logits = logits * gks.transpose(0, 2, 1)[:, :, None,
-                                                             None, :]
-                logits = jnp.where(mask[:, None, None], logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                if quant:
-                    probs = probs * gvs.transpose(0, 2, 1)[:, :, None,
-                                                           None, :]
-                out = jnp.einsum("bcgqk,bkcd->bqcgd", probs,
-                                 gv.astype(jnp.float32)).astype(x.dtype)
-                out = out.reshape(b, w, c.n_heads, c.head_dim)
-        out = out.reshape(b, w, c.d_model)
-        x = x + mods["attn_out"].apply(layer_params["attn_out"], out)
-        h = mods["ln2"].apply(layer_params["ln2"], x)
-        if c.moe_experts > 0:
-            ff, _ = mods["moe"].apply(layer_params["moe"], h)
-        else:
-            ff = model._ffn(mods, layer_params, h)
+                out = gathered_attention(
+                    q, new_kp, new_vp, tables, positions,
+                    new_ksp if quant else None,
+                    new_vsp if quant else None).astype(x.dtype)
+        with jax.named_scope("attn_proj"):
+            out = out.reshape(b, w, c.d_model)
+            x = x + mods["attn_out"].apply(layer_params["attn_out"], out)
+        with jax.named_scope("ffn"):
+            h = mods["ln2"].apply(layer_params["ln2"], x)
+            if c.moe_experts > 0:
+                ff, _ = mods["moe"].apply(layer_params["moe"], h)
+            else:
+                ff = model._ffn(mods, layer_params, h)
+            x = x + ff.astype(x.dtype)
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
             new_pool.update(k_scale=new_ksp, v_scale=new_vsp)
-        return x + ff.astype(x.dtype), new_pool
+        return x, new_pool
 
     def forward(params, pools, tables, starts, ids, valid, lengths):
         # clamp pad columns' embedding positions into range (their
@@ -495,7 +521,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         lengths = jnp.where(active, pos + 1, 0)
         logits, new_pools = forward(params, pools, tables, pos, ids,
                                     jnp.ones((1,), bool), lengths)
-        nxt, key = _sample(logits[:, 0], temperature, key, top_k, top_p)
+        with jax.named_scope("sample"):
+            nxt, key = _sample(logits[:, 0], temperature, key, top_k,
+                               top_p)
         # frozen slots re-write the token already there (idempotent) and
         # hold position — the dense server's exact bookkeeping
         nxt = jnp.where(active, nxt, jnp.take_along_axis(
@@ -898,59 +926,67 @@ class PagedDecodeServer:
         st = self._streams[rid]
         slot = self._slot_of[rid]
         p = len(st.prompt)
-        # late match: a stream that found nothing at ADMISSION retries
-        # the index once at its first prefill chunk — under burst
-        # arrivals several shared-prompt requests admit in one tick
-        # before any of them has registered a block, but streams prefill
-        # FIFO, so by the time this one runs its predecessors' blocks
-        # are indexed (the admission-time match alone would miss the
-        # whole burst)
-        if (self.prefix_cache and st.prefilled == 0
-                and st.n_shared == 0):
-            self._rematch_prefix(st, slot)
-        remaining = p - st.prefilled
-        if remaining <= 0:
-            return True
-        w = min(int(width), remaining)
-        if w < 1:
-            raise ValueError(f"prefill width {width} < 1")
-        # copy-on-write: the FIRST write past the shared boundary lands
-        # here when the matched prefix ended mid-block — fork the
-        # borrowed partial block (reserved target, one on-device copy,
-        # repoint, release the share) BEFORE the chunk writes into it
-        if (st.fork_pending is not None
-                and st.prefilled // self.block_size < st.n_shared):
-            self._cow_fork(st, slot)
-        # sink-invariant extension: every block this chunk writes must
-        # be OWNED by the stream — a shared block is read-only
-        assert st.prefilled // self.block_size >= st.n_shared, (
-            f"prefill would write shared block of rid={rid}: "
-            f"pos {st.prefilled} inside the first {st.n_shared} "
-            "borrowed table entries")
-        bucket = prefill_bucket(w)
-        chunk = st.prompt[st.prefilled:st.prefilled + w] + [0] * (bucket - w)
-        # the device gets a HOST-side copy: on the CPU backend asarray may
-        # alias the numpy buffer (and jnp.array's own copy is an async
-        # device op), while the host mutates self.tables / self.active in
-        # place before the dispatched program has run
-        logits, self.pools = self._prefill_fn(
-            self.params, self.pools,
-            jnp.asarray(self.tables[slot:slot + 1].copy()),
-            jnp.asarray([st.prefilled], jnp.int32),
-            jnp.asarray([chunk], jnp.int32),
-            jnp.asarray(w, jnp.int32))
+        # the three child spans split the scheduler's ``prefill`` span
+        # where the device can fall idle (train/trace.py vocabulary)
+        with trace_lib.span("prefill/prepare"):
+            # late match: a stream that found nothing at ADMISSION retries
+            # the index once at its first prefill chunk — under burst
+            # arrivals several shared-prompt requests admit in one tick
+            # before any of them has registered a block, but streams
+            # prefill FIFO, so by the time this one runs its
+            # predecessors' blocks are indexed (the admission-time match
+            # alone would miss the whole burst)
+            if (self.prefix_cache and st.prefilled == 0
+                    and st.n_shared == 0):
+                self._rematch_prefix(st, slot)
+            remaining = p - st.prefilled
+            if remaining <= 0:
+                return True
+            w = min(int(width), remaining)
+            if w < 1:
+                raise ValueError(f"prefill width {width} < 1")
+            # copy-on-write: the FIRST write past the shared boundary
+            # lands here when the matched prefix ended mid-block — fork
+            # the borrowed partial block (reserved target, one on-device
+            # copy, repoint, release the share) BEFORE the chunk writes
+            # into it
+            if (st.fork_pending is not None
+                    and st.prefilled // self.block_size < st.n_shared):
+                self._cow_fork(st, slot)
+            # sink-invariant extension: every block this chunk writes
+            # must be OWNED by the stream — a shared block is read-only
+            assert st.prefilled // self.block_size >= st.n_shared, (
+                f"prefill would write shared block of rid={rid}: "
+                f"pos {st.prefilled} inside the first {st.n_shared} "
+                "borrowed table entries")
+            bucket = prefill_bucket(w)
+            chunk = (st.prompt[st.prefilled:st.prefilled + w]
+                     + [0] * (bucket - w))
+            # the device gets a HOST-side copy: on the CPU backend asarray
+            # may alias the numpy buffer (and jnp.array's own copy is an
+            # async device op), while the host mutates self.tables /
+            # self.active in place before the dispatched program has run
+            args = (jnp.asarray(self.tables[slot:slot + 1].copy()),
+                    jnp.asarray([st.prefilled], jnp.int32),
+                    jnp.asarray([chunk], jnp.int32),
+                    jnp.asarray(w, jnp.int32))
+        with trace_lib.span("prefill/submit"):
+            logits, self.pools = self._prefill_fn(self.params, self.pools,
+                                                  *args)
         st.prefilled += w
         self._register_prefix(st, final=st.prefilled >= p)
         if st.prefilled < p:
             return False
-        t, tk, tp = self._sampling
-        first_row, self.key = _sample(logits[:, w - 1], t, self.key, tk, tp)
-        self.tokens = self.tokens.at[slot, p].set(first_row[0])
-        self.pos = self.pos.at[slot].set(p)
-        self._pos_host[slot] = p
-        self.active[slot] = st.max_new > 1
-        if st.max_new <= 1:
-            self._finish(rid)
+        with trace_lib.span("prefill/first_token"):
+            t, tk, tp = self._sampling
+            first_row, self.key = _sample(logits[:, w - 1], t, self.key,
+                                          tk, tp)
+            self.tokens = self.tokens.at[slot, p].set(first_row[0])
+            self.pos = self.pos.at[slot].set(p)
+            self._pos_host[slot] = p
+            self.active[slot] = st.max_new > 1
+            if st.max_new <= 1:
+                self._finish(rid)
         return True
 
     def _cow_fork(self, st: _Stream, slot: int) -> None:
@@ -1251,39 +1287,47 @@ class PagedDecodeServer:
         (call :meth:`ensure_blocks` / evict first)."""
         if not self.active.any():
             return []
-        short = self.ensure_blocks()
-        if short:
-            raise BlockExhausted(short)
-        # sink-invariant extension for sharing: an active lane's decode
-        # write position must sit in a block the stream OWNS (decode
-        # positions start past the prompt, and the CoW fork ran during
-        # the suffix prefill — so this can only fire on a bookkeeping
-        # bug, which must not silently corrupt a shared block)
-        for rid, slot in self._slot_of.items():
-            if self.active[slot]:
-                st = self._streams[rid]
-                assert (int(self._pos_host[slot]) // self.block_size
-                        >= st.n_shared), (
-                    f"decode would write shared block of rid={rid}")
-        # non-active lanes (free, finished, MID-PREFILL) see an all-sink
-        # table: their writes land in the sink and their reads gather
-        # garbage that is discarded — so live blocks are written ONLY by
-        # prefill chunks and active decode lanes, and parity never rests
-        # on a frozen lane recomputing bitwise-identical K/V under a
-        # different batch shape
-        masked = np.where(self.active[:, None], self.tables, SINK_BLOCK)
-        self.pools, self.tokens, self.pos, self.key = self._step_fn(
-            self.params, self.pools, self.tokens,
-            jnp.asarray(masked), self.pos,
-            jnp.asarray(self.active.copy()), self.key)   # see prefill_step
+        # the three child spans split the scheduler's ``decode`` span
+        # where the device can fall idle (train/trace.py vocabulary)
+        with trace_lib.span("decode/prepare"):
+            short = self.ensure_blocks()
+            if short:
+                raise BlockExhausted(short)
+            # sink-invariant extension for sharing: an active lane's
+            # decode write position must sit in a block the stream OWNS
+            # (decode positions start past the prompt, and the CoW fork
+            # ran during the suffix prefill — so this can only fire on a
+            # bookkeeping bug, which must not silently corrupt a shared
+            # block)
+            for rid, slot in self._slot_of.items():
+                if self.active[slot]:
+                    st = self._streams[rid]
+                    assert (int(self._pos_host[slot]) // self.block_size
+                            >= st.n_shared), (
+                        f"decode would write shared block of rid={rid}")
+            # non-active lanes (free, finished, MID-PREFILL) see an
+            # all-sink table: their writes land in the sink and their
+            # reads gather garbage that is discarded — so live blocks are
+            # written ONLY by prefill chunks and active decode lanes, and
+            # parity never rests on a frozen lane recomputing
+            # bitwise-identical K/V under a different batch shape
+            masked = np.where(self.active[:, None], self.tables,
+                              SINK_BLOCK)
+            tables = jnp.asarray(masked)
+            active = jnp.asarray(self.active.copy())   # see prefill_step
+        with trace_lib.span("decode/submit"):
+            self.pools, self.tokens, self.pos, self.key = self._step_fn(
+                self.params, self.pools, self.tokens, tables, self.pos,
+                active, self.key)
         finished = []
-        for rid, slot in list(self._slot_of.items()):
-            if not self.active[slot]:
-                continue
-            self._pos_host[slot] += 1
-            if self._pos_host[slot] + 1 >= self._streams[rid].target:
-                self._finish(rid)
-                finished.append(rid)
+        with trace_lib.span("decode/finish"):
+            for rid, slot in list(self._slot_of.items()):
+                if not self.active[slot]:
+                    continue
+                self._pos_host[slot] += 1
+                if self._pos_host[slot] + 1 >= self._streams[rid].target:
+                    self._finish(rid)
+                    finished.append(rid)
         return finished
 
     def _finish(self, rid: int) -> None:

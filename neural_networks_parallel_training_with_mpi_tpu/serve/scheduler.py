@@ -46,8 +46,8 @@ top of the library loop the repo had before this subsystem:
   burn-rate alerts land as ``kind="alert"`` records (observe-and-
   annotate); with a tracer installed, each request id threads an
   admit -> prefill -> decode -> retire Perfetto FLOW across the tick
-  spans (``train/trace.py``) — the primitive a cross-replica block
-  handoff will ride.
+  spans (``train/trace.py``), one point per phase change — the
+  primitive a cross-replica block handoff will ride.
 """
 
 from __future__ import annotations
@@ -528,6 +528,13 @@ class Scheduler:
         # category set prices scheduler dead time instead of dropping it.
         self._gap_wall: Optional[float] = None
         self._gap_state: Optional[str] = None
+        # the gap's mirror in a jax.profiler capture (train/trace.py):
+        # entered at a tick's end, left at the next tick's start
+        self._gap_mirror = None
+        # streams whose prefill just ended, kept while a tracer is
+        # installed: their flow gets its decode point in the next decode
+        # span (a point per phase change, not per stream per tick)
+        self._flow_to_decode: List[int] = []
 
     # ---- client surface ------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int,
@@ -597,6 +604,7 @@ class Scheduler:
         rids completed during this tick."""
         self.tick_no += 1
         done_now: List[int] = []
+        self._leave_gap()
         tracer = trace_lib.active()
         if tracer is not None and self._gap_state is not None:
             gap = time.time() - self._gap_wall
@@ -610,16 +618,15 @@ class Scheduler:
         if self.server.any_active():
             with trace_lib.span("decode", tick=self.tick_no):
                 self._grow_or_evict()
-                if trace_lib.active() is not None:
-                    # flow step per decoding stream: the arrow chain
-                    # that links this tick's decode span into each
-                    # in-flight request's admit->...->retire path
-                    for rid in self._srv_rid:
-                        if rid not in self._prefilling:
-                            trace_lib.flow(
-                                "req", f"{self._flow_prefix}{rid}", "t",
-                                rid=rid, stage="decode",
-                                tick=self.tick_no)
+                # flow step at a stream's FIRST decode tick: the arrow
+                # that links its admit->prefill path into the decode
+                # spans (retire closes the chain)
+                for rid in self._flow_to_decode:
+                    if rid in self._srv_rid:
+                        trace_lib.flow(
+                            "req", f"{self._flow_prefix}{rid}", "t",
+                            rid=rid, stage="decode", tick=self.tick_no)
+                self._flow_to_decode.clear()
                 acct = self.server.keys_accounting()
                 self.attended_keys += acct["attended_keys"]
                 self.padded_keys += acct["padded_keys"]
@@ -632,7 +639,15 @@ class Scheduler:
         self._gap_wall = time.time()
         self._gap_state = ("sched_bubble" if self._srv_rid
                            else ("queue_wait" if self.queue else None))
+        if self._gap_state is not None:
+            self._gap_mirror = trace_lib.annotation(self._gap_state)
+            self._gap_mirror.__enter__()
         return done_now
+
+    def _leave_gap(self) -> None:
+        if self._gap_mirror is not None:
+            self._gap_mirror.__exit__(None, None, None)
+            self._gap_mirror = None
 
     def run_until_drained(self, max_ticks: int = 100_000) -> List[int]:
         """Tick until queue + in-flight are empty; returns completion
@@ -648,6 +663,7 @@ class Scheduler:
             f"{len(self.queue)} in_flight={len(self._srv_rid)}")
 
     def close(self) -> None:
+        self._leave_gap()
         self.telemetry.close(self.tick_no, self._snapshot())
         if self._tracer is not None:
             trace_lib.stop_run(self._tracer)
@@ -712,6 +728,8 @@ class Scheduler:
         self.injected += 1
         trace_lib.flow("req", f"{self._flow_prefix}{rid}", "t",
                        rid=rid, stage="inject", tick=self.tick_no)
+        if trace_lib.active() is not None:
+            self._flow_to_decode.append(rid)
         if self.server.done(srv_rid):
             # degenerate single-token handoff: already complete
             self._retire(srv_rid)
@@ -869,6 +887,8 @@ class Scheduler:
             self._prefilling.popleft()
             req = self.reqs[rid]
             req.t_first = self.now()
+            if trace_lib.active() is not None:
+                self._flow_to_decode.append(rid)
             if self.server.done(srv_rid):   # single-token request
                 done_now.append(self._retire(srv_rid))
             elif self.cfg.role == "prefill" and not req.unified:

@@ -36,6 +36,14 @@ Span vocabulary (the fixed vocabulary the report tool groups by):
 ``rollback``    anomaly/SDC rollback: restore + re-place
 ``admit`` / ``prefill`` / ``decode`` / ``retire``
                 the serving scheduler's tick phases (serve/scheduler.py)
+``prefill/prepare`` / ``prefill/submit`` / ``prefill/first_token``
+                inside ``prefill`` (serve/paged_kv.py ``prefill_step``):
+                host bookkeeping and uploads, the program call, the
+                eager sampling of the first token on the last chunk
+``decode/prepare`` / ``decode/submit`` / ``decode/finish``
+                inside ``decode`` (``PagedServer.step``): block supply
+                checks and uploads, the program call, the position loop
+                and the blocking token reads of finished streams
 ``queue_wait``  serving inter-tick gap with requests queued but no slot
 ``sched_bubble``
                 serving inter-tick gap with decoding streams in flight
@@ -43,11 +51,21 @@ Span vocabulary (the fixed vocabulary the report tool groups by):
 ``compile:<n>`` a ledger-observed XLA compile (utils/compile_ledger.py)
 ==============  ========================================================
 
+**The profiler mirror.**  Every span also enters a
+``jax.profiler.TraceAnnotation("nnpt:<name>")``, whether or not a
+:class:`Tracer` is installed, so a ``jax.profiler`` capture holds the
+program's own phases on the same clock as the device's operations and
+each idle gap of the device can be put down to the span it fell in
+(``benchmark/reducers/host_phases.py``).  The train step's ``dispatch``
+sits inside a ``StepTraceAnnotation("nnpt:train_step", step_num=k)``
+(:func:`step_annotation`).  The JSONL record stays conditional on the
+tracer.  This module is the only caller of ``TraceAnnotation``.
+
 Besides spans, a tracer can emit **flow points** (:func:`flow`): the
 Chrome s/t/f arrow chain that links spans by an id.  The serving
 scheduler threads each request id through admit -> every prefill chunk
--> every decode tick -> retire, so ``tools/trace_report.py``'s merged
-Perfetto timeline draws one request's whole life as a connected arrow
+-> its first decode tick -> retire (a point per phase change), so
+``tools/trace_report.py``'s merged Perfetto timeline draws one request's whole life as a connected arrow
 path across the per-tick phase spans (and, once blocks hand off across
 replicas, across processes).
 
@@ -55,25 +73,36 @@ Relationship to the XLA profiler (``--xla_trace_dir`` →
 ``utils.profiling.trace``): the profiler captures *device* activity —
 per-op HLO timelines, one heavyweight capture window, leader-gated,
 viewed in TensorBoard/XProf.  This module captures *host* phases —
-always-on-able, cross-process, crash-surviving.  Run both on a real
-chip: host spans say which phase starved the device; the XLA trace says
-what the device did inside it.
+always-on-able, cross-process, crash-surviving — and mirrors them into
+that capture.  Run both on a real chip: host spans say which phase
+starved the device; the XLA trace says what the device did inside it.
 
-Everything is zero-cost when no tracer is installed: ``span()`` returns
-a shared null context manager and touches one module global.
+Cost with no tracer installed: one small object and one annotation per
+span (the annotation is a no-op in the profiler's C++ while no capture
+runs); nothing is written.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, Optional
 
+try:    # the mirror needs jax; the span files do not, and a stdlib-only
+    # process may load this module alone (tests/test_goodput.py's child)
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+except ImportError:
+    StepTraceAnnotation = TraceAnnotation = None
+
 RUN_ID_ENV = "NNPT_RUN_ID"
 INCARNATION_ENV = "NNPT_INCARNATION"
 PROCESS_ID_ENV = "NNPT_PROCESS_ID"  # the DESIGN §10 world env channel
+
+# prefix of every span's mirror in a jax.profiler capture
+ANNOTATION_PREFIX = "nnpt:"
 
 # bounded trace discipline: after this many records the file stops
 # growing and the footer reports how many spans were dropped — a
@@ -182,8 +211,8 @@ class Tracer:
         across ticks/threads/processes by ``flow_id``.  ``phase``:
         ``"s"`` start, ``"t"`` step, ``"f"`` finish (the Chrome
         trace-event flow vocabulary).  The serving scheduler threads a
-        request id through admit -> each prefill chunk -> decode ticks
-        -> retire this way, so one request's life is one connected
+        request id through admit -> each prefill chunk -> first decode
+        tick -> retire this way, so one request's life is one connected
         arrow path across the per-tick phase spans."""
         if phase not in ("s", "t", "f"):
             raise ValueError(f"flow phase must be s/t/f, got {phase!r}")
@@ -233,48 +262,53 @@ def remove_listener(fn) -> None:
         pass
 
 
-class _NullSpan:
-    """Shared no-op context manager: the disabled-path cost of a span is
-    one global read and one attribute call."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullSpan()
-
-
 class _Span:
-    __slots__ = ("name", "attrs", "_t_unix", "_t0")
+    __slots__ = ("name", "attrs", "_t_unix", "_t0", "_mirror")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
+        self._mirror = annotation(self.name)
+        self._mirror.__enter__()
         self._t_unix = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        dur = time.perf_counter() - self._t0
+        self._mirror.__exit__(*exc)
         tracer = _ACTIVE
         if tracer is not None:
-            tracer.record_span(self.name, self._t_unix,
-                               time.perf_counter() - self._t0, self.attrs)
+            tracer.record_span(self.name, self._t_unix, dur, self.attrs)
         return False
 
 
 def span(name: str, **attrs):
-    """``with trace.span("dispatch", step=k): ...`` — no-op (shared null
-    object, no allocation) when no tracer is installed."""
-    if _ACTIVE is None:
-        return _NULL
+    """``with trace.span("dispatch", step=k): ...`` — mirrored into a
+    running ``jax.profiler`` capture as ``nnpt:<name>``; recorded to the
+    JSONL timeline only while a tracer is installed."""
     return _Span(name, attrs)
+
+
+def annotation(name: str):
+    """The bare profiler mirror of a span, for a phase whose JSONL record
+    is written after the fact (the scheduler's between-tick gaps): the
+    caller enters and exits it."""
+    if TraceAnnotation is None:
+        return contextlib.nullcontext()
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+def step_annotation(step: int):
+    """``nnpt:train_step`` around one train step's host work, as a
+    ``StepTraceAnnotation`` so profile viewers group the device's
+    operations by step."""
+    if StepTraceAnnotation is None:
+        return contextlib.nullcontext()
+    return StepTraceAnnotation(ANNOTATION_PREFIX + "train_step",
+                               step_num=step)
 
 
 def instant(name: str, **attrs) -> None:
@@ -303,11 +337,8 @@ def install(tracer: Optional[Tracer]) -> None:
 
 def traced_iter(name: str, it):
     """Wrap an iterator so each ``next()`` is a span (the trainer's
-    ``load`` phase).  Returns the iterator UNCHANGED when tracing is off
-    at wrap time; the wrapper closes the inner iterator deterministically
-    (the loader's prefetch-worker release contract)."""
-    if _ACTIVE is None:
-        return it
+    ``load`` phase).  The wrapper closes the inner iterator
+    deterministically (the loader's prefetch-worker release contract)."""
 
     def gen():
         inner = iter(it)

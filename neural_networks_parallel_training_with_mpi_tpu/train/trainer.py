@@ -1267,12 +1267,13 @@ class Trainer:
         thr = Throughput()
         timer = profiling.StepTimer()
         last_loss = float("nan")
-        # host-side step counter: keeps the hot loop free of device->host
-        # syncs so XLA's async dispatch pipelines steps (the whole point of
-        # replacing the reference's blocking gather, :185).  Loss logging
-        # lags one step for the same reason: by the time step k+1 has been
-        # dispatched, step k's loss future has materialized, so device_get
-        # on it does not stall the pipeline.
+        # host-side step counter: the loop never reads the step from the
+        # device.  Loss logging lags one step, but as the loop is written
+        # the fetch of step k-1's loss comes BEFORE the dispatch of step
+        # k: where it is due (every step at log_every=1) the device_get
+        # drains the device, which then sits idle for the whole of the
+        # `dispatch` span that follows.  Dispatching first would hide
+        # that span behind step k-1 (ROADMAP S5).
         step = start_step
         prev: Optional[tuple] = None  # (step, epoch, loss_future)
         last_eval: Optional[tuple] = None  # (step, metrics dict)
@@ -1384,8 +1385,7 @@ class Trainer:
                             (b, 1, self.loader.batch_rows(epoch_start_step + i))
                             for i, b in enumerate(self.loader.epoch(
                                 epoch, start_step=epoch_start_step)))
-                    # each next() is a "load" span (host batch assembly);
-                    # pass-through when tracing is off
+                    # each next() is a "load" span (host batch assembly)
                     dispatches = trace_lib.traced_iter("load", dispatches)
                     for batch, n_steps, rows in dispatches:
                         if shutdown.requested:
@@ -1443,7 +1443,8 @@ class Trainer:
                             self._sdc_batch = batch
                         # "dispatch" measures the HOST-side submission
                         # cost (async — the device runs behind it)
-                        with trace_lib.span("dispatch", step=step):
+                        with trace_lib.step_annotation(step), \
+                                trace_lib.span("dispatch", step=step):
                             if self.k_dispatch > 1:
                                 self.state, outs = self.multi_step(
                                     self.state, batch)

@@ -28,8 +28,10 @@ ledger observes every compile exactly once and the program runs through
 the SAME XLA executable the jit path would build — params are
 bitwise-identical ledger-on vs ledger-off (tests/test_trace.py pins it,
 and ``bench.py --trace-overhead`` measures the host-side cost the
-DESIGN §7 way).  When no ledger is installed the wrapper is a
-pass-through to the original jitted callable: zero behavior change.
+DESIGN §7 way).  A call whose signature is already compiled does no
+per-leaf Python work (:class:`InstrumentedFn`).  When no ledger is
+installed the wrapper is a pass-through to the original jitted
+callable: zero behavior change.
 
 Degradation ladder (never break the run for observability):
 * callables without ``.lower`` (plain-python wrappers around inner jits)
@@ -181,7 +183,17 @@ class InstrumentedFn:
     """Wraps a jitted callable.  Ledger installed → every new signature
     compiles through the AOT path exactly once (recorded + cached + the
     compile shows on the trace timeline); ledger absent → pure
-    pass-through."""
+    pass-through.
+
+    A call whose signature is already compiled does no per-leaf Python
+    work.  The wrapper hands the arguments to the executable it expects
+    them to fit, and that executable's own argument check
+    (``jax.stages.Compiled``: pytree, avals, shardings — made before
+    anything runs or any donated buffer is consumed) is what rejects a
+    signature not yet seen; only then is the full per-leaf key built.
+    Which executable to expect: the only one, or, where several
+    signatures are compiled (prefill buckets), the one picked by the
+    ``_leaf_key`` of the few leaves in which those signatures differ."""
 
     def __init__(self, fn, name: str):
         self._fn = fn
@@ -189,6 +201,9 @@ class InstrumentedFn:
         self._cache: Dict[Tuple, Any] = {}   # sig key -> compiled | None
         self._last_sig: Optional[Dict[str, str]] = None
         self._lock = threading.Lock()
+        # (indices of the leaves whose keys tell the compiled signatures
+        # apart, {those leaves' keys: (sig key, compiled)})
+        self._expect: Tuple[Tuple[int, ...], Dict[Tuple, Tuple]] = ((), {})
 
     # builders/tests that lower the step themselves see through the seam
     def lower(self, *args, **kwargs):
@@ -213,6 +228,24 @@ class InstrumentedFn:
             return self._fn(*args, **kwargs)
         import jax
 
+        probe, table = self._expect
+        if table:
+            if probe:
+                leaves = jax.tree_util.tree_leaves(args)
+                try:
+                    hit = table.get(tuple(_leaf_key(leaves[i])
+                                          for i in probe))
+                except IndexError:      # another tree: not seen yet
+                    hit = None
+            else:
+                hit = next(iter(table.values()))
+            if hit is not None:
+                try:
+                    return self._run(hit[0], hit[1], args)
+                except (TypeError, ValueError):
+                    # the executable's own argument check, made before
+                    # it ran: these arguments are another signature
+                    pass
         leaves, treedef = jax.tree_util.tree_flatten(args)
         # an outer jit/scan tracing through this wrapper must see the
         # raw function — AOT-compiling a tracer signature is meaningless
@@ -225,23 +258,43 @@ class InstrumentedFn:
         if not hit:
             compiled = self._compile_and_record(ledger, key, args)
         if compiled is not None:
-            try:
-                return compiled(*args)
-            except Exception as e:
-                # do NOT retry through the jit path: the failed dispatch
-                # may already have consumed donated buffers (a retry
-                # would die on "Array has been deleted"), and the
-                # ORIGINAL error must propagate — a gloo/XLA peer-loss
-                # error rewrapped by a retry would dodge the CLI's
-                # is_peer_error -> exit 43 classification.  Later calls
-                # for this signature use the jit path instead.
-                with self._lock:
-                    self._cache[key] = None
-                log(f"[compile_ledger] {self.name}: AOT executable "
-                    f"failed ({type(e).__name__}); later calls for this "
-                    "signature ride the jit path")
-                raise
+            return self._run(key, compiled, args)
         return self._fn(*args)
+
+    def _run(self, key, compiled, args):
+        try:
+            return compiled(*args)
+        except (TypeError, ValueError):
+            raise               # rejected before running: see __call__
+        except Exception as e:
+            # do NOT retry through the jit path: the failed dispatch
+            # may already have consumed donated buffers (a retry
+            # would die on "Array has been deleted"), and the
+            # ORIGINAL error must propagate — a gloo/XLA peer-loss
+            # error rewrapped by a retry would dodge the CLI's
+            # is_peer_error -> exit 43 classification.  Later calls
+            # for this signature use the jit path instead.
+            with self._lock:
+                self._cache[key] = None
+                self._index()
+            log(f"[compile_ledger] {self.name}: AOT executable "
+                f"failed ({type(e).__name__}); later calls for this "
+                "signature ride the jit path")
+            raise
+
+    def _index(self) -> None:
+        """Rebuild ``_expect`` from the cache (under the lock, on a
+        compile or a failure — never on a steady-state call)."""
+        live = [(k, c) for k, c in self._cache.items() if c is not None]
+        width = {len(k[1]) for k, _ in live}
+        if len(width) != 1:     # nothing compiled, or trees of several
+            self._expect = ((), {})     # sizes: every call builds its key
+            return
+        keys = [k[1] for k, _ in live]
+        probe = tuple(i for i in range(width.pop())
+                      if any(k[i] != keys[0][i] for k in keys))
+        self._expect = (probe, {tuple(k[1][i] for i in probe): (k, c)
+                                for k, c in live})
 
     def _compile_and_record(self, ledger: Ledger, key, args):
         from ..train import trace as trace_lib
@@ -281,6 +334,7 @@ class InstrumentedFn:
         with self._lock:
             self._cache[key] = compiled
             self._last_sig = sig
+            self._index()
         ledger.record(rec)
         return compiled
 

@@ -103,12 +103,25 @@ def compile_cache() -> str:
     ``<checkout>/.jax_cache`` — the path is part of the cache key, so it
     never comes from a temp dir, a pid or the time.  Returns the directory
     in use.
+
+    The ``jax.named_scope`` path of every operation is made part of a
+    program's cache key.  By default JAX leaves all metadata out of the key,
+    so a program whose scopes changed would load the executable cached
+    before the change, and a device trace of it would carry the old names
+    (seen on the chip: a cache written before the scopes existed gave
+    ``jit(shard_step)/jvp()/dot_general``).  The device trace is read by
+    those names (``benchmark/reducers/scopes.py``), so they belong to the
+    program's identity.  Source files and lines are kept out of the
+    locations instead: with them in the key, a moved checkout or a shifted
+    line would compile everything again.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env_dir = os.environ.get(COMPILE_CACHE_ENV)
     if env_dir:
         return env_dir
-    import jax
-
     cache_dir = str(REPO_ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     return cache_dir

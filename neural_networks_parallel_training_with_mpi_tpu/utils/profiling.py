@@ -1,12 +1,11 @@
 """Tracing / profiling (extension — SURVEY.md §5.1: the reference has no
 timers or profiler hooks, only ``print``).
 
-Three tools, all zero-cost when disabled:
+Two tools, both zero-cost when disabled (named regions inside the
+capture are ``train/trace.py`` spans, mirrored as ``nnpt:<name>``):
 
 * :func:`trace` — leader-only ``jax.profiler`` trace context writing a
   TensorBoard/XProf-compatible trace of device + host activity.
-* :func:`annotate` — named region annotation that shows up inside the
-  trace timeline (wraps ``jax.profiler.TraceAnnotation``).
 * :class:`StepTimer` — host-side per-step wall-clock stats (p50/p95/max,
   steps/sec) measured the async-dispatch-friendly way: the timer never
   forces a device sync itself; call ``tick()`` once per dispatched step
@@ -33,11 +32,6 @@ def trace(log_dir: Optional[str], leader_only: bool = True):
         return
     with jax.profiler.trace(log_dir):
         yield
-
-
-def annotate(name: str):
-    """Named region for the trace timeline: ``with annotate("step"): ...``"""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def device_memory_stats() -> Dict[str, Dict[str, int]]:

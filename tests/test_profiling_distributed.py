@@ -26,9 +26,19 @@ def test_trace_noop_without_dir():
 
 
 def test_annotate_context():
-    with profiling.annotate("unit-test-region"):
+    """A span is a usable region with no tracer installed and no profiler
+    running: its ``nnpt:`` mirror (train/trace.py) is entered and left,
+    and no JSONL record is attempted."""
+    from neural_networks_parallel_training_with_mpi_tpu.train import (
+        trace as trace_lib,
+    )
+
+    assert trace_lib.active() is None
+    with trace_lib.span("unit-test-region") as sp:
         x = np.ones(4).sum()
-    assert x == 4
+    assert x == 4 and sp.name == "unit-test-region"
+    with trace_lib.annotation("unit-test-gap"):
+        pass
 
 
 def test_single_host_degradation():

@@ -1,0 +1,90 @@
+#!/usr/bin/env python3
+"""Where a traced benchmark run's device time went, by the program's names.
+
+    JAX_PLATFORMS=cpu python tools/scope_report.py [benchmark/out]
+    python tools/scope_report.py --workload <cell> --seed <n> --seconds <s>
+
+The first form reads the newest ``*.xplane.pb`` under the run directory of
+``benchmark/run.py --trace 1`` and prints one JSON object: busy time by leaf
+``jax.named_scope`` and what no scope holds (``benchmark/reducers/scopes.py``
+``coverage``), and idle time by the deepest ``nnpt:`` span over it
+(``host_phases.py`` ``by_span``).  PERF.md section 5 is written from it.
+
+The second form (on the chip) makes that traced run itself, through
+``benchmark/run.py``'s own ``main``, with the scope and idle metrics of
+``benchmark/metrics/`` added to the cell's ``per_layer`` list in memory: a
+cell's file lists its metrics, and the accepted cells' files are a
+``benchmark`` PR's to edit (PERF.md section 7).  It prints the run's result
+line, then the report.
+"""
+
+import json
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+NAMED = ("scopes", "host_phases")       # the reducers that read names
+
+
+class _Trace:
+    def __init__(self, path):
+        self.path = path
+
+    def trace_file(self):
+        return self.path
+
+
+def named_metrics(cell: dict, bench: Path) -> list:
+    """The metrics read from names whose suffix is the cell's kind of job
+    (``.train`` / ``.serve``) and which the cell does not list yet."""
+    suffix = "." + cell["job"]["kind"].split("_")[0]
+    specs = (json.loads(p.read_text())
+             for p in sorted((bench / "metrics").glob(f"*{suffix}.json")))
+    return [m["name"] for m in specs
+            if m["reducer"].split(":")[0] in NAMED
+            and m["name"] not in cell["per_layer"]]
+
+
+def run_cell(argv) -> int:
+    from benchmark import run as runner
+    from benchmark.harness import common
+
+    load_cell = common.load_cell
+
+    def with_named(name, bench=common.BENCH):
+        cell = load_cell(name, bench)
+        return {**cell,
+                "per_layer": cell["per_layer"] + named_metrics(cell, bench)}
+
+    common.load_cell = with_named
+    try:
+        return runner.main([*argv, "--trace", "1"])
+    finally:
+        common.load_cell = load_cell
+
+
+def main(argv) -> int:
+    from benchmark.reducers import host_phases, scopes
+
+    out_dir = ROOT / "benchmark" / "out"
+    if "--workload" in argv:
+        rc = run_cell(argv[1:])
+        if rc:
+            return rc
+    elif len(argv) > 1:
+        out_dir = Path(argv[1])
+    traces = sorted(out_dir.glob("xplane/plugins/profile/*/*.xplane.pb"))
+    if not traces:
+        print(f"no trace under {out_dir}", file=sys.stderr)
+        return 1
+    obs = {"profiler": _Trace(traces[-1])}
+    print(json.dumps({"trace": str(traces[-1]),
+                      "coverage": scopes.coverage(obs, top=12),
+                      "idle_by_span_s": host_phases.by_span(obs)}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))

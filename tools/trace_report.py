@@ -156,7 +156,7 @@ def to_chrome(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
                 # Chrome flow events (s/t/f): Perfetto binds each point
                 # to the slice enclosing its ts on this track and draws
                 # the arrows — one request's admit -> prefill chunks ->
-                # decode ticks -> retire path across the tick spans
+                # first decode tick -> retire path across the tick spans
                 # (train/trace.py Tracer.flow; the id carries the
                 # process prefix, so merged fleet flows never collide)
                 ev.update(ph=str(r.get("fph", "t")), cat="flow",
