@@ -57,8 +57,8 @@ class TransformerConfig:
     activation: str = "gelu"
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32   # set bfloat16 for TPU throughput
-    # "auto" dispatches dense-vs-flash by (backend, T) at the measured
-    # crossover (parallel.sequence.resolve_attention_impl)
+    # "auto" dispatches dense-vs-flash by (backend, T, head_dim, dtype)
+    # from the measured table (parallel.sequence.resolve_attention_impl)
     attention: str = "auto"            # auto | dense | flash | ring | ...
     seq_axis: str = "seq"
     # Position encoding: "learned" adds a trained position-embedding table
@@ -88,14 +88,12 @@ class TransformerConfig:
     # cache with grouped local attention.
     n_kv_heads: Optional[int] = None
     # Pallas flash-kernel tile sizes (flash / ring_flash / striped_flash
-    # only; dense and the non-flash ring ignore them).  128 x 128 is the
-    # v5e-safe default — block_k is the MXU contraction tile for the
-    # score matmul and block_q rows live in VMEM across the k-loop, so
-    # larger block_k amortizes loop overhead at the price of VMEM;
-    # bench's flagship sweep (tools/big_lm_sweep.py) tunes these on the
-    # real chip rather than guessing.
-    flash_block_q: int = 128
-    flash_block_k: int = 128
+    # only; dense and the non-flash ring ignore them).  None: the kernels
+    # derive them from (T, head_dim, dtype) out of the tilings timed on
+    # the chip (ops.pallas_kernels.FLASH_BLOCKS; 128 x 128 where a shape
+    # was not timed).  A number is an explicit override.
+    flash_block_q: Optional[int] = None
+    flash_block_k: Optional[int] = None
     remat: bool = False                # jax.checkpoint each block (HBM <-> FLOPs)
     remat_policy: str = "full"         # full | dots | dots_no_batch (models.core.make_remat)
     # lax.scan over a stacked block pytree (leaves (n_layers, ...)) instead

@@ -27,6 +27,7 @@ the same math in plain JAX as the cross-check for tests.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -69,81 +70,133 @@ def _resolve_mask(causal: bool, mask_mode: Optional[str]) -> str:
 # Flash attention
 # ==========================================================================
 
-def _k_block_hi(mask: str, qi, block_q: int, block_k: int,
-                num_k_blocks: int):
-    """Exclusive upper bound on the k-block loop for one q-block: blocks
-    entirely above the (inclusive or exclusive) diagonal are never read."""
+# Measured tilings, ``(head_dim, operand dtype) -> (block_q, block_k)``: the
+# fastest forward + backward of tools/flash_block_sweep.py on one TPU v5e
+# (PR 27, PERF.md section 6; B 4, T 1024, H 16 x 64 and H 24 x 128, bf16).
+# A row is there only if it was timed on the chip; every other shape keeps
+# 128 x 128, the tiling the kernels were written at.
+FLASH_BLOCKS = {
+    (64, "bfloat16"): (512, 512),
+    (128, "bfloat16"): (512, 512),
+}
+_UNTIMED_BLOCKS = (128, 128)
+
+
+def flash_blocks(t: int, head_dim: Optional[int], dtype,
+                 block_q: Optional[int] = None,
+                 block_k: Optional[int] = None
+                 ) -> Optional[Tuple[int, int]]:
+    """The kernels' tiling for this input: an explicit size wins, ``None``
+    is derived from (head_dim, dtype) out of the timed table, and both are
+    cut to ``t``.  None where the tiling does not divide ``t`` (``auto``
+    then answers dense; the kernels' own call raises)."""
+    name = None if dtype is None else jnp.dtype(dtype).name
+    bq, bk = FLASH_BLOCKS.get((head_dim, name), _UNTIMED_BLOCKS)
+    bq = min(bq if block_q is None else block_q, t)
+    bk = min(bk if block_k is None else block_k, t)
+    return None if t % bq or t % bk else (bq, bk)
+
+
+def _checked_blocks(q: jax.Array, block_q: Optional[int],
+                    block_k: Optional[int]) -> Tuple[int, int]:
+    _, t, _, d = q.shape
+    blocks = flash_blocks(t, d, q.dtype, block_q, block_k)
+    if blocks is None:
+        raise ValueError(f"seq_len {t} not divisible by blocks "
+                         f"({block_q or 'derived'}, {block_k or 'derived'})")
+    return blocks
+
+
+def _fold_scale(x, scale: float):
+    """``(x', s_scale)`` with ``(x' . y) * s_scale == (x . y) * scale``.  A
+    power-of-two scale (head_dim 64: 1/8) multiplies the ``(block, D)``
+    operand tile exactly in any float type, and the scores need no pass of
+    their own; any other scale stays an f32 multiply of the f32 scores.
+    The operand is never upcast: the MXU takes it as it arrives."""
+    if math.frexp(scale)[0] == 0.5:
+        return x * jnp.asarray(scale, x.dtype), None
+    return x, scale
+
+
+def _dot(a, b, contract):
+    """MXU product of two operands in the dtype they arrive in, f32 out."""
+    return lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+
+
+def _row_bounds(mask: str, qi, block_q: int, block_k: int, num_k: int):
+    """For q-block ``qi``: ``(full, hi)`` — k-blocks ``[0, full)`` lie
+    wholly on the attendable side of the (inclusive or exclusive) diagonal
+    and need no mask, ``[full, hi)`` straddle it, ``[hi, num_k)`` lie
+    wholly above it and are never read."""
     if mask == "none":
-        return num_k_blocks
-    # highest attendable k index: last q row is (qi+1)*Bq - 1; inclusive
-    # attends k <= that, exclusive k < that
-    last_k = (qi + 1) * block_q - (1 if mask == "causal" else 2)
-    return lax.min(num_k_blocks,
-                   lax.max(0, lax.div(last_k + block_k, block_k)))
+        return num_k, num_k
+    incl = 1 if mask == "causal" else 0
+    # last q row is (qi+1)*Bq - 1: it attends k <= that (k < that)
+    last_k = (qi + 1) * block_q - 2 + incl
+    hi = lax.min(num_k, lax.max(0, lax.div(last_k + block_k, block_k)))
+    # first q row is qi*Bq: block j is full when its last key
+    # (j+1)*Bk - 1 is <= that (< that)
+    full = lax.div(qi * block_q + incl, block_k)
+    return lax.min(full, hi), hi
 
 
-def _mask_scores(mask: str, s, q_pos, k_pos):
+def _col_bounds(mask: str, kj, block_q: int, block_k: int, num_q: int):
+    """For k-block ``kj``: ``(lo, full)`` — q-blocks ``[0, lo)`` lie wholly
+    above the diagonal and are never read, ``[lo, full)`` straddle it,
+    ``[full, num_q)`` need no mask.  (Exclusive shares the inclusive
+    ``lo``: it just admits one nearly-masked block.)"""
     if mask == "none":
-        return s
-    keep = (k_pos <= q_pos) if mask == "causal" else (k_pos < q_pos)
-    return jnp.where(keep, s, NEG_INF)
+        return 0, 0
+    incl = 1 if mask == "causal" else 0
+    lo = lax.div(kj * block_k, block_q)
+    # q-block i is full when its first row i*Bq is >= (>) the k-block's
+    # last key (kj+1)*Bk - 1
+    full = lax.div((kj + 1) * block_k - incl + block_q - 1, block_q)
+    return lo, lax.max(lo, lax.min(full, num_q))
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
-                      block_k: int, seq_len: int, mask: str,
-                      scale: float):
-    """Grid: (batch*heads, T // block_q).  Refs (block-local):
-    q (1, block_q, D), k/v (1, T, D), o (1, block_q, D), lse (1, 1, block_q).
+def _keep(mask: str, rel, q0, k0):
+    """Where a key may be attended; ``rel`` is ``k_local - q_local`` of the
+    tile (built once a program), ``q0``/``k0`` the tile's first positions."""
+    return (rel <= q0 - k0) if mask == "causal" else (rel < q0 - k0)
 
-    lse rides in a (BH, 1, T) layout: Mosaic requires the last two dims of
-    every block shape to be (8, 128)-divisible or equal to the array dims,
-    which a (1, block_q) block over (BH, T) violates (the leading 1 is a
-    grid dim).  With the singleton axis the block's trailing dims are
-    (1, block_q) against array dims (1, T) — legal."""
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (Bq, D)
-    d = q.shape[-1]
-    num_k_blocks = seq_len // block_k
-    hi = _k_block_hi(mask, qi, block_q, block_k, num_k_blocks)
 
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 0)
+def _two_loops(lo, mid, hi, first, second, carry):
+    """``first`` over ``[lo, mid)``, then ``second`` over ``[mid, hi)``."""
+    return lax.fori_loop(mid, hi, second,
+                         lax.fori_loop(lo, mid, first, carry))
 
-    def body(j, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (Bq, Bk)
-        k_pos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = _mask_scores(mask, s, q_pos, k_pos)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = corr * l + p.sum(axis=-1, keepdims=True)
-        acc_new = corr * acc + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
 
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = lax.fori_loop(0, hi, body, (acc0, m0, l0))
-    # exclusive mode can leave a row with NO attendable key (its m never
-    # left NEG_INF — every seen score was the mask fill, or the loop never
-    # ran): emit output 0 / lse NEG_INF, the ring merge's "no
-    # contribution" convention.  Inclusive/none modes never hit this.
-    empty = m < (NEG_INF * 0.5)
-    l_safe = jnp.where(empty, 1.0, l)
-    o_ref[0] = jnp.where(empty, 0.0, acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.where(empty, NEG_INF, m + jnp.log(l_safe))[:, 0]
+# --- heads on the lanes ----------------------------------------------------
+# The kernels read q/k/v where the model keeps them: (B, T, H*D), heads side
+# by side on the minor axis.  A program takes one 128-lane column of it —
+# one head of width 128, or ``pack`` = 128 // D narrower heads — so nothing
+# is transposed in HBM on the way in or out and every load and store fills
+# its lanes.  Heads that share a column are told apart by lane masks: a
+# product contracts q (or k, v, do) with the other heads' lanes zeroed, and
+# a product's output is only read in its own head's lanes.  On a 128 x 128
+# MXU that costs what a lone head of width 64 costs, which leaves half the
+# array idle either way.  A head width that neither divides 128 nor is a
+# multiple of it (or a head count ``pack`` does not divide) takes the
+# heads-major layout (B*H, T, D) through two transposes, one head a program.
+
+def _fold_plan(h: int, d: int) -> Tuple[int, bool]:
+    """``(pack, folded)``: heads a program, and whether they ride the lanes
+    of the model's own layout."""
+    if d % 128 == 0:
+        return 1, True
+    if 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d, True
+    return 1, False
 
 
 def _heads_major(x: jax.Array) -> jax.Array:
-    """(B, T, H, D) -> (B*H, T, D): contiguous per-head rows for kernels."""
+    """(B, T, H, D) -> (B*H, T, D): contiguous per-head rows."""
     b, t, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
@@ -154,52 +207,185 @@ def _heads_minor(x: jax.Array, b: int, h: int) -> jax.Array:
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-def _resolve_blocks(t: int, block_q: int, block_k: int):
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    if t % block_q or t % block_k:
-        raise ValueError(f"seq_len {t} not divisible by blocks "
-                         f"({block_q}, {block_k})")
-    return block_q, block_k
+def _to_kernel(x: jax.Array, folded: bool) -> jax.Array:
+    """(B, T, H, D) -> the kernels' (G, T, L)."""
+    b, t, h, d = x.shape
+    return x.reshape(b, t, h * d) if folded else _heads_major(x)
+
+
+def _from_kernel(x: jax.Array, b: int, h: int, folded: bool) -> jax.Array:
+    return (x.reshape(b, x.shape[1], h, -1) if folded
+            else _heads_minor(x, b, h))
+
+
+def _head_lanes(pack: int, head_dim: int, rows: int):
+    """Per head of the column, where its lanes are: a (rows, pack*D) bool
+    mask each, or ``[None]`` for a lone head."""
+    if pack == 1:
+        return [None]
+    lane = lax.broadcasted_iota(jnp.int32, (rows, pack * head_dim), 1)
+    return [(lane >= i * head_dim) & (lane < (i + 1) * head_dim)
+            for i in range(pack)]
+
+
+def _only(lanes, x):
+    """``x`` with the other heads' lanes zeroed: a contraction operand."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _own_lanes(lanes, per_head):
+    """One (rows, pack*D) value from each head's own lanes of its result."""
+    out = per_head[0]
+    for m, x in zip(lanes[1:], per_head[1:]):
+        out = jnp.where(m, x, out)
+    return out
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
+                      block_k: int, seq_len: int, mask: str, scale: float,
+                      pack: int, head_dim: int):
+    """Grid: (G, columns, T // block_q).  Refs (block-local): q (1, block_q,
+    W), k/v (1, T, W), o (1, block_q, W), lse (1, 1, pack, block_q); W is
+    the column's width, ``pack`` heads of ``head_dim``.
+
+    Products take q/k/v in the dtype they arrive in and accumulate in f32;
+    scores, the running max, the normaliser and the output accumulator are
+    f32, and ``p`` is cast to v's dtype only as the operand of ``p @ v`` —
+    the precision of ``parallel.sequence.attention_reference``.
+
+    lse rides in a (G, columns, pack, T) layout: Mosaic requires the last
+    two dims of every block shape to be (8, 128)-divisible or equal to the
+    array dims, and a (pack, block_q) block against array dims (pack, T)
+    is."""
+    qi = pl.program_id(2)
+    q, s_scale = _fold_scale(q_ref[0], scale)             # (Bq, W)
+    w = q.shape[-1]
+    lanes = _head_lanes(pack, head_dim, block_q)
+    qs = [_only(m, q) for m in lanes]
+    full, hi = _row_bounds(mask, qi, block_q, block_k, seq_len // block_k)
+    rel = (lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+           - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
+
+    def step(masked: bool):
+        def body(j, carry):
+            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            k = k_ref[0, rows, :]
+            v = v_ref[0, rows, :]
+            keep = (_keep(mask, rel, qi * block_q, j * block_k)
+                    if masked else None)
+            out = []
+            for qh, (acc, m, l) in zip(qs, carry):
+                s = _dot(qh, k, _NT)                      # (Bq, Bk) f32
+                if s_scale is not None:
+                    s = s * s_scale
+                if masked:
+                    s = jnp.where(keep, s, NEG_INF)
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l_new = corr * l + p.sum(axis=-1, keepdims=True)
+                acc_new = corr * acc + _dot(p.astype(v.dtype), v, _NN)
+                out.append((acc_new, m_new, l_new))
+            return tuple(out)
+        return body
+
+    carry = ((jnp.zeros((block_q, w), jnp.float32),
+              jnp.full((block_q, 1), NEG_INF, jnp.float32),
+              jnp.zeros((block_q, 1), jnp.float32)),) * pack
+    if mask == "none":
+        carry = lax.fori_loop(0, hi, step(False), carry)
+    else:
+        carry = _two_loops(0, full, hi, step(False), step(True), carry)
+    outs = []
+    for i, (acc, m, l) in enumerate(carry):
+        if mask == "causal_exclusive":
+            # a row with NO attendable key (its m never left NEG_INF —
+            # every seen score was the mask fill, or the loop never ran):
+            # output 0 / lse NEG_INF, the ring merge's "no contribution"
+            # convention.  Inclusive/none modes never hit this.
+            empty = m < (NEG_INF * 0.5)
+            l = jnp.where(empty, 1.0, l)
+            outs.append(jnp.where(empty, 0.0, acc / l))
+            lse_ref[0, 0, i] = jnp.where(empty, NEG_INF,
+                                         m + jnp.log(l))[:, 0]
+        else:
+            outs.append(acc / l)
+            lse_ref[0, 0, i] = (m + jnp.log(l))[:, 0]
+    o_ref[0] = _own_lanes(lanes, outs).astype(o_ref.dtype)
+
+
+def _kernel_geometry(b: int, t: int, h: int, d: int, block_q: int,
+                     block_k: int, itemsize: int):
+    """What the three ``pallas_call``s share: the layout plan, the block
+    specs by role, and the compiler parameters."""
+    pack, folded = _fold_plan(h, d)
+    g, cols, w = (b, h // pack, pack * d) if folded else (b * h, 1, d)
+    mem = {"memory_space": pltpu.VMEM}
+    specs = {
+        # a block of rows of the column / the column's whole rows
+        "q_blk": pl.BlockSpec((1, block_q, w), lambda g_, c, i: (g_, i, c),
+                              **mem),
+        "k_blk": pl.BlockSpec((1, block_k, w), lambda g_, c, i: (g_, i, c),
+                              **mem),
+        "rows": pl.BlockSpec((1, t, w), lambda g_, c, i: (g_, 0, c), **mem),
+        # f32 row statistics (lse, delta), (G, columns, pack, T)
+        "stat_blk": pl.BlockSpec((1, 1, pack, block_q),
+                                 lambda g_, c, i: (g_, c, 0, i), **mem),
+        "stat_rows": pl.BlockSpec((1, 1, pack, t),
+                                  lambda g_, c, i: (g_, c, 0, 0), **mem),
+    }
+    # every grid step is independent; the scoped-VMEM limit follows the
+    # tile: the resident rows (double-buffered, lane-padded) plus the f32
+    # score-sized temporaries of one loop step, per head of the column
+    resident = 8 * t * max(w, 128) * itemsize
+    tiles = 8 * pack * block_q * block_k * 4
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel"),
+        vmem_limit_bytes=int(min(100 << 20,
+                                 max(32 << 20, resident + tiles))))
+    return pack, folded, (g, cols, w), specs, params
 
 
 def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
-                   block_q: int, block_k: int,
+                   block_q: Optional[int], block_k: Optional[int],
                    interpret: Optional[bool],
                    mask_mode: Optional[str] = None):
     """q/k/v: (B, T, H, D) -> out (B, T, H, D), lse (B*H, T) float32."""
-    b, t, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    block_q, block_k = _resolve_blocks(t, block_q, block_k)
+    block_q, block_k = _checked_blocks(q, block_q, block_k)
     if interpret is None:
         interpret = _interpret_default()
-    qh, kh, vh = _heads_major(q), _heads_major(k), _heads_major(v)
+    return _flash_forward_call(q, k, v, mask=_resolve_mask(causal, mask_mode),
+                               block_q=block_q, block_k=block_k,
+                               interpret=interpret)
 
+
+# The calls are jitted so that a model lowers each kernel once, not once a
+# layer: every layer's call is the same jaxpr, and JAX lowers one private
+# function for it (Mosaic lowering is not what the compile cache saves).
+@functools.partial(jax.jit, static_argnames=("mask", "block_q", "block_k",
+                                             "interpret"))
+def _flash_forward_call(q, k, v, *, mask: str, block_q: int, block_k: int,
+                        interpret: bool):
+    b, t, h, d = q.shape
+    pack, folded, (g, cols, w), specs, params = _kernel_geometry(
+        b, t, h, d, block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
-                               block_k=block_k, seq_len=t,
-                               mask=_resolve_mask(causal, mask_mode),
-                               scale=scale)
-    mem = {"memory_space": pltpu.VMEM}
+                               block_k=block_k, seq_len=t, mask=mask,
+                               scale=1.0 / (d ** 0.5), pack=pack, head_dim=d)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda bh, i: (bh, 0, 0), **mem),
-            pl.BlockSpec((1, t, d), lambda bh, i: (bh, 0, 0), **mem),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0), **mem),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i), **mem),
-        ],
+        grid=(g, cols, t // block_q),
+        in_specs=[specs["q_blk"], specs["rows"], specs["rows"]],
+        out_specs=[specs["q_blk"], specs["stat_blk"]],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((g, t, cols * w), q.dtype),
+            jax.ShapeDtypeStruct((g, cols, pack, t), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
         name="flash_fwd",
-    )(qh, kh, vh)
-    return _heads_minor(out, b, h), lse.reshape(b * h, t)
+    )(*(_to_kernel(x, folded) for x in (q, k, v)))
+    return _from_kernel(out, b, h, folded), lse.reshape(b * h, t)
 
 
 def _blocked_attention_reference(q, k, v, causal: bool, block_k: int):
@@ -245,182 +431,204 @@ def _blocked_attention_reference(q, k, v, causal: bool, block_k: int):
 # --------------------------------------------------------------------------
 # Backward kernels (FlashAttention-2 split: one kernel accumulates dq over
 # k-blocks, one accumulates dk/dv over q-blocks; p is recomputed from
-# (q, k, lse), delta = rowsum(do * o) is precomputed outside).
+# (q, k, lse), delta = rowsum(do * o) is precomputed outside).  As in the
+# forward: operands in the dtype they arrive in, f32 scores, lse, delta and
+# accumulators; p and ds are cast only as the operand of the next product,
+# and the softmax scale lands on the (block, D) accumulators, once.
 # --------------------------------------------------------------------------
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, block_q: int, block_k: int, seq_len: int,
-                         mask: str, scale: float):
-    """Grid: (B*H, T // block_q).  q/do/dq blocks (1, block_q, D); k/v full
-    rows (1, T, D); lse/delta blocks (1, 1, block_q) float32 (the singleton
-    axis keeps the trailing block dims Mosaic-legal, see _flash_fwd_kernel)."""
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]     # (Bq, 1)
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
-    d = q.shape[-1]
-    num_k_blocks = seq_len // block_k
-    hi = _k_block_hi(mask, qi, block_q, block_k, num_k_blocks)
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 0)
-    # exclusive mode marks no-key rows with lse = NEG_INF; exp(s - lse)
-    # would blow up there, and their true gradient is 0
-    live = lse > (NEG_INF * 0.5)
-    lse_safe = jnp.where(live, lse, 0.0)
+                         mask: str, scale: float, pack: int, head_dim: int):
+    """Grid: (G, columns, T // block_q).  q/do/dq blocks (1, block_q, W);
+    k/v whole rows (1, T, W); lse/delta blocks (1, 1, pack, block_q)
+    float32 (see _flash_fwd_kernel for the layout)."""
+    qi = pl.program_id(2)
+    q, s_scale = _fold_scale(q_ref[0], scale)
+    do = do_ref[0]
+    w = q.shape[-1]
+    lanes = _head_lanes(pack, head_dim, block_q)
+    full, hi = _row_bounds(mask, qi, block_q, block_k, seq_len // block_k)
+    rel = (lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+           - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
+    heads = []
+    for i, m in enumerate(lanes):
+        lse = lse_ref[0, 0, i][:, None]                   # (Bq, 1) f32
+        if mask == "causal_exclusive":
+            # no-key rows carry lse = NEG_INF; every score they see is the
+            # mask fill, so exp(fill - 0) is the 0 their gradient is, where
+            # exp(fill - NEG_INF) would be 1
+            lse = jnp.where(lse > (NEG_INF * 0.5), lse, 0.0)
+        heads.append((_only(m, q), _only(m, do), lse,
+                      delta_ref[0, 0, i][:, None]))
 
-    def body(j, dq_acc):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_pos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = _mask_scores(mask, s, q_pos, k_pos)
-        p = jnp.where(live, jnp.exp(s - lse_safe), 0.0)   # (Bq, Bk)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def step(masked: bool):
+        def body(j, dq_accs):
+            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            k = k_ref[0, rows, :]
+            v = v_ref[0, rows, :]
+            keep = (_keep(mask, rel, qi * block_q, j * block_k)
+                    if masked else None)
+            out = []
+            for (qh, doh, lse, delta), dq_acc in zip(heads, dq_accs):
+                s = _dot(qh, k, _NT)
+                if s_scale is not None:
+                    s = s * s_scale
+                if masked:
+                    s = jnp.where(keep, s, NEG_INF)
+                p = jnp.exp(s - lse)                      # (Bq, Bk)
+                ds = p * (_dot(doh, v, _NT) - delta)
+                out.append(dq_acc + _dot(ds.astype(k.dtype), k, _NN))
+            return tuple(out)
+        return body
 
-    dq = lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dqs = (jnp.zeros((block_q, w), jnp.float32),) * pack
+    if mask == "none":
+        dqs = lax.fori_loop(0, hi, step(False), dqs)
+    else:
+        dqs = _two_loops(0, full, hi, step(False), step(True), dqs)
+    dq_ref[0] = (_own_lanes(lanes, dqs) * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, block_k: int,
-                          seq_len: int, mask: str, scale: float):
-    """Grid: (B*H, T // block_k).  k/v/dk/dv blocks (1, block_k, D);
-    q/do full rows (1, T, D); lse/delta full rows (1, 1, T) float32."""
-    kj = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                      # (Bk, D)
-    v = v_ref[0].astype(jnp.float32)
-    d = k.shape[-1]
-    num_q_blocks = seq_len // block_q
-    # causal (either diagonal): k-block kj only feeds q rows >= kj*block_k
-    # (exclusive needs strictly greater — the shared bound just admits one
-    # nearly-masked extra block)
-    lo = 0 if mask == "none" else lax.div(kj * block_k, block_q)
-    k_pos = kj * block_k + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 1)
+                          seq_len: int, mask: str, scale: float, pack: int,
+                          head_dim: int):
+    """Grid: (G, columns, T // block_k).  k/v/dk/dv blocks (1, block_k, W);
+    q/do whole rows (1, T, W); lse/delta whole rows (1, 1, pack, T) float32.
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        # slice from the refs (Mosaic lowers pl.ds ref reads; value-level
-        # lax.dynamic_slice has no TPU lowering rule)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)].astype(
-            jnp.float32)[:, None]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)].astype(
-            jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        q_pos = i * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        s = _mask_scores(mask, s, q_pos, k_pos)
-        live = lse > (NEG_INF * 0.5)  # no-key rows: lse = NEG_INF, grad 0
-        p = jnp.where(live, jnp.exp(s - jnp.where(live, lse, 0.0)), 0.0)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                     # (Bq, Bk)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
+    Works on the TRANSPOSED tile, keys down and queries across: ``s^T = k
+    q^T`` comes out of the MXU that way, ``p^T`` and ``ds^T`` are then the
+    left operands of plain products, and lse/delta are read as the
+    (1, block_q) lane rows they are stored as — no transposed-operand
+    product and no row-to-column relayout in the loop."""
+    kj = pl.program_id(2)
+    v = v_ref[0]                                          # (Bk, W)
+    k, s_scale = _fold_scale(k_ref[0], scale)
+    w = k.shape[-1]
+    lanes = _head_lanes(pack, head_dim, block_k)
+    ks = [_only(m, k) for m in lanes]
+    vs = [_only(m, v) for m in lanes]
+    num_q = seq_len // block_q
+    lo, full = _col_bounds(mask, kj, block_q, block_k, num_q)
+    # k_local - q_local on the transposed tile
+    rel = (lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+           - lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1))
 
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(lo, num_q_blocks, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    def step(masked: bool):
+        def body(i, carry):
+            start = pl.multiple_of(i * block_q, block_q)
+            q = q_ref[0, pl.ds(start, block_q), :]        # (Bq, W)
+            do = do_ref[0, pl.ds(start, block_q), :]
+            keep = (_keep(mask, rel, i * block_q, kj * block_k)
+                    if masked else None)
+            out = []
+            for n, (kh, vh, (dk_acc, dv_acc)) in enumerate(
+                    zip(ks, vs, carry)):
+                # slice from the refs (Mosaic lowers pl.ds ref reads;
+                # value-level lax.dynamic_slice has no TPU lowering rule)
+                lse = lse_ref[0, 0, n:n + 1, pl.ds(start, block_q)]
+                delta = delta_ref[0, 0, n:n + 1, pl.ds(start, block_q)]
+                if mask == "causal_exclusive":            # see the dq kernel
+                    lse = jnp.where(lse > (NEG_INF * 0.5), lse, 0.0)
+                st = _dot(kh, q, _NT)                     # (Bk, Bq) f32
+                if s_scale is not None:
+                    st = st * s_scale
+                if masked:
+                    st = jnp.where(keep, st, NEG_INF)
+                pt = jnp.exp(st - lse)                    # lse: (1, Bq)
+                dv_acc = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
+                dst = pt * (_dot(vh, do, _NT) - delta)
+                dk_acc = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
+                out.append((dk_acc, dv_acc))
+            return tuple(out)
+        return body
+
+    zeros = jnp.zeros((block_k, w), jnp.float32)
+    carry = ((zeros, zeros),) * pack
+    if mask == "none":
+        carry = lax.fori_loop(0, num_q, step(False), carry)
+    else:
+        carry = _two_loops(lo, full, num_q, step(True), step(False), carry)
+    dk_ref[0] = (_own_lanes(lanes, [dk for dk, _ in carry])
+                 * scale).astype(dk_ref.dtype)
+    dv_ref[0] = _own_lanes(lanes, [dv for _, dv in carry]).astype(
+        dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal: bool, block_q: int,
-                    block_k: int, interpret: Optional[bool],
+def _flash_backward(q, k, v, out, lse, g, causal: bool,
+                    block_q: Optional[int], block_k: Optional[int],
+                    interpret: Optional[bool],
                     g_lse: Optional[jax.Array] = None,
                     mask_mode: Optional[str] = None):
-    b, t, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    block_q, block_k = _resolve_blocks(t, block_q, block_k)
+    block_q, block_k = _checked_blocks(q, block_q, block_k)
     if interpret is None:
         interpret = _interpret_default()
-    qh, kh, vh = _heads_major(q), _heads_major(k), _heads_major(v)
-    doh = _heads_major(g)
+    return _flash_backward_call(q, k, v, out, lse, g, g_lse,
+                                mask=_resolve_mask(causal, mask_mode),
+                                block_q=block_q, block_k=block_k,
+                                interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("mask", "block_q", "block_k",
+                                             "interpret"))
+def _flash_backward_call(q, k, v, out, lse, g, g_lse, *, mask: str,
+                         block_q: int, block_k: int, interpret: bool):
+    b, t, h, d = q.shape
+    pack, folded, (grp, cols, w), specs, params = _kernel_geometry(
+        b, t, h, d, block_q, block_k, q.dtype.itemsize)
     # delta_i = sum_j p_ij * dp_ij = rowsum(do * o): one fused elementwise
-    # reduce in XLA, shared by both kernels.  lse/delta travel as
-    # (BH, 1, T) so every block shape's trailing dims stay Mosaic-legal.
+    # reduce in XLA over the model's own layout, shared by both kernels.
     #
     # A cotangent on the lse OUTPUT (flash_attention_with_lse) folds into
     # the same kernels: d lse_i / d s_ij = p_ij, so
     # ds_ij = p_ij * (dp_ij - delta_i + g_lse_i) — i.e. shift delta by
     # -g_lse and nothing else changes (dv is lse-independent).
-    delta = (doh.astype(jnp.float32)
-             * _heads_major(out).astype(jnp.float32)).sum(-1)  # (BH, T)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    delta = delta.transpose(0, 2, 1).reshape(b * h, t)         # (BH, T)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
-    lse3 = lse.reshape(b * h, 1, t)
-    delta3 = delta.reshape(b * h, 1, t)
+    stats = (lse.reshape(grp, cols, pack, t),
+             delta.reshape(grp, cols, pack, t))
+    qh, kh, vh, doh = (_to_kernel(x, folded) for x in (q, k, v, g))
 
-    mem = {"memory_space": pltpu.VMEM}
-    row = dict(block_q=block_q, block_k=block_k, seq_len=t,
-               mask=_resolve_mask(causal, mask_mode), scale=scale)
-    full = lambda spec_t: pl.BlockSpec((1, spec_t, d),
-                                       lambda bh, i: (bh, 0, 0), **mem)
+    row = dict(block_q=block_q, block_k=block_k, seq_len=t, mask=mask,
+               scale=1.0 / (d ** 0.5), pack=pack, head_dim=d)
+    like = lambda x: jax.ShapeDtypeStruct((grp, t, cols * w), x.dtype)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **row),
-        grid=(b * h, t // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0), **mem),
-            full(t), full(t),
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0), **mem),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i), **mem),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i), **mem),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0),
-                               **mem),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        grid=(grp, cols, t // block_q),
+        in_specs=[specs["q_blk"], specs["rows"], specs["rows"],
+                  specs["q_blk"], specs["stat_blk"], specs["stat_blk"]],
+        out_specs=specs["q_blk"],
+        out_shape=like(q),
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qh, kh, vh, doh, lse3, delta3)
+    )(qh, kh, vh, doh, *stats)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **row),
-        grid=(b * h, t // block_k),
-        in_specs=[
-            full(t),
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0), **mem),
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0), **mem),
-            full(t),
-            pl.BlockSpec((1, 1, t), lambda bh, j: (bh, 0, 0), **mem),
-            pl.BlockSpec((1, 1, t), lambda bh, j: (bh, 0, 0), **mem),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0), **mem),
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0), **mem),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
-        ],
+        grid=(grp, cols, t // block_k),
+        in_specs=[specs["rows"], specs["k_blk"], specs["k_blk"],
+                  specs["rows"], specs["stat_rows"], specs["stat_rows"]],
+        out_specs=[specs["k_blk"], specs["k_blk"]],
+        out_shape=[like(k), like(v)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qh, kh, vh, doh, lse3, delta3)
-    return (_heads_minor(dq, b, h), _heads_minor(dk, b, h),
-            _heads_minor(dv, b, h))
+    )(qh, kh, vh, doh, *stats)
+    return tuple(_from_kernel(x, b, h, folded) for x in (dq, dk, dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128,
+                    causal: bool = True, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Blocked attention, Pallas forward + Pallas backward.
-    q/k/v: (B, T, H, D)."""
+    q/k/v: (B, T, H, D).  ``block_q``/``block_k`` None: derived from
+    (T, head_dim, dtype) by :func:`flash_blocks`."""
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
     return out
 
@@ -441,8 +649,9 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
-                             causal: bool = True, block_q: int = 128,
-                             block_k: int = 128,
+                             causal: bool = True,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
                              interpret: Optional[bool] = None,
                              mask_mode: Optional[str] = None
                              ) -> Tuple[jax.Array, jax.Array]:

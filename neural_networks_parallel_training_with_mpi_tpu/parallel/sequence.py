@@ -235,7 +235,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          axis: str = "seq", causal: bool = True,
                          scale: Optional[float] = None,
-                         block_q: int = 128, block_k: int = 128,
+                         block_q: Optional[int] = None,
+                         block_k: Optional[int] = None,
                          interpret: Optional[bool] = None) -> jax.Array:
     """Ring attention whose LOCAL block compute is the Pallas flash kernel
     (ops.pallas_kernels) — blockwise ring attention with the hot loop on
@@ -327,7 +328,8 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def striped_ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  axis: str = "seq", causal: bool = True,
                                  scale: Optional[float] = None,
-                                 block_q: int = 128, block_k: int = 128,
+                                 block_q: Optional[int] = None,
+                                 block_k: Optional[int] = None,
                                  interpret: Optional[bool] = None
                                  ) -> jax.Array:
     """Ring attention over ROUND-ROBIN token stripes with the Pallas flash
@@ -424,32 +426,48 @@ SEQ_SHARDED_IMPLS = ("ring", "ring_flash", "striped", "striped_flash",
                      "ulysses")
 
 
-# Shape-based dispatch for ``attention="auto"`` (VERDICT r4 item 3): the
-# measured single-chip crossover between the XLA dense path (materialized
-# (B,H,T,T) scores, fused softmax) and the Pallas flash kernel.  Seeded
-# from BENCH_ATTENTION.json (TPU v5 lite, head_dim 64): full-step flash is
-# 0.89x at T=512 and only ~1.03-1.05x at 1024-2048, while kernel-only
-# flash LOSES until T=4096 (0.91x @ 1k, 0.98x @ 2k, 1.36x @ 4k, 9.7x @
-# 8k) — and dense's quadratic scores tensor stops compiling at 8k anyway.
-# 2048 is the conservative switch point: below it dense is never worse
-# than ~2% and often 10% better; above it flash wins on both time and
-# memory.  Backends without a measured row (cpu: the kernel runs in
-# interpret mode, orders of magnitude slow) never auto-select flash.
-AUTO_FLASH_MIN_SEQ = {"tpu": 2048}
+# Shape-based dispatch for ``attention="auto"``: from which sequence length
+# the Pallas flash kernels (ops.pallas_kernels) beat the XLA dense path
+# (materialised (B, H, T, T) f32 scores, kept for the backward pass), as
+# ``(backend, head_dim, operand dtype) -> smallest T``.  A row is there only
+# if it was timed on the chip: PR 27, one TPU v5e, forward + backward at
+# B 4, T 1024, H 16 x 64 and H 24 x 128, bf16, the kernels at their derived
+# tilings against ``attention_reference`` (tools/flash_block_sweep.py; the
+# numbers are in PERF.md section 6, "PR 27").
+AUTO_FLASH_MIN_SEQ = {
+    ("tpu", 64, "bfloat16"): 1024,
+    ("tpu", 128, "bfloat16"): 1024,
+}
+# Every shape without a row keeps the rule it had before the table: dense
+# below 2048, the kernels from there (f32 operands included, untimed).
+# Backends absent here (cpu: the kernels run in interpret mode, orders of
+# magnitude slow) never auto-select flash.
+AUTO_FLASH_MIN_SEQ_UNTIMED = {"tpu": 2048}
 
 
 def resolve_attention_impl(impl: str, seq_len: int,
-                           backend: Optional[str] = None) -> str:
-    """Resolve ``"auto"`` to a concrete impl for this (backend, T) —
-    THE single consult point (sequence_sharded_attention resolves through
-    here, so every model/parallel path inherits the same table).  Any
-    other ``impl`` passes through unchanged."""
+                           backend: Optional[str] = None,
+                           head_dim: Optional[int] = None,
+                           dtype=None) -> str:
+    """Resolve ``"auto"`` to a concrete impl for this (backend, T,
+    head_dim, dtype) — THE single consult point
+    (sequence_sharded_attention resolves through here, so every
+    model/parallel path inherits the same table).  ``auto`` answers
+    ``flash`` from the measured row's T up (the untimed rule where the
+    shape has no row) and only where the kernels' derived tiling divides
+    T; any other ``impl`` passes through unchanged."""
     if impl != "auto":
         return impl
     if backend is None:
         backend = jax.default_backend()
-    thresh = AUTO_FLASH_MIN_SEQ.get(backend)
-    return "flash" if thresh is not None and seq_len >= thresh else "dense"
+    name = None if dtype is None else jnp.dtype(dtype).name
+    thresh = AUTO_FLASH_MIN_SEQ.get(
+        (backend, head_dim, name), AUTO_FLASH_MIN_SEQ_UNTIMED.get(backend))
+    if thresh is None or seq_len < thresh:
+        return "dense"
+    from ..ops.pallas_kernels import flash_blocks
+
+    return "flash" if flash_blocks(seq_len, head_dim, dtype) else "dense"
 
 
 def validate_ulysses_under_tp(n_heads: int, tp: int, sp: int,
@@ -479,14 +497,35 @@ def global_positions(impl: str, axis: str, t: int) -> jax.Array:
     return jnp.arange(t)
 
 
+def _note_resolved(impl: str, q, block_q, block_k) -> None:
+    """Say on the compile ledger's event of the program being lowered what
+    implements its attention, and at which tiling where that is a kernel's
+    (one field a program; ``compile_ledger.note`` does nothing outside a
+    ledger's compile)."""
+    from ..ops.pallas_kernels import flash_blocks
+    from ..utils import compile_ledger
+
+    rec = {"impl": impl}
+    if impl in ("flash", "ring_flash", "striped_flash"):
+        blocks = flash_blocks(q.shape[1], q.shape[-1], q.dtype,
+                              block_q, block_k)
+        if blocks:      # else the kernel's own call raises, in full
+            rec["block_q"], rec["block_k"] = blocks
+    compile_ledger.note("attention", rec)
+
+
 def sequence_sharded_attention(impl: str, q, k, v, *, axis: str = "seq",
                                causal: bool = True,
                                scale: Optional[float] = None,
-                               block_q: int = 128,
-                               block_k: int = 128,
+                               block_q: Optional[int] = None,
+                               block_k: Optional[int] = None,
                                rope_theta: Optional[float] = None
                                ) -> jax.Array:
-    impl = resolve_attention_impl(impl, q.shape[1])
+    """``block_q``/``block_k``: the flash kernels' tiling; None derives it
+    from (T, head_dim, dtype) (ops.pallas_kernels.flash_blocks)."""
+    impl = resolve_attention_impl(impl, q.shape[1], head_dim=q.shape[-1],
+                                  dtype=q.dtype)
+    _note_resolved(impl, q, block_q, block_k)
     if rope_theta is not None:
         # RoPE rotates q/k by their GLOBAL positions before any impl or
         # collective — global_positions already answers "what are this

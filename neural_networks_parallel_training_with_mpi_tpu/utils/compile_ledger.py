@@ -20,8 +20,10 @@ installed, every NEW argument signature is compiled through the AOT path
   collapse, now quantified per run),
 * the lowered module's SHA-256 fingerprint (same program text ⇒ same
   fingerprint — cross-run compile-cache attribution),
-* XLA cost analysis (flops, bytes accessed) where the backend reports
-  it.
+* XLA cost analysis (flops, bytes accessed) and the compiler's memory
+  count (temporaries, arguments, outputs) where the backend reports them,
+* what the program said of itself while it was traced (:func:`note`:
+  ``attention``, the resolved implementation and the kernels' tiling).
 
 The compiled executable is cached per signature and reused, so the
 ledger observes every compile exactly once and the program runs through
@@ -44,6 +46,7 @@ Degradation ladder (never break the run for observability):
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import threading
@@ -52,7 +55,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .logging import log
 
-__all__ = ["Ledger", "InstrumentedFn", "instrument", "install", "active"]
+__all__ = ["Ledger", "InstrumentedFn", "instrument", "install", "active",
+           "note"]
 
 
 class Ledger:
@@ -69,6 +73,23 @@ class Ledger:
                        "inc": int(incarnation)}
         self._lock = threading.Lock()
         self._f = open(path, "a") if path else None
+        # what the program being lowered says of itself (a compile belongs
+        # to the thread that asked for it)
+        self._lowering = threading.local()
+
+    @contextlib.contextmanager
+    def collecting_notes(self):
+        """The notes made while the body lowers one program."""
+        self._lowering.notes = notes = {}
+        try:
+            yield notes
+        finally:
+            self._lowering.notes = None
+
+    def note(self, key: str, value: Any) -> None:
+        notes = getattr(self._lowering, "notes", None)
+        if notes is not None:
+            notes.setdefault(key, value)
 
     def record(self, rec: Dict[str, Any]) -> None:
         rec = {**rec, **self._ident}
@@ -102,6 +123,15 @@ def install(ledger: Optional[Ledger]) -> None:
 
 def active() -> Optional[Ledger]:
     return _ACTIVE
+
+
+def note(key: str, value: Any) -> None:
+    """Called by code that runs while a program is traced: put ``key`` on
+    the ledger's event for that program (the first value wins, so a choice
+    made once a layer is recorded once).  Outside a ledger's compile it
+    does nothing."""
+    if _ACTIVE is not None:
+        _ACTIVE.note(key, value)
 
 
 # ---------------------------------------------------------------------------
@@ -173,6 +203,21 @@ def _cost_analysis(compiled) -> Dict[str, Optional[float]]:
                 "bytes_accessed": float(by) if by is not None else None}
     except Exception:
         return {"flops": None, "bytes_accessed": None}
+
+
+def _memory_analysis(compiled) -> Dict[str, Dict[str, int]]:
+    """The compiler's own count of the program's device memory (what it
+    refuses a program by; the runtime's ``peak_bytes_in_use`` leaves
+    temporaries out)."""
+    try:
+        m = compiled.memory_analysis()
+        return {"memory": {
+            "temp_bytes": int(m.temp_size_in_bytes),
+            "argument_bytes": int(m.argument_size_in_bytes),
+            "output_bytes": int(m.output_size_in_bytes),
+            "alias_bytes": int(m.alias_size_in_bytes)}}
+    except Exception:
+        return {}
 
 
 # ---------------------------------------------------------------------------
@@ -314,10 +359,12 @@ class InstrumentedFn:
             try:
                 with trace_lib.span(f"compile:{self.name}"):
                     t0 = time.perf_counter()
-                    lowered = lower(*args)
+                    with ledger.collecting_notes() as notes:
+                        lowered = lower(*args)
                     t1 = time.perf_counter()
                     compiled = lowered.compile()
                     t2 = time.perf_counter()
+                rec.update(notes)
                 rec["lower_ms"] = round((t1 - t0) * 1e3, 3)
                 rec["compile_ms"] = round((t2 - t1) * 1e3, 3)
                 try:
@@ -326,6 +373,7 @@ class InstrumentedFn:
                 except Exception:
                     rec["hlo_sha256"] = None
                 rec.update(_cost_analysis(compiled))
+                rec.update(_memory_analysis(compiled))
             except Exception as e:  # lowering unsupported here: degrade
                 compiled = None
                 rec["note"] = f"aot-unavailable: {type(e).__name__}: {e}"

@@ -95,6 +95,65 @@ def test_flash_backward(spec):
     assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
 
 
+def test_flash_at_the_cells_shape_and_derived_blocks(spec):
+    """The training cells' own attention (B 4, T 1024, 16 x 64, bf16), at
+    the tiling the code derives, and ``starcoder2-3b``'s head beside it:
+    three kernels each, inside the chip's VMEM."""
+    for heads, head_dim in ((16, 64), (24, 128)):
+        qkv = spec((4, 1024, heads, head_dim), BF16)
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, True, None, None, False)
+            return out.astype(jnp.float32).sum()
+
+        text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                              qkv, qkv, qkv)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
+
+
+def test_flash_lowering_does_not_move_with_path_or_lines(spec, tmp_path):
+    """What the compile cache keys a program by must not move with the
+    checkout's path or a comment line in ``ops/pallas_kernels.py``
+    (ROADMAP S6, "PR 22 run C"): the kernels' lowering, Mosaic payload and
+    locations included, is byte-identical from a copy of the file under
+    another path with its lines shifted, given the settings of
+    ``utils.platform.compile_cache``."""
+    import importlib.util
+
+    from neural_networks_parallel_training_with_mpi_tpu.ops import (
+        pallas_kernels,
+    )
+
+    src = open(pallas_kernels.__file__).read()
+    moved = tmp_path / "elsewhere" / "pallas_kernels.py"
+    moved.parent.mkdir()
+    moved.write_text("# a comment line\n# and another\n" + src)
+    mod_spec = importlib.util.spec_from_file_location("moved_kernels", moved)
+    copy = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(copy)
+
+    qkv = spec((2, 256, 2, 64), BF16)
+
+    def lowered(flash):
+        def loss(q, k, v):
+            with jax.named_scope("attention"):
+                out = flash(q, k, v, True, 128, 128, False)
+            return out.astype(jnp.float32).sum()
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            qkv, qkv, qkv).as_text(debug_info=True)
+
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        here, there = (lowered(pallas_kernels.flash_attention),
+                       lowered(copy.flash_attention))
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert "tpu_custom_call" in here and "attention" in here
+    assert here == there
+
+
 def test_fused_layernorm(spec):
     text = _compiled_text(
         lambda x, s, b: fused_layernorm(x, s, b, interpret=False),

@@ -212,3 +212,175 @@ def test_flash_block_config_reaches_kernel():
                                rtol=2e-5, atol=2e-5)
     with pytest.raises(ValueError, match="not divisible"):
         mk(flash_block_k=24).apply(params, ids)
+
+
+# ---- bf16 operands (PR 27): the kernels take q/k/v as they arrive --------
+
+def _dense_masked(q, k, v, mode):
+    """``attention_reference``'s arithmetic (operands as they arrive, f32
+    scores and softmax, probs cast for the values product) under any of the
+    kernels' three masks; a row with no key gives 0, as the kernels do."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    pos = jnp.arange(t)
+    keep = {"none": jnp.ones((t, t), bool),
+            "causal": pos[None, :] <= pos[:, None],
+            "causal_exclusive": pos[None, :] < pos[:, None]}[mode]
+    s = jnp.where(keep[None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1) * keep.any(-1)[None, None, :, None]
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (16, 32), (64, 16)])
+@pytest.mark.parametrize("mode", ["causal", "none", "causal_exclusive"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_bf16_matches_dense_bf16(head_dim, mode, blocks):
+    """Forward and dq/dk/dv on bf16 inputs against the dense path on the
+    SAME bf16 inputs, at the tolerance two bf16 paths owe each other: both
+    round their operands' products to f32 sums in another order and cast
+    probabilities to bf16 (2^-8 relative) for the values product."""
+    rng = np.random.default_rng(head_dim + len(mode))
+    q, k, v, w = (jnp.asarray(rng.standard_normal((2, 64, 2, head_dim)),
+                              jnp.bfloat16) for _ in range(4))
+    bq, bk = blocks
+
+    def flash(q_, k_, v_):
+        return pk.flash_attention_with_lse(q_, k_, v_, True, bq, bk, True,
+                                           mask_mode=mode)[0]
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32)
+                                  * w.astype(jnp.float32))
+
+    got = flash(q, k, v)
+    want = _dense_masked(q, k, v, mode)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+    g_got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.grad(loss(lambda *a: _dense_masked(*a, mode)),
+                      argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g_got, g_want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape and np.isfinite(a).all()
+        # against the gradient's own size: single entries of a bf16
+        # gradient carry the rounding of the whole row's sum
+        assert np.abs(a - b).max() <= 3e-2 * np.abs(b).max(), name
+
+
+def _kernel_dots(jaxpr, inside=False):
+    """(lhs dtype, rhs dtype) of every ``dot_general`` inside a
+    ``pallas_call`` of ``jaxpr``, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        kernel = inside or eqn.primitive.name == "pallas_call"
+        if inside and eqn.primitive.name == "dot_general":
+            found.append(tuple(v.aval.dtype for v in eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_dots(sub, kernel)
+    return found
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_kernels_feed_the_mxu_bf16(head_dim):
+    """The guard against the upcast coming back: with bf16 inputs no
+    product inside the three kernels has an f32 operand (head_dim 64 folds
+    its power-of-two scale into the operand, 128 scales the f32 scores),
+    and every product accumulates in f32."""
+    x = jnp.zeros((1, 64, 2, head_dim), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out, lse = pk.flash_attention_with_lse(q, k, v, True, 32, 32, True)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    dots = _kernel_dots(jaxpr.jaxpr)
+    # fwd 2, dq 3, dkv 4 products, in two loops each (off and on the
+    # diagonal), per head of the 128-lane column (two of width 64 share one)
+    assert len(dots) == 2 * (2 + 3 + 4) * (128 // head_dim)
+    assert set(dots) == {(jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16))}
+    # and f32 inputs stay f32 products (what the CPU tests feed)
+    x32 = x.astype(jnp.float32)
+    dots32 = _kernel_dots(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(x32, x32, x32).jaxpr)
+    assert set(dots32) == {(jnp.dtype(jnp.float32), jnp.dtype(jnp.float32))}
+
+
+@pytest.mark.parametrize("key,swept", sorted(pk.FLASH_BLOCKS.items()))
+def test_derived_blocks_follow_the_swept_table(key, swept):
+    """For each timed row: the derived tiling at the row's smallest T is
+    the swept one and divides T; shorter T clamps; an explicit
+    flash_block_q/k still wins; an untimed shape keeps 128 x 128."""
+    from neural_networks_parallel_training_with_mpi_tpu.parallel.sequence import (
+        AUTO_FLASH_MIN_SEQ,
+    )
+
+    head_dim, dtype = key
+    t = AUTO_FLASH_MIN_SEQ[("tpu", head_dim, dtype)]
+    bq, bk = pk.flash_blocks(t, head_dim, dtype)
+    assert (bq, bk) == swept and t % bq == 0 and t % bk == 0
+    assert pk.flash_blocks(4 * t, head_dim, dtype) == swept
+    assert pk.flash_blocks(64, head_dim, dtype) == (64, 64)
+    assert pk.flash_blocks(t + 128, head_dim, dtype) is None
+    assert pk.flash_blocks(t, head_dim, dtype, 128, 256) == (128, 256)
+    assert pk.flash_blocks(t, head_dim, dtype, None, 256) == (bq, 256)
+    assert pk.flash_blocks(t, 96, dtype) == (128, 128)
+    assert pk.flash_blocks(t, head_dim, "float32") == (128, 128)
+
+
+def test_flash_default_blocks_are_none():
+    from neural_networks_parallel_training_with_mpi_tpu.models.transformer import (
+        TransformerConfig,
+    )
+
+    c = TransformerConfig(vocab_size=8)
+    assert c.flash_block_q is None and c.flash_block_k is None
+
+
+@pytest.mark.parametrize("heads,head_dim,want", [
+    (16, 64, (2, True)),        # gpt2-medium: two heads a 128-lane column
+    (24, 128, (1, True)),       # starcoder2-3b: one head a column
+    (4, 256, (1, True)),
+    (8, 16, (8, True)),
+    (2, 16, (1, False)),        # 8 heads a column, 2 do not fill one
+    (3, 64, (1, False)),
+    (4, 96, (1, False)),        # 96 neither divides 128 nor is a multiple
+])
+def test_fold_plan(heads, head_dim, want):
+    assert pk._fold_plan(heads, head_dim) == want
+
+
+@pytest.mark.parametrize("mode", ["causal", "none", "causal_exclusive"])
+def test_heads_on_lanes_equal_heads_major(mode, monkeypatch):
+    """Heads that share a 128-lane column (four of width 32 here), told
+    apart by lane masks, give what one head a program over the heads-major
+    layout gives: outputs, lse and all three gradients."""
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 64, 4, 32)), jnp.float32)
+               for _ in range(3))
+    assert pk._fold_plan(4, 32) == (4, True)
+
+    def run():
+        def loss(q_, k_, v_):
+            o, l = pk.flash_attention_with_lse(q_, k_, v_, True, 32, 16,
+                                               True, mask_mode=mode)
+            live = l > -1e29
+            return (o ** 2).sum() + jnp.where(live, jnp.sin(l), 0.0).sum()
+
+        out = pk.flash_attention_with_lse(q, k, v, True, 32, 16, True,
+                                          mask_mode=mode)
+        return (*out, *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+    folded = run()
+    monkeypatch.setattr(pk, "_fold_plan", lambda h, d: (1, False))
+    jax.clear_caches()          # the jitted calls keyed the folded plan
+    try:
+        major = run()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), folded, major):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
