@@ -3,6 +3,8 @@ check, the profiler window, and the lines a run prints."""
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import sys
 import time
@@ -21,19 +23,50 @@ def _load(kind: str, name: str, bench: Path) -> dict:
     return json.loads((bench / kind / f"{name}.json").read_text())
 
 
-def model_of(config: dict) -> dict:
+# model keys the harness itself reads, whatever the family
+HARNESS_KEYS = ("vocab_size", "n_layers", "param_dtype")
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str, bench: Path = BENCH):
+    """The module ``families/<name>.py`` under ``bench``: what the benchmark
+    knows of one kind of block."""
+    path = bench / "families" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown family {name!r}: there is no "
+                         f"families/{name}.py under {bench}")
+    importlib.import_module("benchmark.families")   # its relative imports
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark.families.{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def model_of(config: dict, bench: Path = BENCH) -> dict:
     """The flat model dict a configuration's ``mapping`` spells: a value
-    ``"$key"`` is read from the published keys, anything else is itself."""
-    pub = config["published"]
-    return {k: pub[v[1:]] if isinstance(v, str) and v.startswith("$") else v
-            for k, v in config["mapping"].items()}
+    ``"$key"`` is read from the published keys, anything else is itself.
+    ``family`` (the module the configuration names) and ``config`` (its
+    name) ride along, so that whoever holds the dict can ask the family."""
+    if "family" not in config:
+        raise ValueError(f"configuration {config['name']!r} names no family")
+    family = load_family(config["family"], bench)
+    mapping, pub = config["mapping"], config["published"]
+    for key in (*HARNESS_KEYS, *family.MODEL_KEYS):
+        if key not in mapping:
+            raise ValueError(
+                f"configuration {config['name']!r}: its mapping lacks "
+                f"{key!r}, which family {config['family']!r} declares")
+    model = {k: pub[v[1:]] if isinstance(v, str) and v.startswith("$") else v
+             for k, v in mapping.items()}
+    return {**model, "family": family, "config": config["name"]}
 
 
 def load_cell(name: str, bench: Path = BENCH) -> dict:
     """A cell by name: its own file, its configuration's and its traffic's."""
     cell = _load("workloads", name, bench)
     config = _load("configs", cell["config"], bench)
-    return {**cell, "config_file": config, "model": model_of(config),
+    return {**cell, "config_file": config, "model": model_of(config, bench),
             "job": _load("traffic", cell["traffic"], bench)}
 
 

@@ -21,7 +21,7 @@ import time
 
 import numpy as np
 
-from . import check, common, program, weights
+from . import check, common, weights
 
 
 def draw_lengths(spec: dict, n: int, rng) -> np.ndarray:
@@ -73,9 +73,6 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, dev: dict,
         reference_kwargs=None) -> dict:
     import jax
 
-    from neural_networks_parallel_training_with_mpi_tpu.models import (
-        Transformer,
-    )
     from neural_networks_parallel_training_with_mpi_tpu.serve import (
         Scheduler, ServeConfig, prewarm,
     )
@@ -91,9 +88,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, dev: dict,
     model, job = cell["model"], cell["job"]
     shutil.rmtree(out_dir / "serve_trace", ignore_errors=True)
     out_dir.mkdir(parents=True, exist_ok=True)
-    net = Transformer(program.transformer_config(model))
+    fam = model["family"]
+    net = fam.program_model(model)
     maker = weights.Maker(model, seed)
-    params = program.to_program(maker.outer(), maker.layers())
+    params = fam.to_program(model, maker.outer(), maker.layers())
     jax.block_until_ready(params)
     common.mark("weights on the device")
     serve_cfg = dict(job["serve_config"], seed=int(seed) & 0x7FFFFFFF)

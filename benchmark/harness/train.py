@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from . import check, common, program, weights
+from . import check, common, weights
 
 
 def make_tokens(seed: int, rows: int, seq_len: int, vocab: int) -> dict:
@@ -47,7 +47,7 @@ class FitDriver:
         self.ledger = self.compiles_at_t0 = None
         self.stopped = False
 
-    # -- small jitted reads of the state, by the benchmark's flat names ------
+    # -- small jitted reads of the state, by the family's leaf names ----------
     def _leaf_sq(self, tree):
         import jax
         import jax.numpy as jnp
@@ -55,7 +55,7 @@ class FitDriver:
         model = self.cell["model"]
         return jax.jit(lambda t: {
             n: (x.astype(jnp.float32) ** 2).sum()
-            for n, x in program.flat_names(model, t).items()})(tree)
+            for n, x in flat_names(model, t).items()})(tree)
 
     def _change_sq(self, params):
         """Squared norm of every leaf's change from the start, which is made
@@ -64,19 +64,20 @@ class FitDriver:
         import jax.numpy as jnp
 
         model = self.cell["model"]
+        fam = model["family"]
         maker = weights.Maker(model, self.seed,
                               jax.tree_util.tree_leaves(params)[0].sharding)
         sq = lambda a, b: {n: ((a[n].astype(jnp.float32)        # noqa: E731
                                 - b[n].astype(jnp.float32)) ** 2).sum()
                            for n in a}
-        layer = jax.jit(lambda a, b: sq(program.layer_leaves(model, a),
-                                        program.layer_leaves(model, b)))
-        outer = jax.jit(lambda a, b: sq(program.outer_leaves(a),
-                                        program.outer_leaves(b)))
-        out = outer({k: v for k, v in params.items() if k != "blocks"},
-                    program.to_program_outer(maker.outer()))
-        for i, blk in enumerate(params["blocks"]):
-            mine = layer(blk, program.to_program_layer(maker.layer(i)))
+        layer = jax.jit(lambda a, b: sq(fam.layer_leaves(model, a),
+                                        fam.layer_leaves(model, b)))
+        outer = jax.jit(lambda a, b: sq(fam.outer_leaves(model, a),
+                                        fam.outer_leaves(model, b)))
+        mine_outer, mine_layers = fam.split_program(model, params)
+        out = outer(mine_outer, fam.to_program_outer(model, maker.outer()))
+        for i, blk in enumerate(mine_layers):
+            mine = layer(blk, fam.to_program_layer(model, maker.layer(i), i))
             out.update({f"L{i}.{n}": x for n, x in mine.items()})
         return out
 
@@ -139,6 +140,18 @@ class FitDriver:
             self._stop()
 
 
+def flat_names(model: dict, tree) -> dict:
+    """A tree shaped like the program's parameters -> {leaf name: leaf}, with
+    the names ``reference.train.leaf_norms`` gives (``L3.attn_out.w``)."""
+    fam = model["family"]
+    outer, layers = fam.split_program(model, tree)
+    out = dict(fam.outer_leaves(model, outer))
+    for i, blk in enumerate(layers):
+        out.update({f"L{i}.{n}": x
+                    for n, x in fam.layer_leaves(model, blk).items()})
+    return out
+
+
 def build_trainer(cell: dict, seed: int, devices, out_dir, extra_flags=()):
     """The trainer as a user's command line builds it, with the benchmark's
     tokens and weights in place of the program's own."""
@@ -161,7 +174,8 @@ def build_trainer(cell: dict, seed: int, devices, out_dir, extra_flags=()):
     )
 
     model, job = cell["model"], cell["job"]
-    flags = program.train_flags(model, job, seed, out_dir) + list(extra_flags)
+    fam = model["family"]
+    flags = fam.train_flags(model, job, seed, out_dir) + list(extra_flags)
     cfg = config_from_args(build_argparser().parse_args(flags))
     mesh = make_mesh(MeshConfig(data=len(devices)), devices=list(devices))
     data = make_tokens(seed, job["global_batch"] * job["steps_of_data"],
@@ -170,7 +184,7 @@ def build_trainer(cell: dict, seed: int, devices, out_dir, extra_flags=()):
     common.mark("trainer built")
     replicated = NamedSharding(mesh, PartitionSpec())
     maker = weights.Maker(model, seed, replicated)
-    params = program.to_program(maker.outer(), maker.layers())
+    params = fam.to_program(model, maker.outer(), maker.layers())
     opt_state = jax.jit(trainer.optimizer.init,
                         out_shardings=replicated)(params)
     trainer.state = jax.device_put(

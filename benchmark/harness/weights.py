@@ -2,14 +2,15 @@
 
 The program under test and the plain reference both get their weights from
 here, so neither takes anything the other has made.  A ``Maker`` builds one
-layer (or the embeddings, final norm and head) per jitted call on the device,
-in the type the configuration stores them in: one call a layer, which is also
-how the reference walks a model that does not fit the chip in float32.
+layer (or the outer part: embeddings, final norm and head) per jitted call on
+the device, in the type the configuration stores them in: one call a layer,
+which is also how the reference walks a model that does not fit the chip in
+float32.
 
-Tensors are named flat: ``embed``, ``pos`` (learned positions only), ``ln_f.scale``,
-``ln_f.bias``, ``head.w`` outside the layers and ``ln1.scale`` ... ``ff_out.b``
-inside one.  Matrices are stored ``(in, out)``; the fused qkv projection is laid
-out ``[q | k | v]`` with heads contiguous.
+Which tensors a model has, their shapes and how each is initialised is its
+family's to say (``benchmark/families/<family>.py``: ``outer_shapes``,
+``layer_shapes``, ``init_tensor``); the key each tensor is drawn with follows
+its place in those lists.
 """
 
 from __future__ import annotations
@@ -19,40 +20,33 @@ import math
 import jax
 import jax.numpy as jnp
 
-OUTER = ("embed", "pos", "ln_f.scale", "ln_f.bias", "head.w")
-LAYER = ("ln1.scale", "ln1.bias", "qkv.w", "qkv.b", "attn_out.w",
-         "attn_out.b", "ln2.scale", "ln2.bias", "ff_in.w", "ff_in.b",
-         "ff_out.w", "ff_out.b")
-
-
-def shapes(model: dict) -> dict:
-    """name -> shape for every tensor of one layer and of the outer part."""
-    d, ff, v = model["d_model"], model["d_ff"], model["vocab_size"]
-    hd = d // model["n_heads"]
-    qkv = d + 2 * model["n_kv_heads"] * hd
-    out = {"embed": (v, d), "ln_f.scale": (d,), "ln_f.bias": (d,),
-           "head.w": (d, v),
-           "ln1.scale": (d,), "ln1.bias": (d,), "qkv.w": (d, qkv),
-           "qkv.b": (qkv,), "attn_out.w": (d, d), "attn_out.b": (d,),
-           "ln2.scale": (d,), "ln2.bias": (d,), "ff_in.w": (d, ff),
-           "ff_in.b": (ff,), "ff_out.w": (ff, d), "ff_out.b": (d,)}
-    if model["pos_encoding"] == "learned":
-        out["pos"] = (model["max_seq_len"], d)
-    return out
-
-
-def split_qkv(model: dict, x) -> dict:
-    """The q, k and v columns of a fused qkv tensor (last axis)."""
-    d = model["d_model"]
-    kvw = model["n_kv_heads"] * (d // model["n_heads"])
-    return {"q": x[..., :d], "k": x[..., d:d + kvw], "v": x[..., d + kvw:]}
-
 
 def n_params(model: dict) -> int:
-    s = shapes(model)
-    per_layer = sum(math.prod(s[n]) for n in LAYER)
-    outer = sum(math.prod(s[n]) for n in OUTER if n in s)
-    return model["n_layers"] * per_layer + outer
+    fam = model["family"]
+    groups = [fam.outer_shapes(model)] + [
+        fam.layer_shapes(model, i) for i in range(model["n_layers"])]
+    return sum(math.prod(s) for g in groups for s in g.values()
+               if s is not None)
+
+
+def layer_kind(model: dict, i: int) -> tuple:
+    """Layer ``i``'s tensors, names and shapes in order, as a hashable: layers
+    of one kind are made by one program and walked by one ``scan``."""
+    return tuple(model["family"].layer_shapes(model, i).items())
+
+
+def layer_runs(model: dict) -> list:
+    """[(first, count)]: the layers as runs of neighbours of one kind (one run
+    where every layer is alike)."""
+    runs, last = [], None
+    for i in range(model["n_layers"]):
+        kind = layer_kind(model, i)
+        if kind == last:
+            runs[-1][1] += 1
+        else:
+            runs.append([i, 1])
+        last = kind
+    return [tuple(r) for r in runs]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -63,48 +57,40 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, seed >> 31)
 
 
-def _tensor(model: dict, key, name: str, shape, dtype):
-    kind = name.rsplit(".", 1)[-1]
-    if name in ("embed", "pos"):
-        x = jax.random.normal(key, shape, jnp.float32)
-    elif kind == "scale":
-        x = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
-    elif kind == "bias":
-        x = 0.1 * jax.random.normal(key, shape, jnp.float32)
-    else:   # a matrix (in, out) or its bias (out,): +-1/sqrt(fan_in)
-        fan_in = (shape[0] if kind == "w" else
-                  model["d_ff"] if name == "ff_out.b" else model["d_model"])
-        bound = 1.0 / math.sqrt(fan_in)
-        x = jax.random.uniform(key, shape, jnp.float32, -bound, bound)
-    return x.astype(dtype)
-
-
-def _group(model: dict, key, names) -> dict:
-    s = shapes(model)
+def _group(model: dict, key, shapes: dict) -> dict:
+    """One tensor a name, each from the key folded with its place in
+    ``shapes``; a name with shape None keeps its place and makes nothing."""
     dtype = jnp.dtype(model["param_dtype"])
-    return {n: _tensor(model, jax.random.fold_in(key, i), n, s[n], dtype)
-            for i, n in enumerate(names) if n in s}
+    init = model["family"].init_tensor
+    return {n: init(model, jax.random.fold_in(key, i), n, s, dtype)
+            for i, (n, s) in enumerate(shapes.items()) if s is not None}
 
 
 class Maker:
     """The weights of one model from one seed.  The key and the layer's index
-    are traced arguments, so the two small programs compile once for a model
-    and are found in the compile cache whatever the seed."""
+    are traced arguments, so the small programs (one for the outer part, one
+    for each kind of layer the family has) compile once for a model and are
+    found in the compile cache whatever the seed."""
 
     def __init__(self, model: dict, seed: int, sharding=None):
-        self.model, self.key = model, seed_key(seed)
+        self.model, self.key, self.sharding = model, seed_key(seed), sharding
+        outer = model["family"].outer_shapes(model)
         self._outer = jax.jit(
-            lambda key: _group(model, jax.random.fold_in(key, 1 << 20), OUTER),
+            lambda key: _group(model, jax.random.fold_in(key, 1 << 20), outer),
             out_shardings=sharding)
-        self._layer = jax.jit(
-            lambda key, i: _group(model, jax.random.fold_in(key, i), LAYER),
-            out_shardings=sharding)
+        self._layers = {}       # a layer's kind -> the program that makes it
 
     def outer(self) -> dict:
         return self._outer(self.key)
 
     def layer(self, i: int) -> dict:
-        return self._layer(self.key, i)
+        model, kind = self.model, layer_kind(self.model, i)
+        if kind not in self._layers:
+            self._layers[kind] = jax.jit(
+                lambda key, i: _group(model, jax.random.fold_in(key, i),
+                                      dict(kind)),
+                out_shardings=self.sharding)
+        return self._layers[kind](self.key, i)
 
     def layers(self) -> list:
         return [self.layer(i) for i in range(self.model["n_layers"])]

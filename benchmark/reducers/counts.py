@@ -1,28 +1,38 @@
 """Operations and bytes, counted from shapes by the benchmark (never by the
-program's own ``fwd_flops``), and the shares of the chip's peak they give."""
+program's own ``fwd_flops``), and the shares of the chip's peak they give.
+
+The formulas' outer structure is here and the same for every model; the terms
+that depend on the kind of block (which parameters meet every token in a
+matrix product, what attention costs at a context, which weights a decode tick
+reads, what a cached token holds) are its family's, reached through the model
+dict (``model["family"]``, ``benchmark/families/<family>.py``)."""
 
 from __future__ import annotations
 
-from ..harness import common, weights
+import numpy as np
+
+from ..harness import common
 
 BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 
 
+def dtype_bytes(dtype: str) -> int:
+    if dtype not in BYTES:
+        raise ValueError(f"dtype {dtype!r} has no byte count in counts.BYTES "
+                         f"({', '.join(BYTES)})")
+    return BYTES[dtype]
+
+
 def matmul_params(model: dict) -> int:
-    """Parameters that take part in a matrix product for every token: the
-    layers' four projections and the head (not the embedding tables, which
-    are looked up, nor norms and biases)."""
-    s = weights.shapes(model)
-    per_layer = sum(s[n][0] * s[n][1]
-                    for n in ("qkv.w", "attn_out.w", "ff_in.w", "ff_out.w"))
-    return model["n_layers"] * per_layer + s["head.w"][0] * s["head.w"][1]
+    """Parameters that take part in a matrix product for every token."""
+    return model["family"].matmul_params(model)
 
 
 def forward_flops_per_token(model: dict, context: float) -> float:
     """One token's forward pass attending ``context`` keys: 2 per
     multiply-add in the projections, and scores plus values in attention."""
-    attn = 4.0 * model["n_layers"] * model["d_model"] * context
-    return 2.0 * matmul_params(model) + attn
+    return (2.0 * matmul_params(model)
+            + model["family"].attention_flops(model, context))
 
 
 def train_flops_per_token(model: dict, seq_len: int) -> float:
@@ -36,23 +46,20 @@ def request_flops(model: dict, prompt: int, output: int) -> float:
     """Prefill of ``prompt`` tokens (causal: half the square) and ``output``
     decoded tokens, each attending everything before it."""
     n = prompt + output
-    attn = 4.0 * model["n_layers"] * model["d_model"] * (n * (n + 1) / 2.0)
-    return 2.0 * matmul_params(model) * n + attn
+    attn = model["family"].attention_flops(model, np.arange(1, n + 1)).sum()
+    return 2.0 * matmul_params(model) * n + float(attn)
 
 
-def weight_bytes(model: dict) -> int:
-    """Bytes a decode tick has to read of the weights: everything but the
-    embedding tables (of which it reads one row a stream)."""
-    s = weights.shapes(model)
-    tables = s["embed"][0] * s["embed"][1] + (
-        s["pos"][0] * s["pos"][1] if "pos" in s else 0)
-    return (weights.n_params(model) - tables) * BYTES[model["param_dtype"]]
+def weight_bytes(model: dict, obs=None) -> int:
+    """Bytes a decode tick has to read of the weights; ``obs`` is what the
+    harness observed in the window, for a family whose ticks read only the
+    weights their tokens reached."""
+    return model["family"].decode_weight_bytes(model, obs)
 
 
 def kv_bytes_per_token(model: dict) -> int:
-    hd = model["d_model"] // model["n_heads"]
-    return (2 * model["n_layers"] * model["n_kv_heads"] * hd
-            * BYTES[model["compute_dtype"]])
+    """Bytes of cache one token holds, all layers."""
+    return model["family"].cache_bytes_per_token(model)
 
 
 # ---- reducers (obs, cell, dev, **args) -> value or None ---------------------
@@ -74,7 +81,7 @@ def mfu_serve(obs, cell, dev):
 
 
 def decode_hbm_share(obs, cell, dev, module):
-    """Bytes one decode tick must read (weights once, live K and V of the
+    """Bytes one decode tick must read (weights once, the live cache of the
     decoding streams) over the peak bandwidth, against the tick's measured
     device time."""
     from . import xplane
@@ -83,7 +90,7 @@ def decode_hbm_share(obs, cell, dev, module):
     if ms is None or not obs["decode_ticks"]:
         return None
     live = obs["attended_keys"] / obs["decode_ticks"]
-    need = (weight_bytes(cell["model"])
+    need = (weight_bytes(cell["model"], obs)
             + live * kv_bytes_per_token(cell["model"]))
     least_ms = need / common.peaks(dev["kind"])["hbm_bytes_per_s"] * 1e3
     return 100.0 * least_ms / ms

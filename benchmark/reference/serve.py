@@ -3,7 +3,8 @@
 One full forward pass in float32 over each prompt with its served tokens (no
 cache, causal, so right-padding is harmless).  A 3-billion-parameter model does
 not fit the chip in float32, so the weights of one layer at a time are made from
-the seed, used for every sequence and dropped.
+the seed, used for every sequence and dropped.  The walk is here; ``embed``,
+``block`` and ``head_logits`` are the model's family's.
 """
 
 from __future__ import annotations
@@ -15,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..harness import weights
-from . import transformer as tf
 
 F32 = jnp.float32
 
@@ -35,18 +35,20 @@ def generated_logits(model: dict, seed: int, seqs, prompt_lens, *,
         ids[i, :len(s)] = s
         for t in range(p, len(s)):          # token t is predicted at t - 1
             rows.append(i), cols.append(t - 1), toks.append(s[t])
+    fam = model["family"]
     with jax.default_matmul_precision("highest"):
         maker = weights.Maker(model, seed)
         outer = maker.outer()
-        embed = jax.jit(functools.partial(tf.embed, model))
+        embed = jax.jit(functools.partial(fam.embed, model))
         xs = [embed(outer, ids[i:i + 1]) for i in range(len(seqs))]
         to_f32 = jax.jit(lambda p: {k: v.astype(F32) for k, v in p.items()})
-        layer = jax.jit(lambda p, x: tf.block(model, p, x, quant),
+        # the layer's index is traced: one program for every layer of a shape
+        layer = jax.jit(lambda p, x, i: fam.block(model, p, x, i, quant),
                         donate_argnums=(1,))
         for i in range(model["n_layers"]):
             p = to_f32(maker.layer(i))
-            xs = [layer(p, x) for x in xs]      # one sequence at a time
+            xs = [layer(p, x, i) for x in xs]   # one sequence at a time
         picked = jnp.stack([xs[r][0, c] for r, c in zip(rows, cols)])
-        logits = jax.jit(lambda o, h: tf.head_logits(model, o, h, quant))(
+        logits = jax.jit(lambda o, h: fam.head_logits(model, o, h, quant))(
             outer, picked)
     return logits, np.asarray(toks, np.int32)

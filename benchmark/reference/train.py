@@ -5,6 +5,8 @@ Next-token cross-entropy, mean over every token of the batch, its gradient by
 correction).  The batch is walked in blocks of rows and each layer is
 recomputed in the backward pass, so that the whole thing fits one chip beside
 nothing else; neither changes the arithmetic beyond the order of float32 sums.
+The walk, the optimizer and the norms are here; ``embed``, ``block``,
+``head_logits`` and the names of the leaves are the model's family's.
 
 ``fault`` plants what a broken data-parallel step would do, for the readings
 that set the limits in the cells' files (never used in a benchmark run):
@@ -21,27 +23,32 @@ import jax
 import jax.numpy as jnp
 
 from ..harness import weights
-from . import transformer as tf
 
 F32 = jnp.float32
 
 
 def init_params(model: dict, seed: int):
-    """{'outer': {...}, 'layers': {name: (L, ...)}} in float32."""
+    """{'outer': {...}, 'layers': [{name: (count, ...)} a run]} in float32."""
     maker = weights.Maker(model, seed)
     per = maker.layers()
+    runs = weights.layer_runs(model)
     stack = jax.jit(lambda outer, per: {
         "outer": {k: v.astype(F32) for k, v in outer.items()},
-        "layers": {n: jnp.stack([p[n] for p in per]).astype(F32)
-                   for n in per[0]}})
+        "layers": [{n: jnp.stack([p[n] for p in per[a:a + c]]).astype(F32)
+                    for n in per[a]} for a, c in runs]})
     return stack(maker.outer(), per)
 
 
 def nll_sum(model, params, ids, labels):
-    x = tf.embed(model, params["outer"], ids)
-    body = jax.checkpoint(lambda x, p: (tf.block(model, p, x), None))
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    logits = tf.head_logits(model, params["outer"], x)
+    fam = model["family"]
+    x = fam.embed(model, params["outer"], ids)
+    body = jax.checkpoint(
+        lambda x, pi: (fam.block(model, pi[0], x, pi[1]), None))
+    for (first, count), stacked in zip(weights.layer_runs(model),
+                                       params["layers"]):
+        x, _ = jax.lax.scan(body, x,
+                            (stacked, first + jnp.arange(count)))
+    logits = fam.head_logits(model, params["outer"], x)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return (logz - gold).sum()
@@ -63,18 +70,18 @@ def adamw(params, grads, m, v, t, opt):
 
 
 def leaf_norms(model, tree) -> dict:
-    """Flat {name: norm}; a stacked layer tensor gives one norm per layer,
-    and the fused qkv tensors one each for their q, k and v columns."""
+    """Flat {name: norm} over the leaves the model's family names (it may
+    split a fused tensor into the published model's own); a stacked layer
+    tensor gives one norm per layer, ``L<i>.<leaf>``."""
+    fam = model["family"]
     out = {n: jnp.sqrt((x.astype(F32) ** 2).sum())
-           for n, x in tree["outer"].items()}
-    for n, x in tree["layers"].items():
-        base, part = n.rsplit(".", 1)
-        pieces = ({f"{m}.{part}": y
-                   for m, y in weights.split_qkv(model, x).items()}
-                  if base == "qkv" else {n: x})
-        for name, y in pieces.items():
-            per = jnp.sqrt((y ** 2).reshape(y.shape[0], -1).sum(-1))
-            out.update({f"L{i}.{name}": per[i] for i in range(y.shape[0])})
+           for n, x in fam.leaves(model, tree["outer"]).items()}
+    for (first, count), stacked in zip(weights.layer_runs(model),
+                                       tree["layers"]):
+        for name, y in fam.leaves(model, stacked).items():
+            per = jnp.sqrt((y ** 2).reshape(count, -1).sum(-1))
+            out.update({f"L{first + i}.{name}": per[i]
+                        for i in range(count)})
     return out
 
 

@@ -3,8 +3,8 @@
 Run with ``python -m pytest benchmark/tests -q`` (not part of tier-1).  The
 toys live in ``tests/data`` as the same kinds of file a real cell is made of;
 ``bench_dir`` overlays them on a temporary copy of ``benchmark/``, which is
-also the proof that a cell, a configuration, a mix and a metric are added as
-files, with no edit to a file that is there.
+also the proof that a cell, a configuration, a mix, a metric and a family are
+added as files, with no edit to a file that is there.
 """
 
 import os
@@ -28,11 +28,13 @@ if str(ROOT) not in sys.path:
 @pytest.fixture(scope="session")
 def bench_dir(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("bench")
-    for sub in ("configs", "traffic", "workloads", "metrics", "reducers"):
-        shutil.copytree(BENCH / sub, tmp / sub)
+    for sub in ("configs", "traffic", "workloads", "metrics", "reducers",
+                "families"):
+        shutil.copytree(BENCH / sub, tmp / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
         overlay = BENCH / "tests" / "data" / sub
         if overlay.is_dir():
-            for f in overlay.iterdir():
+            for f in (f for f in overlay.iterdir() if f.is_file()):
                 assert not (tmp / sub / f.name).exists(), f"{f.name} edits"
                 shutil.copy(f, tmp / sub / f.name)
     return tmp

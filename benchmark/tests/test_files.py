@@ -68,14 +68,16 @@ def test_manifest_agrees_with_the_files():
     for c in MANIFEST["configs"]:
         f = json.loads((ROOT / c["file"]).read_text())
         assert (f["source"], f["reduced"]) == (c["source"], c["reduced"])
+        assert set(common.load_family(f["family"]).MODEL_KEYS) \
+            <= set(f["mapping"])
     four = [w for w in cells.values() if w["chips"] == 4]
     assert len(four) <= max(1, len(cells) // 4)
 
 
 def test_a_cell_is_added_as_files_alone(bench_dir):
-    """The toys of tests/data are a configuration, a mix, a cell and a metric
-    that ``benchmark/`` has not: ``bench_dir`` adds them without touching a
-    file that is there, and the harness finds each by its name."""
+    """The toys of tests/data are a configuration, a mix, a cell, a metric
+    and a family that ``benchmark/`` has not: ``bench_dir`` adds them without
+    touching a file that is there, and the harness finds each by its name."""
     from benchmark.harness import common
 
     cell = common.load_cell("tiny-train", bench_dir)
@@ -84,6 +86,10 @@ def test_a_cell_is_added_as_files_alone(bench_dir):
         == {"span": "fetch"}
     assert common.load_cell("gpt2m-train-b4", bench_dir)["model"]["d_model"] \
         == 1024
+    switch = common.load_cell("tiny-switch-serve", bench_dir)["model"]
+    assert switch["family"].__name__ == "benchmark.families.switch_toy"
+    assert "n_experts" in switch["family"].MODEL_KEYS
+    assert not (BENCH / "families" / "switch_toy.py").exists()
 
 
 def test_unknown_device_has_no_peak():

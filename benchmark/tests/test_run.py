@@ -138,8 +138,9 @@ def _alter_a_token(sched):
     sched.server.result = broken
 
 
-def test_an_altered_token_is_not_correct(run_cell):
-    res = run_cell("tiny-serve", seconds=1.0, tamper=_alter_a_token)[2]
+@pytest.mark.parametrize("cell", ["tiny-serve", "tiny-switch-serve"])
+def test_an_altered_token_is_not_correct(run_cell, cell):
+    res = run_cell(cell, seconds=1.0, tamper=_alter_a_token)[2]
     assert not res["correct"] and names(res, False) == ["served_gap_mean_sigma"]
 
 
@@ -151,7 +152,7 @@ def test_control_fp8_serving_is_not_correct(run_cell):
 
     from benchmark.harness import check
     from benchmark.reference import serve as ref_serve
-    from benchmark.reference import transformer as tf
+    from benchmark.reference import control
 
     cell, _dev, res = run_cell("tiny-serve", seconds=1.0)
     assert res["correct"]
@@ -160,7 +161,7 @@ def test_control_fp8_serving_is_not_correct(run_cell):
     ref, _ = ref_serve.generated_logits(cell["model"], 7, seqs, plens,
                                         pad_to=16)
     low, _ = ref_serve.generated_logits(cell["model"], 7, seqs, plens,
-                                        pad_to=16, quant=tf.fp8_cast)
+                                        pad_to=16, quant=control.fp8_cast)
     gaps = check.served_gap(ref, jax.device_get(low.argmax(-1)))
     verdict = check.serve_checks(0, gaps, cell["limits"])
     assert [c["name"] for c in verdict if not c["ok"]] \
