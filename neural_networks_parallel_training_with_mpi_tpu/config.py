@@ -179,6 +179,27 @@ class ModelConfig:
     # tokens over capacity fall through the residual (models/moe.py)
     moe_capacity_factor: float = 1.25
     moe_top_k: int = 1  # 1 = Switch; 2 = GShard-style top-2 routing
+    # The block's other kinds (models.transformer.TransformerConfig has the
+    # meanings): the norm and its eps, projections and feed-forward
+    # without biases, latent attention with its five sizes and the
+    # long-context rotary, routing without drops over the experts held
+    # here beside a shared expert.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    use_bias: bool = True
+    rope_theta: float = 10000.0
+    attention_kind: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim, query_scale_beta), or () for plain rotary
+    rope_scaling: Tuple[float, ...] = ()
+    moe_dropless: bool = False
+    moe_experts_held: Tuple[int, ...] = ()     # (first, count); () = all
+    moe_shared_ff: int = 0
     # transformer: fused chunked cross-entropy — evaluate LM head + CE
     # ce_chunk tokens at a time under jax.checkpoint so the (B, T, vocab)
     # f32 logits tensor is never materialized (0 = off).  Loss math is
@@ -724,6 +745,33 @@ def build_argparser() -> argparse.ArgumentParser:
                    "independent of depth; plain DP/SP paths)")
     p.add_argument("--moe_top_k", type=int, default=1,
                    help="experts per token: 1 = Switch, 2 = GShard top-2")
+    p.add_argument("--norm", choices=["layernorm", "rmsnorm"],
+                   default="layernorm",
+                   help="the transformer's norm (rmsnorm: no mean, no bias)")
+    p.add_argument("--norm_eps", type=float, default=1e-5)
+    _add_bool_flag(p, "bias", True,
+                   "biases on the transformer's projections and "
+                   "feed-forward (--no-bias takes them off)")
+    p.add_argument("--rope_theta", type=float, default=10000.0)
+    p.add_argument("--attention_kind", choices=["mha", "mla"], default="mha",
+                   help="mla = latent attention (models/mla.py), with the "
+                        "five sizes below")
+    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim"):
+        p.add_argument(f"--{name}", type=int, default=0)
+    p.add_argument("--rope_scaling", type=str, default="",
+                   help="YaRN and the query scale by position, seven "
+                        "comma-separated numbers: factor, original max "
+                        "positions, beta_fast, beta_slow, mscale, "
+                        "mscale_all_dim, query_scale_beta")
+    _add_bool_flag(p, "moe-dropless", False,
+                   "route without capacity or drops (models.moe."
+                   "DroplessMoE); --moe_experts is the router's width")
+    p.add_argument("--moe_experts_held", type=str, default="",
+                   help="first,count: the contiguous range of a layer's "
+                        "experts held here (default: all)")
+    p.add_argument("--moe_shared_ff", type=int, default=0,
+                   help="width of the shared expert (0 = none)")
     p.add_argument("--moe_capacity_factor", type=float, default=None,
                    help="per-expert slot count = ceil(factor * group_tokens "
                         "/ n_experts); overflow tokens fall through residual "
@@ -1057,6 +1105,17 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     if args.moe_capacity_factor is not None:
         cfg.model.moe_capacity_factor = args.moe_capacity_factor
     cfg.model.moe_top_k = args.moe_top_k
+    m = cfg.model
+    m.norm, m.norm_eps, m.use_bias = args.norm, args.norm_eps, args.bias
+    m.rope_theta, m.attention_kind = args.rope_theta, args.attention_kind
+    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim", "moe_shared_ff"):
+        setattr(m, name, getattr(args, name))
+    m.rope_scaling = tuple(float(x) for x in args.rope_scaling.split(",")
+                           if x)
+    m.moe_dropless = args.moe_dropless
+    m.moe_experts_held = tuple(int(x) for x in
+                               args.moe_experts_held.split(",") if x)
     if args.ep > 1:
         # expert-sharded MoE: route token slots over the 'expert' axis
         cfg.model.moe_expert_axis = "expert"

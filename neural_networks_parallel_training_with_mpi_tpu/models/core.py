@@ -241,6 +241,25 @@ class LayerNorm(Module):
 
 
 @dataclass(frozen=True)
+class RMSNorm(Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``: no mean taken off, no bias;
+    statistics in float32 whatever the input's type."""
+
+    dim: int
+    eps: float = 1e-6
+    param_dtype: Any = jnp.float32
+
+    def init(self, key: jax.Array) -> Pytree:
+        return {"scale": jnp.ones((self.dim,), self.param_dtype)}
+
+    def apply(self, params: Pytree, x: jax.Array, **kwargs) -> jax.Array:
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
+                                + self.eps)
+        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
+
+
+@dataclass(frozen=True)
 class Embedding(Module):
     vocab_size: int
     dim: int

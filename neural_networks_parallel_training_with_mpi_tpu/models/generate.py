@@ -53,6 +53,7 @@ def init_kv_cache(model: Transformer, batch: int, max_len: int,
     contractions: the K scale multiplies each key position's logit
     column, and the V scale folds into the softmax weights before the
     value einsum, so dequantization never materializes an f32 cache."""
+    model.cfg.require_plain_block("the dense KV cache (models.generate)")
     c = model.cfg
     shape = (batch, max_len, c.kv_heads, c.head_dim)
     if quant:

@@ -61,6 +61,7 @@ def init_tp_kv_cache(model: Transformer, batch: int, max_len: int, tp: int):
     — under GQA the cache holds this rank's kv_heads/tp grouped heads
     (the same per-rank assignment as training, megatron.qkv_tp_permutation),
     stacking the GQA cache shrink on top of the head sharding."""
+    model.cfg.require_plain_block("generate_tp's head-sharded KV cache")
     c = model.cfg
     shape = (batch, max_len, c.kv_heads // tp, c.head_dim)
     zeros = lambda: jnp.zeros(shape, c.compute_dtype)

@@ -33,6 +33,25 @@ from jax import lax
 from .core import ACTIVATIONS, Module, Pytree, _uniform
 
 
+# ---- the router, shared by the capacity layer and the layer without drops --
+
+def router_probs(gate_params: Pytree, x: jax.Array) -> jax.Array:
+    """(N, d) -> (N, E) softmax scores over ALL of the layer's experts, in
+    float32 whatever the compute type: the k-th and (k+1)-th score are often
+    closer than a bfloat16 step, and a flipped choice is another expert."""
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        gate_params["w"].astype(jnp.float32))
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def top_k_weights(probs: jax.Array, k: int):
+    """The k largest scores of each token, divided by their sum
+    (GShard's renormalisation, ``norm_topk_prob``): ((N, k) weights,
+    (N, k) expert ids, best first)."""
+    top_p, top_i = jax.lax.top_k(probs, k)
+    return top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9), top_i
+
+
 @dataclass(frozen=True)
 class MoEFFN(Module):
     """Top-1 gated mixture of ``n_experts`` two-layer FFNs.
@@ -130,9 +149,7 @@ class MoEFFN(Module):
         """x: (N, d) -> dispatch (N, E, C) bool-ish, combine (N, E, C),
         aux scalar."""
         e, k = self.n_experts, self.router_top_k
-        logits = jnp.matmul(x.astype(jnp.float32),
-                            gate_params["w"].astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)            # (N, E)
+        probs = router_probs(gate_params, x)               # (N, E)
         counts = jnp.zeros((e,), jnp.float32)
         if k == 1:
             # Switch: combine weight = the chosen expert's RAW probability
@@ -147,9 +164,7 @@ class MoEFFN(Module):
             # rank r claims expert queue slots after ranks < r (dropped
             # tokens still consume their attempted position — keeps slot
             # assignment one cumsum per rank instead of data-dependent)
-            top_p, top_i = jax.lax.top_k(probs, k)         # (N, k)
-            weights = top_p / jnp.maximum(
-                top_p.sum(-1, keepdims=True), 1e-9)
+            weights, top_i = top_k_weights(probs, k)       # (N, k)
             dispatch = jnp.zeros((x.shape[0], e, cap), jnp.float32)
             combine = jnp.zeros_like(dispatch)
             for r in range(k):
@@ -234,3 +249,189 @@ class MoEFFN(Module):
                                  split_axis=1, concat_axis=0, tiled=True)
         y = jnp.einsum("nec,ecd->nd", combine.astype(cdt), out)
         return y.reshape(*lead, d).astype(cdt), aux
+
+
+# ---- routing without drops over the experts a layer holds -------------------
+
+# what implements the grouped product ``rows of group e @ w[e]``:
+#   "ragged"  jax.lax.ragged_dot (XLA's own; the CPU's only choice)
+#   "gmm"     the Pallas grouped matmul (jax's megablox kernel): walks the
+#             row tiles of the groups that have rows and reads no weight of
+#             an expert no token reached
+# "auto" takes the one the chip timing favoured at both of the serving
+# shapes (a decode tick of 32 tokens, a prefill chunk of 1024; PERF.md)
+GROUPED_IMPLS = ("auto", "ragged", "gmm")
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """Row / contraction / column tile of the Pallas grouped matmul at a
+    problem size, from the chip timing (``tools/moe_grouped_timing.py``,
+    PERF.md): few rows (a decode tick's 128) want a short row tile, so that
+    an expert with one token multiplies 16 rows and not 128, a prefill
+    chunk's 4096 rows want 256; the weight tiles are as wide as VMEM takes
+    (2048 x 1024), because the product is bound by reading them."""
+    tm = 256 if m >= 4096 else (128 if m >= 512 else 16)
+    while m % tm:
+        tm //= 2
+    return tm, min(k, 2048), min(n, 1024)
+
+
+def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                   impl: str = "auto") -> jax.Array:
+    """``xs`` (M, K) sorted by group, ``w`` (G, K, N), ``group_sizes`` (G,)
+    int32 -> (M, N) float32: row r of group g times ``w[g]``.  Rows past
+    ``group_sizes.sum()`` belong to no group; what comes back there is
+    unspecified (the caller masks it)."""
+    if impl == "auto":
+        impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
+    if impl == "gmm":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(xs, w, group_sizes, preferred_element_type=jnp.float32,
+                   tiling=gmm_tiling(xs.shape[0], w.shape[1], w.shape[2]),
+                   interpret=jax.default_backend() != "tpu")
+    return lax.ragged_dot(xs, w, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+@dataclass(frozen=True)
+class DroplessMoE(Module):
+    """Top-k routing over ``n_experts`` with no capacity and no drops, of
+    which this layer HOLDS a contiguous range ``held = (first, count)``:
+    one chip's share of an expert-parallel deployment.
+
+    The router keeps its full width and its k choices a token; the layer
+    computes the part of the result that its own experts give, ``sum over
+    the chosen e in held of w_e E_e(y)``, plus the shared expert, which
+    every chip computes alike.  A token whose choices all lie elsewhere
+    gets the shared expert's output alone.  What the absent experts would
+    have added is NOT stood in for: on one chip the layer runs without its
+    exchange.  ``held=None`` holds them all (the uncut layer).
+
+    Experts are gated: ``E(y) = (silu(y W_gate) * (y W_in)) W_out``, no
+    biases.  Tokens are sorted by expert and multiplied in one grouped
+    product over the held experts (:func:`grouped_matmul`); the combine
+    weights are applied on the way back.
+    """
+
+    d_model: int
+    d_ff: int
+    n_experts: int
+    top_k: int = 1
+    held: Optional[Tuple[int, int]] = None
+    shared_ff: int = 0            # width of the shared expert (0 = none)
+    routed_scale: float = 1.0
+    impl: str = "auto"
+    param_dtype: Any = jnp.float32
+    compute_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        first, count = self.span
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"held experts [{first}, {first + count}) lie "
+                             f"outside the layer's {self.n_experts}")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k must be in [1, {self.n_experts}], got "
+                             f"{self.top_k}")
+        if self.impl not in GROUPED_IMPLS:
+            raise ValueError(f"impl must be one of {GROUPED_IMPLS}")
+
+    @property
+    def span(self) -> Tuple[int, int]:
+        return (0, self.n_experts) if self.held is None else self.held
+
+    def init(self, key: jax.Array) -> Pytree:
+        kg, k1, k2, k3, k4, k5, k6 = jax.random.split(key, 7)
+        d, f, g = self.d_model, self.d_ff, self.span[1]
+        bd, bf = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
+        out = {
+            "gate": {"w": _uniform(kg, (d, self.n_experts), bd,
+                                   self.param_dtype)},
+            "experts": {
+                "w_gate": _uniform(k1, (g, d, f), bd, self.param_dtype),
+                "w_in": _uniform(k2, (g, d, f), bd, self.param_dtype),
+                "w_out": _uniform(k3, (g, f, d), bf, self.param_dtype)},
+        }
+        if self.shared_ff:
+            s = self.shared_ff
+            out["shared"] = {
+                "w_gate": _uniform(k4, (d, s), bd, self.param_dtype),
+                "w_in": _uniform(k5, (d, s), bd, self.param_dtype),
+                "w_out": _uniform(k6, (s, d), 1.0 / math.sqrt(s),
+                                  self.param_dtype)}
+        return out
+
+    def route(self, gate_params: Pytree, toks: jax.Array,
+              mask: Optional[jax.Array]):
+        """(N, d) -> the sorted dispatch: ``order`` (N*k,) assignment
+        indices sorted by held expert (those that fell elsewhere, and those
+        of masked tokens, last), ``sizes`` (count,) rows of each held
+        expert, ``weight`` (N, k) combine weights with 0 where the choice
+        is not computed here, and the router's ``probs`` / first choices
+        for the load-balance term."""
+        first, count = self.span
+        probs = router_probs(gate_params, toks)
+        weight, top_i = top_k_weights(probs, self.top_k)
+        local = top_i - first
+        here = (local >= 0) & (local < count)
+        if mask is not None:
+            here = here & mask[:, None]
+        gid = jnp.where(here, local, count).reshape(-1)
+        order = jnp.argsort(gid, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[gid].add(1)[:count]
+        weight = jnp.where(here, weight * self.routed_scale, 0.0)
+        return order, sizes, weight, probs, top_i
+
+    def experts_ffn(self, ep: Pytree, xs: jax.Array,
+                    sizes: jax.Array) -> jax.Array:
+        """Sorted rows (M, d) -> (M, d) float32 through each row's expert."""
+        cdt = self.compute_dtype
+        xs = xs.astype(cdt)
+        gate = grouped_matmul(xs, ep["w_gate"].astype(cdt), sizes, self.impl)
+        up = grouped_matmul(xs, ep["w_in"].astype(cdt), sizes, self.impl)
+        h = (jax.nn.silu(gate) * up).astype(cdt)
+        return grouped_matmul(h, ep["w_out"].astype(cdt), sizes, self.impl)
+
+    def shared_ffn(self, sp: Pytree, toks: jax.Array) -> jax.Array:
+        cdt = self.compute_dtype
+        x = toks.astype(cdt)
+        h = jax.nn.silu(jnp.matmul(x, sp["w_gate"].astype(cdt))) \
+            * jnp.matmul(x, sp["w_in"].astype(cdt))
+        return jnp.matmul(h, sp["w_out"].astype(cdt))
+
+    def apply(self, params: Pytree, x: jax.Array, mask=None,
+              return_load: bool = False, **kwargs):
+        """x (..., d) -> (y, aux), or (y, aux, load) with ``return_load``:
+        ``load`` (count,) int32 is how many assignments each held expert
+        got.  ``mask`` (...,) bool leaves tokens out of the routed part
+        (pad columns, idle lanes): they reach no expert and read none."""
+        lead, d = x.shape[:-1], x.shape[-1]
+        toks = x.reshape(-1, d)
+        n, k = toks.shape[0], self.top_k
+        with jax.named_scope("moe_route"):
+            order, sizes, weight, probs, top_i = self.route(
+                params["gate"], toks,
+                None if mask is None else mask.reshape(-1))
+            xs = jnp.take(toks, order // k, axis=0)          # (N*k, d)
+        with jax.named_scope("moe_experts"):
+            out = self.experts_ffn(params["experts"], xs, sizes)
+        with jax.named_scope("moe_combine"):
+            # rows past the held experts' groups carry whatever the
+            # grouped product left there: zeroed, not weighted by zero
+            rows = jnp.arange(n * k) < sizes.sum()
+            out = jnp.where(rows[:, None], out, 0.0)
+            back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+                jnp.arange(n * k, dtype=jnp.int32))
+            y = (jnp.take(out, back, axis=0).reshape(n, k, d)
+                 * weight[:, :, None]).sum(1)
+        if self.shared_ff:
+            with jax.named_scope("moe_shared"):
+                y = y + self.shared_ffn(params["shared"], toks)
+        # Switch / GShard load-balance term on the first choice, over all
+        # of the layer's experts (1.0 when uniform)
+        first_choice = jax.nn.one_hot(top_i[:, 0], self.n_experts,
+                                      dtype=jnp.float32)
+        aux = self.n_experts * jnp.sum(first_choice.mean(0) * probs.mean(0))
+        y = y.reshape(*lead, d).astype(self.compute_dtype)
+        return (y, aux, sizes) if return_load else (y, aux)

@@ -26,6 +26,12 @@ def build_model(cfg: ModelConfig) -> Module:
                        activation=cfg.activation, param_dtype=pdt,
                        compute_dtype=cdt)
     if cfg.arch == "transformer":
+        from ..ops.rope import RopeScaling
+
+        scaling = None
+        if cfg.rope_scaling:
+            f, orig, fast, slow, ms, ms_all, beta = cfg.rope_scaling
+            scaling = RopeScaling(f, int(orig), fast, slow, ms, ms_all, beta)
         tc = TransformerConfig(
             vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
             n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
@@ -39,6 +45,15 @@ def build_model(cfg: ModelConfig) -> Module:
             moe_expert_axis=cfg.moe_expert_axis,
             moe_capacity_factor=cfg.moe_capacity_factor,
             moe_top_k=cfg.moe_top_k,
+            norm=cfg.norm, norm_eps=cfg.norm_eps, use_bias=cfg.use_bias,
+            rope_theta=cfg.rope_theta, attention_kind=cfg.attention_kind,
+            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, rope_scaling=scaling,
+            moe_dropless=cfg.moe_dropless,
+            moe_experts_held=tuple(cfg.moe_experts_held) or None,
+            moe_shared_ff=cfg.moe_shared_ff,
             ce_chunk=cfg.ce_chunk,
             matmul_dtype=cfg.matmul_dtype,
             matmul_skip=tuple(cfg.matmul_skip),

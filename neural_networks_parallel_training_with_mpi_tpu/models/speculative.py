@@ -109,6 +109,7 @@ def _chunk_program(model: Transformer, max_len: int, chunk: int,
     """One jitted (params, caches, ids (B, chunk), pos) -> (logits,
     caches) per (model, shapes): position is TRACED, so draft steps and
     verify chunks at every position share one compiled program each."""
+    model.cfg.require_plain_block("speculative decoding")
 
     def run(params, caches, ids, pos):
         return _forward_chunk(model, params, caches, ids, pos)
@@ -327,6 +328,8 @@ def _spec_device_program(target: Transformer, draft: Transformer,
     - 1 - k`` (a k+1 chunk never writes past the buffer); the <= k
     remaining tokens finish as predicated single steps inside the same
     program."""
+    for m in (target, draft):
+        m.cfg.require_plain_block("speculative decoding")
 
     def run(t_params, d_params, prompt):
         i32 = jnp.int32

@@ -19,8 +19,10 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.rope import RopeScaling
 from ..parallel.sequence import sequence_sharded_attention
-from .core import Embedding, LayerNorm, Linear, Module, ACTIVATIONS
+from .core import (ACTIVATIONS, Embedding, LayerNorm, Linear, Module,
+                   RMSNorm)
 
 
 def split_qkv(c: "TransformerConfig", qkv: jax.Array):
@@ -141,6 +143,83 @@ class TransformerConfig:
     # wants actual logits); picked up via fused_loss_sum by
     # parallel.data_parallel.make_loss_fn.
     ce_chunk: int = 0
+    # The norm: "layernorm" (mean and bias) or "rmsnorm" (neither), with
+    # this eps; ``use_bias=False`` takes the biases off every projection
+    # and off the feed-forward (``activation="swiglu"`` is the gated SiLU
+    # feed-forward).
+    norm: str = "layernorm"            # layernorm | rmsnorm
+    norm_eps: float = 1e-5
+    use_bias: bool = True
+    # The attention: "mha" is the fused-qkv multi-head / grouped-query
+    # attention above; "mla" is latent attention (models/mla.py), which
+    # owns its projections and its cache row.  The five sizes and the
+    # long-context rotary (ops.rope.RopeScaling: YaRN and the query scale
+    # by position) are the model's own config fields; positions are rotary
+    # with adjacent pairs.
+    attention_kind: str = "mha"        # mha | mla
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    # Routing without drops (models.moe.DroplessMoE) in place of the
+    # capacity layer: ``moe_experts`` is the router's width, ``moe_top_k``
+    # the choices a token, ``d_ff`` an expert's width, and
+    # ``moe_experts_held = (first, count)`` the contiguous range of the
+    # layer's experts that live HERE (None: all): one chip's share of an
+    # expert-parallel deployment, computed without its exchange.
+    # ``moe_shared_ff`` is the shared expert's width (0: none).
+    moe_dropless: bool = False
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    moe_shared_ff: int = 0
+
+    def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be layernorm or rmsnorm, got "
+                             f"{self.norm!r}")
+        if self.attention_kind not in ("mha", "mla"):
+            raise ValueError(f"attention_kind must be mha or mla, got "
+                             f"{self.attention_kind!r}")
+        if self.attention_kind == "mla":
+            if self.pos_encoding != "rope":
+                raise ValueError("latent attention carries its positions in "
+                                 "the rotary key: pos_encoding must be 'rope'")
+            if self.attention not in ("auto", "dense"):
+                raise ValueError(
+                    f"latent attention has a dense expanded form only; "
+                    f"attention={self.attention!r} cannot run it yet")
+            sizes = (self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim)
+            if self.matmul_dtype != "bf16":
+                raise ValueError("the quantized-matmul seam does not reach "
+                                 "latent attention's projections: "
+                                 "matmul_dtype must be 'bf16'")
+            if min(sizes) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(f"latent attention needs its five sizes "
+                                 f"(an even rotary one), got {sizes}")
+        if self.moe_dropless and self.moe_experts < 1:
+            raise ValueError("moe_dropless needs moe_experts >= 1")
+        if self.moe_dropless and self.moe_expert_axis is not None:
+            raise ValueError(
+                "routing without drops runs one chip's share without its "
+                "exchange; moe_expert_axis (parallel/expert.py's all-to-all) "
+                "belongs to the capacity layer")
+
+    def require_plain_block(self, who: str) -> None:
+        """Paths that know the fused-qkv block and the capacity layer only
+        refuse the other kinds by name, where they are built."""
+        kinds = [k for k, on in (
+            ("latent attention (attention_kind='mla')",
+             self.attention_kind != "mha"),
+            ("routing without drops (moe_dropless)", self.moe_dropless))
+            if on]
+        if kinds:
+            raise ValueError(f"{who} cannot run a block with "
+                             f"{' and '.join(kinds)} yet; the paged server "
+                             "(serve.PagedDecodeServer / Scheduler) and "
+                             "Transformer.apply can")
 
     @property
     def head_dim(self) -> int:
@@ -174,20 +253,61 @@ class Transformer(Module):
         c = self.cfg
         return "bf16" if role in c.matmul_skip else c.matmul_dtype
 
+    def _norm(self):
+        """The model's norm over ``d_model`` (block norms and the final
+        one)."""
+        c = self.cfg
+        if c.norm == "rmsnorm":
+            return RMSNorm(c.d_model, c.norm_eps, c.param_dtype)
+        return LayerNorm(c.d_model, c.norm_eps, c.param_dtype)
+
+    def cache_row(self):
+        """What one token holds in one layer of a serving cache: pool name
+        -> the row's trailing shape.  The paged cache (serve/paged_kv.py)
+        asks this and assumes nothing else about the attention: per-head K
+        and V here, one latent row under latent attention."""
+        c = self.cfg
+        if c.attention_kind == "mla":
+            return self._block_modules()["attn"].cache_row()
+        return {"k": (c.kv_heads, c.head_dim), "v": (c.kv_heads, c.head_dim)}
+
     def _block_modules(self):
         c = self.cfg
-        mods = {
-            "ln1": LayerNorm(c.d_model, param_dtype=c.param_dtype),
-            "qkv": Linear(c.d_model, c.qkv_dim, param_dtype=c.param_dtype,
-                          compute_dtype=c.compute_dtype,
-                          matmul_dtype=self._mm("qkv"), q_role="qkv"),
-            "attn_out": Linear(c.d_model, c.d_model, param_dtype=c.param_dtype,
-                               compute_dtype=c.compute_dtype,
-                               matmul_dtype=self._mm("attn_out"),
-                               q_role="attn_out"),
-            "ln2": LayerNorm(c.d_model, param_dtype=c.param_dtype),
-        }
-        if c.moe_experts > 0:
+        if c.attention_kind == "mla":
+            from .mla import LatentAttention
+
+            mods = {"ln1": self._norm(),
+                    "attn": LatentAttention(
+                        c.d_model, c.n_heads, c.q_lora_rank, c.kv_lora_rank,
+                        c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+                        rope_theta=c.rope_theta, rope_scaling=c.rope_scaling,
+                        norm_eps=c.norm_eps, param_dtype=c.param_dtype,
+                        compute_dtype=c.compute_dtype),
+                    "ln2": self._norm()}
+        else:
+            mods = {
+                "ln1": self._norm(),
+                "qkv": Linear(c.d_model, c.qkv_dim, use_bias=c.use_bias,
+                              param_dtype=c.param_dtype,
+                              compute_dtype=c.compute_dtype,
+                              matmul_dtype=self._mm("qkv"), q_role="qkv"),
+                "attn_out": Linear(c.d_model, c.d_model,
+                                   use_bias=c.use_bias,
+                                   param_dtype=c.param_dtype,
+                                   compute_dtype=c.compute_dtype,
+                                   matmul_dtype=self._mm("attn_out"),
+                                   q_role="attn_out"),
+                "ln2": self._norm(),
+            }
+        if c.moe_dropless:
+            from .moe import DroplessMoE
+
+            mods["moe"] = DroplessMoE(
+                c.d_model, c.d_ff, c.moe_experts, top_k=c.moe_top_k,
+                held=c.moe_experts_held, shared_ff=c.moe_shared_ff,
+                param_dtype=c.param_dtype,
+                compute_dtype=c.compute_dtype)
+        elif c.moe_experts > 0:
             from .moe import MoEFFN
 
             mods["moe"] = MoEFFN(
@@ -198,7 +318,7 @@ class Transformer(Module):
                 router_top_k=c.moe_top_k,
                 param_dtype=c.param_dtype, compute_dtype=c.compute_dtype)
         else:
-            mods["ff_in"] = Linear(c.d_model, c.d_ff,
+            mods["ff_in"] = Linear(c.d_model, c.d_ff, use_bias=c.use_bias,
                                    param_dtype=c.param_dtype,
                                    compute_dtype=c.compute_dtype,
                                    matmul_dtype=self._mm("ff_in"),
@@ -209,11 +329,12 @@ class Transformer(Module):
                 # projection; pick d_ff ~2/3 of the ungated width for
                 # iso-parameter comparisons.
                 mods["ff_gate"] = Linear(c.d_model, c.d_ff,
+                                         use_bias=c.use_bias,
                                          param_dtype=c.param_dtype,
                                          compute_dtype=c.compute_dtype,
                                          matmul_dtype=self._mm("ff_gate"),
                                          q_role="ff_gate")
-            mods["ff_out"] = Linear(c.d_ff, c.d_model,
+            mods["ff_out"] = Linear(c.d_ff, c.d_model, use_bias=c.use_bias,
                                     param_dtype=c.param_dtype,
                                     compute_dtype=c.compute_dtype,
                                     matmul_dtype=self._mm("ff_out"),
@@ -274,7 +395,7 @@ class Transformer(Module):
         out = {
             "embed": embed.init(keys[-3]),
             "blocks": blocks,
-            "ln_f": LayerNorm(c.d_model, param_dtype=c.param_dtype).init(keys[-1]),
+            "ln_f": self._norm().init(keys[-1]),
             "head": head.init(keys[-1]),
         }
         if c.pos_encoding != "rope":   # RoPE has no position parameters
@@ -295,6 +416,9 @@ class Transformer(Module):
         # the named scopes are what the device trace is read by
         # (benchmark/reducers/scopes.py); each norm and residual add sits
         # in the scope of the matrix product it feeds or follows
+        if c.attention_kind == "mla":
+            x = self._latent_attention(mods, params, x)
+            return self._ffn_half(mods, params, x, qkw, qobs)
         with jax.named_scope("attn_proj"):
             h = mods["ln1"].apply(params["ln1"], x)
             qkv = mods["qkv"].apply(params["qkv"], h, **qkw)
@@ -314,6 +438,13 @@ class Transformer(Module):
         with jax.named_scope("attn_proj"):
             out = out.reshape(*out.shape[:2], c.d_model)
             x = x + mods["attn_out"].apply(params["attn_out"], out, **qkw)
+        return self._ffn_half(mods, params, x, qkw, qobs)
+
+    def _ffn_half(self, mods, params, x: jax.Array, qkw, qobs):
+        """``x + FFN(norm(x))``, the block's second half, whatever the
+        first was: the two-matrix or gated feed-forward, the capacity
+        layer, or routing without drops."""
+        c = self.cfg
         with jax.named_scope("ffn"):
             h = mods["ln2"].apply(params["ln2"], x)
             if c.moe_experts > 0:
@@ -322,6 +453,15 @@ class Transformer(Module):
                 ff = self._ffn(mods, params, h, **qkw)
                 aux = jnp.zeros((), jnp.float32)
             return x + ff.astype(x.dtype), aux, (qobs or {})
+
+    def _latent_attention(self, mods, params, x: jax.Array) -> jax.Array:
+        """``x + LatentAttn(norm(x))`` over a whole causal sequence from
+        position 0, in the expanded form (the training forward)."""
+        with jax.named_scope("attn_proj"):
+            h = mods["ln1"].apply(params["ln1"], x)
+        out = mods["attn"].apply(params["attn"], h)
+        with jax.named_scope("attn_proj"):
+            return x + out.astype(x.dtype)
 
     def add_pos(self, params, x_tokens: jax.Array,
                 positions: jax.Array) -> jax.Array:
@@ -356,8 +496,7 @@ class Transformer(Module):
         drift argument as :meth:`add_pos`)."""
         c = self.cfg
         with jax.named_scope("lm_head"):
-            return LayerNorm(c.d_model, param_dtype=c.param_dtype).apply(
-                params["ln_f"], x)
+            return self._norm().apply(params["ln_f"], x)
 
     def head_logits(self, params, x: jax.Array, qscales=None) -> jax.Array:
         """Final LayerNorm + untied head -> f32 logits (shared with
@@ -380,13 +519,25 @@ class Transformer(Module):
         c = self.cfg
         b, t = x_shape
         d, ff, v = c.d_model, c.d_ff, c.vocab_size
-        per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv projection (GQA-aware)
-        per_layer += 2.0 * b * t * d * d        # attention out projection
-        per_layer += 2.0 * (2.0 * b * t * t * d)  # scores + values
+        if c.attention_kind == "mla":
+            # the low-rank projections and the expanded scores + values
+            per_layer = b * t * self._block_modules()[
+                "attn"].fwd_flops_per_token(t)
+        else:
+            per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv (GQA-aware)
+            per_layer += 2.0 * b * t * d * d        # attention out
+            per_layer += 2.0 * (2.0 * b * t * t * d)  # scores + values
         # FFN in + out per expert; SwiGLU adds the (d, ff) gate matmul
-        ffn = 2.0 * ((3.0 if c.activation == "swiglu" else 2.0)
-                     * b * t * d * ff)
-        if c.moe_experts > 0:
+        gated = c.activation == "swiglu" or c.moe_dropless
+        ffn = 2.0 * ((3.0 if gated else 2.0) * b * t * d * ff)
+        if c.moe_dropless:
+            # of a token's k choices the share that is held here, plus
+            # the shared expert
+            count = (c.moe_experts_held or (0, c.moe_experts))[1]
+            ffn *= c.moe_top_k * count / c.moe_experts
+            ffn += 2.0 * 3.0 * b * t * d * c.moe_shared_ff
+            per_layer += 2.0 * b * t * d * c.moe_experts  # router
+        elif c.moe_experts > 0:
             ffn *= c.moe_top_k
             per_layer += 2.0 * b * t * d * c.moe_experts  # router
         per_layer += ffn

@@ -197,6 +197,7 @@ def make_moe_train_step(model: Transformer, optimizer: Optimizer, mesh: Mesh,
     replicated params across the expert axis).
     """
     c = model.cfg
+    c.require_plain_block("the expert-parallel step (parallel/expert.py)")
     ep = int(mesh.shape[EXPERT_AXIS])
     if c.moe_experts <= 0:
         raise ValueError("model has no MoE layers; use the spmd/gspmd step")
@@ -437,6 +438,7 @@ def _validate_moe_tp(model: Transformer, mesh: Mesh, seq_axis=None):
     from .sequence import SEQ_SHARDED_IMPLS
 
     c = model.cfg
+    c.require_plain_block("the expert x tensor step (parallel/expert.py)")
     ep = int(mesh.shape.get(EXPERT_AXIS, 1))
     tp = int(mesh.shape.get(TENSOR_AXIS, 1))
     use_seq = _seq_active(mesh, seq_axis)

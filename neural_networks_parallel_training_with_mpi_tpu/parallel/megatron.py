@@ -125,6 +125,8 @@ def permute_qkv(blocks: Pytree, d_model: int, n_heads: int, tp: int,
 
 
 def validate_tp(cfg, tp: int) -> None:
+    if hasattr(cfg, "require_plain_block"):
+        cfg.require_plain_block("Megatron tensor parallelism")
     kv = getattr(cfg, "kv_heads", cfg.n_heads)
     if kv % tp:
         # same divisibility contract (and exception type) as the

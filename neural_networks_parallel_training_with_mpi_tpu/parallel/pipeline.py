@@ -400,6 +400,7 @@ def _stage_fns(model: Transformer, tp: int):
 
 def _validate_pipe(model: Transformer, mesh: Mesh, interleave: int = 1):
     c = model.cfg
+    c.require_plain_block("the pipeline step (parallel/pipeline.py)")
     n_stages = int(mesh.shape[PIPE_AXIS])
     tp = int(mesh.shape.get("tensor", 1))
     if n_stages < 2:

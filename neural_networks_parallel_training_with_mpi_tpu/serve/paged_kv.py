@@ -8,10 +8,17 @@ the TPU angle is that everything must stay static-shape so one compiled
 step serves any mix of lengths).  Here the cache is a pool of fixed-size
 blocks:
 
-* **Pools**: per layer, ``k``/``v`` of shape ``(num_blocks, block_size,
-  kv_heads, head_dim)`` (plus f32 scale pools under ``kv_quant`` — the
-  same int8 scheme as :func:`models.generate.init_kv_cache`, quantized
-  per (position, head) so block boundaries never change the numbers).
+* **Pools**: per layer, one pool per entry of the attention's **cache
+  row** (``Transformer.cache_row()``: pool name -> the trailing shape a
+  token holds), each ``(num_blocks, block_size, *row)``.  Multi-head and
+  grouped-query attention answer ``k``/``v`` of ``(kv_heads, head_dim)``
+  (plus f32 scale pools under ``kv_quant`` — the same int8 scheme as
+  :func:`models.generate.init_kv_cache`, quantized per (position, head)
+  so block boundaries never change the numbers); latent attention
+  answers one pool ``latent`` of ``(kv_lora_rank + qk_rope_head_dim,)``,
+  normed and rotated before it is written.  Allocation, block tables,
+  copy-on-write, export / import and the handoff geometry read the row
+  and nothing else of the attention.
 * **Block tables**: per slot, ``(max_blocks,)`` int32 indices into the
   pool, host-owned (a tiny traced argument each step — never a
   recompile).  Unallocated entries point at the reserved **sink block
@@ -108,6 +115,17 @@ Pytree = Any
 # Pallas paged-attention kernel and stops at each stream's true length
 # (ops.pallas_kernels.paged_attention — token-identical, pinned)
 ATTN_IMPLS = ("gathered", "fused")
+
+# cumulative expert-load counters of a model that routes without drops
+# (models.moe.DroplessMoE), carried on the device beside the pools as one
+# int32 vector and brought to the host in the fetch ``_finish`` makes
+# anyway: choices that fell on held experts (all programs), the busiest
+# held expert's count summed over programs and layers, held experts with
+# at least one token summed over decode ticks and layers, decode ticks,
+# and the first of these over prefill chunks alone with their count
+EXPERT_COUNTERS = ("expert_assignments", "expert_tokens_max",
+                   "experts_reached", "decode_ticks_counted",
+                   "prefill_expert_assignments", "prefill_chunks_counted")
 
 # block 0 is reserved: pad positions and frozen slots write (and gather)
 # here, so a scatter never needs dynamic masking to be allocation-safe
@@ -321,22 +339,28 @@ class PrefixIndex:
 
 def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
                   quant: bool = False):
-    """Per-layer paged pools ``(num_blocks, block_size, kv_heads,
-    head_dim)`` — :func:`models.generate.init_kv_cache` with the length
-    axis split into (block, offset).  ``quant=True`` stores int8 codes
-    plus one f32 scale per (block, offset, head), the identical scheme
-    the dense cache uses (scales are per position, so paging cannot
-    change the numbers)."""
+    """Per-layer paged pools, one per entry of the attention's cache row
+    (``model.cache_row()``), each ``(num_blocks, block_size, *row)`` —
+    :func:`models.generate.init_kv_cache` with the length axis split into
+    (block, offset).  ``quant=True`` (per-head K and V only) stores int8
+    codes plus one f32 scale per (block, offset, head), the identical
+    scheme the dense cache uses (scales are per position, so paging
+    cannot change the numbers)."""
     c = model.cfg
-    shape = (num_blocks, block_size, c.kv_heads, c.head_dim)
+    row = model.cache_row()
+    lead = (num_blocks, block_size)
     if quant:
-        zeros = lambda: jnp.zeros(shape, jnp.int8)          # noqa: E731
-        ones = lambda: jnp.ones(shape[:-1], jnp.float32)    # noqa: E731
-        return [{"k": zeros(), "v": zeros(),
-                 "k_scale": ones(), "v_scale": ones()}
+        if set(row) != {"k", "v"}:
+            raise ValueError(
+                "kv_quant stores int8 codes of per-head K and V; the cache "
+                f"row {sorted(row)} of this attention has no such scheme yet")
+        return [{**{n: jnp.zeros(lead + r, jnp.int8)
+                    for n, r in row.items()},
+                 **{f"{n}_scale": jnp.ones(lead + r[:-1], jnp.float32)
+                    for n, r in row.items()}}
                 for _ in range(c.n_layers)]
-    zeros = lambda: jnp.zeros(shape, c.compute_dtype)       # noqa: E731
-    return [{"k": zeros(), "v": zeros()} for _ in range(c.n_layers)]
+    return [{n: jnp.zeros(lead + r, c.compute_dtype) for n, r in row.items()}
+            for _ in range(c.n_layers)]
 
 
 @functools.lru_cache(maxsize=8)
@@ -360,6 +384,16 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     if attn_impl not in ATTN_IMPLS:
         raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
                          f"got {attn_impl!r}")
+    latent = c.attention_kind == "mla"
+    if latent and attn_impl == "fused":
+        raise ValueError(
+            "attn_impl='fused' is the Pallas paged kernel over per-head K "
+            "and V pools; latent attention's cache row has no paged kernel "
+            "yet: use attn_impl='gathered'")
+    if latent and kv_quant:
+        raise ValueError(
+            "kv_quant stores int8 codes of per-head K and V; latent "
+            "attention's cache row has no such scheme yet")
 
     def gathered_attention(q, kp, vp, tables, positions, ksp, vsp):
         """Attention over each row's whole table width: ``pool[table]``
@@ -409,19 +443,21 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                              gv.astype(jnp.float32))
             return out.reshape(b, w, c.n_heads, c.head_dim)
 
-    def block_fwd(layer_params, pool, tables, starts, x, valid, lengths):
-        """One transformer block over a chunk ``x`` (B, W, D) whose rows
-        sit at per-row start positions, K/V scattered into the paged
-        pool and attention read back through the block tables — gathered
-        (``pool[table]`` then a full-width masked reduction) or fused
-        (the paged kernel walks only ``ceil(lengths/bs)`` live blocks).
-        Mirrors ``models.generate._block_chunk`` (the pinned dense
-        math) with the cache axis split into (block, offset).  ``valid``
-        (W,) masks pad columns of a bucketed prefill chunk: their writes
-        divert to the sink block.  ``lengths`` (B,) is each row's
-        attendable-key count (0 = inactive lane), traced like the
-        tables so length churn never recompiles."""
-        mods = model._block_modules()
+    def scatter_coords(tables, positions, valid):
+        """(block, offset) of every position of a chunk: each position
+        resolves its own block via the row's table (chunks straddle block
+        boundaries freely); pad columns land in the sink."""
+        blk = jnp.take_along_axis(tables, positions // bs, axis=1)
+        blk = jnp.where(valid[None, :], blk, SINK_BLOCK)
+        off = jnp.where(valid[None, :], positions % bs, 0)
+        return blk, off
+
+    def dense_attention_half(mods, layer_params, pool, tables, starts, x,
+                             valid, lengths):
+        """``x + Attn(LN(x))`` with per-head K and V rows: the fused qkv
+        projection, K/V scattered into the pools, attention gathered or
+        fused.  Mirrors ``models.generate._block_chunk`` (the pinned dense
+        math) with the cache axis split into (block, offset)."""
         quant = "k_scale" in pool
         # named scopes as in ``Transformer._block`` (the device trace is
         # read by them): ``attention`` is the work, the inner scopes say
@@ -439,12 +475,7 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 q = rope_rotate(q, positions, c.rope_theta)
                 k = rope_rotate(k, positions, c.rope_theta)
             with jax.named_scope("paged_scatter"):
-                # scatter coordinates: each position resolves its own
-                # block via the row's table (chunks straddle block
-                # boundaries freely); pad columns land in the sink
-                blk = jnp.take_along_axis(tables, positions // bs, axis=1)
-                blk = jnp.where(valid[None, :], blk, SINK_BLOCK)
-                off = jnp.where(valid[None, :], positions % bs, 0)
+                blk, off = scatter_coords(tables, positions, valid)
                 if quant:
                     k, ks = _quantize_kv(k)
                     v, vs = _quantize_kv(v)
@@ -474,19 +505,85 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         with jax.named_scope("attn_proj"):
             out = out.reshape(b, w, c.d_model)
             x = x + mods["attn_out"].apply(layer_params["attn_out"], out)
-        with jax.named_scope("ffn"):
-            h = mods["ln2"].apply(layer_params["ln2"], x)
-            if c.moe_experts > 0:
-                ff, _ = mods["moe"].apply(layer_params["moe"], h)
-            else:
-                ff = model._ffn(mods, layer_params, h)
-            x = x + ff.astype(x.dtype)
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
             new_pool.update(k_scale=new_ksp, v_scale=new_vsp)
         return x, new_pool
 
-    def forward(params, pools, tables, starts, ids, valid, lengths):
+    def latent_attention_half(mods, layer_params, pool, tables, starts, x,
+                              valid, lengths, decode):
+        """``x + LatentAttn(norm(x))`` with the latent row: the chunk's rows
+        ``[c_kv | k_rope]`` (normed, rotated) scattered into the one pool,
+        each stream's rows gathered through its table, and attended in the
+        expanded form (prefill: rows expanded through ``W_kvb``) or the
+        absorbed form (decode: the cache is never expanded).  ``lengths``
+        bounds the keys a prefill chunk walks."""
+        attn, ap = mods["attn"], layer_params["attn"]
+        b, w, _ = x.shape
+        positions = starts[:, None] + jnp.arange(w)[None, :]      # (B, W)
+        with jax.named_scope("attn_proj"):
+            h = mods["ln1"].apply(layer_params["ln1"], x)
+            q_nope, q_rope, rows = attn.project(ap, h, positions)
+        with jax.named_scope("attention"):
+            with jax.named_scope("paged_scatter"):
+                blk, off = scatter_coords(tables, positions, valid)
+                new_lp = pool["latent"].at[blk, off].set(
+                    rows.astype(pool["latent"].dtype))
+            with jax.named_scope("paged_gather"):
+                got = new_lp[tables].reshape(b, t_cap, attn.row_dim)
+            with jax.named_scope("attn_core"):
+                mask = (jnp.arange(t_cap)[None, None, :]
+                        <= positions[:, :, None])           # (B, W, T_cap)
+                if decode:
+                    out = attn.attend_absorbed(ap, q_nope, q_rope, got, mask)
+                else:
+                    # a chunk sees no key past its own last position: the
+                    # expanded form walks the keys that exist
+                    out = attn.attend_expanded(ap, q_nope, q_rope, got, mask,
+                                               n_keys=lengths.max())
+        with jax.named_scope("attn_proj"):
+            x = x + attn.output(ap, out).astype(x.dtype)
+        return x, {"latent": new_lp}
+
+    def block_fwd(layer_params, pool, tables, starts, x, valid, lengths,
+                  decode):
+        """One transformer block over a chunk ``x`` (B, W, D) whose rows
+        sit at per-row start positions: the attention half of the model's
+        kind writes the chunk's cache rows into the paged pool and reads
+        the streams' rows back through the block tables, then the
+        feed-forward half.  ``valid`` (W,) masks pad columns of a bucketed
+        prefill chunk: their writes divert to the sink block.  ``lengths``
+        (B,) is each row's attendable-key count (0 = inactive lane), traced
+        like the tables so length churn never recompiles.  ``decode``
+        (static) picks latent attention's absorbed form.  Returns (x, the
+        new pool, the held experts' load (count,) int32 under routing
+        without drops, else None)."""
+        mods = model._block_modules()
+        if latent:
+            x, new_pool = latent_attention_half(
+                mods, layer_params, pool, tables, starts, x, valid, lengths,
+                decode)
+        else:
+            x, new_pool = dense_attention_half(
+                mods, layer_params, pool, tables, starts, x, valid, lengths)
+        load = None
+        with jax.named_scope("ffn"):
+            h = mods["ln2"].apply(layer_params["ln2"], x)
+            if c.moe_dropless:
+                # pad columns and idle lanes reach no expert (and read
+                # none): the load counts what the traffic asked for
+                live = valid[None, :] & (lengths > 0)[:, None]
+                ff, _, load = mods["moe"].apply(
+                    layer_params["moe"], h, mask=live, return_load=True)
+            elif c.moe_experts > 0:
+                ff, _ = mods["moe"].apply(layer_params["moe"], h)
+            else:
+                ff = model._ffn(mods, layer_params, h)
+            x = x + ff.astype(x.dtype)
+        return x, new_pool, load
+
+    def forward(params, pools, tables, starts, ids, valid, lengths,
+                decode):
         # clamp pad columns' embedding positions into range (their
         # outputs are discarded; learned positional tables have no row
         # past max_seq_len)
@@ -494,24 +591,44 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         emb_pos = jnp.minimum(starts[:, None] + jnp.arange(w)[None, :],
                               c.max_seq_len - 1)
         x = model.embed(params, ids, emb_pos)
-        new_pools = []
+        new_pools, loads = [], []
         for layer_params, pool in zip(params["blocks"], pools):
-            x, pool = block_fwd(layer_params, pool, tables, starts, x,
-                                valid, lengths)
+            x, pool, load = block_fwd(layer_params, pool, tables, starts, x,
+                                      valid, lengths, decode)
             new_pools.append(pool)
-        return model.head_logits(params, x), new_pools
+            loads.append(load)
+        return model.head_logits(params, x), new_pools, loads
 
-    def prefill(params, pools, table, start, chunk, true_w):
+    def count_load(stats, loads, decode):
+        """Fold one program's expert loads (a (count,) int32 per layer)
+        into the cumulative counters the server carries on the device
+        (``EXPERT_COUNTERS``); ``stats`` is ``{}`` for a model without
+        routing without drops, and stays so."""
+        if not stats:
+            return stats
+        load = jnp.stack(loads)                               # (L, count)
+        assigned, busiest = load.sum(), load.max(axis=1).sum()
+        zero, one = jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32)
+        if decode:
+            step = [assigned, busiest, (load > 0).sum(), one, zero, zero]
+        else:
+            step = [assigned, busiest, zero, zero, assigned, one]
+        return {"experts": stats["experts"]
+                + jnp.stack(step).astype(jnp.int32)}
+
+    def prefill(params, pools, stats, table, start, chunk, true_w):
         # chunk (1, W_bucket) int32; logits for ALL columns return and
         # the caller indexes the true last position (same contract as
         # the dense server's bucketed prefill).  attendable keys after
         # this chunk's writes: everything up to start + true_w (pad
         # columns wrote to the sink, which is past every length)
         valid = jnp.arange(chunk.shape[1]) < true_w
-        return forward(params, pools, table, start, chunk, valid,
-                       start + true_w)
+        logits, new_pools, loads = forward(params, pools, table, start,
+                                           chunk, valid, start + true_w,
+                                           False)
+        return logits, new_pools, count_load(stats, loads, False)
 
-    def step(params, pools, tokens, tables, pos, active, key):
+    def step(params, pools, stats, tokens, tables, pos, active, key):
         s = tokens.shape[0]
         cap = tokens.shape[1] - 1
         ids = jnp.take_along_axis(tokens, pos[:, None], axis=1)  # (S, 1)
@@ -519,8 +636,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         # inactive lanes carry length 0, so the fused kernel walks ZERO
         # of their blocks (the gathered path computes-and-discards them)
         lengths = jnp.where(active, pos + 1, 0)
-        logits, new_pools = forward(params, pools, tables, pos, ids,
-                                    jnp.ones((1,), bool), lengths)
+        logits, new_pools, loads = forward(params, pools, tables, pos, ids,
+                                           jnp.ones((1,), bool), lengths,
+                                           True)
         with jax.named_scope("sample"):
             nxt, key = _sample(logits[:, 0], temperature, key, top_k,
                                top_p)
@@ -531,7 +649,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         write_at = jnp.minimum(pos + 1, cap)
         tokens = tokens.at[jnp.arange(s), write_at].set(nxt)
         pos = jnp.where(active, jnp.minimum(pos + 1, cap), pos)
-        return new_pools, tokens, pos, key
+        # the counters come last: the dense programs' results keep their
+        # places (and their compile-cache keys)
+        return new_pools, tokens, pos, key, count_load(stats, loads, True)
 
     def cow(pools, src, dst):
         """Copy-on-write fork: duplicate block row ``src`` into the
@@ -566,9 +686,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
 
     tag = (f"bs{bs}x{mb}" + ("/int8" if kv_quant else "")
            + f"/{attn_impl}")
-    return (ledger_lib.instrument(jax.jit(prefill, donate_argnums=(1,)),
+    return (ledger_lib.instrument(jax.jit(prefill, donate_argnums=(1, 2)),
                                   f"serve_prefill[{tag}]"),
-            ledger_lib.instrument(jax.jit(step, donate_argnums=(1, 2, 4)),
+            ledger_lib.instrument(jax.jit(step, donate_argnums=(1, 2, 3, 5)),
                                   f"serve_decode[{tag}]"),
             ledger_lib.instrument(jax.jit(cow, donate_argnums=(0,)),
                                   f"serve_cow[{tag}]"),
@@ -654,6 +774,15 @@ class PagedDecodeServer:
             self.kv_quant, self.attn_impl)
         self.pools = init_paged_kv(model, self.num_blocks,
                                    self.block_size, quant=self.kv_quant)
+        # expert-load counters (EXPERT_COUNTERS): cumulative on the device
+        # modulo 2**32, folded into host integers at every fetch; {} for a
+        # model that does not route without drops
+        self.stats = ({"experts": jnp.zeros((len(EXPERT_COUNTERS),),
+                                            jnp.int32)}
+                      if c.moe_dropless else {})
+        self._experts_seen = np.zeros((len(EXPERT_COUNTERS),), np.int64)
+        self.expert_counters: Dict[str, int] = (
+            dict.fromkeys(EXPERT_COUNTERS, 0) if c.moe_dropless else {})
         self.tokens = jnp.zeros((self.slots, self.t_cap), jnp.int32)
         self.pos = jnp.zeros((self.slots,), jnp.int32)
         self.tables = np.zeros((self.slots, self.max_blocks), np.int32)
@@ -959,20 +1088,22 @@ class PagedDecodeServer:
                 f"prefill would write shared block of rid={rid}: "
                 f"pos {st.prefilled} inside the first {st.n_shared} "
                 "borrowed table entries")
-            bucket = prefill_bucket(w)
-            chunk = (st.prompt[st.prefilled:st.prefilled + w]
-                     + [0] * (bucket - w))
+            # the chunk as one fresh numpy row (a list of a thousand Python
+            # ints costs 2 ms to hand over, with the device idle behind it
+            # whenever a finished stream's fetch has just drained the queue)
+            chunk = np.zeros((1, prefill_bucket(w)), np.int32)
+            chunk[0, :w] = st.prompt[st.prefilled:st.prefilled + w]
             # the device gets a HOST-side copy: on the CPU backend asarray
             # may alias the numpy buffer (and jnp.array's own copy is an
             # async device op), while the host mutates self.tables /
             # self.active in place before the dispatched program has run
             args = (jnp.asarray(self.tables[slot:slot + 1].copy()),
                     jnp.asarray([st.prefilled], jnp.int32),
-                    jnp.asarray([chunk], jnp.int32),
+                    jnp.asarray(chunk),
                     jnp.asarray(w, jnp.int32))
         with trace_lib.span("prefill/submit"):
-            logits, self.pools = self._prefill_fn(self.params, self.pools,
-                                                  *args)
+            logits, self.pools, self.stats = self._prefill_fn(
+                self.params, self.pools, self.stats, *args)
         st.prefilled += w
         self._register_prefix(st, final=st.prefilled >= p)
         if st.prefilled < p:
@@ -1141,11 +1272,12 @@ class PagedDecodeServer:
         return {
             "block_size": self.block_size,
             "n_layers": len(self.pools),
-            "kv_heads": int(self.model.cfg.kv_heads),
-            "head_dim": int(self.model.cfg.head_dim),
+            # the attention's cache row: pool name -> what a token holds
+            "row": {n: [int(d) for d in r]
+                    for n, r in self.model.cache_row().items()},
             "kv_quant": self.kv_quant,
-            "dtype": str(np.dtype(
-                np.asarray(jax.device_get(self.pools[0]["k"][:1])).dtype)),
+            "dtype": str(np.dtype(next(iter(
+                self.pools[0][n] for n in self.model.cache_row())).dtype)),
         }
 
     def export_stream(self, rid: int) -> Dict[str, Any]:
@@ -1233,19 +1365,17 @@ class PagedDecodeServer:
             return None
         # decode the per-layer block rows; shapes are fixed by geometry,
         # so a short buffer is a hard error, not a retry
-        bs = self.block_size
-        kv, hd = mine["kv_heads"], mine["head_dim"]
         decoded = []
         for li, rec in enumerate(payload["layers"]):
             pool = self.pools[li]
             out = {}
             for name, b64 in rec.items():
-                arr = np.asarray(jax.device_get(pool[name][:1]))
-                shape = (n_copy, bs, kv) if name.endswith("_scale") \
-                    else (n_copy, bs, kv, hd)
-                raw = np.frombuffer(base64.b64decode(b64),
-                                    dtype=arr.dtype).reshape(shape)
-                out[name] = raw
+                # a block row of this pool, whatever the attention's row is
+                # (a scale pool's is the row without its last axis)
+                out[name] = np.frombuffer(
+                    base64.b64decode(b64),
+                    dtype=np.dtype(pool[name].dtype)).reshape(
+                        (n_copy,) + pool[name].shape[1:])
             decoded.append(out)
         for i in range(n_copy):
             rows = [{name: jnp.asarray(lay[name][i])
@@ -1316,9 +1446,10 @@ class PagedDecodeServer:
             tables = jnp.asarray(masked)
             active = jnp.asarray(self.active.copy())   # see prefill_step
         with trace_lib.span("decode/submit"):
-            self.pools, self.tokens, self.pos, self.key = self._step_fn(
-                self.params, self.pools, self.tokens, tables, self.pos,
-                active, self.key)
+            (self.pools, self.tokens, self.pos, self.key,
+             self.stats) = self._step_fn(
+                self.params, self.pools, self.stats, self.tokens, tables,
+                self.pos, active, self.key)
         finished = []
         with trace_lib.span("decode/finish"):
             for rid, slot in list(self._slot_of.items()):
@@ -1333,8 +1464,16 @@ class PagedDecodeServer:
     def _finish(self, rid: int) -> None:
         st = self._streams.pop(rid)
         slot = self._slot_of.pop(rid)
-        row = np.asarray(jax.device_get(self.tokens[slot]))
-        self._results[rid] = [int(t) for t in row[:st.target]]
+        # the one fetch a finished stream costs brings the expert-load
+        # counters along: no round trip of their own
+        row, stats = jax.device_get((self.tokens[slot], self.stats))
+        self._results[rid] = [int(t) for t in np.asarray(row)[:st.target]]
+        if stats:
+            raw = np.asarray(stats["experts"]).astype(np.int64) % (1 << 32)
+            for name, d in zip(EXPERT_COUNTERS,
+                               (raw - self._experts_seen) % (1 << 32)):
+                self.expert_counters[name] += int(d)
+            self._experts_seen = raw
         self._release_stream(st, slot)
 
     # ---- results -------------------------------------------------------

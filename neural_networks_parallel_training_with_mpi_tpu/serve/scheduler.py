@@ -509,6 +509,13 @@ class Scheduler:
         self.attended_keys = 0
         self.padded_keys = 0
         self.kernel_keys = 0
+        # expert-load counters of a model that routes without drops
+        # (paged_kv.EXPERT_COUNTERS; {} otherwise): cumulative, as of the
+        # server's last fetch of a finished stream, stamped on the
+        # ``retire`` span of every tick that finished one (so a stamp is
+        # exact as of its tick) for whoever listens to spans
+        self.expert_counters: Dict[str, int] = dict(
+            self.server.expert_counters)
         self.telemetry = _ServeTelemetry(cfg)
         # per-request flow-trace ids must stay unique across the fleet's
         # merged timeline: prefix the scheduler-local rid with this
@@ -632,9 +639,14 @@ class Scheduler:
                 self.padded_keys += acct["padded_keys"]
                 self.kernel_keys += acct["kernel_keys"]
                 finished = self.server.step()
-            with trace_lib.span("retire", tick=self.tick_no):
+            with trace_lib.span("retire", tick=self.tick_no) as retire:
                 for srv_rid in finished:
                     done_now.append(self._retire(srv_rid))
+                if finished and self.expert_counters:
+                    # fresh as of this tick's step: the fetch of a
+                    # finished stream brought them
+                    self.expert_counters = dict(self.server.expert_counters)
+                    retire.attrs.update(self.expert_counters)
         self.telemetry.on_tick(self.tick_no, self._snapshot())
         self._gap_wall = time.time()
         self._gap_state = ("sched_bubble" if self._srv_rid
@@ -1014,6 +1026,7 @@ class Scheduler:
             "attended_keys": self.attended_keys,
             "padded_keys": self.padded_keys,
             "kernel_keys": self.kernel_keys,
+            **self.expert_counters,
             "attended_ratio": (
                 round(self.attended_keys / self.padded_keys, 4)
                 if self.padded_keys else None),

@@ -298,7 +298,8 @@ def test_donation_audit_fused_decode_program():
                             block_size=8, attn_impl="fused")
     masked = np.where(srv.active[:, None], srv.tables, 0)
     comp = srv._step_fn.lower(
-        srv.params, srv.pools, srv.tokens, jnp.asarray(masked), srv.pos,
+        srv.params, srv.pools, srv.stats, srv.tokens, jnp.asarray(masked),
+        srv.pos,
         jnp.asarray(srv.active), srv.key).compile()
     rep = donation_report(comp)
     donated = len(jax.tree_util.tree_leaves(srv.pools)) + 2  # + tokens, pos

@@ -137,10 +137,11 @@ def paged_names():
     srv = PagedDecodeServer(model, model.init(prng.init_key(0)), slots=2,
                             num_blocks=24, block_size=8)
     step = srv._step_fn.lower(
-        srv.params, srv.pools, srv.tokens, jnp.asarray(srv.tables), srv.pos,
+        srv.params, srv.pools, srv.stats, srv.tokens,
+        jnp.asarray(srv.tables), srv.pos,
         jnp.asarray(srv.active), srv.key).compile()
     prefill = srv._prefill_fn.lower(
-        srv.params, srv.pools, jnp.asarray(srv.tables[:1]),
+        srv.params, srv.pools, srv.stats, jnp.asarray(srv.tables[:1]),
         jnp.zeros((1,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
         jnp.asarray(5, jnp.int32)).compile()
     return {"step": _op_names(step), "prefill": _op_names(prefill)}
