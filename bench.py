@@ -2769,7 +2769,7 @@ def bench_goodput(out_path: str = "BENCH_GOODPUT.json",
 
 
 def bench_serve(out_path: str = "BENCH_SERVE.json",
-                attn_impl: str = "gathered") -> str:
+                attn_impl: str = "auto") -> str:
     """The serving-subsystem bench (serve/): a CLOSED-LOOP load sweep of
     the continuous-batching scheduler over the paged KV cache — tokens/s
     and p50/p99 TTFT/ITL vs. offered load (concurrent clients) — plus
@@ -4402,12 +4402,13 @@ def main() -> int:
                          "digest; write BENCH_CHAOS.json")
     ap.add_argument("--chaos-inproc", action="store_true",
                     help=argparse.SUPPRESS)  # internal: child entry
-    ap.add_argument("--serve-attn-impl", choices=["gathered", "fused"],
-                    default="gathered",
+    ap.add_argument("--serve-attn-impl",
+                    choices=["auto", "gathered", "fused"], default="auto",
                     help="attention dispatch for the --serve sweep: "
-                         "'gathered' (pool[table] materialization, the "
-                         "parity reference) or 'fused' (Pallas paged-"
-                         "attention kernel)")
+                         "'auto' (the kernel where a TPU runs the per-head "
+                         "K/V row, else gathered), 'gathered' (pool[table] "
+                         "materialization, the parity reference) or 'fused' "
+                         "(Pallas paged-attention kernel)")
     ap.add_argument("--paged-attn", action="store_true",
                     help="fused paged-attention bench: gathered-vs-fused "
                          "decode A/B at ragged stream lengths (token-"

@@ -690,57 +690,112 @@ flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)
 # Paged attention (serving: decode + chunked prefill over a block pool)
 # ==========================================================================
 
+# Measured tilings of the paged kernel, ``(block_size, kv_heads * head_dim,
+# "decode" | "chunk") -> (pages, tile_cols)``: pool pages DMA'd and scored a
+# loop step, and query columns a row tile (1 at decode).  Keyed by what the
+# kernel sees of its operands; a row is there only if it was timed on the
+# chip (tools/paged_attn_timing.py on one TPU v5e, PR 30, PERF.md section
+# 6: 64 pages tie with 32 at the cell's lengths and win at the table's full
+# width; a chunk is flat between 64 and 128 columns, and 64 compiles in a
+# third of the time).  Every other shape walks ``_UNTIMED_KEYS`` key
+# positions a step and tiles a chunk at about ``_UNTIMED_ROWS`` query rows
+# a KV head.
+PAGED_TILES = {
+    (16, 256, "decode"): (64, 1),
+    (16, 256, "chunk"): (32, 64),
+}
+_UNTIMED_KEYS = 128
+_UNTIMED_ROWS = 256
+
+
+def paged_tiles(block_size: int, lanes: int, width: int, groups: int,
+                max_blocks: int, pages: Optional[int] = None,
+                tile_cols: Optional[int] = None,
+                quant: bool = False) -> Tuple[int, int]:
+    """``(pages, tile_cols)`` of the paged kernel for this input: an
+    explicit size wins, ``None`` comes from the timed table (else the
+    untimed rule).  ``pages`` is cut to the table's width, and is 1 for
+    int8 pools (``quant``: a 16-row int8 page is half a sublane tile, so
+    pages do not stack in one landing buffer); ``tile_cols`` must divide
+    ``width`` into tiles whose rows (``tile_cols * groups``) fill whole
+    sublanes, else the chunk is one tile."""
+    kind = "decode" if width == 1 else "chunk"
+    t_pages, t_cols = PAGED_TILES.get(
+        (block_size, lanes, kind),
+        (max(1, _UNTIMED_KEYS // block_size),
+         max(1, _UNTIMED_ROWS // groups)))
+    pages = 1 if quant else max(
+        1, min(t_pages if pages is None else pages, max_blocks))
+    cols = min(t_cols if tile_cols is None else tile_cols, width)
+    fits = [c for c in range(cols, 0, -1)
+            if width % c == 0 and (c * groups) % 8 == 0]
+    return pages, (fits[0] if fits else width)
+
+
 def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
-                       v_hbm, *rest, block_size: int, kv_heads: int,
-                       groups: int, width: int, scale: float,
-                       quant: bool):
-    """Grid: (streams,).  Each program walks ITS stream's allocated
-    block-table entries — ``ceil(len/block_size)`` of them, a dynamic
-    ``fori_loop`` bound — double-buffering pool blocks HBM→VMEM with
-    ``make_async_copy`` (block ``j+1``'s DMA is in flight while ``j``
-    computes) and carrying the online-softmax (max, denom, acc) in the
-    loop.  KV heads are unrolled in-program: one block fetch serves every
-    head (a (stream, kv_head) grid would DMA each block ``kv_heads``
-    times).
+                       v_hbm, *rest, block_size: int, pages: int,
+                       kv_heads: int, groups: int, tile_cols: int,
+                       scale: float, quant: bool):
+    """Grid: (streams, row tiles).  A program owns ``tile_cols`` query
+    columns of one stream (all heads: ``tile_cols * groups`` rows a KV
+    head) and walks the stream's block table ``pages`` entries a loop step,
+    up to the last key ITS rows can see — ``min(len, first + tile_cols)``,
+    a dynamic ``fori_loop`` bound.  All ``pages`` copies of step ``j+1``
+    are in flight (``make_async_copy`` into the other half of a two-slot
+    landing buffer) while step ``j`` computes lane-dense ``(rows, pages *
+    block_size)`` scores, carrying the online-softmax (max, denom, acc) a
+    head.  KV heads are unrolled in-program: one page fetch serves every
+    head.  A step's copies are issued and awaited by a loop over its pages,
+    not an unrolled list: unrolled they ran 15 % faster at decode and cost
+    3.5 s of lowering a program (PERF.md section 6, PR 30).
 
-    Refs: ``tables (S, MB)`` / ``lens (S,)`` / ``starts (S,)`` ride
-    scalar prefetch (SMEM) — runtime VALUES, not compile-time constants,
-    so table churn and length growth re-run the same compiled kernel.
-    ``q (1, KV, W·G, hd)`` in VMEM; ``k``/``v`` pools ``(NB, bs, KV·hd)``
-    (and int8 scale pools ``(NB, 1, KV·bs)`` when ``quant``) stay
-    UNBLOCKED in HBM — only the blocks a stream actually owns ever cross
-    into VMEM, which is the bandwidth half of the win (the FLOPs half is
-    the loop bound).  Every pool operand's last dim is lane-dense: Mosaic
-    refuses to DMA-slice a block out of an array whose last dim is below
-    the 128-lane tile, so heads are folded into it and head ``h`` is a
-    static lane slice.  Scratch: 2-slot VMEM landing buffers per pool
-    operand + a (2, n_operands) DMA semaphore array.
+    Refs: ``tables (S, MB)`` / ``lens (S,)`` / ``starts (S,)`` ride scalar
+    prefetch (SMEM) — runtime VALUES, not compile-time constants, so table
+    churn and length growth re-run the same compiled kernel.  ``q (1, KV,
+    rows, hd)`` in VMEM; the pools ``(NB, bs, KV·hd)`` (and int8 scale
+    pools ``(NB, 1, KV·bs)`` when ``quant``, one page a step) stay
+    UNBLOCKED in HBM — only the pages a tile needs ever cross into VMEM.
+    A pool's last dim is lane-dense (heads folded into it; head ``h`` is a
+    static lane slice): Mosaic refuses to DMA-slice a page out of an array
+    whose last dim is below the 128-lane tile.
 
-    Blocks past a stream's true length (and every block of an inactive
-    ``len=0`` lane, whose loop never runs) contribute NOTHING.  Within
-    the last live block the tail positions ``>= len`` are masked, so the
-    sink block's frozen garbage is never attended.  int8 pools apply
-    their per-(position, head) scales to the scores and probabilities —
-    the gathered path's scheme.  A ``len=0`` lane exits with output 0,
-    the flash kernels' "no contribution" convention."""
+    Operands reach the MXU in the pool's type (bf16 pools: bf16 products,
+    f32 accumulation; f32 pools stay exact; int8 pools are cast to f32 and
+    scaled as the gathered path scales them); scores, the ``1/sqrt(hd)``
+    scale and the softmax state are f32.
+
+    Steps wholly below the tile's first query position and the stream's
+    length need no mask; the last one or two apply ``k < len`` and the
+    per-row causal bound, and zero the value rows past the last fetched
+    key (a page that is not needed is not fetched, so its landing rows
+    hold whatever was there).  A ``len == 0`` lane, and a tile that starts
+    at or past ``len`` (the pad columns of a bucketed chunk), walks nothing
+    and exits with output 0, the flash kernels' "no contribution"
+    convention; the sink block is never attended."""
     if quant:
         (ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, ks_buf, vs_buf, sem) = rest
     else:
         o_ref, k_buf, v_buf, sem = rest
-    s = pl.program_id(0)
+    s, t = pl.program_id(0), pl.program_id(1)
     ln = lens_ref[s]
-    nb = lax.div(ln + block_size - 1, block_size)
-    rows = width * groups
+    first = starts_ref[s] + t * tile_cols       # the tile's first query
+    limit = lax.min(ln, first + tile_cols)      # keys its rows can see
+    span = pages * block_size
+    n_pages = lax.div(limit + block_size - 1, block_size)
+    n_steps = lax.div(limit + span - 1, span)
+    n_full = lax.div(lax.min(first + 1, limit), span)
+    rows = tile_cols * groups
     hd = q_ref.shape[-1]
 
-    def _copies(j):
+    def page_copies(j, i):
         slot = lax.rem(j, 2)
-        blk = tables_ref[s, j]
+        blk = tables_ref[s, j * pages + i]
+        dst = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
         ops = [
-            pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot],
+            pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, dst],
                                   sem.at[slot, 0]),
-            pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot],
+            pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, dst],
                                   sem.at[slot, 1]),
         ]
         if quant:
@@ -752,77 +807,95 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
             ]
         return ops
 
-    # rows are (W, G) flattened: row r is query column r // groups
-    k_off = lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
-    q_pos = starts_ref[s] + lax.broadcasted_iota(
-        jnp.int32, (rows, block_size), 0) // groups
+    def each_page(j, act, guarded: bool):
+        """``act`` on every copy of step ``j``; ``guarded`` stops at the
+        tile's last page (a full step has them all)."""
+        count = (lax.min(pages, n_pages - j * pages) if guarded else pages)
 
-    def body(j, carry):
-        acc, m, l = carry
+        def one(i, carry):
+            for c in page_copies(j, i):
+                act(c)
+            return carry
 
-        @pl.when(j + 1 < nb)
-        def _prefetch():
-            for c in _copies(j + 1):
-                c.start()
+        lax.fori_loop(0, count, one, 0)
 
-        for c in _copies(j):
-            c.wait()
-        slot = lax.rem(j, 2)
-        k = k_buf[slot].astype(jnp.float32)          # (bs, KV*hd)
-        v = v_buf[slot].astype(jnp.float32)
+    # rows are (column, group) flattened: row r is query column r // groups,
+    # so key offset o is at or below it where o * groups <= r
+    k_off = lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    rel = k_off * groups - lax.broadcasted_iota(jnp.int32, (rows, span), 0)
+    v_row = lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+    qs = [q_ref[0, h] for h in range(kv_heads)]             # (rows, hd)
+    if quant:
+        qs = [q.astype(jnp.float32) for q in qs]
 
-        def head_scale(buf, h):
-            # (1, bs), sliced from the REF: a value slice past lane 128
-            # of the (1, KV*bs) row does not lower
-            return buf[slot, :, h * block_size:(h + 1) * block_size]
+    def step(masked: bool):
+        def body(j, carry):
+            @pl.when(j + 1 < n_steps)
+            def _prefetch():
+                each_page(j + 1, lambda c: c.start(), True)
 
-        k_pos = j * block_size + k_off
-        keep = (k_pos < ln) & (k_pos <= q_pos)       # (rows, bs)
-        acc, m, l = list(acc), list(m), list(l)
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32) * scale    # (rows, hd)
-            sc = jax.lax.dot_general(
-                q, k[:, h * hd:(h + 1) * hd], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (rows, bs)
-            if quant:
-                sc = sc * head_scale(ks_buf, h)
-            sc = jnp.where(keep, sc, NEG_INF)
-            m_new = jnp.maximum(m[h], sc.max(axis=-1, keepdims=True))
-            # a row with no attendable key in THIS block keeps its prior
-            # max; every live row sees position 0 in block 0, so m is
-            # finite before the running exp() can ever see exp(0) garbage
-            p = jnp.exp(sc - m_new)
-            corr = jnp.exp(m[h] - m_new)
-            l[h] = corr * l[h] + p.sum(axis=-1, keepdims=True)
-            if quant:
-                p = p * head_scale(vs_buf, h)
-            acc[h] = corr * acc[h] + jax.lax.dot_general(
-                p, v[:, h * hd:(h + 1) * hd], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m[h] = m_new
-        return tuple(acc), tuple(m), tuple(l)
+            each_page(j, lambda c: c.wait(), masked)
+            slot = lax.rem(j, 2)
+
+            def head_scale(buf, h):
+                # (1, bs), sliced from the REF: a value slice past lane 128
+                # of the (1, KV*bs) row does not lower
+                return buf[slot, :, h * block_size:(h + 1) * block_size]
+
+            if masked:
+                k0 = j * span
+                keep = (k_off < ln - k0) & (rel <= (first - k0) * groups)
+                fetched = v_row < limit - k0
+            out = []
+            for h, (acc, m, l) in enumerate(carry):
+                lanes = slice(h * hd, (h + 1) * hd)
+                k = k_buf[slot, :, lanes]                   # (span, hd)
+                v = v_buf[slot, :, lanes]
+                if quant:
+                    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+                if masked:
+                    v = jnp.where(fetched, v, jnp.zeros_like(v))
+                sc = _dot(qs[h], k, _NT) * scale            # (rows, span)
+                if quant:
+                    sc = sc * head_scale(ks_buf, h)
+                if masked:
+                    sc = jnp.where(keep, sc, NEG_INF)
+                m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+                # every walked row sees key 0 in step 0 (its position is
+                # >= 0 and len > 0), so m is finite before the running
+                # exp() can ever see exp(0) garbage
+                p = jnp.exp(sc - m_new)
+                corr = jnp.exp(m - m_new)
+                l_new = corr * l + p.sum(axis=-1, keepdims=True)
+                if quant:
+                    p = p * head_scale(vs_buf, h)
+                acc_new = corr * acc + _dot(p.astype(v.dtype), v, _NN)
+                out.append((acc_new, m_new, l_new))
+            return tuple(out)
+        return body
 
     # per-head carries as tuples: the kv_heads loop is a Python unroll,
     # and a stacked (kv_heads, rows, ...) carry updated with .at[h].set
     # is a scatter, which Mosaic does not lower
-    acc0 = (jnp.zeros((rows, hd), jnp.float32),) * kv_heads
-    m0 = (jnp.full((rows, 1), NEG_INF, jnp.float32),) * kv_heads
-    l0 = (jnp.zeros((rows, 1), jnp.float32),) * kv_heads
+    carry0 = ((jnp.zeros((rows, hd), jnp.float32),
+               jnp.full((rows, 1), NEG_INF, jnp.float32),
+               jnp.zeros((rows, 1), jnp.float32)),) * kv_heads
+    walk = (ln > 0) & (first < ln)
 
-    @pl.when(nb == 0)
+    @pl.when(jnp.logical_not(walk))
     def _inactive():
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    @pl.when(nb > 0)
+    @pl.when(walk)
     def _walk():
-        for c in _copies(0):
-            c.start()
-        acc, m, l = lax.fori_loop(0, nb, body, (acc0, m0, l0))
-        for h in range(kv_heads):
-            empty = m[h] < (NEG_INF * 0.5)
-            l_safe = jnp.where(empty, 1.0, l[h])
+        each_page(0, lambda c: c.start(), True)
+        carry = _two_loops(0, n_full, n_steps, step(False), step(True),
+                           carry0)
+        for h, (acc, m, l) in enumerate(carry):
+            empty = m < (NEG_INF * 0.5)
+            l_safe = jnp.where(empty, 1.0, l)
             o_ref[0, h] = jnp.where(empty, 0.0,
-                                    acc[h] / l_safe).astype(o_ref.dtype)
+                                    acc / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -830,69 +903,101 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     starts: jax.Array, *,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
+                    pages: Optional[int] = None,
+                    tile_cols: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused paged attention: reads K/V straight from the serving block
     pool through per-stream block tables and reduces over each stream's
     TRUE length instead of the table capacity ``max_blocks·block_size``
-    (serve/paged_kv.py's gathered path; ROADMAP 1(b)'s FLOPs win).
+    (serve/paged_kv.py's gathered path).
 
     One kernel covers the family: ``width == 1`` is the batched decode
     step (each stream's single query at position ``lengths-1``),
     ``width > 1`` is a chunked-prefill bucket (rows at absolute positions
-    ``starts .. starts+width-1``, flash-style causal within the chunk).
+    ``starts .. starts+width-1``, flash-style causal within the chunk),
+    tiled over its query columns.
 
     * ``q``: (streams, width, n_heads, head_dim) — GQA folds in-kernel
       (``n_heads`` must be a multiple of the pool's ``kv_heads``).
-    * ``k_pool``/``v_pool``: (num_blocks, block_size, kv_heads, head_dim)
-      — f32/bf16, or int8 with ``k_scale``/``v_scale``
-      (num_blocks, block_size, kv_heads) f32, applied to the scores and
-      probabilities.
+    * ``k_pool``/``v_pool``: (num_blocks, block_size, kv_heads, head_dim),
+      or with the heads already folded into the lanes, (num_blocks,
+      block_size, kv_heads·head_dim) — the layout the kernel reads, so a
+      pool stored that way reaches it without a copy — f32/bf16, or int8
+      with ``k_scale``/``v_scale`` (num_blocks, block_size, kv_heads) f32,
+      applied to the scores and probabilities.
     * ``tables``: (streams, max_blocks) int32 pool indices; unallocated
-      entries point at the sink block and are NEVER walked (the block
-      loop stops at ``ceil(length/block_size)``).
+      entries point at the sink block and are NEVER walked (the page
+      walk stops at ``ceil(length/block_size)``).
     * ``lengths``: (streams,) int32 attendable keys per stream (0 = an
-      inactive lane: zero blocks walked, zero blocks fetched, output 0).
+      inactive lane: zero pages walked, zero pages fetched, output 0).
     * ``starts``: (streams,) int32 absolute position of each stream's
       first query row (decode passes ``lengths - 1``).
+    * ``pages`` / ``tile_cols``: pages a loop step and query columns a row
+      tile; ``None`` asks :func:`paged_tiles` (int8 pools walk one page a
+      step).
 
     Tables/lengths/starts are traced scalar-prefetch operands: block-table
     churn (admission, growth, eviction) re-runs the SAME compiled kernel
     — pinned by tests/test_paged_attn.py's compile-count test."""
     s_n, width, n_heads, hd = q.shape
-    nb, bs, kv_heads, hd_k = k_pool.shape
-    if hd_k != hd:
-        raise ValueError(f"head_dim mismatch: q {hd} vs pool {hd_k}")
+    if k_pool.ndim == 3:
+        nb, bs, lanes = k_pool.shape
+        if lanes % hd:
+            raise ValueError(f"folded pool row {lanes} is not a multiple of "
+                             f"head_dim {hd}")
+        kv_heads = lanes // hd
+    else:
+        nb, bs, kv_heads, hd_k = k_pool.shape
+        if hd_k != hd:
+            raise ValueError(f"head_dim mismatch: q {hd} vs pool {hd_k}")
     if n_heads % kv_heads:
         raise ValueError(f"n_heads {n_heads} not a multiple of kv_heads "
                          f"{kv_heads}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("int8 pools need BOTH k_scale and v_scale")
-    quant = k_scale is not None
-    groups = n_heads // kv_heads
-    scale = 1.0 / (hd ** 0.5)
     if interpret is None:
         interpret = _interpret_default()
+    pages, tile_cols = paged_tiles(
+        bs, kv_heads * hd, width, n_heads // kv_heads, tables.shape[1],
+        pages, tile_cols, quant=k_scale is not None)
+    return _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
+                                 k_scale, v_scale, pages=pages,
+                                 tile_cols=tile_cols, interpret=interpret)
+
+
+# jitted for the flash calls' reason: 30 unrolled layers lower one kernel
+@functools.partial(jax.jit, static_argnames=("pages", "tile_cols",
+                                             "interpret"))
+def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
+                          k_scale, v_scale, *, pages: int, tile_cols: int,
+                          interpret: bool):
+    s_n, width, n_heads, hd = q.shape
+    nb, bs = k_pool.shape[:2]
+    lanes = math.prod(k_pool.shape[2:])
+    kv_heads = lanes // hd
+    quant = k_scale is not None
+    groups = n_heads // kv_heads
+    rows = tile_cols * groups
+    span = pages * bs
 
     # (S, W, H, hd) -> (S, KV, W·G, hd): per-kv-head query rows contiguous
     qk = q.reshape(s_n, width, kv_heads, groups, hd)
     qk = qk.transpose(0, 2, 1, 3, 4).reshape(s_n, kv_heads,
                                              width * groups, hd)
+    if not quant:
+        qk = qk.astype(k_pool.dtype)
 
-    row_map = lambda s, tbl, lns, sts: (s, 0, 0, 0)      # noqa: E731
+    row_map = lambda s, t, tbl, lns, sts: (s, 0, t, 0)      # noqa: E731
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)      # never blocked
-    in_specs = [
-        pl.BlockSpec((1, kv_heads, width * groups, hd), row_map),
-        hbm_spec, hbm_spec,
-    ]
-    # pool blocks cross into VMEM as lane-dense (bs, KV*hd) rows (see
+    in_specs = [pl.BlockSpec((1, kv_heads, rows, hd), row_map),
+                hbm_spec, hbm_spec]
+    # pool pages cross into VMEM as lane-dense (bs, KV*hd) rows (see
     # the kernel's docstring); head h is the lane slice [h*hd, (h+1)*hd)
-    operands = [qk, k_pool.reshape(nb, bs, kv_heads * hd),
-                v_pool.reshape(nb, bs, kv_heads * hd)]
+    operands = [qk, k_pool.reshape(nb, bs, lanes),
+                v_pool.reshape(nb, bs, lanes)]
     n_dma = 2
-    scratch = [
-        pltpu.VMEM((2, bs, kv_heads * hd), k_pool.dtype),
-        pltpu.VMEM((2, bs, kv_heads * hd), v_pool.dtype),
-    ]
+    scratch = [pltpu.VMEM((2, span, lanes), k_pool.dtype),
+               pltpu.VMEM((2, span, lanes), v_pool.dtype)]
     if quant:
         # scales ride head-major, (1, KV*bs) per block, so head h's
         # per-position scales are a static lane slice that broadcasts
@@ -908,18 +1013,28 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_n,),
+        grid=(s_n, width // tile_cols),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kv_heads, width * groups, hd), row_map),
+        out_specs=pl.BlockSpec((1, kv_heads, rows, hd), row_map),
         scratch_shapes=scratch,
     )
+    # the landing buffers, the query and output tiles (double-buffered,
+    # lane-padded) and the f32 score-sized temporaries of one loop step
+    landing = 4 * span * lanes * k_pool.dtype.itemsize
+    tiles = 4 * kv_heads * rows * max(hd, 128) * 4
+    scores = 8 * max(rows, 8) * max(span, 128) * 4
     out = pl.pallas_call(
         functools.partial(
-            _paged_attn_kernel, block_size=bs, kv_heads=kv_heads,
-            groups=groups, width=width, scale=scale, quant=quant),
+            _paged_attn_kernel, block_size=bs, pages=pages,
+            kv_heads=kv_heads, groups=groups, tile_cols=tile_cols,
+            scale=1.0 / (hd ** 0.5), quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (s_n, kv_heads, width * groups, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(100 << 20, max(
+                32 << 20, landing + tiles + scores)))),
         interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),

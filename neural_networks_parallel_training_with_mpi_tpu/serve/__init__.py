@@ -7,11 +7,15 @@ when full".  This package is the service-shaped runtime above it:
 * :mod:`serve.paged_kv` — a block-allocated KV pool with per-stream
   block tables, so heterogeneous stream lengths share device memory
   instead of each padding to max.  Attention is dispatched behind the
-  ``attn_impl`` seam: ``'gathered'`` (static-shape ``pool[table]``
-  materialization, the parity reference) or ``'fused'`` (the Pallas
-  paged-attention kernel, ``ops.pallas_kernels.paged_attention``, which
-  reads K/V straight from the pool and stops at each stream's true
-  length — the FLOPs win on top of the memory win).  With
+  ``attn_impl`` seam, default ``'auto'``: on a TPU, for the per-head K/V
+  cache row at a lane-dense ``kv_heads * head_dim``, that is ``'fused'``
+  (the Pallas paged-attention kernel, ``ops.pallas_kernels.paged_attention``,
+  which reads K/V straight from the pool through the block tables, several
+  pages a loop step, and stops at each stream's true length, in the decode
+  and the prefill-chunk program alike); anywhere else (the CPU, the latent
+  row, int8 KV) it is ``'gathered'`` (static-shape ``pool[table]``
+  materialization, the parity reference).  Both stay as explicit values.
+  With
   ``prefix_cache=True`` identical prompt prefixes share blocks across
   streams (refcounts + a host-side prefix index + copy-on-write forks),
   so a cached prefix admits without re-prefilling — near-zero TTFT for

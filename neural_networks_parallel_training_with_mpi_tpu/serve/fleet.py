@@ -2052,7 +2052,7 @@ def _worker_argparser():
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=32)
     ap.add_argument("--queue-depth", type=int, default=64)
-    ap.add_argument("--attn-impl", default="gathered")
+    ap.add_argument("--attn-impl", default="auto")
     ap.add_argument("--prefix-cache", action="store_true")
     ap.add_argument("--kv-quant", action="store_true")
     ap.add_argument("--temperature", type=float, default=0.0)

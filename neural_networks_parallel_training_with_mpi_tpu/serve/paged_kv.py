@@ -24,21 +24,29 @@ blocks:
   recompile).  Unallocated entries point at the reserved **sink block
   0**, which is never handed to a stream: pad/frozen writes land there
   harmlessly and are never attended.
-* **Attention dispatch** (``attn_impl``): the default **gathered** path
-  gathers each row's blocks ``pool[table] -> (T_cap, kv_heads,
-  head_dim)`` (``T_cap = max_blocks * block_size``) and attends under
-  the causal mask ``t <= pos`` — the same reduction, over the same
-  values in the same order, as the dense cache path, which is why
-  greedy paged decode is token-identical to ``DecodeServer`` /
-  ``models.generate.generate`` (pinned by tests/test_serve_paged.py).
-  The gather materializes the attended window transiently (what dense
-  attention reads anyway); the win is the PERSISTENT allocation, which
-  now tracks actual tokens in flight instead of slots x max_len.  The
-  **fused** path (``ops.pallas_kernels.paged_attention``) adds the
-  FLOPs/bandwidth win on top: the Pallas kernel reads K/V straight from
-  the pool through the tables and walks only ``ceil(len/block_size)``
-  blocks per stream — token-identical to gathered, pinned by
-  tests/test_paged_attn.py.
+* **Attention dispatch** (``attn_impl``, default ``auto``, resolved once
+  in :func:`resolve_attn_impl` from what the code can observe): on a TPU,
+  with the per-head K/V row and a lane-dense ``kv_heads * head_dim``,
+  ``auto`` is the **fused** path (``ops.pallas_kernels.paged_attention``):
+  the Pallas kernel reads K/V straight from the pool through the tables,
+  several pages a loop step, and stops at each stream's own length — the
+  decode program and the prefill-chunk program alike materialise no
+  ``pool[tables]`` and reduce over no ``T_cap`` keys.  Its pools are stored
+  with the heads folded into the lanes, ``(num_blocks, block_size,
+  kv_heads * head_dim)``: the layout the kernel reads, so the pool reaches
+  it without a copy (the bytes of a block row are the same either way, so
+  export / import and the handoff do not care).  Anywhere else (the CPU,
+  the latent row, int8 KV, a head row that does not fill the lanes)
+  ``auto`` is the **gathered** path, which gathers each row's blocks
+  ``pool[table] -> (T_cap, kv_heads, head_dim)`` (``T_cap = max_blocks *
+  block_size``) and attends under the causal mask ``t <= pos`` — the same
+  reduction, over the same values in the same order, as the dense cache
+  path, which is why greedy paged decode is token-identical to
+  ``DecodeServer`` / ``models.generate.generate`` (pinned by
+  tests/test_serve_paged.py); the fused path is token-identical to it
+  (tests/test_paged_attn.py).  ``"gathered"`` and ``"fused"`` stay as
+  explicit values: the parity reference, and the kernel in interpret mode
+  on the CPU.
 * **Writes** are scatters at ``(table[pos // block_size], pos %
   block_size)`` — one position per row at decode, a chunk of positions
   at prefill (chunks may straddle block boundaries; each position
@@ -104,8 +112,9 @@ import numpy as np
 
 from ..models.generate import _quantize_kv, _sample
 from ..models.transformer import Transformer, split_qkv
-from ..ops.pallas_kernels import paged_attention
+from ..ops.pallas_kernels import paged_attention, paged_tiles
 from ..train import trace as trace_lib
+from ..utils import compile_ledger as ledger_lib
 
 Pytree = Any
 
@@ -113,8 +122,10 @@ Pytree = Any
 # over all max_blocks*block_size key positions per stream (the parity
 # reference); 'fused' reads K/V straight from the block pool via the
 # Pallas paged-attention kernel and stops at each stream's true length
-# (ops.pallas_kernels.paged_attention — token-identical, pinned)
-ATTN_IMPLS = ("gathered", "fused")
+# (ops.pallas_kernels.paged_attention — token-identical, pinned); 'auto'
+# is 'fused' where a TPU runs a per-head row the kernel takes, else
+# 'gathered' (resolve_attn_impl)
+ATTN_IMPLS = ("auto", "gathered", "fused")
 
 # cumulative expert-load counters of a model that routes without drops
 # (models.moe.DroplessMoE), carried on the device beside the pools as one
@@ -337,29 +348,61 @@ class PrefixIndex:
             self.version += 1
 
 
+def resolve_attn_impl(model: Transformer, attn_impl: str = "auto",
+                      kv_quant: bool = False) -> str:
+    """``'gathered'`` or ``'fused'`` for this model on this backend.  An
+    explicit value is kept (``fused`` refuses the latent row by name);
+    ``auto`` takes the kernel where a TPU runs it on shapes it was made
+    for: the cache row is per-head K and V, ``kv_heads * head_dim`` fills
+    whole 128-lane tiles (a pool page is then one lane-dense DMA), and
+    the pools are not int8 (that walk is one page a step and was never
+    timed on the chip).  No width rule: the kernel beat ``gathered`` at
+    every length timed (PERF.md section 6, PR 30)."""
+    c = model.cfg
+    if attn_impl not in ATTN_IMPLS:
+        raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
+                         f"got {attn_impl!r}")
+    latent = c.attention_kind == "mla"
+    if latent and attn_impl == "fused":
+        raise ValueError(
+            "attn_impl='fused' is the Pallas paged kernel over per-head K "
+            "and V pools; latent attention's cache row has no paged kernel "
+            "yet: use attn_impl='gathered'")
+    if attn_impl != "auto":
+        return attn_impl
+    kernel = (jax.default_backend() == "tpu" and not latent and not kv_quant
+              and (c.kv_heads * c.head_dim) % 128 == 0)
+    return "fused" if kernel else "gathered"
+
+
 def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
-                  quant: bool = False):
+                  quant: bool = False, folded: bool = False):
     """Per-layer paged pools, one per entry of the attention's cache row
     (``model.cache_row()``), each ``(num_blocks, block_size, *row)`` —
     :func:`models.generate.init_kv_cache` with the length axis split into
-    (block, offset).  ``quant=True`` (per-head K and V only) stores int8
-    codes plus one f32 scale per (block, offset, head), the identical
+    (block, offset).  ``folded`` (the fused kernel's layout) stores a
+    per-head row with its heads folded into the lanes, ``(num_blocks,
+    block_size, kv_heads * head_dim)``: the same bytes a block row, the
+    shape the kernel DMAs.  ``quant=True`` (per-head K and V only) stores
+    int8 codes plus one f32 scale per (block, offset, head), the identical
     scheme the dense cache uses (scales are per position, so paging
     cannot change the numbers)."""
     c = model.cfg
     row = model.cache_row()
     lead = (num_blocks, block_size)
+    fold = lambda r: (int(np.prod(r)),) if folded else r      # noqa: E731
     if quant:
         if set(row) != {"k", "v"}:
             raise ValueError(
                 "kv_quant stores int8 codes of per-head K and V; the cache "
                 f"row {sorted(row)} of this attention has no such scheme yet")
-        return [{**{n: jnp.zeros(lead + r, jnp.int8)
+        return [{**{n: jnp.zeros(lead + fold(r), jnp.int8)
                     for n, r in row.items()},
                  **{f"{n}_scale": jnp.ones(lead + r[:-1], jnp.float32)
                     for n, r in row.items()}}
                 for _ in range(c.n_layers)]
-    return [{n: jnp.zeros(lead + r, c.compute_dtype) for n, r in row.items()}
+    return [{n: jnp.zeros(lead + fold(r), c.compute_dtype)
+             for n, r in row.items()}
             for _ in range(c.n_layers)]
 
 
@@ -373,23 +416,17 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     block-handoff import scatter (``serve_import`` — the CoW copy's
     sibling with the source row arriving from the host instead of
     another pool row).  Cached per (model, geometry, sampling,
-    attn_impl) so several servers compile once.  ``attn_impl='fused'``
-    swaps the gathered attention for the Pallas paged kernel;
-    everything else (scatter coordinates, sampling, bookkeeping) is
-    shared, which is what makes gathered-vs-fused an attention-only
-    A/B."""
+    attn_impl) so several servers compile once.  ``attn_impl`` is
+    resolved here, once (:func:`resolve_attn_impl`): ``'fused'`` swaps the
+    gathered attention for the Pallas paged kernel over pools whose heads
+    are folded into the lanes (``init_paged_kv(folded=True)``); everything
+    else (scatter coordinates, sampling, bookkeeping) is shared, which is
+    what makes gathered-vs-fused an attention-only A/B."""
     bs, mb = int(block_size), int(max_blocks)
     t_cap = bs * mb
     c = model.cfg
-    if attn_impl not in ATTN_IMPLS:
-        raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
-                         f"got {attn_impl!r}")
+    attn_impl = resolve_attn_impl(model, attn_impl, kv_quant)
     latent = c.attention_kind == "mla"
-    if latent and attn_impl == "fused":
-        raise ValueError(
-            "attn_impl='fused' is the Pallas paged kernel over per-head K "
-            "and V pools; latent attention's cache row has no paged kernel "
-            "yet: use attn_impl='gathered'")
     if latent and kv_quant:
         raise ValueError(
             "kv_quant stores int8 codes of per-head K and V; latent "
@@ -481,23 +518,35 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                     v, vs = _quantize_kv(v)
                     new_ksp = pool["k_scale"].at[blk, off].set(ks)
                     new_vsp = pool["v_scale"].at[blk, off].set(vs)
+                # a token's row in the pool's own trailing shape: (KV, hd),
+                # or KV*hd under the fused kernel's folded layout
+                row = pool["k"].shape[2:]
                 new_kp = pool["k"].at[blk, off].set(
-                    k.astype(pool["k"].dtype))
+                    k.astype(pool["k"].dtype).reshape(b, w, *row))
                 new_vp = pool["v"].at[blk, off].set(
-                    v.astype(pool["v"].dtype))
+                    v.astype(pool["v"].dtype).reshape(b, w, *row))
             if attn_impl == "fused":
                 # the Pallas kernel reads K/V straight from the pool
                 # through the tables and reduces over each row's TRUE
                 # length — no pool[table] materialization, no
                 # max_blocks*bs reduction.  int8 scale pools ride in and
                 # dequantize on load.
+                pages, cols = paged_tiles(
+                    bs, c.kv_heads * c.head_dim, w, c.n_heads // c.kv_heads,
+                    mb, quant=quant)
+                ledger_lib.note("attention", {
+                    "impl": "paged", "pages": pages, "tile_cols": cols,
+                    "block_size": bs})
                 with jax.named_scope("attn_core"), \
                         jax.named_scope("paged_attention_fused"):
                     out = paged_attention(
                         q, new_kp, new_vp, tables, lengths, starts,
                         k_scale=new_ksp if quant else None,
-                        v_scale=new_vsp if quant else None).astype(x.dtype)
+                        v_scale=new_vsp if quant else None,
+                        pages=pages, tile_cols=cols).astype(x.dtype)
             else:
+                ledger_lib.note("attention", {"impl": "gathered",
+                                              "keys": t_cap})
                 out = gathered_attention(
                     q, new_kp, new_vp, tables, positions,
                     new_ksp if quant else None,
@@ -682,8 +731,6 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     # may legitimately add an entry).  Cache-hit admissions, CoW forks
     # and shared-block evictions ride the same contract: src/dst/table
     # values are runtime data, so the ledger stays flat.
-    from ..utils import compile_ledger as ledger_lib
-
     tag = (f"bs{bs}x{mb}" + ("/int8" if kv_quant else "")
            + f"/{attn_impl}")
     return (ledger_lib.instrument(jax.jit(prefill, donate_argnums=(1, 2)),
@@ -729,7 +776,7 @@ class PagedDecodeServer:
                  block_size: int = 16, max_len: Optional[int] = None,
                  temperature: float = 0.0, top_k: int = 0,
                  top_p: float = 1.0, seed: int = 0,
-                 kv_quant: bool = False, attn_impl: str = "gathered",
+                 kv_quant: bool = False, attn_impl: str = "auto",
                  prefix_cache: bool = False):
         c = model.cfg
         self.model, self.params = model, params
@@ -764,16 +811,16 @@ class PagedDecodeServer:
         self._lookup_memo = None      # (prompt, index-version) -> walk
         self._sampling = (float(temperature), int(top_k), float(top_p))
         self.kv_quant = bool(kv_quant)
-        if attn_impl not in ATTN_IMPLS:
-            raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
-                             f"got {attn_impl!r}")
-        self.attn_impl = attn_impl
+        # what runs, never 'auto': the programs and the pools' layout
+        # follow it
+        self.attn_impl = resolve_attn_impl(model, attn_impl, self.kv_quant)
         (self._prefill_fn, self._step_fn, self._cow_fn,
          self._import_fn) = _paged_programs(
             model, self.block_size, self.max_blocks, *self._sampling,
             self.kv_quant, self.attn_impl)
         self.pools = init_paged_kv(model, self.num_blocks,
-                                   self.block_size, quant=self.kv_quant)
+                                   self.block_size, quant=self.kv_quant,
+                                   folded=self.attn_impl == "fused")
         # expert-load counters (EXPERT_COUNTERS): cumulative on the device
         # modulo 2**32, folded into host integers at every fetch; {} for a
         # model that does not route without drops
@@ -823,8 +870,12 @@ class PagedDecodeServer:
         needs (sum of pos+1 over active lanes), ``kernel_keys`` is what
         the fused kernel touches (whole blocks: ceil((pos+1)/bs)·bs per
         lane), ``padded_keys`` is what the gathered path reduces over
-        (t_cap per active lane).  attended/padded is the measurable
-        skipped-work ratio the telemetry and BENCH_PAGED_ATTN report."""
+        (t_cap per active lane; ``serve_tokens_per_s`` is counted from it
+        whatever implements attention), ``walked_keys`` is whichever of
+        the two this server's implementation reads.  attended/padded is
+        the measurable skipped-work ratio the telemetry and
+        BENCH_PAGED_ATTN report; walked/padded says the mechanism
+        engaged."""
         att = kern = n_active = 0
         for rid, slot in self._slot_of.items():
             if not self.active[slot]:
@@ -833,9 +884,13 @@ class PagedDecodeServer:
             att += ln
             kern += -(-ln // self.block_size) * self.block_size
             n_active += 1
+        padded = n_active * self.t_cap
         return {"attended_keys": att,
                 "kernel_keys": kern,
-                "padded_keys": n_active * self.t_cap,
+                "padded_keys": padded,
+                # what THIS server's attention reads: whole pages up to
+                # each length under the kernel, the table's width gathered
+                "walked_keys": kern if self.attn_impl == "fused" else padded,
                 "active_streams": n_active}
 
     # ---- prefix cache --------------------------------------------------

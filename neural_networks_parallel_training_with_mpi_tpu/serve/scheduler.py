@@ -89,10 +89,15 @@ class ServeConfig:
     top_p: float = 1.0
     seed: int = 0
     kv_quant: bool = False
-    attn_impl: str = "gathered"    # 'gathered' (parity reference) or
+    attn_impl: str = "auto"        # 'auto': 'fused' where a TPU runs the
+    #                                per-head K/V row at a lane-dense
+    #                                kv_heads * head_dim, else 'gathered'
+    #                                (serve/paged_kv.resolve_attn_impl);
+    #                                'gathered' (parity reference) and
     #                                'fused' (Pallas paged-attention
-    #                                kernel: walks only allocated blocks,
+    #                                kernel: reads the pool in place,
     #                                stops at each stream's true length)
+    #                                force one
     prefix_cache: bool = False     # share identical prompt-prefix blocks
     #                                across streams (refcounts + copy-on-
     #                                write; serve/paged_kv.py): a cached
@@ -509,6 +514,7 @@ class Scheduler:
         self.attended_keys = 0
         self.padded_keys = 0
         self.kernel_keys = 0
+        self.walked_keys = 0
         # expert-load counters of a model that routes without drops
         # (paged_kv.EXPERT_COUNTERS; {} otherwise): cumulative, as of the
         # server's last fetch of a finished stream, stamped on the
@@ -638,6 +644,7 @@ class Scheduler:
                 self.attended_keys += acct["attended_keys"]
                 self.padded_keys += acct["padded_keys"]
                 self.kernel_keys += acct["kernel_keys"]
+                self.walked_keys += acct["walked_keys"]
                 finished = self.server.step()
             with trace_lib.span("retire", tick=self.tick_no) as retire:
                 for srv_rid in finished:
@@ -1029,5 +1036,10 @@ class Scheduler:
             **self.expert_counters,
             "attended_ratio": (
                 round(self.attended_keys / self.padded_keys, 4)
+                if self.padded_keys else None),
+            # 1.0 under 'gathered'; under the fused kernel the share of
+            # the table's width its page walk reads
+            "walked_keys_share": (
+                round(self.walked_keys / self.padded_keys, 4)
                 if self.padded_keys else None),
         }

@@ -111,13 +111,16 @@ def test_flash_at_the_cells_shape_and_derived_blocks(spec):
         assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
 
 
-def test_flash_lowering_does_not_move_with_path_or_lines(spec, tmp_path):
+@pytest.mark.parametrize("kernel", ["flash", "paged"])
+def test_kernel_lowering_does_not_move_with_path_or_lines(spec, tmp_path,
+                                                          kernel):
     """What the compile cache keys a program by must not move with the
     checkout's path or a comment line in ``ops/pallas_kernels.py``
     (ROADMAP S6, "PR 22 run C"): the kernels' lowering, Mosaic payload and
     locations included, is byte-identical from a copy of the file under
     another path with its lines shifted, given the settings of
-    ``utils.platform.compile_cache``."""
+    ``utils.platform.compile_cache`` — the flash kernels of the train step
+    and the paged kernel of the serving programs alike."""
     import importlib.util
 
     from neural_networks_parallel_training_with_mpi_tpu.ops import (
@@ -132,22 +135,35 @@ def test_flash_lowering_does_not_move_with_path_or_lines(spec, tmp_path):
     copy = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(copy)
 
-    qkv = spec((2, 256, 2, 64), BF16)
+    if kernel == "flash":
+        qkv = spec((2, 256, 2, 64), BF16)
 
-    def lowered(flash):
-        def loss(q, k, v):
-            with jax.named_scope("attention"):
-                out = flash(q, k, v, True, 128, 128, False)
-            return out.astype(jnp.float32).sum()
+        def lowered(mod):
+            def loss(q, k, v):
+                with jax.named_scope("attention"):
+                    out = mod.flash_attention(q, k, v, True, 128, 128, False)
+                return out.astype(jnp.float32).sum()
 
-        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            qkv, qkv, qkv).as_text(debug_info=True)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                qkv, qkv, qkv).as_text(debug_info=True)
+    else:
+        pool = spec((65, 16, 256), BF16)
+        args = (spec((4, 1, 24, 128), BF16), pool, pool,
+                spec((4, 16), jnp.int32), spec((4,), jnp.int32),
+                spec((4,), jnp.int32))
+
+        def lowered(mod):
+            def fn(q, kp, vp, tables, lens, starts):
+                with jax.named_scope("attention"):
+                    return mod.paged_attention(q, kp, vp, tables, lens,
+                                               starts, interpret=False)
+
+            return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
     limit = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 0)
     try:
-        here, there = (lowered(pallas_kernels.flash_attention),
-                       lowered(copy.flash_attention))
+        here, there = lowered(pallas_kernels), lowered(copy)
     finally:
         jax.config.update("jax_traceback_in_locations_limit", limit)
     assert "tpu_custom_call" in here and "attention" in here
@@ -217,6 +233,86 @@ def test_gathered_prefill_bucket(spec, serve_programs):
         params, pools, {}, spec((1, MAX_BLOCKS), jnp.int32),
         spec((1,), jnp.int32), spec((1, PREFILL), jnp.int32),
         spec((), jnp.int32)).compile()
+
+
+# ---- the paged kernel in the serving programs, at sc2-3b-serve-code's shapes --
+
+CELL = dict(slots=16, heads=24, kv_heads=2, head_dim=128, block_size=16,
+            max_blocks=256, num_blocks=2305, chunk=512)
+
+
+@pytest.fixture(scope="module")
+def cell_programs(spec):
+    """The paged server's programs over 2 layers of ``starcoder2-3b`` (d
+    3072, 24 heads over 2 KV heads of 128, FFN 12288, rotary, bf16) with the
+    cell's geometry, as ``attn_impl="auto"`` resolves them on a TPU: the
+    kernel over pools stored with the heads folded into the lanes."""
+    from unittest import mock
+
+    model = Transformer(TransformerConfig(
+        vocab_size=49152, max_seq_len=16384, n_layers=2, d_model=3072,
+        n_heads=CELL["heads"], n_kv_heads=CELL["kv_heads"], d_ff=12288,
+        pos_encoding="rope", rope_theta=999999.44, param_dtype=BF16,
+        compute_dtype=BF16))
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    # ``auto`` and the kernel's ``interpret`` default both ask the default
+    # backend, which is the CPU here: say TPU while the programs are built
+    # and lowered (the kernels_compiled fixture of the latent tests, held
+    # for the module)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert paged_kv.resolve_attn_impl(model, "auto") == "fused"
+        pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+            model, CELL["num_blocks"], CELL["block_size"], folded=True)))
+        prefill, step, _, _ = paged_kv._paged_programs(
+            model, CELL["block_size"], CELL["max_blocks"], 0.0, 0, 1.0,
+            False, "auto")
+        s, mb = CELL["slots"], CELL["max_blocks"]
+        lowered = {
+            "decode": step.lower(
+                params, pools, {}, spec((s, mb * CELL["block_size"]),
+                                        jnp.int32),
+                spec((s, mb), jnp.int32), spec((s,), jnp.int32),
+                spec((s,), jnp.bool_), spec((2,), jnp.uint32)),
+            "prefill": prefill.lower(
+                params, pools, {}, spec((1, mb), jnp.int32),
+                spec((1,), jnp.int32), spec((1, CELL["chunk"]), jnp.int32),
+                spec((), jnp.int32))}
+    return lowered
+
+
+_POOL_SHAPED = r"\[2305,16,(?:256|2,128)\]"
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_cell_programs_run_the_kernel_over_the_pool_in_place(cell_programs,
+                                                             program):
+    """The decode program and a 512-token prefill bucket compile with the
+    paged kernel; the kernel is lowered once a program, not once a layer
+    (one ``tpu_custom_call`` in the lowered text, in a private function
+    each layer calls; one a layer once XLA has inlined it); and nothing
+    pool-shaped is copied, transposed or gathered: each pool goes from the
+    program's parameter through its scatter into the kernel and out, in
+    the layout it is stored in."""
+    import re
+
+    lowered = cell_programs[program]
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attention" in text
+    compiled = lowered.compile().as_text()
+    assert compiled.count('custom_call_target="tpu_custom_call"') == 2
+    assert "paged_gather" not in compiled
+    moved = [line.strip()[:160] for line in compiled.splitlines()
+             if re.search(r"= \S*" + _POOL_SHAPED
+                          + r"\S* (?:copy|transpose|gather|copy-start)\(",
+                          line)]
+    assert not moved, moved
+    # the pools reach the kernel as the scatter leaves them: 2 layers x
+    # (k, v) scatter fusions, each feeding a custom call
+    assert len(re.findall(r"= \S*" + _POOL_SHAPED + r"\S* fusion\(",
+                          compiled)) == 4
 
 
 # ---- latent attention + routing without drops, at the published widths ----

@@ -156,6 +156,163 @@ def test_kernel_int8_dequant_on_load():
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
 
 
+def _scattered_tables(rng, lens, nb, bs, mb):
+    """Each stream's live pages drawn without order from the pool (never
+    the sink); the table's other entries stay at the sink."""
+    tables = np.zeros((len(lens), mb), np.int32)
+    free = list(rng.permutation(np.arange(1, nb)))
+    for i, ln in enumerate(lens):
+        for j in range(-(-int(ln) // bs)):
+            tables[i, j] = free.pop()
+    return tables
+
+
+# (lens, starts or None for decode, width, heads, kv, hd, bs, mb, pages,
+#  tile_cols): what the walk of several pages a step and the row tiles
+# must get right
+_WALKS = {
+    # decode over lengths that are no multiple of pages * block_size (3 x 4
+    # = 12 keys a step): tails of 1, 5 and 11 keys, and one exact multiple
+    "decode-ragged-tails": ([13, 29, 35, 24], None, 1, 4, 2, 8, 4, 10, 3,
+                            None),
+    # one page, and less than one page, where a step holds eight
+    "decode-one-page": ([4, 3, 1], None, 1, 4, 2, 8, 4, 6, 8, None),
+    # idle lanes between live ones walk nothing and write zeros
+    "decode-idle-between": ([0, 9, 0, 0, 17, 0], None, 1, 4, 4, 8, 4, 6, 2,
+                            None),
+    # one page a step: the walk the int8 pools take
+    "decode-page-a-step": ([11, 6, 22], None, 1, 4, 2, 8, 4, 6, 1, None),
+    # a chunk in four row tiles at a start that is no multiple of the
+    # page, of the step or of the tile: rows cross all three borders
+    "chunk-tiles-cross-pages": ([16 + 21], [21], 16, 4, 2, 8, 4, 12, 2, 4),
+    # a bucketed chunk: 16 columns of which 9 are real (the rest lie past
+    # ``len``: whole pad tiles walk nothing)
+    "chunk-pad-columns": ([6 + 9], [6], 16, 4, 2, 8, 4, 8, 2, 4),
+    # two streams' chunks side by side, one at position 0
+    "chunk-two-streams": ([8, 19], [0, 11], 8, 6, 2, 8, 4, 8, 4, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALKS))
+def test_kernel_walks_pages_and_row_tiles(case):
+    """The kernel against the numpy reference where the multi-page walk
+    and the row tiles can go wrong, over tables whose live pages are
+    scattered over the pool; the pool's sink block holds huge values, so a
+    key past a stream's length that reached a softmax would show."""
+    lens, starts, w, heads, kv, hd, bs, mb, pages, cols = _WALKS[case]
+    rng = np.random.default_rng(len(case))
+    nb = 1 + sum(-(-ln // bs) for ln in lens) + 3
+    kp = rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)
+    vp = rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)
+    kp[0], vp[0] = 1e4, 1e4                       # the sink: never attended
+    lens = np.asarray(lens, np.int32)
+    starts = (np.maximum(lens - 1, 0) if starts is None
+              else np.asarray(starts)).astype(np.int32)
+    tables = _scattered_tables(rng, lens, nb, bs, mb)
+    q = jnp.asarray(rng.normal(size=(len(lens), w, heads, hd)), jnp.float32)
+    got = np.asarray(paged_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+        jnp.asarray(lens), jnp.asarray(starts), pages=pages, tile_cols=cols))
+    want = _np_reference(q, kp, vp, tables, lens, starts)
+    # rows at or past ``len`` (pad columns) are the caller's to discard:
+    # a tile that holds a live row computes them, a tile of pad rows alone
+    # writes zeros
+    live = (starts[:, None] + np.arange(w)[None, :]) < lens[:, None]
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert np.isfinite(got).all()
+    assert np.all(got[lens == 0] == 0.0)
+    # the folded pool, the layout the fused server stores, reads the same
+    folded = np.asarray(paged_attention(
+        q, jnp.asarray(kp.reshape(nb, bs, kv * hd)),
+        jnp.asarray(vp.reshape(nb, bs, kv * hd)), jnp.asarray(tables),
+        jnp.asarray(lens), jnp.asarray(starts), pages=pages, tile_cols=cols))
+    np.testing.assert_array_equal(folded, got)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("width", [1, 512], ids=["decode", "chunk512"])
+def test_kernel_at_the_cells_heads(width, dtype, tol):
+    """``starcoder2-3b``'s heads (24 over 2 KV heads of 128, blocks of 16)
+    at the tiling the timed table gives them: a decode step over four
+    streams, and a 512-wide chunk whose 32-column row tiles cross page and
+    step borders (start 37).  f32 pools to the f32 tolerance; bf16 pools at
+    the tolerance the flash kernels' bf16 tests use, against the reference
+    on the same bf16 values."""
+    from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import (  # noqa: E501
+        PAGED_TILES, paged_tiles,
+    )
+
+    heads, kv, hd, bs, mb = 24, 2, 128, 16, 64
+    kind = "decode" if width == 1 else "chunk"
+    assert paged_tiles(bs, kv * hd, width, heads // kv, mb) \
+        == PAGED_TILES[(bs, kv * hd, kind)]
+    rng = np.random.default_rng(width)
+    if width == 1:
+        lens = np.asarray([300, 0, 17, 513], np.int32)
+        starts = np.maximum(lens - 1, 0).astype(np.int32)
+    else:
+        starts = np.asarray([37], np.int32)
+        lens = starts + 300                       # 300 real columns of 512
+    nb = 1 + int(sum(-(-int(ln) // bs) for ln in lens)) + 2
+    kp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)), dtype)
+    tables = _scattered_tables(rng, lens, nb, bs, mb)
+    q = jnp.asarray(rng.normal(size=(len(lens), width, heads, hd)), dtype)
+    got = paged_attention(q, kp.reshape(nb, bs, kv * hd),
+                          vp.reshape(nb, bs, kv * hd), jnp.asarray(tables),
+                          jnp.asarray(lens), jnp.asarray(starts))
+    assert got.dtype == jnp.dtype(dtype)
+    got = np.asarray(got, np.float32)
+    want = _np_reference(np.asarray(q, np.float32),
+                         np.asarray(kp, np.float32),
+                         np.asarray(vp, np.float32), tables, lens, starts)
+    live = (starts[:, None] + np.arange(width)[None, :]) < lens[:, None]
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    assert np.isfinite(got).all()
+
+
+def test_kernel_feeds_the_mxu_in_the_pools_type():
+    """bf16 pools: every product inside the kernel takes bf16 operands and
+    accumulates in f32 (no upcast before the MXU); f32 pools stay f32
+    products, and int8 pools keep their f32 scheme."""
+    import jax
+
+    def dots(dtype, scales=False):
+        q = jnp.zeros((2, 1, 4, 8), jnp.float32 if scales else dtype)
+        pool = jnp.zeros((6, 4, 2, 8), dtype)
+        sc = jnp.ones((6, 4, 2), jnp.float32) if scales else None
+        tables = jnp.zeros((2, 3), jnp.int32)
+        lens = jnp.ones((2,), jnp.int32)
+
+        def fn(q_, kp, vp):
+            return paged_attention(q_, kp, vp, tables, lens, lens - 1,
+                                   k_scale=sc, v_scale=sc)
+
+        found = []
+
+        def walk(jaxpr, inside):
+            for eqn in jaxpr.eqns:
+                kernel = inside or eqn.primitive.name == "pallas_call"
+                if inside and eqn.primitive.name == "dot_general":
+                    found.append((tuple(v.aval.dtype for v in eqn.invars),
+                                  eqn.params["preferred_element_type"]))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, kernel)
+
+        walk(jax.make_jaxpr(fn)(q, pool, pool).jaxpr, False)
+        return found
+
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    found = dots(jnp.bfloat16)
+    # scores and values, per KV head, in the unmasked and the masked loop
+    assert len(found) == 2 * 2 * 2
+    assert {d for d, _ in found} == {(bf16, bf16)}
+    assert {acc for _, acc in found} == {f32}
+    assert {d for d, _ in dots(jnp.float32)} == {(f32, f32)}
+    assert {d for d, _ in dots(jnp.int8, scales=True)} == {(f32, f32)}
+
+
 def test_kernel_validates_shapes():
     rng, kp, vp, tables = _pool_fixture()
     lens = jnp.zeros((3,), jnp.int32)
@@ -205,6 +362,32 @@ def test_fused_matches_gathered_staggered_straddling():
     through both attention impls — token-identical, allocator drained.
     (gathered == dense DecodeServer == generate() is pinned by
     tests/test_serve_paged.py, so this chains fused to the reference.)"""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    outs = {}
+    for impl in ("gathered", "fused"):
+        srv = PagedDecodeServer(model, params, slots=4, num_blocks=40,
+                                block_size=8, attn_impl=impl)
+        outs[impl] = _staggered_scenario(srv)
+    assert outs["fused"] == outs["gathered"]
+
+
+@pytest.mark.parametrize("keys_a_step,rows_a_tile", [(16, 8), (8, 8)],
+                         ids=["2-pages-2-tiles", "1-page-2-tiles"])
+def test_fused_matches_gathered_in_several_steps_and_tiles(
+        monkeypatch, keys_a_step, rows_a_tile):
+    """The same scenario with the kernel's tiling forced small, so that
+    the server's own calls walk each stream in several loop steps and tile
+    each prefill bucket over its rows (at the toy's default tiling one step
+    and one tile hold everything): token-identical still."""
+    from neural_networks_parallel_training_with_mpi_tpu.ops import (
+        pallas_kernels,
+    )
+
+    monkeypatch.setattr(pallas_kernels, "_UNTIMED_KEYS", keys_a_step)
+    monkeypatch.setattr(pallas_kernels, "_UNTIMED_ROWS", rows_a_tile)
+    assert pallas_kernels.paged_tiles(8, 32, 16, 1, 8) \
+        == (keys_a_step // 8, rows_a_tile)
     model = _model()
     params = model.init(prng.init_key(0))
     outs = {}

@@ -307,19 +307,22 @@ def test_scheduler_fuzz_fused_kernel(seed):
                attn_impl="fused")
 
 
-def test_attended_keys_accounting_and_records(tmp_path):
+@pytest.mark.parametrize("attn_impl", ["fused", "gathered"])
+def test_attended_keys_accounting_and_records(tmp_path, attn_impl):
     """The serving-telemetry satellite: kind="serve" records carry
     attended/padded/kernel key counters whose values match the
     scheduler's block accounting exactly (single deterministic stream:
-    closed-form sums), the final snapshot carries the ratio, and
-    metrics_summary renders it."""
+    closed-form sums), the final snapshot carries the ratio and
+    ``walked_keys_share`` — the kernel's whole pages over the padded
+    width under ``fused``, 1.0 under ``gathered``, with ``padded_keys``
+    meaning the same under both — and metrics_summary renders them."""
     model = _model()
     params = model.init(prng.init_key(0))
     tdir = str(tmp_path / "t")
     p, n, bs = 5, 6, 8
     sched = Scheduler(model, params, ServeConfig(
         slots=2, num_blocks=20, block_size=bs, max_len=64,
-        telemetry_dir=tdir, metrics_every=1, attn_impl="fused"))
+        telemetry_dir=tdir, metrics_every=1, attn_impl=attn_impl))
     rid = sched.submit(list(range(1, p + 1)), n)
     sched.run_until_drained()
     sched.result(rid)
@@ -341,6 +344,8 @@ def test_attended_keys_accounting_and_records(tmp_path):
     assert finals[-1]["padded_keys"] == want_padded
     assert finals[-1]["attended_ratio"] == round(
         want_attended / want_padded, 4)
+    assert finals[-1]["walked_keys_share"] == (
+        round(want_kernel / want_padded, 4) if attn_impl == "fused" else 1.0)
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "metrics_summary", os.path.join(
@@ -350,7 +355,7 @@ def test_attended_keys_accounting_and_records(tmp_path):
     summary = ms.summarize(records)
     assert "attended_ratio" in summary["serving_ticks"]
     text = ms.render_text(summary, records, None, None, None)
-    assert "attended keys" in text
+    assert "attended keys" in text and "walked keys share" in text
 
 
 def test_telemetry_serve_records_and_heartbeat(tmp_path):

@@ -167,7 +167,8 @@ def summarize(records: List[Dict[str, Any]],
         # prefix-cache hit/fork/eviction counters
         for key in ("admitted", "rejected", "evicted", "completed",
                     "tokens_out", "attended_keys", "padded_keys",
-                    "attended_ratio", "prefix_hits", "prefix_misses",
+                    "attended_ratio", "walked_keys_share", "prefix_hits",
+                    "prefix_misses",
                     "prefix_hit_tokens", "prefix_hit_rate",
                     "shared_blocks", "cow_forks", "cache_evictions",
                     "blocks_saved", "cached_free_blocks"):
@@ -267,6 +268,11 @@ def serving_lines(summary: Dict[str, Any]) -> List[str]:
                 f"{st.get('padded_keys')} padded "
                 f"({st['attended_ratio']:.3f} "
                 "— the fused kernel's skipped work)")
+        if st.get("walked_keys_share") is not None:
+            lines.append(
+                f"  walked keys share: {st['walked_keys_share']:.3f} of "
+                "the padded width (1.0 = gathered; below it the paged "
+                "kernel ran)")
         if "prefix_hits" in st:
             rate = st.get("prefix_hit_rate")
             lines.append(

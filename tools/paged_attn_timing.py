@@ -1,0 +1,162 @@
+#!/usr/bin/env python3
+"""Times the paged-attention kernel's tilings on the chip.
+
+    chiprun --chips 1 -- python tools/paged_attn_timing.py [--quick]
+
+At ``sc2-3b-serve-code``'s shapes (24 query heads over 2 KV heads of 128,
+bf16 pools of 2305 blocks of 16 with the heads folded into the lanes, 256
+blocks a table): the decode step over 16 streams whose lengths are drawn
+like the cell's (mean about 800), the same at the table's full width (the
+kernel's worst case), and a 512-token prefill chunk at three depths of its
+prompt and with 200 real columns.  Each row is one layer's attention as the
+serving programs call it (the query's and the output's transposes around
+the kernel included), run ``--reps`` times inside one program (each call's
+output is the next call's query, so nothing overlaps) and divided.
+``ops/pallas_kernels.py`` ``PAGED_TILES`` holds the row chosen of each kind;
+PERF.md section 6 (PR 30) prints the table.  What the kernel replaces is
+not timed here: alone in a loop, XLA lifts ``pool[tables]`` out of it (the
+pool does not change), so a gathered row would leave the gather out; its
+cost is read from the parent's trace, per layer inside the programs
+(``tools/scope_ops.py``).  Also timed: the scatter of a chunk's 512 rows
+into either pool layout.  Results go to ``chiprun_out/paged_attn_timing.json``
+and, one JSON line a row, to standard output.  There is no CPU path.
+"""
+
+import argparse
+import json
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import jax                                              # noqa: E402
+import jax.numpy as jnp                                 # noqa: E402
+import numpy as np                                      # noqa: E402
+
+from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import (  # noqa: E402
+    paged_attention,
+)
+
+H, KV, HD, BS, NB, MB = 24, 2, 128, 16, 2305, 256
+T_CAP = BS * MB
+
+
+def timed(fn, args, reps: int) -> float:
+    """ms a call of ``fn(q, *rest)``, ``reps`` chained calls a program."""
+    def many(q, *rest):
+        return jax.lax.fori_loop(0, reps, lambda _i, x: fn(x, *rest), q)
+
+    run = jax.jit(many)
+    jax.block_until_ready(run(*args))              # compile + warm
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        best = min(best, time.perf_counter() - t)
+    return 1e3 * best / reps
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--reps", type=int, default=30)
+    ap.add_argument("--quick", action="store_true",
+                    help="the table's rows and their neighbours only")
+    ap.add_argument("--seed", type=int, default=30)
+    args = ap.parse_args(argv)
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print("paged_attn_timing: no TPU; a CPU timing says nothing",
+              file=sys.stderr)
+        return 3
+    rng = np.random.default_rng(args.seed)
+    kp4 = jnp.asarray(rng.normal(size=(NB, BS, KV, HD)), jnp.bfloat16)
+    vp4 = jnp.asarray(rng.normal(size=(NB, BS, KV, HD)), jnp.bfloat16)
+    kp3, vp3 = kp4.reshape(NB, BS, KV * HD), vp4.reshape(NB, BS, KV * HD)
+    rows = []
+
+    def emit(**row):
+        row["device"] = dev.device_kind
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    def tables_for(lens):
+        """Each stream's live pages scattered over the pool, the rest at
+        the sink."""
+        tables = np.zeros((len(lens), MB), np.int32)
+        free = rng.permutation(np.arange(1, NB))
+        at = 0
+        for i, ln in enumerate(lens):
+            n = -(-int(ln) // BS)
+            tables[i, :n] = free[at:at + n]
+            at += n
+        return jnp.asarray(tables)
+
+    # ---- decode: 16 streams, the cell's lengths (prompt + answer so far)
+    prompts = np.clip(rng.lognormal(np.log(768), 0.6, 16), 128, 2048)
+    lens = (prompts + rng.uniform(0, 96, 16)).astype(np.int32)
+    tables = tables_for(lens)
+    lens_j = jnp.asarray(lens)
+    starts = lens_j - 1
+    q = jnp.asarray(rng.normal(size=(16, 1, H, HD)), jnp.bfloat16)
+    live = int(lens.sum())
+    for pages in ((16, 32, 64) if args.quick else (2, 4, 8, 16, 32, 64, 128)):
+        ms = timed(lambda x, t, ln, st, pages=pages: paged_attention(
+            x, kp3, vp3, t, ln, st, pages=pages), (q, tables, lens_j, starts),
+            args.reps)
+        emit(kind="decode", pages=pages, ms=ms, mean_len=float(lens.mean()),
+             live_gb_s=live * 2 * KV * HD * 2 / ms / 1e6)
+    # every stream at the table's full width: the kernel's worst case
+    full = jnp.full((16,), T_CAP, jnp.int32)
+    t_full = tables_for([T_CAP // 8] * 16)
+    t_full = jnp.tile(t_full[:, :MB // 8], (1, 8))
+    for pages in (16, 32, 64, 128):
+        emit(kind="decode_full_width", pages=pages,
+             ms=timed(lambda x, t, ln, st, pages=pages: paged_attention(
+                 x, kp3, vp3, t, ln, st, pages=pages),
+                 (q, t_full, full, full - 1), args.reps))
+
+    # ---- a 512-token chunk at three depths of its prompt, and a short one
+    q = jnp.asarray(rng.normal(size=(1, 512, H, HD)), jnp.bfloat16)
+    for start, true_w in ((0, 512), (512, 512), (1536, 512), (512, 200)):
+        ln = jnp.asarray([start + true_w], jnp.int32)
+        st = jnp.asarray([start], jnp.int32)
+        tbl = tables_for([start + true_w])
+        reps = max(4, args.reps // 4)
+        grid = (((16, 64), (32, 64), (32, 128)) if args.quick else
+                ((8, 32), (8, 64), (16, 32), (16, 64), (16, 128), (32, 32),
+                 (32, 64), (32, 128), (64, 64), (64, 128)))
+        for pages, cols in grid:
+            ms = timed(lambda x, t, l_, s_, pages=pages, cols=cols:
+                       paged_attention(x, kp3, vp3, t, l_, s_, pages=pages,
+                                       tile_cols=cols),
+                       (q, tbl, ln, st), reps)
+            emit(kind="chunk", start=start, true_w=true_w,
+                 pages=pages, tile_cols=cols, ms=ms)
+
+    # ---- the scatter of a chunk's rows into either pool layout
+    blk = jnp.asarray(rng.integers(1, NB, (1, 512)), jnp.int32)
+    off = jnp.asarray(rng.integers(0, BS, (1, 512)), jnp.int32)
+    new = jnp.asarray(rng.normal(size=(1, 512, KV, HD)), jnp.bfloat16)
+    for name, pool, rows_ in (("(NB,bs,KV,hd)", kp4, new),
+                              ("(NB,bs,KV*hd)", kp3,
+                               new.reshape(1, 512, KV * HD))):
+        fn = jax.jit(lambda p, r: p.at[blk, off].set(r), donate_argnums=0)
+        pool = fn(pool + 0, rows_)
+        jax.block_until_ready(pool)
+        t = time.perf_counter()
+        for _ in range(50):
+            pool = fn(pool, rows_)
+        jax.block_until_ready(pool)
+        emit(kind="scatter512", layout=name,
+             ms=1e3 * (time.perf_counter() - t) / 50)
+
+    out = ROOT / "chiprun_out"
+    out.mkdir(exist_ok=True)
+    (out / "paged_attn_timing.json").write_text(json.dumps(rows, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

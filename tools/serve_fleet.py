@@ -129,8 +129,8 @@ def main(argv=None) -> int:
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=32)
     ap.add_argument("--replica-queue-depth", type=int, default=16)
-    ap.add_argument("--attn-impl", default="gathered",
-                    choices=["gathered", "fused"])
+    ap.add_argument("--attn-impl", default="auto",
+                    choices=["auto", "gathered", "fused"])
     # router policy
     ap.add_argument("--queue-depth", type=int, default=128,
                     help="the ROUTER's bounded fleet wait queue "
