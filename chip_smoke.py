@@ -8,7 +8,7 @@ One process, run from the root of a checkout on a machine with a TPU:
 
 It drives the main path through the entry points a user calls — the trainer
 through ``cli.main``, the paged server through ``Scheduler`` — at the full
-width of ``big_lm`` (bench.py ``_BIG``: 12 layers, d_model 1024, 16 heads x
+width of ``big_lm`` (``BIG_LM`` below: 12 layers, d_model 1024, 16 heads x
 head_dim 64, d_ff 4096, vocab 32768, T 1024, bf16), with seeded random
 weights, and checks what comes out by the repo's own means.
 
@@ -43,7 +43,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 OUT_DIR = ROOT / "chip_smoke_out"
 
-# bench.py _BIG — the repo's largest supported model
+# big_lm — the invented shape this smoke has always run
 BIG_LM = dict(vocab_size=32768, seq_len=1024, n_layers=12, d_model=1024,
               n_heads=16, d_ff=4096)
 # serving geometry at that width, and six ragged prompts
@@ -138,7 +138,7 @@ def _train_flags(out_dir: Path, tag: str, *, vocab_size, seq_len, n_layers,
                  d_model, n_heads, d_ff, batch_size, steps,
                  compute_dtype="bfloat16") -> list:
     """The CLI flags that spell the model (big_lm by default) with the
-    optimizer of bench.py's canonical step: SGD-momentum, lr 1e-4."""
+    optimizer: SGD-momentum, lr 1e-4."""
     return [
         "--dataset", "lm", "--arch", "transformer",
         "--vocab_size", str(vocab_size), "--seq_len", str(seq_len),

@@ -13,8 +13,7 @@
 # Arms 3 and 4 must agree on most greedy tokens (asserted below at the
 # 60% tolerance DESIGN.md §14 states — on a trained model the per-token
 # activation rounding can flip near-tie argmaxes, which then cascade;
-# the random-init exact pin lives in tests/test_qmm.py and the bench
-# prompts' exactness boolean in BENCH_QUANT.json).  The int8-compute
+# the random-init exact pin lives in tests/test_qmm.py).  The int8-compute
 # arm is the one that also runs the arithmetic at int8 MXU rates on
 # real hardware.  The reference has no inference path at all (its eval
 # blocks are dead code, dataParallelTraining_NN_MPI.py:213-236).

@@ -6,7 +6,7 @@
 # stream lengths share one block pool instead of each reserving max_len.
 # Greedy results are token-identical to the single-stream generate()
 # (pinned by tests/test_serve_paged.py); per-request TTFT/ITL print at
-# the end — the numbers BENCH_SERVE.json sweeps against offered load.
+# the end (CPU numbers: they show the shape of the output, not a speed).
 # The same request set then re-runs with attn_impl='fused' (the Pallas
 # paged-attention kernel, interpret mode on CPU) and must emit the SAME
 # tokens — the dispatch seam is invisible to clients.
@@ -34,7 +34,7 @@ params = model.init(prng.init_key(0))
 
 # 8 streams max in the batched step; 33 blocks x 16 positions of KV pool
 # shared by every stream (a dense slot server with this memory would
-# hold FOUR 128-token streams; see BENCH_SERVE.json's capacity A/B).
+# hold FOUR 128-token streams; tests/test_serve_paged.py counts it).
 # attn_impl toggles the attention dispatch: 'gathered' materializes
 # pool[table]; 'fused' walks only allocated blocks in a Pallas kernel
 cfg = dict(slots=8, num_blocks=33, block_size=16, prefill_chunk=32,

@@ -52,8 +52,8 @@ class Module:
         ``x_shape`` (2 x MACs; elementwise ops excluded — they are noise
         next to the matmuls on the MXU).  None = unaccounted architecture.
         One optimizer step is conventionally ``3 x fwd_flops`` (forward +
-        ~2x for the backward).  Single source for bench.py's MFU and the
-        Trainer's achieved-FLOPs metric."""
+        ~2x for the backward).  Single source for the telemetry's MFU and
+        the Trainer's achieved-FLOPs metric."""
         return None
 
 

@@ -75,8 +75,3 @@ def wide_mlp(in_features: int = 2, width: int = 512, depth: int = 4,
                out_features=out_features, param_dtype=param_dtype,
                compute_dtype=compute_dtype)
 
-
-def mnist_mlp(param_dtype=jnp.float32, compute_dtype=None) -> MLP:
-    """BASELINE.json config #3: 784 -> 256 -> 128 -> 10 classifier."""
-    return MLP(in_features=784, hidden=(256, 128), out_features=10,
-               param_dtype=param_dtype, compute_dtype=compute_dtype)

@@ -30,8 +30,9 @@ Design (slot server):
   advance deterministically (+1 per active slot per step), so ``step()``
   performs ZERO per-token device syncs — the old per-step blocking
   ``device_get(self.pos)`` serialized the host against the device
-  pipeline every token (measured delta in BENCH_SERVE.json;
-  ``sync_per_step=True`` keeps the legacy fetch for that measurement).
+  pipeline every token (not measured on a chip; ``sync_per_step=True``
+  keeps the legacy fetch, held to the same tokens by
+  tests/test_serve_paged.py).
 * Greedy (temperature=0) decode matches :func:`models.generate.generate`
   token-for-token per request — pinned by tests/test_serve.py — because
   each row's attention reduces over exactly the same values in the same
@@ -165,7 +166,7 @@ class DecodeServer:
         # NO device fetch — the per-token blocking device_get this loop
         # used to pay serialized every step against the device pipeline.
         # ``sync_per_step=True`` restores the old fetch, kept ONLY so
-        # bench.py can measure the delta (BENCH_SERVE.json).
+        # that the delta can be measured.
         self._pos_host = np.zeros((self.slots,), np.int64)
         self._sync_per_step = bool(sync_per_step)
         self.key = jax.random.PRNGKey(seed)

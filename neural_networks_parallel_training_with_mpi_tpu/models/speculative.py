@@ -308,10 +308,9 @@ def _spec_device_program(target: Transformer, draft: Transformer,
     program for the whole greedy speculative decode (round 5).
 
     The host-loop :func:`speculative_generate` pays ~2 host dispatches
-    per draft token plus a device->host logits round trip per round —
-    measured 4x SLOWER than the single-program plain ``generate`` on the
-    trained-pair eval (BENCH_DECODE_SPEC_CPU.json) even though it cut
-    target passes per token to ~0.65.  The TPU-first fix is structural:
+    per draft token plus a device->host logits round trip per round,
+    which can outweigh the target passes it saves (not measured on a
+    chip).  The TPU-first fix is structural:
     draft proposals run as a ``lax.scan``, greedy acceptance (an argmax
     prefix-agreement count) runs on device, and rounds run under
     ``lax.while_loop`` — zero host traffic until the final tokens.

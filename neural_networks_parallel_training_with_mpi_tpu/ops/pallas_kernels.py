@@ -11,11 +11,8 @@ provides:
   the FlashAttention recurrence.  Causal blocks above the diagonal are
   skipped entirely (the fori_loop upper bound shrinks per q-block), saving
   ~2x FLOPs at long T.  O(T) memory per head instead of O(T^2).
-* **fused_layernorm** — single-pass LayerNorm on the VPU; one read of x
-  per row instead of XLA's separate mean/var/normalize passes when fusion
-  declines.
 
-Both run in interpreter mode on CPU (tests, SURVEY.md §4's fake-device
+The kernels run in interpreter mode on CPU (tests, SURVEY.md §4's fake-device
 strategy) and compiled through Mosaic on TPU.  The backward pass of
 flash_attention is also Pallas: the forward additionally emits the per-row
 logsumexp, and two backward kernels (dq; dk+dv) recompute the probability
@@ -1043,47 +1040,3 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
     out = out.reshape(s_n, kv_heads, width, groups, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_n, width, n_heads, hd)
 
-
-# ==========================================================================
-# Fused LayerNorm
-# ==========================================================================
-
-def _ln_kernel(x_ref, scale_ref, bias_ref, o_ref, *, eps: float):
-    x = x_ref[:].astype(jnp.float32)
-    mean = x.mean(-1, keepdims=True)
-    xc = x - mean
-    var = (xc * xc).mean(-1, keepdims=True)
-    y = xc * lax.rsqrt(var + eps)
-    o_ref[:] = (y * scale_ref[:].astype(jnp.float32)
-                + bias_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def fused_layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array,
-                    eps: float = 1e-5, block_rows: int = 256,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """LayerNorm over the last dim; rows processed in VMEM blocks."""
-    if interpret is None:
-        interpret = _interpret_default()
-    lead = x.shape[:-1]
-    d = x.shape[-1]
-    rows = 1
-    for s in lead:
-        rows *= s
-    x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
-    if rows % block_rows:
-        block_rows = 1  # degenerate but correct fallback
-    mem = {"memory_space": pltpu.VMEM}
-    out = pl.pallas_call(
-        functools.partial(_ln_kernel, eps=eps),
-        grid=(rows // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0), **mem),
-            pl.BlockSpec((d,), lambda i: (0,), **mem),
-            pl.BlockSpec((d,), lambda i: (0,), **mem),
-        ],
-        out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0), **mem),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
-    )(x2, scale, bias)
-    return out.reshape(*lead, d)

@@ -346,7 +346,7 @@ def make_train_step(model, optimizer: Optimizer, mesh: Mesh,
                     lambda g: lax.psum(g, DATA_AXES) / total, grads)
                 loss = lax.psum(s, DATA_AXES) / total
             elif grad_reduction == "local":
-                # MEASUREMENT-ONLY ablation (bench.py --scaling): the
+                # MEASUREMENT-ONLY ablation (tests/test_trainer.py): the
                 # exact same per-shard compute with ZERO cross-device
                 # collectives, so (global_mean step time) - (local step
                 # time) isolates the gradient allreduce cost at each mesh

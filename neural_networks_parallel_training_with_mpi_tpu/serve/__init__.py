@@ -26,9 +26,10 @@ when full".  This package is the service-shaped runtime above it:
   SLO-aware eviction/requeue under block exhaustion.  Serving metrics
   ride the PR 2 telemetry records + heartbeat, so the PR 1 supervisor
   can babysit a serving fleet unchanged.
-* :mod:`serve.loadgen` — a closed-loop load generator measuring
-  tokens/s and TTFT/ITL percentiles vs. offered load
-  (``bench.py --serve`` -> BENCH_SERVE.json).
+* :mod:`serve.loadgen` — a closed-loop load generator over a seeded
+  request plan (tokens/s and TTFT/ITL percentiles for tests, examples
+  and the fleet's tools; the benchmark's cells use the harness's own
+  loop).
 """
 
 from .paged_kv import (
@@ -47,7 +48,6 @@ from .loadgen import (
     resolve_mix,
     run_closed_loop,
     run_fleet_closed_loop,
-    sweep_loads,
 )
 from .fleet import (
     Fleet,
@@ -71,7 +71,7 @@ __all__ = [
     "ATTN_IMPLS", "BlockAllocator", "BlockExhausted", "PagedDecodeServer",
     "PrefixIndex", "init_paged_kv", "Request", "Scheduler", "ServeConfig",
     "MIXES", "make_requests", "prewarm", "resolve_mix",
-    "run_closed_loop", "sweep_loads",
+    "run_closed_loop",
     "Fleet", "FleetRequest", "FleetRouter", "InprocReplica", "LoadSignal",
     "ProcReplica", "TPGenerateReplica", "launch_fleet", "role_kind",
     "run_fleet_closed_loop",

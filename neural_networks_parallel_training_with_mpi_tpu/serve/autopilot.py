@@ -59,8 +59,8 @@ keep a flapping signal from thrashing replicas.
 
 No extra thread: :meth:`tick` rides the owner's service loop
 (``Fleet.pump`` calls it when the autopilot is attached), so the
-control loop's steady-state cost shows up — and is priced, bench.py
-``--autopilot`` — in the same tokens/s the fleet reports.
+control loop's steady-state cost shows up in the same tokens/s the
+fleet reports (not measured on a chip).
 """
 
 from __future__ import annotations

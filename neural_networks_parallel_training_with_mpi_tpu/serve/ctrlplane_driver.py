@@ -1,12 +1,12 @@
-"""Killable control-plane driver: the process the crash benches SIGKILL.
+"""Killable control-plane driver: the process the chaos campaign SIGKILLs.
 
 The router lives in the operator's process, so "kill the control plane"
 cannot be modelled in-process — the experimenter would die with its
 subject.  This module is the subject: it launches a fleet (router +
 workers, WAL-backed via ``router_kwargs["wal_dir"]``), runs the
 closed-loop load, and writes one JSON result row atomically (tmp +
-``os.replace``) to ``--out``.  The parent (``bench.py --ctrlplane`` or
-the chaos ``fleet_ctrlplane`` scenario) spawns it with
+``os.replace``) to ``--out``.  The parent (the chaos
+``fleet_ctrlplane`` scenario) spawns it with
 ``start_new_session=True`` and then:
 
 * **router_kill** — ``os.kill(driver_pid, SIGKILL)``.  Workers inherit

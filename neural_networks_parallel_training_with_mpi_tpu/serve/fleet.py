@@ -2069,10 +2069,10 @@ def _worker_argparser():
                     help="ticks between status (load-report) events")
     ap.add_argument("--step-sleep-ms", type=float, default=0.0,
                     help="emulated device latency added per decode "
-                         "tick (bench.py --serve-fleet: on a CPU-only "
-                         "host this stands in for the accelerator step "
-                         "the host would overlap; disclosed in the "
-                         "artifact)")
+                         "tick: on a CPU-only host it stands in for the "
+                         "accelerator step the host would overlap (the "
+                         "chaos scenarios and the slow-canary fault use "
+                         "it; never a measurement)")
     ap.add_argument("--tp", type=int, default=0,
                     help="span this replica over a tensor-parallel "
                          "mesh of N local (virtual) devices through "

@@ -10,8 +10,10 @@ queueing theory, closed-loop measures the server).
 Per request we record TTFT (submit -> first output token, queue wait
 included — that is what a client experiences) and mean ITL (decode span
 / (new_tokens - 1)); the sweep reports p50/p99 of each across requests,
-plus aggregate generated tokens/s.  ``bench.py --serve`` drives
-:func:`sweep_loads` at >= 3 offered loads into ``BENCH_SERVE.json``.
+plus aggregate generated tokens/s.  Tests, examples and the fleet's tools
+drive it; the benchmark's serving cells use the harness's own closed loop
+(``benchmark/harness/serve_closed_loop.py``), and no number from here is
+a result.
 
 **Shared-prefix traffic mixes** (``shared_prefix_len`` /
 ``shared_fraction``): real chat fleets share system prompts, so a
@@ -21,7 +23,7 @@ prefix_cache``) exists for.  The request stream is pre-generated
 per seed (client-major, independent of queue dynamics), so a cache-off
 and a cache-on arm serve BYTE-IDENTICAL requests and the row's
 ``tokens_sha256`` digest pins greedy output equality across the A/B
-(``bench.py --prefix-cache`` -> BENCH_PREFIX_CACHE.json).  TTFT
+(tests/test_prefix_cache.py).  TTFT
 percentiles split by class (shared-prefix vs unique) and per-tick
 blocks-in-use peak/mean expose the two wins: cached-prefix TTFT and
 pool residency.
@@ -285,34 +287,6 @@ def run_closed_loop(scheduler, clients: int, requests_per_client: int,
     if getattr(scheduler.cfg, "prefix_cache", False):
         row["prefix_cache"] = scheduler.server.prefix_stats()
     return row
-
-
-def sweep_loads(make_scheduler, loads: List[int],
-                requests_per_client: int, *, vocab_size: int,
-                prompt_lens=(4, 24), max_new=(8, 32), seed: int = 0,
-                slo_ms: Optional[float] = None,
-                shared_prefix_len: int = 0,
-                shared_fraction: float = 0.0,
-                warm: bool = True) -> List[Dict[str, Any]]:
-    """One :func:`run_closed_loop` row per offered load (client count),
-    a FRESH scheduler each (``make_scheduler()`` factory) so load points
-    don't share warm state beyond compiled programs — which
-    :func:`prewarm` populates up front (``warm=False`` opts out for
-    callers measuring cold-start itself)."""
-    rows = []
-    if warm and loads:
-        prewarm(make_scheduler, prompt_lens=prompt_lens)
-    for c in loads:
-        sched = make_scheduler()
-        try:
-            rows.append(run_closed_loop(
-                sched, c, requests_per_client, vocab_size=vocab_size,
-                prompt_lens=prompt_lens, max_new=max_new, seed=seed,
-                slo_ms=slo_ms, shared_prefix_len=shared_prefix_len,
-                shared_fraction=shared_fraction))
-        finally:
-            sched.close()
-    return rows
 
 
 def run_fleet_closed_loop(router, clients: int,

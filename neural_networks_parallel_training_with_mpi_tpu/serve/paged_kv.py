@@ -873,9 +873,8 @@ class PagedDecodeServer:
         (t_cap per active lane; ``serve_tokens_per_s`` is counted from it
         whatever implements attention), ``walked_keys`` is whichever of
         the two this server's implementation reads.  attended/padded is
-        the measurable skipped-work ratio the telemetry and
-        BENCH_PAGED_ATTN report; walked/padded says the mechanism
-        engaged."""
+        the measurable skipped-work ratio the telemetry reports;
+        walked/padded says the mechanism engaged."""
         att = kern = n_active = 0
         for rid, slot in self._slot_of.items():
             if not self.active[slot]:

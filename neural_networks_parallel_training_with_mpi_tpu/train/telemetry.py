@@ -35,8 +35,7 @@ arXiv:2204.06514, Hessel et al. arXiv:2104.06272).  Four pillars:
    the model config (``Module.fwd_flops``: MLP, ConvNet, Transformer incl.
    attention + CE head, GQA-, SwiGLU- and MoE-top-k-aware; ``ce_chunk``
    changes memory, not the analytic FLOPs) against the backend peak-FLOPs
-   table below — the single source ``bench.py`` and the sweep tools
-   consume.  MFU exists on a TPU only: off-TPU the records carry
+   table below.  MFU exists on a TPU only: off-TPU the records carry
    ``mfu: null``, and a TPU kind missing from the table is an error.
 4. **Run-health heartbeat** — a leader-written, atomically-replaced
    ``heartbeat.json`` (step, dispatch timestamp, steps/sec EMA, last
@@ -105,12 +104,10 @@ METRIC_KEYS = ("loss", "grad_norm", "param_norm", "update_ratio", "skipped")
 _HEARTBEAT_MIN_INTERVAL_S = 0.5
 
 # ---------------------------------------------------------------------------
-# Pillar 3: FLOPs / MFU accounting (single source for bench.py + trainer)
+# Pillar 3: FLOPs / MFU accounting
 # ---------------------------------------------------------------------------
 
 # Peak dense bf16 FLOPs/s per chip by device_kind substring (public specs).
-# Moved here from bench.py so the trainer's metrics stream, bench.py's
-# headline and tools/big_lm_sweep.py's rows all divide by the same table.
 PEAK_FLOPS = (
     ("v6", 918e12), ("trillium", 918e12),
     ("v5p", 459e12), ("v5e", 197e12), ("v5", 197e12),

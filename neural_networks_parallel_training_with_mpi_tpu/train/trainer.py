@@ -143,8 +143,8 @@ class Trainer:
             raise ValueError("expert axis > 1 requires a transformer with "
                              "moe_experts > 0 (--moe_experts)")
         if cfg.grad_reduction not in ("global_mean", "per_shard_mean"):
-            # 'local' exists in data_parallel.make_train_step ONLY as
-            # bench.py's collective-cost ablation — replicas silently
+            # 'local' exists in data_parallel.make_train_step ONLY as a
+            # collective-cost ablation — replicas silently
             # diverge; it must never reach a training job (the CLI choices
             # already exclude it; this guards programmatic configs too)
             raise ValueError(

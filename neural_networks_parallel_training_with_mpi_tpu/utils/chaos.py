@@ -106,7 +106,7 @@ BUILTIN_PLANS: Dict[str, Dict[str, Any]] = {
              "rids": 6, "at": 3},
         ],
     },
-    # the bench plan (BENCH_CHAOS.json): lite plus the subprocess-fleet
+    # the full plan: lite plus the subprocess-fleet
     # scenarios — SIGKILL vs advance-notice A/B, health eviction, and
     # the disaggregated prefill/decode handoff under a crash-looping
     # prefill pool (DESIGN.md §11).
@@ -1355,8 +1355,8 @@ def run_campaign(plan: Dict[str, Any], repeat: int = 1,
                  ) -> Dict[str, Any]:
     """Run every scenario ``repeat`` times (>=2 checks determinism:
     identical canonical digests across passes).  The campaign document
-    is the artifact ``bench.py --chaos`` embeds and
-    ``tools/chaos_campaign.py`` gates its exit code on."""
+    is what ``tools/chaos_campaign.py`` gates its exit code on and
+    ``tests/test_chaos.py`` asserts on."""
     log = log or (lambda msg: None)
     seed = int(plan.get("seed", 0))
     passes: List[List[Dict[str, Any]]] = []

@@ -28,9 +28,8 @@ installed, every NEW argument signature is compiled through the AOT path
 The compiled executable is cached per signature and reused, so the
 ledger observes every compile exactly once and the program runs through
 the SAME XLA executable the jit path would build — params are
-bitwise-identical ledger-on vs ledger-off (tests/test_trace.py pins it,
-and ``bench.py --trace-overhead`` measures the host-side cost the
-DESIGN §7 way).  A call whose signature is already compiled does no
+bitwise-identical ledger-on vs ledger-off (tests/test_trace.py pins it;
+PERF.md §6, PR 26, has the host-side cost on the chip).  A call whose signature is already compiled does no
 per-leaf Python work (:class:`InstrumentedFn`).  When no ledger is
 installed the wrapper is a pass-through to the original jitted
 callable: zero behavior change.

@@ -117,8 +117,8 @@ handoff events, a DECODE worker counts inject ops; both honor
                           handoff timeout must abort and retry with
                           jittered backoff.
 
-Control-plane faults (the DRIVER fires these — ``bench.py
---ctrlplane`` and the chaos ``fleet_ctrlplane`` scenario poll
+Control-plane faults (the DRIVER fires these — the chaos
+``fleet_ctrlplane`` scenario polls
 :meth:`FaultPlan.fire_if_due` with the router's COMPLETED count as the
 step; the victim is the operator process itself, which a worker-side
 hook can never reach):

@@ -15,12 +15,12 @@ and has two halves:
    process's covered wall-clock — every second lands in exactly one
    category of a fixed, exhaustive set, gaps between spans are
    *attributed, never dropped*, and the categories provably sum to the
-   covered interval (``sum_ok`` is asserted by tests and the bench);
+   covered interval (``sum_ok`` is asserted by tests);
 
 2. an **online meter** (:class:`GoodputMeter`) that subscribes to the
    span stream via ``train.trace.add_listener`` and keeps the same
    category set incrementally, cheap enough to ride every traced process
-   (priced by ``bench.py --goodput``), feeding ``kind="goodput"``
+   (one dict update per span), feeding ``kind="goodput"``
    rollup records through the existing telemetry channel so
    ``tools/obs_agg.py`` can merge a fleet-wide goodput fraction into
    fleet.json / Prometheus / the dashboard.
@@ -448,7 +448,7 @@ class GoodputMeter:
     """Incremental category accounting from the live span stream.
 
     Subscribes via ``train.trace.add_listener(meter.on_span)``; per span
-    the cost is one dict update, priced by ``bench.py --goodput``.  It
+    the cost is one dict update.  It
     is an *online approximation* of the exact offline sweep: spans
     arrive at END time, so overlaps are resolved by a frontier rule
     (only time beyond the furthest end yet seen is newly accounted, so

@@ -12,7 +12,7 @@ process that will do the work, never by a helper child:
 * ``tpu``  — initialise the backend here and fail if it is not a TPU.
 * ``auto`` — pin nothing; JAX and ``JAX_PLATFORMS`` decide.
 
-:func:`select` is what the CLI, ``bench.py`` and the fleet worker call;
+:func:`select` is what the CLI, the benchmark and the fleet worker call;
 nothing re-pins a process whose backend is already up, and no path falls
 back from a requested platform to another one.
 """

@@ -25,12 +25,12 @@ from neural_networks_parallel_training_with_mpi_tpu.models.transformer import (
     Transformer, TransformerConfig,
 )
 from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import (
-    flash_attention, fused_layernorm, paged_attention,
+    flash_attention, paged_attention,
 )
 from neural_networks_parallel_training_with_mpi_tpu.serve import paged_kv
 from neural_networks_parallel_training_with_mpi_tpu.utils import prng
 
-# big_lm widths (bench.py _BIG); the serving geometry is chip_smoke's
+# big_lm widths and the serving geometry are chip_smoke.py's
 BATCH, SEQ, HEADS, HEAD_DIM, D_MODEL = 8, 1024, 16, 64, 1024
 SLOTS, NUM_BLOCKS, BLOCK_SIZE, PREFILL = 8, 513, 16, 128
 MAX_BLOCKS = SEQ // BLOCK_SIZE
@@ -168,14 +168,6 @@ def test_kernel_lowering_does_not_move_with_path_or_lines(spec, tmp_path,
         jax.config.update("jax_traceback_in_locations_limit", limit)
     assert "tpu_custom_call" in here and "attention" in here
     assert here == there
-
-
-def test_fused_layernorm(spec):
-    text = _compiled_text(
-        lambda x, s, b: fused_layernorm(x, s, b, interpret=False),
-        spec((BATCH * SEQ, D_MODEL), BF16), spec((D_MODEL,), jnp.float32),
-        spec((D_MODEL,), jnp.float32))
-    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("width,int8_kv", [

@@ -25,7 +25,7 @@ Pins, by acceptance criterion:
 All in-process (the core-lane shape); the subprocess versions — a
 SIGKILL'd driver process, orphan drain via stdin EOF, whole-process-
 group kill — live in the chaos campaign's ``stub_router_kill`` /
-``fleet_ctrlplane`` scenarios and ``bench.py --ctrlplane``.
+``fleet_ctrlplane`` scenarios (tests/test_chaos.py).
 """
 
 import json

@@ -23,8 +23,8 @@ duplicate or lost execution would show up as a token diff or a counter):
 
 All in-process (the core-lane shape); the subprocess versions — SIGKILL
 at the Nth handoff under the group supervisor — live in the chaos
-campaign's ``fleet_disagg_handoff`` scenario and ``bench.py
---serve-disagg``'s chaos arms.
+campaign's ``fleet_disagg_handoff`` scenario
+(tests/test_chaos.py, slow lane).
 """
 
 import time

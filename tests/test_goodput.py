@@ -1,5 +1,5 @@
 """Goodput accounting & step anatomy (utils/goodput.py, utils/jsonl.py,
-tools/goodput_report.py, tools/bench_diff.py).
+tools/goodput_report.py).
 
 Pins, by acceptance criterion:
 
@@ -11,9 +11,8 @@ Pins, by acceptance criterion:
   (the re-trained step window after restore) — never dropped time.
 * **torn-line tolerance**: the shared JSONL reader skips-and-counts a
   torn final line (a crashed writer's last record) instead of dying.
-* **tool smokes**: goodput_report runs under ``python -S`` (stdlib
-  proof) and bench_diff's direction-aware gate catches regressions but
-  refuses honesty-flag category errors.
+* **tool smoke**: goodput_report runs under ``python -S`` (stdlib
+  proof).
 
 The subprocess supervised-crash e2e is marked chaos; everything else is
 core-lane cheap (no jax imports).  ``-m goodput`` runs the lane alone.
@@ -240,7 +239,7 @@ def test_reader_missing_file_and_non_dict_lines(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tools: python -S report smoke + bench_diff gates
+# tools: python -S report smoke
 # ---------------------------------------------------------------------------
 
 def _write_fixture_dir(d):
@@ -271,32 +270,6 @@ def test_goodput_report_runs_under_python_S(tmp_path):
     assert all(p["sum_ok"] for p in doc["processes"])
 
 
-def test_bench_diff_directions_and_gates(tmp_path):
-    bd = _load_tool("bench_diff")
-    assert bd.direction("arms.on.step_ms_best") == "lower"
-    assert bd.direction("serve.tokens_per_s_best") == "higher"
-    assert bd.direction("chaos.goodput_fraction") == "higher"
-    assert bd.direction("reps") is None
-    old = {"step_ms_best": 100.0, "tokens_per_s": 50.0, "pin": True,
-           "_meta": {"honesty": {"cpu_fallback": True}}}
-    worse = dict(old, step_ms_best=150.0, pin=False)
-    rep = bd.compare(old, worse, rel_tol=0.10)
-    keys = {r["key"] for r in rep["regressions"]}
-    assert keys == {"step_ms_best", "pin"}
-    within = dict(old, step_ms_best=104.0)
-    assert bd.compare(old, within, rel_tol=0.10)["regressions"] == []
-    op, np_, tp = (tmp_path / n for n in ("o.json", "n.json", "t.json"))
-    op.write_text(json.dumps(old))
-    np_.write_text(json.dumps(worse))
-    tpu = dict(old, _meta={"honesty": {"cpu_fallback": False}})
-    tp.write_text(json.dumps(tpu))
-    assert bd.main([str(op), str(np_)]) == 1
-    assert bd.main([str(op), str(op)]) == 0
-    # honesty mismatch is a category error, not a comparison
-    assert bd.main([str(op), str(tp)]) == 2
-    assert bd.main([str(op), str(tp), "--allow-honesty-mismatch"]) == 0
-
-
 def test_obs_agg_merges_goodput_to_prometheus(tmp_path):
     oa = _load_tool("obs_agg")
     dirs = []
@@ -323,6 +296,78 @@ def test_obs_agg_merges_goodput_to_prometheus(tmp_path):
     assert 'nnpt_goodput_seconds_total{role="train",category="step"}' \
         in prom
     assert 'nnpt_goodput_fraction{role="serve"} 0.6' in prom
+
+
+# ---------------------------------------------------------------------------
+# the meter is pure observation: same parameters, same served tokens
+# ---------------------------------------------------------------------------
+
+def _goodput_records(telemetry_dir):
+    return [r for r in jz.read_jsonl(
+        os.path.join(telemetry_dir, "metrics.jsonl"))[0]
+        if r.get("kind") == "goodput"]
+
+
+def test_params_bitwise_identical_goodput_on_off(tmp_path, mesh8):
+    """Both runs are traced; the only difference is the span listener,
+    its frontier update per span and a snapshot per rollup."""
+    import jax
+    import numpy as np
+
+    from neural_networks_parallel_training_with_mpi_tpu.config import (
+        DataConfig, TrainConfig,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.train.trainer import (
+        Trainer,
+    )
+
+    leaves = {}
+    for arm in (False, True):
+        tdir = str(tmp_path / f"gp{arm}")
+        t = Trainer(TrainConfig(
+            nepochs=2, batch_size=8, full_batch=False, lr=1e-2,
+            momentum=0.9, skip_nonfinite=True,
+            data=DataConfig(dataset="regression", n_samples=32),
+            telemetry_dir=tdir, trace=True, metrics_every=1,
+            rollup_every=2, goodput=arm), mesh=mesh8)
+        t.fit()
+        leaves[arm] = jax.tree_util.tree_leaves(
+            jax.device_get(t.state.params))
+        assert bool(_goodput_records(tdir)) == arm
+    for a, b in zip(leaves[False], leaves[True]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_served_tokens_identical_goodput_on_off(tmp_path):
+    """The same requests through a ``Scheduler`` with the meter, its
+    ``kind="goodput"`` rollups and the burn budget on and off: the
+    accounting cannot reach the sampler."""
+    from neural_networks_parallel_training_with_mpi_tpu.models.transformer import (  # noqa: E501
+        Transformer, TransformerConfig,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.serve.scheduler import (  # noqa: E501
+        Scheduler, ServeConfig,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.utils import prng
+
+    model = Transformer(TransformerConfig(
+        vocab_size=64, max_seq_len=64, n_layers=2, d_model=32,
+        n_heads=4, d_ff=64))
+    params = model.init(prng.init_key(0))
+    prompts = [[1, 2, 3], [4, 5, 6, 7], [9, 10]]
+    tokens = {}
+    for arm in (False, True):
+        tdir = str(tmp_path / f"serve{arm}")
+        sched = Scheduler(model, params, ServeConfig(
+            slots=4, num_blocks=40, block_size=8, prefill_chunk=8,
+            telemetry_dir=tdir, rollup_every=4, goodput=arm))
+        rids = [sched.submit(p, 8) for p in prompts]
+        sched.run_until_drained()
+        tokens[arm] = [sched.result(r) for r in rids]
+        sched.close()
+        assert bool(_goodput_records(tdir)) == arm
+    assert tokens[False] == tokens[True]
+    assert [len(t) for t in tokens[True]] == [len(p) + 8 for p in prompts]
 
 
 # ---------------------------------------------------------------------------

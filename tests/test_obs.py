@@ -38,6 +38,7 @@ import sys
 import time
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -147,6 +148,28 @@ def test_trainer_rollups_off_by_default(tmp_path, mesh8):
     d = str(tmp_path / "t")
     Trainer(_cfg(telemetry_dir=d), mesh=mesh8).fit()
     assert not [r for r in _records(d) if r["kind"] == "rollup"]
+
+
+def test_params_bitwise_identical_obs_on_off(tmp_path, mesh8):
+    """The plane is pure observation: sketch feeds, detectors, rollup
+    serialization and the heartbeat are host arithmetic on fetched
+    floats, so the parameters after the same steps are bit for bit those
+    of a run with no telemetry, and of one with the metrics stream
+    alone."""
+    def fit(**kw):
+        t = Trainer(_cfg(lr=1e-2, momentum=0.9, skip_nonfinite=True, **kw),
+                    mesh=mesh8)
+        t.fit()
+        return jax.tree_util.tree_leaves(jax.device_get(t.state.params))
+
+    on_dir = str(tmp_path / "on")
+    bare = fit(metrics_every=0)
+    stream = fit(telemetry_dir=str(tmp_path / "stream"), alerts=False)
+    plane = fit(telemetry_dir=on_dir, rollup_every=2, alerts=True)
+    assert [r for r in _records(on_dir) if r["kind"] == "rollup"]
+    for a, b, c in zip(bare, stream, plane):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 # ------------------------------------------------------------------- alerts

@@ -88,13 +88,3 @@ def test_mfu_exists_on_a_known_tpu_only():
     assert telemetry.telemetry_peak_flops("cpu", "cpu") is None
     with pytest.raises(ValueError, match="no peak FLOPs/s entry"):
         telemetry.peak_flops_per_chip("TPU v9x")
-
-
-def test_bench_asked_for_a_tpu_exits_nonzero_without_a_number():
-    out = subprocess.run(
-        [sys.executable, str(REPO / "bench.py"), "--platform", "tpu"],
-        capture_output=True, text=True, timeout=120, cwd=str(REPO),
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert out.returncode != 0
-    assert out.stdout.strip() == ""                 # no record, no number
-    assert "platform 'tpu' was asked for" in out.stderr

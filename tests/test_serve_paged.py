@@ -192,8 +192,7 @@ def test_unservable_request_raises():
 def test_capacity_beats_dense_at_equal_memory():
     """The tentpole claim at unit scale: the same cache positions, paged
     into blocks, admit MORE short concurrent streams than dense slots
-    (measured by admitting until refusal — the bench's capacity A/B at
-    bench scale writes BENCH_SERVE.json)."""
+    (counted by admitting until refusal)."""
     model = _model()
     params = model.init(prng.init_key(0))
     dense = DecodeServer(model, params, slots=2, max_len=64)

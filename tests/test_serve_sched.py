@@ -445,30 +445,6 @@ def test_loadgen_closed_loop_smoke():
     sched.server.allocator.assert_drained()
 
 
-@pytest.mark.serve
-@pytest.mark.slow
-def test_bench_serve_writes_artifact(tmp_path, monkeypatch):
-    """bench.py --serve end to end at bench scale (the slow serve lane):
-    the artifact carries >= 3 load points with the percentile fields and
-    the capacity A/B."""
-    import pathlib
-    import sys
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
-    import bench
-
-    monkeypatch.chdir(tmp_path)
-    path = bench.bench_serve(str(tmp_path / "BENCH_SERVE.json"))
-    doc = json.load(open(path))
-    assert len(doc["load_sweep"]) >= 3
-    for row in doc["load_sweep"]:
-        for k in ("tokens_per_sec", "ttft_ms_p50", "ttft_ms_p99",
-                  "itl_ms_p50", "itl_ms_p99"):
-            assert row[k] is not None
-    cap = doc["capacity_equal_memory"]
-    assert cap["paged_streams_admitted"] > cap["dense_streams_admitted"]
-    assert doc["dense_host_sync_fix"]["tokens_per_sec_host_tracked"] > 0
-
-
 # ---------------------------------------------------------------------------
 # drain/requeue semantics (the fleet router's replica-death contract,
 # pinned in ISOLATION: one scheduler, no router, no subprocesses)

@@ -350,12 +350,15 @@ def test_train_step_flops_mlp_and_peak_table():
     m = MLP(in_features=2, hidden=(3,), out_features=1)
     assert m.fwd_flops((5, 2)) == 2 * 5 * (2 * 3 + 3 * 1)
     assert telemetry_lib.train_step_flops(m, (5, 2)) == 3.0 * 2 * 5 * 9
-    # the peak table is the single source bench.py re-exports
-    import bench
-
-    assert bench.peak_flops("TPU v5e") == 197e12
-    assert bench.peak_flops("TPU v4") == 275e12
-    assert bench.peak_flops("cpu") is None
+    assert telemetry_lib.peak_flops_per_chip("TPU v5e") == 197e12
+    assert telemetry_lib.peak_flops_per_chip("TPU v4") == 275e12
+    assert telemetry_lib.peak_flops_per_chip("cpu") is None
+    # the benchmark keeps its own table: the two agree until ROADMAP D2
+    # merges them
+    peaks = json.loads(
+        (REPO / "benchmark" / "reducers" / "peaks.json").read_text())
+    assert (telemetry_lib.peak_flops_per_chip("TPU v5 lite")
+            == peaks["devices"]["TPU v5 lite"]["bf16_flops"])
     # no utilization against an invented peak: off-TPU there is none
     assert telemetry_lib.telemetry_peak_flops("cpu", "cpu") is None
     assert telemetry_lib.telemetry_peak_flops("TPU v4", "tpu") == 275e12

@@ -152,7 +152,7 @@ def test_eval_accuracy_classification(mesh8):
     assert 0.0 <= metrics["accuracy"] <= 1.0
 
 def test_trainer_rejects_ablation_grad_reduction():
-    """grad_reduction='local' is bench.py's collective-cost ablation
+    """grad_reduction='local' is a collective-cost ablation
     (replicas diverge); the Trainer must refuse it even though
     data_parallel.make_train_step accepts it for the measurement path."""
     import dataclasses

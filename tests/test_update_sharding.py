@@ -351,8 +351,7 @@ def _deep_cfg(update_sharding):
     # hidden (64, 128, 64): two shardable matmul slots with DIFFERENT
     # scatter dims ((64,128) axis 1, (128,64) axis 0), so the compiled
     # program must carry >= 2 distinct per-leaf reduce-scatters — cheap
-    # MLP compile; the transformer-scale evidence (23 reduce-scatters,
-    # 17/75 dots after the first) lives in BENCH_UPDATE_SHARDING.json
+    # MLP compile
     c = _cfg(update_sharding)
     return dataclasses.replace(
         c, model=dataclasses.replace(c.model, hidden=(64, 128, 64)))

@@ -13,11 +13,8 @@ kernel's own), and idle time by the deepest ``nnpt:`` span over it
 (``host_phases.py`` ``by_span``).  PERF.md section 5 is written from it.
 
 The second form (on the chip) makes that traced run itself, through
-``benchmark/run.py``'s own ``main``, with the scope and idle metrics of
-``benchmark/metrics/`` added to the cell's ``per_layer`` list in memory: a
-cell's file lists its metrics, and the accepted cells' files are a
-``benchmark`` PR's to edit (PERF.md section 7).  It prints the run's result
-line, then the report.
+``benchmark/run.py``'s own ``main`` (the cells list the scope and idle
+metrics themselves), so it prints the run's result line, then the report.
 """
 
 import json
@@ -28,7 +25,6 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-NAMED = ("scopes", "host_phases")       # the reducers that read names
 # path components: what implements the attention, and the Pallas kernels'
 # own names (``pl.pallas_call(name=...)``) under it
 _IMPL = re.compile(r"(?:^|[/(])(attn_\w+?)(?=[)/]|$)")
@@ -42,17 +38,6 @@ class _Trace:
 
     def trace_file(self):
         return self.path
-
-
-def named_metrics(cell: dict, bench: Path) -> list:
-    """The metrics read from names whose suffix is the cell's kind of job
-    (``.train`` / ``.serve``) and which the cell does not list yet."""
-    suffix = "." + cell["job"]["kind"].split("_")[0]
-    specs = (json.loads(p.read_text())
-             for p in sorted((bench / "metrics").glob(f"*{suffix}.json")))
-    return [m["name"] for m in specs
-            if m["reducer"].split(":")[0] in NAMED
-            and m["name"] not in cell["per_layer"]]
 
 
 def attention_by_impl(obs) -> dict:
@@ -79,30 +64,14 @@ def attention_by_impl(obs) -> dict:
     return out
 
 
-def run_cell(argv) -> int:
-    from benchmark import run as runner
-    from benchmark.harness import common
-
-    load_cell = common.load_cell
-
-    def with_named(name, bench=common.BENCH):
-        cell = load_cell(name, bench)
-        return {**cell,
-                "per_layer": cell["per_layer"] + named_metrics(cell, bench)}
-
-    common.load_cell = with_named
-    try:
-        return runner.main([*argv, "--trace", "1"])
-    finally:
-        common.load_cell = load_cell
-
-
 def main(argv) -> int:
     from benchmark.reducers import host_phases, scopes
 
     out_dir = ROOT / "benchmark" / "out"
     if "--workload" in argv:
-        rc = run_cell(argv[1:])
+        from benchmark import run as runner
+
+        rc = runner.main([*argv[1:], "--trace", "1"])
         if rc:
             return rc
     elif len(argv) > 1:

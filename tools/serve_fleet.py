@@ -8,8 +8,7 @@ replica relaunches under its own backoff/budget while siblings keep
 serving), fronted by the SLO-aware ``serve.fleet.FleetRouter`` in THIS
 process.  The built-in closed-loop load generator then drives the
 router and prints the measured row as JSON — the smallest end-to-end
-demonstration of the fleet (example 23 wraps it; ``bench.py
---serve-fleet`` runs the replica-count sweep into BENCH_FLEET.json).
+demonstration of the fleet (example 23 wraps it).
 
 Telemetry: with ``--telemetry-dir`` every replica writes its own
 ``replica-K/`` dir (rollups/heartbeats under its NNPT_PROCESS_ID=K
@@ -156,7 +155,7 @@ def main(argv=None) -> int:
                          "run it, half run the no-SLO bulk class")
     ap.add_argument("--step-sleep-ms", type=float, default=0.0,
                     help="emulated per-tick device latency in each "
-                         "replica (bench.py --serve-fleet's knob)")
+                         "replica (the worker's --step-sleep-ms)")
     ap.add_argument("--prewarm", action="store_true",
                     help="replicas pay every compile before reporting "
                          "ready — use with --autopilot so a canary's "

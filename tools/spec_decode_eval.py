@@ -17,12 +17,12 @@ This tool measures the lever's actual value proposition:
 3. Greedy rows additionally assert the exactness contract on the trained
    pair (output == plain generate, token for token).
 
-Artifact: ``BENCH_DECODE_SPEC.json`` (real accelerator) or
-``BENCH_DECODE_SPEC_CPU.json`` (CPU — the accept-rate curve is
-platform-independent, so the CPU row is real evidence for it; its
-tokens/sec column is not a device number).  The platform is whatever JAX
-brings up (utils.platform.select("auto")); the final stdout line is one
-JSON object that names it.
+The record goes to ``chiprun_out/spec_decode_eval.json`` (where the repo's
+other timing tools write; never the checkout's root) and its summary is
+the final stdout line, one JSON object that names the platform: whatever
+JAX brings up (utils.platform.select("auto")).  The accept-rate curve is a
+count and holds on any platform; a tokens/sec column from a CPU run is not
+a device number.
 
 The reference (dataParallelTraining_NN_MPI.py) has no serving path at all;
 this is a beyond-parity lever, measured because BASELINE.md promised it.
@@ -286,9 +286,9 @@ def main() -> int:
                         "device_ratio_vs_plain":
                             best_row["device_ratio_vs_plain"]},
     }
-    name = ("BENCH_DECODE_SPEC.json" if platform != "cpu"
-            else "BENCH_DECODE_SPEC_CPU.json")
-    path = os.path.join(REPO, name)
+    out_dir = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    path = os.path.join(out_dir, "spec_decode_eval.json")
     with open(path, "w") as f:
         json.dump(doc, f, indent=2)
     print(json.dumps({"metric": "speculative_trained_accept_rate",
@@ -298,7 +298,7 @@ def main() -> int:
                       "device_ratio_vs_plain":
                           best_row["device_ratio_vs_plain"],
                       "platform": platform,
-                      "spec_artifact": name}))
+                      "spec_artifact": os.path.relpath(path, REPO)}))
     return 0
 
 
