@@ -200,20 +200,42 @@ class LatentAttention(Module):
         # pad column past ``n_keys`` has seen none and reads 0, not 0 / 0
         return acc / jnp.maximum(norm, 1e-30).transpose(0, 2, 1)[..., None]
 
+    def absorb_weights(self, params: Pytree):
+        """``W_kvb`` split for the absorbed form: ``(W^K, W^V)``, (rank, H,
+        nope) and (rank, H, v) in the compute type."""
+        w_kvb = self._kv_b(params)
+        return (w_kvb[..., :self.qk_nope_head_dim],
+                w_kvb[..., self.qk_nope_head_dim:])
+
+    def absorb_query(self, w_k, q_nope) -> jax.Array:
+        """``q_nope W^K``: the query in the latent row's own lanes, (B, W, H,
+        rank) float32 (scope ``mla_absorb``)."""
+        with jax.named_scope("mla_absorb"):
+            return jnp.einsum("bqhd,rhd->bqhr",
+                              q_nope.astype(self.compute_dtype), w_k,
+                              preferred_element_type=jnp.float32)
+
+    def absorb_value(self, w_v, u) -> jax.Array:
+        """``u W^V``: the weighted sum of latent rows (B, W, H, rank) to each
+        head's value (B, W, H, v), float32 (scope ``mla_absorb``)."""
+        with jax.named_scope("mla_absorb"):
+            return jnp.einsum("bqhr,rhd->bqhd", u.astype(self.compute_dtype),
+                              w_v, preferred_element_type=jnp.float32)
+
     def attend_absorbed(self, params: Pytree, q_nope, q_rope, rows,
                         mask) -> jax.Array:
         """Absorbed form: same arguments, same result; the latent rows are
         never expanded.  Scope ``mla_absorb`` holds the two products that
-        take ``W_kvb``'s place around the cache."""
+        take ``W_kvb``'s place around the cache (:meth:`absorb_query`,
+        :meth:`absorb_value`); between them every head scores against the
+        same row and weighs its first ``kv_lora_rank`` lanes, which is what
+        the paged kernel's shared-row mode does over the pool in place
+        (serve/paged_kv.py)."""
         cdt = self.compute_dtype
         c_kv = rows[..., :self.kv_lora_rank].astype(cdt)
         k_rope = rows[..., self.kv_lora_rank:].astype(cdt)
-        w_kvb = self._kv_b(params)
-        w_k, w_v = (w_kvb[..., :self.qk_nope_head_dim],
-                    w_kvb[..., self.qk_nope_head_dim:])
-        with jax.named_scope("mla_absorb"):
-            q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(cdt), w_k,
-                               preferred_element_type=jnp.float32)
+        w_k, w_v = self.absorb_weights(params)
+        q_lat = self.absorb_query(w_k, q_nope)
         s = (jnp.einsum("bqhr,bkr->bhqk", q_lat.astype(cdt), c_kv,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(cdt), k_rope,
@@ -222,9 +244,7 @@ class LatentAttention(Module):
         p = jax.nn.softmax(s, axis=-1)
         u = jnp.einsum("bhqk,bkr->bqhr", p.astype(cdt), c_kv,
                        preferred_element_type=jnp.float32)
-        with jax.named_scope("mla_absorb"):
-            return jnp.einsum("bqhr,rhd->bqhd", u.astype(cdt), w_v,
-                              preferred_element_type=jnp.float32)
+        return self.absorb_value(w_v, u)
 
     def apply(self, params: Pytree, h: jax.Array, **kwargs) -> jax.Array:
         """The full causal forward over ``h`` (B, T, d), positions 0..T-1,

@@ -687,19 +687,25 @@ flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)
 # Paged attention (serving: decode + chunked prefill over a block pool)
 # ==========================================================================
 
-# Measured tilings of the paged kernel, ``(block_size, kv_heads * head_dim,
+# Measured tilings of the paged kernel, ``(block_size, lanes of a pool row,
 # "decode" | "chunk") -> (pages, tile_cols)``: pool pages DMA'd and scored a
 # loop step, and query columns a row tile (1 at decode).  Keyed by what the
-# kernel sees of its operands; a row is there only if it was timed on the
-# chip (tools/paged_attn_timing.py on one TPU v5e, PR 30, PERF.md section
-# 6: 64 pages tie with 32 at the cell's lengths and win at the table's full
+# kernel sees of its operands (the lanes are ``kv_heads * head_dim`` of a
+# per-head row, the stored width of a row that is its own value); a row is
+# there only if it was timed on the chip (tools/paged_attn_timing.py on one
+# TPU v5e, PERF.md section 6.  PR 30, the per-head row of 256 lanes: 64
+# pages tie with 32 at the cell's lengths and win at the table's full
 # width; a chunk is flat between 64 and 128 columns, and 64 compiles in a
-# third of the time).  Every other shape walks ``_UNTIMED_KEYS`` key
+# third of the time.  PR 32, the latent row stored 384 lanes wide, one copy
+# a page: 64 pages tie with 128 at the cell's lengths and lose 3 % to them
+# at the table's full width, at half the landing buffer; the untimed rule's
+# 8 pages take 1.6 x as long).  Every other shape walks ``_UNTIMED_KEYS`` key
 # positions a step and tiles a chunk at about ``_UNTIMED_ROWS`` query rows
 # a KV head.
 PAGED_TILES = {
     (16, 256, "decode"): (64, 1),
     (16, 256, "chunk"): (32, 64),
+    (16, 384, "decode"): (64, 1),
 }
 _UNTIMED_KEYS = 128
 _UNTIMED_ROWS = 256
@@ -730,9 +736,10 @@ def paged_tiles(block_size: int, lanes: int, width: int, groups: int,
 
 
 def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
-                       v_hbm, *rest, block_size: int, pages: int,
+                       *rest, block_size: int, pages: int,
                        kv_heads: int, groups: int, tile_cols: int,
-                       scale: float, quant: bool):
+                       scale: float, quant: bool,
+                       v_lanes: Optional[int] = None):
     """Grid: (streams, row tiles).  A program owns ``tile_cols`` query
     columns of one stream (all heads: ``tile_cols * groups`` rows a KV
     head) and walks the stream's block table ``pages`` entries a loop step,
@@ -756,10 +763,17 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     static lane slice): Mosaic refuses to DMA-slice a page out of an array
     whose last dim is below the 128-lane tile.
 
+    ``v_lanes`` is the **shared-row mode** (latent attention's absorbed
+    decode: multi-query attention whose one KV head's value is the head of
+    its key): there is no value pool, a page is fetched once into the one
+    landing buffer, scores are ``q . page^T`` over the row's whole width and
+    values ``p . page[:, :v_lanes]``, so the output is ``v_lanes`` wide.
+
     Operands reach the MXU in the pool's type (bf16 pools: bf16 products,
     f32 accumulation; f32 pools stay exact; int8 pools are cast to f32 and
-    scaled as the gathered path scales them); scores, the ``1/sqrt(hd)``
-    scale and the softmax state are f32.
+    scaled as the gathered path scales them); scores, the softmax
+    scale (``1/sqrt(hd)`` unless the caller hands one in) and the softmax
+    state are f32.
 
     Steps wholly below the tile's first query position and the stream's
     length need no mask; the last one or two apply ``k < len`` and the
@@ -769,11 +783,14 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     at or past ``len`` (the pad columns of a bucketed chunk), walks nothing
     and exits with output 0, the flash kernels' "no contribution"
     convention; the sink block is never attended."""
-    if quant:
-        (ks_hbm, vs_hbm, o_ref,
+    shared = v_lanes is not None
+    if shared:
+        o_ref, k_buf, sem = rest
+    elif quant:
+        (v_hbm, ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, ks_buf, vs_buf, sem) = rest
     else:
-        o_ref, k_buf, v_buf, sem = rest
+        v_hbm, o_ref, k_buf, v_buf, sem = rest
     s, t = pl.program_id(0), pl.program_id(1)
     ln = lens_ref[s]
     first = starts_ref[s] + t * tile_cols       # the tile's first query
@@ -784,17 +801,18 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     n_full = lax.div(lax.min(first + 1, limit), span)
     rows = tile_cols * groups
     hd = q_ref.shape[-1]
+    vd = v_lanes if shared else hd              # lanes of a value row
 
     def page_copies(j, i):
         slot = lax.rem(j, 2)
         blk = tables_ref[s, j * pages + i]
         dst = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
-        ops = [
-            pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, dst],
-                                  sem.at[slot, 0]),
-            pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, dst],
-                                  sem.at[slot, 1]),
-        ]
+        ops = [pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, dst],
+                                     sem.at[slot, 0])]
+        if not shared:
+            ops.append(
+                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, dst],
+                                      sem.at[slot, 1]))
         if quant:
             ops += [
                 pltpu.make_async_copy(ks_hbm.at[blk], ks_buf.at[slot],
@@ -847,7 +865,7 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
             for h, (acc, m, l) in enumerate(carry):
                 lanes = slice(h * hd, (h + 1) * hd)
                 k = k_buf[slot, :, lanes]                   # (span, hd)
-                v = v_buf[slot, :, lanes]
+                v = k[:, :vd] if shared else v_buf[slot, :, lanes]
                 if quant:
                     k, v = k.astype(jnp.float32), v.astype(jnp.float32)
                 if masked:
@@ -874,7 +892,7 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     # per-head carries as tuples: the kv_heads loop is a Python unroll,
     # and a stacked (kv_heads, rows, ...) carry updated with .at[h].set
     # is a scatter, which Mosaic does not lower
-    carry0 = ((jnp.zeros((rows, hd), jnp.float32),
+    carry0 = ((jnp.zeros((rows, vd), jnp.float32),
                jnp.full((rows, 1), NEG_INF, jnp.float32),
                jnp.zeros((rows, 1), jnp.float32)),) * kv_heads
     walk = (ln > 0) & (first < ln)
@@ -895,11 +913,14 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
                                     acc / l_safe).astype(o_ref.dtype)
 
 
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+def paged_attention(q: jax.Array, k_pool: jax.Array,
+                    v_pool: Optional[jax.Array],
                     tables: jax.Array, lengths: jax.Array,
                     starts: jax.Array, *,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
+                    v_lanes: Optional[int] = None,
+                    scale: Optional[float] = None,
                     pages: Optional[int] = None,
                     tile_cols: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -922,6 +943,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       pool stored that way reaches it without a copy — f32/bf16, or int8
       with ``k_scale``/``v_scale`` (num_blocks, block_size, kv_heads) f32,
       applied to the scores and probabilities.
+    * ``v_pool=None`` with ``v_lanes``: the **shared-row mode** — one pool
+      row is key and value (latent attention's absorbed decode: every query
+      head scores against the same row, and the value is its first
+      ``v_lanes`` lanes).  ``k_pool`` is (num_blocks, block_size, lanes) and
+      ``q`` (streams, width, n_heads, lanes): a page is fetched once, and
+      the result is (streams, width, n_heads, v_lanes).  Lanes of the row
+      that are padding hold zeros in the pool or in the query.
     * ``tables``: (streams, max_blocks) int32 pool indices; unallocated
       entries point at the sink block and are NEVER walked (the page
       walk stops at ``ceil(length/block_size)``).
@@ -929,6 +957,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       inactive lane: zero pages walked, zero pages fetched, output 0).
     * ``starts``: (streams,) int32 absolute position of each stream's
       first query row (decode passes ``lengths - 1``).
+    * ``scale``: the softmax scale of the f32 scores; ``None`` is
+      ``1/sqrt(head_dim)``.
     * ``pages`` / ``tile_cols``: pages a loop step and query columns a row
       tile; ``None`` asks :func:`paged_tiles` (int8 pools walk one page a
       step).
@@ -937,7 +967,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     churn (admission, growth, eviction) re-runs the SAME compiled kernel
     — pinned by tests/test_paged_attn.py's compile-count test."""
     s_n, width, n_heads, hd = q.shape
-    if k_pool.ndim == 3:
+    if v_pool is None:
+        if v_lanes is None or k_scale is not None or v_scale is not None:
+            raise ValueError("a pool row that is its own value (v_pool=None) "
+                             "needs v_lanes, and has no int8 scheme")
+        if k_pool.ndim != 3 or k_pool.shape[2] != hd or not 0 < v_lanes <= hd:
+            raise ValueError(
+                f"shared-row mode: the query's {hd} lanes must be the pool "
+                f"row's (pool {k_pool.shape}), the value its first v_lanes "
+                f"({v_lanes})")
+        bs, kv_heads = k_pool.shape[1], 1
+    elif v_lanes is not None:
+        raise ValueError("v_lanes belongs to the shared-row mode "
+                         "(v_pool=None)")
+    elif k_pool.ndim == 3:
         nb, bs, lanes = k_pool.shape
         if lanes % hd:
             raise ValueError(f"folded pool row {lanes} is not a multiple of "
@@ -959,23 +1002,30 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         pages, tile_cols, quant=k_scale is not None)
     return _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
                                  k_scale, v_scale, pages=pages,
-                                 tile_cols=tile_cols, interpret=interpret)
+                                 tile_cols=tile_cols, interpret=interpret,
+                                 v_lanes=v_lanes, scale=scale)
 
 
 # jitted for the flash calls' reason: 30 unrolled layers lower one kernel
 @functools.partial(jax.jit, static_argnames=("pages", "tile_cols",
-                                             "interpret"))
+                                             "interpret", "v_lanes", "scale"))
 def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
                           k_scale, v_scale, *, pages: int, tile_cols: int,
-                          interpret: bool):
+                          interpret: bool, v_lanes: Optional[int] = None,
+                          scale: Optional[float] = None):
     s_n, width, n_heads, hd = q.shape
     nb, bs = k_pool.shape[:2]
     lanes = math.prod(k_pool.shape[2:])
     kv_heads = lanes // hd
     quant = k_scale is not None
+    shared = v_pool is None
     groups = n_heads // kv_heads
     rows = tile_cols * groups
     span = pages * bs
+    # the value's lanes inside the kernel: whole 128-lane tiles of the
+    # shared row (a narrower slice of a landed page does not lower), cut to
+    # ``v_lanes`` on the way out
+    vd = min(-(-v_lanes // 128) * 128, hd) if shared else hd
 
     # (S, W, H, hd) -> (S, KV, W·G, hd): per-kv-head query rows contiguous
     qk = q.reshape(s_n, width, kv_heads, groups, hd)
@@ -986,15 +1036,14 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
 
     row_map = lambda s, t, tbl, lns, sts: (s, 0, t, 0)      # noqa: E731
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)      # never blocked
-    in_specs = [pl.BlockSpec((1, kv_heads, rows, hd), row_map),
-                hbm_spec, hbm_spec]
     # pool pages cross into VMEM as lane-dense (bs, KV*hd) rows (see
     # the kernel's docstring); head h is the lane slice [h*hd, (h+1)*hd)
-    operands = [qk, k_pool.reshape(nb, bs, lanes),
-                v_pool.reshape(nb, bs, lanes)]
-    n_dma = 2
-    scratch = [pltpu.VMEM((2, span, lanes), k_pool.dtype),
-               pltpu.VMEM((2, span, lanes), v_pool.dtype)]
+    pools = [k_pool] if shared else [k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, kv_heads, rows, hd), row_map),
+                *[hbm_spec] * len(pools)]
+    operands = [qk, *[p.reshape(nb, bs, lanes) for p in pools]]
+    n_dma = len(pools)
+    scratch = [pltpu.VMEM((2, span, lanes), p.dtype) for p in pools]
     if quant:
         # scales ride head-major, (1, KV*bs) per block, so head h's
         # per-position scales are a static lane slice that broadcasts
@@ -1012,22 +1061,23 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
         num_scalar_prefetch=3,
         grid=(s_n, width // tile_cols),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kv_heads, rows, hd), row_map),
+        out_specs=pl.BlockSpec((1, kv_heads, rows, vd), row_map),
         scratch_shapes=scratch,
     )
     # the landing buffers, the query and output tiles (double-buffered,
     # lane-padded) and the f32 score-sized temporaries of one loop step
-    landing = 4 * span * lanes * k_pool.dtype.itemsize
+    landing = 2 * len(pools) * span * lanes * k_pool.dtype.itemsize
     tiles = 4 * kv_heads * rows * max(hd, 128) * 4
     scores = 8 * max(rows, 8) * max(span, 128) * 4
     out = pl.pallas_call(
         functools.partial(
             _paged_attn_kernel, block_size=bs, pages=pages,
             kv_heads=kv_heads, groups=groups, tile_cols=tile_cols,
-            scale=1.0 / (hd ** 0.5), quant=quant),
+            scale=1.0 / (hd ** 0.5) if scale is None else scale,
+            quant=quant, v_lanes=vd if shared else None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (s_n, kv_heads, width * groups, hd), q.dtype),
+            (s_n, kv_heads, width * groups, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(min(100 << 20, max(
@@ -1036,7 +1086,7 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
         name="paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       starts.astype(jnp.int32), *operands)
-    # (S, KV, W·G, hd) -> (S, W, H, hd)
-    out = out.reshape(s_n, kv_heads, width, groups, hd)
-    return out.transpose(0, 2, 1, 3, 4).reshape(s_n, width, n_heads, hd)
-
+    # (S, KV, W·G, vd) -> (S, W, H, vd)
+    out = out.reshape(s_n, kv_heads, width, groups, vd)
+    out = out.transpose(0, 2, 1, 3, 4).reshape(s_n, width, n_heads, vd)
+    return out[..., :v_lanes] if shared and vd != v_lanes else out

@@ -26,25 +26,35 @@ blocks:
   harmlessly and are never attended.
 * **Attention dispatch** (``attn_impl``, default ``auto``, resolved once
   in :func:`resolve_attn_impl` from what the code can observe): on a TPU,
-  with the per-head K/V row and a lane-dense ``kv_heads * head_dim``,
+  with pools that are not int8 and a cache row that is per-head K/V at a
+  lane-dense ``kv_heads * head_dim`` or latent attention's one row,
   ``auto`` is the **fused** path (``ops.pallas_kernels.paged_attention``):
-  the Pallas kernel reads K/V straight from the pool through the tables,
-  several pages a loop step, and stops at each stream's own length — the
-  decode program and the prefill-chunk program alike materialise no
-  ``pool[tables]`` and reduce over no ``T_cap`` keys.  Its pools are stored
-  with the heads folded into the lanes, ``(num_blocks, block_size,
-  kv_heads * head_dim)``: the layout the kernel reads, so the pool reaches
-  it without a copy (the bytes of a block row are the same either way, so
-  export / import and the handoff do not care).  Anywhere else (the CPU,
-  the latent row, int8 KV, a head row that does not fill the lanes)
-  ``auto`` is the **gathered** path, which gathers each row's blocks
-  ``pool[table] -> (T_cap, kv_heads, head_dim)`` (``T_cap = max_blocks *
-  block_size``) and attends under the causal mask ``t <= pos`` — the same
-  reduction, over the same values in the same order, as the dense cache
-  path, which is why greedy paged decode is token-identical to
-  ``DecodeServer`` / ``models.generate.generate`` (pinned by
-  tests/test_serve_paged.py); the fused path is token-identical to it
-  (tests/test_paged_attn.py).  ``"gathered"`` and ``"fused"`` stay as
+  the Pallas kernel reads the cache straight from the pool through the
+  tables, several pages a loop step, and stops at each stream's own
+  length.  Its pools are stored in the layout the kernel DMAs
+  (:func:`stored_rows`), so a pool reaches it without a copy: a per-head
+  row with the heads folded into the lanes, ``(num_blocks, block_size,
+  kv_heads * head_dim)`` (the same bytes a block row); the latent row
+  zero-padded to whole 128-lane tiles, ``(num_blocks, block_size, 384)``
+  for a row of 320 (what leaves the server stays 320 wide, so export /
+  import and the handoff do not care).  With the per-head row the decode
+  program and the prefill-chunk program alike materialise no
+  ``pool[tables]`` and reduce over no ``T_cap`` keys.  With the latent row
+  the decode program does not either: the kernel's shared-row mode (one
+  row is key and value: multi-query attention over ``[c_kv | k_rope]``)
+  sits between the absorbed form's two ``W_kvb`` products; its prefill
+  chunk gathers its one stream's rows from the same pool and keeps the
+  expanded form (the absorbed form a chunk through the kernel would need
+  executes about three times the expanded form's FLOPs).  Anywhere else
+  (the CPU, int8 KV, a head row that does not fill the lanes) ``auto`` is
+  the **gathered** path, which gathers each row's blocks ``pool[table] ->
+  (T_cap, kv_heads, head_dim)`` (``T_cap = max_blocks * block_size``) and
+  attends under the causal mask ``t <= pos`` — the same reduction, over
+  the same values in the same order, as the dense cache path, which is
+  why greedy paged decode is token-identical to ``DecodeServer`` /
+  ``models.generate.generate`` (pinned by tests/test_serve_paged.py); the
+  fused path is token-identical to it (tests/test_paged_attn.py,
+  tests/test_mla_moe_model.py).  ``"gathered"`` and ``"fused"`` stay as
   explicit values: the parity reference, and the kernel in interpret mode
   on the CPU.
 * **Writes** are scatters at ``(table[pos // block_size], pos %
@@ -123,8 +133,8 @@ Pytree = Any
 # reference); 'fused' reads K/V straight from the block pool via the
 # Pallas paged-attention kernel and stops at each stream's true length
 # (ops.pallas_kernels.paged_attention — token-identical, pinned); 'auto'
-# is 'fused' where a TPU runs a per-head row the kernel takes, else
-# 'gathered' (resolve_attn_impl)
+# is 'fused' where a TPU runs a cache row the kernel takes (per-head K/V
+# that fills the lanes, the latent row), else 'gathered' (resolve_attn_impl)
 ATTN_IMPLS = ("auto", "gathered", "fused")
 
 # cumulative expert-load counters of a model that routes without drops
@@ -351,28 +361,44 @@ class PrefixIndex:
 def resolve_attn_impl(model: Transformer, attn_impl: str = "auto",
                       kv_quant: bool = False) -> str:
     """``'gathered'`` or ``'fused'`` for this model on this backend.  An
-    explicit value is kept (``fused`` refuses the latent row by name);
-    ``auto`` takes the kernel where a TPU runs it on shapes it was made
-    for: the cache row is per-head K and V, ``kv_heads * head_dim`` fills
-    whole 128-lane tiles (a pool page is then one lane-dense DMA), and
-    the pools are not int8 (that walk is one page a step and was never
-    timed on the chip).  No width rule: the kernel beat ``gathered`` at
-    every length timed (PERF.md section 6, PR 30)."""
+    explicit value is kept; ``auto`` takes the kernel where a TPU runs it
+    on shapes it was made for: the pools are not int8 (that walk is one
+    page a step and was never timed on the chip; the latent row has no int8
+    scheme at all), and the cache row is either per-head K and V whose
+    ``kv_heads * head_dim`` fills whole 128-lane tiles (a pool page is then
+    one lane-dense DMA), or latent attention's one row, which is stored
+    padded to whole lane tiles for the kernel (:func:`stored_rows`).  No
+    width rule: the kernel beat ``gathered`` at every length timed (PERF.md
+    section 6, PR 30 and PR 32)."""
     c = model.cfg
     if attn_impl not in ATTN_IMPLS:
         raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, "
                          f"got {attn_impl!r}")
-    latent = c.attention_kind == "mla"
-    if latent and attn_impl == "fused":
-        raise ValueError(
-            "attn_impl='fused' is the Pallas paged kernel over per-head K "
-            "and V pools; latent attention's cache row has no paged kernel "
-            "yet: use attn_impl='gathered'")
     if attn_impl != "auto":
         return attn_impl
-    kernel = (jax.default_backend() == "tpu" and not latent and not kv_quant
-              and (c.kv_heads * c.head_dim) % 128 == 0)
+    per_head = set(model.cache_row()) == {"k", "v"}
+    kernel = (jax.default_backend() == "tpu" and not kv_quant
+              and (not per_head or (c.kv_heads * c.head_dim) % 128 == 0))
     return "fused" if kernel else "gathered"
+
+
+def stored_rows(row: Dict[str, Tuple[int, ...]],
+                folded: bool) -> Dict[str, Tuple[int, ...]]:
+    """Pool name -> the trailing shape a token's row is STORED in.  Not
+    ``folded`` (the gathered path): the attention's own ``cache_row()``.
+    ``folded`` (the fused kernel's layout) is flat and lane-dense, the
+    shape a page is DMA'd in: per-head K and V with the heads folded into
+    the lanes (the same bytes a block row), a row that is its own value
+    (latent attention's) zero-padded up to whole 128-lane tiles, so that the
+    default layout needs no padding the compiler would transpose the pool
+    to avoid.  What leaves the server (export / import, the handoff
+    geometry) is ``cache_row()`` wide whatever is stored."""
+    if not folded:
+        return dict(row)
+    flat = {n: int(np.prod(r)) for n, r in row.items()}
+    if set(row) == {"k", "v"}:
+        return {n: (f,) for n, f in flat.items()}
+    return {n: (-(-f // 128) * 128,) for n, f in flat.items()}
 
 
 def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
@@ -380,29 +406,31 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
     """Per-layer paged pools, one per entry of the attention's cache row
     (``model.cache_row()``), each ``(num_blocks, block_size, *row)`` —
     :func:`models.generate.init_kv_cache` with the length axis split into
-    (block, offset).  ``folded`` (the fused kernel's layout) stores a
-    per-head row with its heads folded into the lanes, ``(num_blocks,
-    block_size, kv_heads * head_dim)``: the same bytes a block row, the
-    shape the kernel DMAs.  ``quant=True`` (per-head K and V only) stores
-    int8 codes plus one f32 scale per (block, offset, head), the identical
-    scheme the dense cache uses (scales are per position, so paging
-    cannot change the numbers)."""
+    (block, offset).  ``folded`` (the fused kernel's layout,
+    :func:`stored_rows`) stores a per-head row with its heads folded into
+    the lanes, ``(num_blocks, block_size, kv_heads * head_dim)``: the same
+    bytes a block row, the shape the kernel DMAs; and the latent row padded
+    with zero lanes to whole lane tiles, ``(num_blocks, block_size, 384)``
+    for a row of 320.  ``quant=True`` (per-head K and V only) stores int8
+    codes plus one f32 scale per (block, offset, head), the identical
+    scheme the dense cache uses (scales are per position, so paging cannot
+    change the numbers)."""
     c = model.cfg
     row = model.cache_row()
     lead = (num_blocks, block_size)
-    fold = lambda r: (int(np.prod(r)),) if folded else r      # noqa: E731
+    stored = stored_rows(row, folded)
     if quant:
         if set(row) != {"k", "v"}:
             raise ValueError(
                 "kv_quant stores int8 codes of per-head K and V; the cache "
                 f"row {sorted(row)} of this attention has no such scheme yet")
-        return [{**{n: jnp.zeros(lead + fold(r), jnp.int8)
-                    for n, r in row.items()},
+        return [{**{n: jnp.zeros(lead + r, jnp.int8)
+                    for n, r in stored.items()},
                  **{f"{n}_scale": jnp.ones(lead + r[:-1], jnp.float32)
                     for n, r in row.items()}}
                 for _ in range(c.n_layers)]
-    return [{n: jnp.zeros(lead + fold(r), c.compute_dtype)
-             for n, r in row.items()}
+    return [{n: jnp.zeros(lead + r, c.compute_dtype)
+             for n, r in stored.items()}
             for _ in range(c.n_layers)]
 
 
@@ -418,10 +446,13 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     another pool row).  Cached per (model, geometry, sampling,
     attn_impl) so several servers compile once.  ``attn_impl`` is
     resolved here, once (:func:`resolve_attn_impl`): ``'fused'`` swaps the
-    gathered attention for the Pallas paged kernel over pools whose heads
-    are folded into the lanes (``init_paged_kv(folded=True)``); everything
-    else (scatter coordinates, sampling, bookkeeping) is shared, which is
-    what makes gathered-vs-fused an attention-only A/B."""
+    gathered attention for the Pallas paged kernel over pools stored in the
+    layout it reads (``init_paged_kv(folded=True)``): in both programs of a
+    per-head row, in the decode program of the latent row (its prefill
+    chunk gathers its one stream's rows from the same pools and keeps the
+    expanded form); everything else (scatter coordinates, sampling,
+    bookkeeping) is shared, which is what makes gathered-vs-fused an
+    attention-only A/B."""
     bs, mb = int(block_size), int(max_blocks)
     t_cap = bs * mb
     c = model.cfg
@@ -562,14 +593,27 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     def latent_attention_half(mods, layer_params, pool, tables, starts, x,
                               valid, lengths, decode):
         """``x + LatentAttn(norm(x))`` with the latent row: the chunk's rows
-        ``[c_kv | k_rope]`` (normed, rotated) scattered into the one pool,
-        each stream's rows gathered through its table, and attended in the
-        expanded form (prefill: rows expanded through ``W_kvb``) or the
-        absorbed form (decode: the cache is never expanded).  ``lengths``
-        bounds the keys a prefill chunk walks."""
+        ``[c_kv | k_rope]`` (normed, rotated) scattered into the one pool and
+        attended in the expanded form (prefill: the stream's rows gathered
+        through its table and expanded through ``W_kvb``; ``lengths`` bounds
+        the keys a chunk walks) or the absorbed form (decode: the cache is
+        never expanded).  Under ``fused`` the decode reads the pool in place:
+        the paged kernel's shared-row mode walks each stream's pages up to
+        its length between the two ``mla_absorb`` products, over a pool whose
+        rows are stored padded to whole lane tiles (zero lanes, which a
+        zero-padded query adds nothing for); nothing of width ``T_cap`` is
+        gathered or reduced over."""
         attn, ap = mods["attn"], layer_params["attn"]
         b, w, _ = x.shape
         positions = starts[:, None] + jnp.arange(w)[None, :]      # (B, W)
+        lanes = pool["latent"].shape[-1]        # as stored
+        extra = lanes - attn.row_dim            # zero lanes of a stored row
+
+        def widen(a):
+            """``a``'s last axis from the row's width to the stored one."""
+            return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, extra)]) \
+                if extra else a
+
         with jax.named_scope("attn_proj"):
             h = mods["ln1"].apply(layer_params["ln1"], x)
             q_nope, q_rope, rows = attn.project(ap, h, positions)
@@ -577,19 +621,43 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             with jax.named_scope("paged_scatter"):
                 blk, off = scatter_coords(tables, positions, valid)
                 new_lp = pool["latent"].at[blk, off].set(
-                    rows.astype(pool["latent"].dtype))
-            with jax.named_scope("paged_gather"):
-                got = new_lp[tables].reshape(b, t_cap, attn.row_dim)
-            with jax.named_scope("attn_core"):
-                mask = (jnp.arange(t_cap)[None, None, :]
-                        <= positions[:, :, None])           # (B, W, T_cap)
-                if decode:
-                    out = attn.attend_absorbed(ap, q_nope, q_rope, got, mask)
-                else:
-                    # a chunk sees no key past its own last position: the
-                    # expanded form walks the keys that exist
-                    out = attn.attend_expanded(ap, q_nope, q_rope, got, mask,
-                                               n_keys=lengths.max())
+                    widen(rows.astype(pool["latent"].dtype)))
+            if decode and attn_impl == "fused":
+                pages, cols = paged_tiles(bs, lanes, w, c.n_heads, mb)
+                ledger_lib.note("attention", {
+                    "impl": "paged", "pages": pages, "tile_cols": cols,
+                    "block_size": bs})
+                cdt = attn.compute_dtype
+                w_k, w_v = attn.absorb_weights(ap)
+                q_lat = attn.absorb_query(w_k, q_nope)
+                with jax.named_scope("attn_core"), \
+                        jax.named_scope("paged_attention_fused"):
+                    q_row = widen(jnp.concatenate(
+                        [q_lat.astype(cdt), q_rope.astype(cdt)], axis=-1))
+                    u = paged_attention(
+                        q_row, new_lp, None, tables, lengths, starts,
+                        v_lanes=attn.kv_lora_rank, scale=attn.softmax_scale,
+                        pages=pages, tile_cols=cols)
+                out = attn.absorb_value(w_v, u)
+            else:
+                ledger_lib.note("attention", {"impl": "gathered",
+                                              "keys": t_cap})
+                with jax.named_scope("paged_gather"):
+                    got = new_lp[tables].reshape(b, t_cap, lanes)
+                    if extra:
+                        got = got[..., :attn.row_dim]
+                with jax.named_scope("attn_core"):
+                    mask = (jnp.arange(t_cap)[None, None, :]
+                            <= positions[:, :, None])       # (B, W, T_cap)
+                    if decode:
+                        out = attn.attend_absorbed(ap, q_nope, q_rope, got,
+                                                   mask)
+                    else:
+                        # a chunk sees no key past its own last position:
+                        # the expanded form walks the keys that exist
+                        out = attn.attend_expanded(ap, q_nope, q_rope, got,
+                                                   mask,
+                                                   n_keys=lengths.max())
         with jax.named_scope("attn_proj"):
             x = x + attn.output(ap, out).astype(x.dtype)
         return x, {"latent": new_lp}
@@ -1356,12 +1424,17 @@ class PagedDecodeServer:
                 "prefill->decode boundary only")
         n_copy = self.blocks_for(p)
         idx = jnp.asarray(np.asarray(st.blocks[:n_copy], np.int64))
+        wide = {n: int(np.prod(r))
+                for n, r in self.model.cache_row().items()}
         layers = []
         for pool in self.pools:
             rec = {}
             for name, arr in pool.items():
-                rows = np.ascontiguousarray(
-                    np.asarray(jax.device_get(arr[idx])))
+                # a row stored padded to whole lane tiles travels as the
+                # attention's own row (a folded per-head row is the same
+                # bytes as it stands)
+                rows = arr[idx][..., :wide.get(name)]
+                rows = np.ascontiguousarray(np.asarray(jax.device_get(rows)))
                 rec[name] = base64.b64encode(rows.tobytes()).decode("ascii")
             layers.append(rec)
         first_token = int(jax.device_get(self.tokens[slot, p]))
@@ -1425,11 +1498,18 @@ class PagedDecodeServer:
             out = {}
             for name, b64 in rec.items():
                 # a block row of this pool, whatever the attention's row is
-                # (a scale pool's is the row without its last axis)
-                out[name] = np.frombuffer(
+                # (a scale pool's is the row without its last axis); a pool
+                # stored wider than the row that travels (padded to whole
+                # lane tiles) gets its zero lanes back
+                stored = pool[name].shape[1:]
+                got = np.frombuffer(
                     base64.b64decode(b64),
                     dtype=np.dtype(pool[name].dtype)).reshape(
-                        (n_copy,) + pool[name].shape[1:])
+                        n_copy, self.block_size, -1)
+                short = int(np.prod(stored[1:])) - got.shape[-1]
+                if short:
+                    got = np.pad(got, [(0, 0), (0, 0), (0, short)])
+                out[name] = got.reshape((n_copy,) + stored)
             decoded.append(out)
         for i in range(n_copy):
             rows = [{name: jnp.asarray(lay[name][i])

@@ -192,6 +192,22 @@ def test_paged_attention(spec, width, int8_kv):
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
+def test_paged_attention_shared_row(spec):
+    """The kernel's shared-row mode at ``ms4-119b-ep4-serve-docqa``'s decode
+    shapes: 32 streams of 32 query rows against one row stored 384 lanes
+    wide, the value its first 256, tables of 544 blocks (68 KB of scalar
+    prefetch) over a pool of 17409."""
+    args = [spec((32, 1, 32, 384), BF16), spec((17409, 16, 384), BF16),
+            spec((32, 544), jnp.int32), spec((32,), jnp.int32),
+            spec((32,), jnp.int32)]
+
+    def fn(q, pool, tables, lens, starts):
+        return paged_attention(q, pool, None, tables, lens, starts,
+                               v_lanes=256, scale=0.1, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
 @pytest.fixture(scope="module")
 def serve_programs(spec):
     """The gathered paged server's jitted programs over a 2-layer model
@@ -315,8 +331,10 @@ def latent_programs(spec):
     routed block at its published widths (d 4096, 32 heads, ranks 1024 / 256,
     128 experts of width 2048 of which 32 are held, top 4), abstract params
     and pools on the described chip, with the serving cell's geometry (32
-    slots, 8704 positions).  The grouped product is the Pallas kernel, as
-    on the chip: ``auto`` asks the default backend, which is the CPU here."""
+    slots, 8704 positions).  The kernels are Pallas, as on the chip:
+    ``auto`` asks the default backend, which is the CPU here."""
+    from unittest import mock
+
     from neural_networks_parallel_training_with_mpi_tpu.ops.rope import (
         RopeScaling,
     )
@@ -334,39 +352,65 @@ def latent_programs(spec):
     abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
         lambda x: spec(x.shape, x.dtype), tree)
     params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
-    pools = abstract(jax.eval_shape(
-        lambda: paged_kv.init_paged_kv(model, 17409, 16)))
+    # as ``attn_impl="auto"`` resolves them on a TPU: the paged kernel's
+    # shared-row mode in the decode program, over pools stored 384 lanes wide
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert paged_kv.resolve_attn_impl(model, "auto") == "fused"
+        pools = abstract(jax.eval_shape(
+            lambda: paged_kv.init_paged_kv(model, 17409, 16, folded=True)))
+        prefill, step, _, _ = paged_kv._paged_programs(
+            model, 16, 544, 0.0, 0, 1.0, False, "auto")
     assert {n: p.shape for n, p in pools[0].items()} \
-        == {"latent": (17409, 16, 320)}
-    prefill, step, _, _ = paged_kv._paged_programs(
-        model, 16, 544, 0.0, 0, 1.0, False, "gathered")
+        == {"latent": (17409, 16, 384)}
     stats = {"experts": spec((len(paged_kv.EXPERT_COUNTERS),), jnp.int32)}
     return params, pools, stats, prefill, step
 
 
 @pytest.fixture
 def kernels_compiled(monkeypatch):
-    """Lower the Pallas grouped matmul for the chip, not interpreted."""
+    """Lower the Pallas kernels (the grouped matmul, the paged walk) for
+    the chip, not interpreted."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+def _latent_pool_moved(compiled_text: str) -> list:
+    """Operations that copy, transpose or gather something pool-shaped."""
+    import re
+
+    shaped = r"\[17409,(?:16,(?:320|384)|(?:320|384),16)\]"
+    return [line.strip()[:160] for line in compiled_text.splitlines()
+            if re.search(r"= \S*" + shaped
+                         + r"\S* (?:copy|transpose|gather|copy-start)\(",
+                         line)]
+
+
 def test_latent_routed_decode_step(spec, latent_programs, kernels_compiled):
+    """The decode tick reads the latent pool in place: the paged kernel
+    between the two ``mla_absorb`` products, no scope ``paged_gather``, and
+    nothing pool-shaped copied, transposed or gathered (the 320-lane pool
+    was transposed whole before and after its scatter in every layer: PERF.md
+    section 6, PR 32)."""
     params, pools, stats, _, step = latent_programs
-    text = step.lower(
+    lowered = step.lower(
         params, pools, stats, spec((32, 8704), jnp.int32),
         spec((32, 544), jnp.int32), spec((32,), jnp.int32),
-        spec((32,), jnp.bool_), spec((2,), jnp.uint32)).compile().as_text()
+        spec((32,), jnp.bool_), spec((2,), jnp.uint32))
+    assert "paged_attention" in lowered.as_text()
+    text = lowered.compile().as_text()
     for scope in ("mla_absorb", "moe_route", "moe_experts", "moe_shared",
-                  "moe_combine", "paged_gather", "paged_scatter"):
+                  "moe_combine", "paged_scatter", "paged_attention_fused"):
         assert scope in text, scope
+    assert "paged_gather" not in text
     assert "gmm" in text
+    assert not _latent_pool_moved(text)
 
 
 def test_latent_routed_prefill_chunk(spec, latent_programs, kernels_compiled):
     """A 1024-token chunk: the expanded form walks the keys in a loop whose
     trip count is the chunk's own length in blocks, so the (32, 1024, 8704)
     float32 scores are never whole (3.7 GB of temporaries before, 0.34
-    after, by the compiler's count at 6 layers)."""
+    after, by the compiler's count at 6 layers); its one stream's rows are
+    gathered from the pool as stored, and the pool itself is not copied."""
     params, pools, stats, prefill, _ = latent_programs
     compiled = prefill.lower(
         params, pools, stats, spec((1, 544), jnp.int32),
@@ -374,4 +418,5 @@ def test_latent_routed_prefill_chunk(spec, latent_programs, kernels_compiled):
         spec((), jnp.int32)).compile()
     text = compiled.as_text()
     assert "while" in text and "moe_experts" in text
+    assert "paged_gather" in text and not _latent_pool_moved(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -11,6 +11,7 @@ moves a combine weight by about 4e-3, a dropped assignment takes a whole
 expert's output (about 0.1 of the scale) away, and both are shown to fail it.
 """
 
+import base64
 import sys
 from pathlib import Path
 
@@ -328,16 +329,20 @@ def serve(net, params, prompts, n_new, **cfg):
         sched.close()
 
 
-def test_prefill_in_chunks_then_decode_through_the_latent_cache(toy):
+@pytest.mark.parametrize("impl", ["gathered", "fused"])
+def test_prefill_in_chunks_then_decode_through_the_latent_cache(toy, impl):
     """Chunked prefill (expanded) then decode (absorbed), three streams of
     different lengths side by side, against the reference's full forward:
     the prefill program's logits to TIGHT, and every served token the
     reference's own first choice (its logit gap to the reference's best is
-    0 in float32)."""
+    0 in float32).  Under ``fused`` the decode is the paged kernel's
+    shared-row walk over the pool stored 128 lanes wide (interpreted here),
+    and the chunk gathers its stream's rows from that pool: the same
+    tokens, so ``fused`` equals ``gathered`` token for token."""
     net, params, outer, layers = toy
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 96, size=n).tolist() for n in (5, 19, 30)]
-    served, counters = serve(net, params, prompts, 20)
+    served, counters = serve(net, params, prompts, 20, attn_impl=impl)
     for prompt, toks in zip(prompts, served):
         assert toks[:len(prompt)] == prompt and len(toks) == len(prompt) + 20
         logits = reference_logits(outer, layers, jnp.asarray([toks]))[0]
@@ -352,7 +357,7 @@ def test_prefill_in_chunks_then_decode_through_the_latent_cache(toy):
         "prefill_expert_assignments"] > 0
     # the prefill program's own logits, one chunk of a 30-token prompt
     srv = PagedDecodeServer(net, params, slots=2, num_blocks=17,
-                            block_size=8, max_len=64)
+                            block_size=8, max_len=64, attn_impl=impl)
     rid = srv.try_admit(prompts[2], 4)
     slot = srv._slot_of[rid]
     logits, _pools, _stats = srv._prefill_fn(
@@ -372,12 +377,17 @@ def dense_toy():
     return net, net.init(jax.random.PRNGKey(0))
 
 
-@pytest.fixture(scope="module", params=["per_head", "latent"])
+@pytest.fixture(scope="module", params=["per_head", "latent",
+                                        "latent-fused"])
 def row_kind(request, toy):
-    """The same cache tests over both answers to ``cache_row()``."""
-    if request.param == "latent":
-        return request.param, toy[0], toy[1]
-    return (request.param, *dense_toy())
+    """The same cache tests over both answers to ``cache_row()``, the
+    latent row under both implementations (``fused`` stores it padded to
+    128 lanes; the per-head row under the kernel is tests/test_paged_attn
+    .py's): ``(kind, net, params, attn_impl)``."""
+    kind, _, impl = request.param.partition("-")
+    if kind == "latent":
+        return kind, toy[0], toy[1], impl or "gathered"
+    return (kind, *dense_toy(), "gathered")
 
 
 def drain(srv, rid, width=4):
@@ -389,12 +399,14 @@ def drain(srv, rid, width=4):
 
 
 def test_pools_follow_the_attentions_row(row_kind):
-    kind, net, params = row_kind
+    kind, net, params, impl = row_kind
     srv = PagedDecodeServer(net, params, slots=2, num_blocks=9, block_size=8,
-                            max_len=64)
+                            max_len=64, attn_impl=impl)
     shapes = {n: p.shape for n, p in srv.pools[0].items()}
     if kind == "latent":
-        assert shapes == {"latent": (9, 8, 24)}
+        # stored in whole lane tiles under the kernel; what leaves the
+        # server is the attention's own row either way
+        assert shapes == {"latent": (9, 8, 128 if impl == "fused" else 24)}
         assert srv._handoff_geometry()["row"] == {"latent": [24]}
     else:
         assert shapes == {"k": (9, 8, 2, 12), "v": (9, 8, 2, 12)}
@@ -405,17 +417,17 @@ def test_copy_on_write_with_either_row(row_kind):
     """A warm admission shares the prompt's blocks and forks the partial
     tail block before writing into it; tokens equal the cold run's and the
     run's without the cache."""
-    _kind, net, params = row_kind
+    _kind, net, params, impl = row_kind
     prompt, n = list(range(1, 21)), 8          # bs 8: 2 full blocks + 4
     on = PagedDecodeServer(net, params, slots=4, num_blocks=40, block_size=8,
-                           max_len=64, prefix_cache=True)
+                           max_len=64, prefix_cache=True, attn_impl=impl)
     cold = drain(on, on.try_admit(prompt, n))
     warm_rid = on.try_admit(prompt, n)
     assert on.prefill_remaining(warm_rid) == 1
     warm = drain(on, warm_rid)
     assert on.cow_forks == 1
     off = PagedDecodeServer(net, params, slots=4, num_blocks=40, block_size=8,
-                            max_len=64)
+                            max_len=64, attn_impl=impl)
     assert cold == warm == drain(off, off.try_admit(prompt, n))
     on.allocator.assert_drained()
 
@@ -423,10 +435,11 @@ def test_copy_on_write_with_either_row(row_kind):
 def test_export_and_import_with_either_row(row_kind):
     """A prefilled stream's block rows travel as bytes and decode on the
     importing server to the tokens of an undivided run."""
-    kind, net, params = row_kind
+    kind, net, params, impl = row_kind
     prompt, n = list(range(3, 24)), 9
     make = lambda: PagedDecodeServer(                          # noqa: E731
-        net, params, slots=2, num_blocks=17, block_size=8, max_len=64)
+        net, params, slots=2, num_blocks=17, block_size=8, max_len=64,
+        attn_impl=impl)
     a, b, c = make(), make(), make()
     whole = drain(c, c.try_admit(prompt, n))
     rid = a.try_admit(prompt, n)
@@ -435,6 +448,10 @@ def test_export_and_import_with_either_row(row_kind):
     payload = a.export_stream(rid)
     assert payload["n_blocks"] == 3
     assert set(payload["layers"][0]) == set(net.cache_row())
+    # the attention's own row travels, whatever the pools store
+    wide = sum(int(np.prod(r)) for r in net.cache_row().values())
+    assert sum(len(base64.b64decode(v)) for v in payload["layers"][0]
+               .values()) == 3 * 8 * wide * 4
     rid_b = b.import_stream(payload)
     while not b.done(rid_b):
         b.step()
@@ -450,10 +467,10 @@ def test_export_and_import_with_either_row(row_kind):
 def test_prefill_to_decode_handoff_with_either_row(row_kind):
     """A prefill-role scheduler exports at the prefill/decode boundary and a
     decode-role scheduler takes the stream on: the unified run's tokens."""
-    _kind, net, params = row_kind
+    _kind, net, params, impl = row_kind
     prompt, n = list(range(5, 30)), 7
     cfg = dict(slots=2, block_size=8, num_blocks=17, max_len=64,
-               prefill_chunk=8)
+               prefill_chunk=8, attn_impl=impl)
     want = serve(net, params, [prompt], n, **cfg)[0][0]
     pre = Scheduler(net, params, ServeConfig(role="prefill", **cfg))
     dec = Scheduler(net, params, ServeConfig(role="decode", **cfg))
@@ -474,16 +491,129 @@ def test_prefill_to_decode_handoff_with_either_row(row_kind):
         dec.close()
 
 
-# ---- what cannot run it yet says so ----------------------------------------
+# ---- the kernel over the latent row; what cannot run it yet says so ---------
 
-def test_latent_rows_refuse_the_fused_kernel_and_int8(toy):
+def test_latent_rows_run_the_fused_kernel_and_refuse_int8(toy):
+    """``fused`` serves the latent row (it refused it by name until PR 32):
+    staggered admissions, a prompt prefilled in chunks that straddle block
+    borders, an eviction in mid-stream and its re-admission give the
+    ``gathered`` server's tokens; the pool drains.  int8 pools still have
+    no scheme for the row, under either implementation."""
     net, params = toy[0], toy[1]
-    with pytest.raises(ValueError, match="no paged kernel.*gathered"):
-        PagedDecodeServer(net, params, slots=2, num_blocks=9, block_size=8,
-                          max_len=64, attn_impl="fused")
-    with pytest.raises(ValueError, match="int8 codes of per-head K and V"):
-        PagedDecodeServer(net, params, slots=2, num_blocks=9, block_size=8,
-                          max_len=64, kv_quant=True)
+
+    def scenario(impl):
+        srv = PagedDecodeServer(net, params, slots=3, num_blocks=33,
+                                block_size=8, max_len=64, attn_impl=impl)
+        a = srv.try_admit(list(range(1, 12)), 12)
+        while not srv.prefill_step(a, 4):
+            pass
+        srv.step(); srv.step()
+        b = srv.try_admit([7, 8], 9)
+        while not srv.prefill_step(b, 16):
+            pass
+        srv.step(); srv.step(); srv.step()
+        prompt, max_new = srv.evict(b)
+        c = srv.try_admit(prompt, max_new)
+        out = [drain(srv, c, 16)]
+        while not srv.done(a):
+            srv.step()
+        out.append(srv.result(a))
+        srv.allocator.assert_drained()
+        return out
+
+    assert scenario("fused") == scenario("gathered")
+    for impl in ("auto", "fused"):
+        with pytest.raises(ValueError, match="int8 codes of per-head K and "
+                                             "V"):
+            PagedDecodeServer(net, params, slots=2, num_blocks=9,
+                              block_size=8, max_len=64, kv_quant=True,
+                              attn_impl=impl)
+
+
+def test_garbage_past_a_streams_length_changes_nothing_under_fused(toy):
+    """Non-zero garbage planted in every pool position no stream holds (the
+    sink, free blocks, the tail of each stream's last page) before and
+    between decode steps: the tokens of the untouched run."""
+    net, params = toy[0], toy[1]
+
+    def run(dirty):
+        srv = PagedDecodeServer(net, params, slots=2, num_blocks=17,
+                                block_size=8, max_len=64, attn_impl="fused")
+        rid = srv.try_admit(list(range(2, 13)), 10)      # 11: 1 block + 3
+        while not srv.prefill_step(rid, 8):
+            pass
+        while not srv.done(rid):
+            if dirty:
+                held = np.zeros((17, 8), bool)
+                n = int(srv._pos_host[srv._slot_of[rid]])   # rows written
+                blocks = srv._streams[rid].blocks
+                for t in range(n):
+                    held[blocks[t // 8], t % 8] = True
+                keep = jnp.asarray(held)[..., None]
+                srv.pools = [{"latent": jnp.where(keep, p["latent"], 1e4)}
+                             for p in srv.pools]
+            srv.step()
+        return srv.result(rid)
+
+    assert run(True) == run(False)
+
+
+def test_latent_table_churn_and_growth_never_recompile_under_fused(toy):
+    """Tables and lengths are traced operands of the latent decode program
+    too: admission, growth over a block border, eviction and re-admission
+    re-run one compiled step; the compile ledger's event of that step names
+    the kernel with its tiling, the prefill buckets say what they run, and
+    the scheduler's records read ``walked_keys_share`` below 1."""
+    import json
+    import os
+    import tempfile
+
+    from neural_networks_parallel_training_with_mpi_tpu.utils import (
+        compile_ledger,
+    )
+
+    net, params = toy[0], toy[1]
+    led = compile_ledger.Ledger(None)
+    compile_ledger.install(led)
+    try:
+        # a geometry no other test of this file uses: the programs are new
+        srv = PagedDecodeServer(net, params, slots=3, num_blocks=25,
+                                block_size=8, max_len=56, attn_impl="fused")
+        a = srv.try_admit([1] * 12, 12)
+        while not srv.prefill_step(a, 16):
+            pass
+        for _ in range(4):
+            srv.step()
+        n_events = len(led.events)
+        b = srv.try_admit([9] * 11, 8)
+        while not srv.prefill_step(b, 16):
+            pass
+        srv.step()
+        srv.evict(b)
+        c = srv.try_admit([3] * 9, 6)
+        while not srv.prefill_step(c, 16):
+            pass
+        while not (srv.done(a) and srv.done(c)):
+            srv.step()
+        srv.allocator.assert_drained()
+        assert len(led.events) == n_events
+    finally:
+        compile_ledger.install(None)
+    decode = led.events_for("serve_decode[bs8x7/fused]")
+    assert len(decode) == 1 and decode[0]["attention"] == {
+        "impl": "paged", "pages": 7, "tile_cols": 1, "block_size": 8}
+    prefill = led.events_for("serve_prefill[bs8x7/fused]")
+    assert prefill and all(e["attention"] == {"impl": "gathered", "keys": 56}
+                           for e in prefill)
+    with tempfile.TemporaryDirectory() as tdir:
+        served, _ = serve(net, params, [list(range(1, 20))], 12, max_len=64,
+                          attn_impl="fused", telemetry_dir=tdir,
+                          metrics_every=1)
+        finals = [r for r in map(json.loads, open(os.path.join(
+            tdir, "metrics.jsonl"))) if r.get("kind") == "serve"
+            and r.get("final")]
+    assert len(served[0]) == 19 + 12
+    assert 0 < finals[-1]["walked_keys_share"] < 1
 
 
 # ---- what ``attn_impl="auto"`` resolves to ----------------------------------
@@ -500,10 +630,11 @@ def _lane_dense_toy():
     ("tpu", "lane_dense", False, "fused"),
     ("tpu", "lane_dense", True, "gathered"),     # the int8 walk was not timed
     ("tpu", "narrow", False, "gathered"),        # 2 x 12 lanes: not a page DMA
-    ("tpu", "latent", False, "gathered"),
+    ("tpu", "latent", False, "fused"),           # stored in whole lane tiles
+    ("tpu", "latent", True, "gathered"),         # (and then refused: no int8)
     ("cpu", "latent", False, "gathered"),
 ], ids=["cpu", "tpu-per-head", "tpu-int8", "tpu-narrow-row", "tpu-latent",
-        "cpu-latent"])
+        "tpu-latent-int8", "cpu-latent"])
 def test_auto_resolves_from_backend_row_and_shapes(toy, monkeypatch, backend,
                                                    model, kv_quant, want):
     """``auto`` looks at the backend, the cache row and the row's width,
@@ -515,8 +646,7 @@ def test_auto_resolves_from_backend_row_and_shapes(toy, monkeypatch, backend,
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert paged_kv.resolve_attn_impl(net, "auto", kv_quant) == want
     assert paged_kv.resolve_attn_impl(net, "gathered", kv_quant) == "gathered"
-    if model != "latent":
-        assert paged_kv.resolve_attn_impl(net, "fused", kv_quant) == "fused"
+    assert paged_kv.resolve_attn_impl(net, "fused", kv_quant) == "fused"
     with pytest.raises(ValueError, match="attn_impl must be one of"):
         paged_kv.resolve_attn_impl(net, "flash", kv_quant)
 
@@ -539,11 +669,13 @@ def test_auto_is_the_default_and_the_server_says_what_runs(toy):
                              block_size=8, max_len=64).attn_impl == "gathered"
 
 
-def test_fused_exports_to_a_gathered_importer_and_back():
-    """A block row's bytes are the same folded or not: a stream prefilled
-    under the kernel decodes on a gathered server to the tokens of an
-    undivided run, and the other way round."""
-    net, params = dense_toy()
+@pytest.mark.parametrize("row", ["per_head", "latent"])
+def test_fused_exports_to_a_gathered_importer_and_back(toy, row):
+    """A block row's bytes are the same folded or not, and the latent row
+    travels 24 lanes wide though the kernel's pool stores 128: a stream
+    prefilled under the kernel decodes on a gathered server to the tokens
+    of an undivided run, and the other way round."""
+    net, params = dense_toy() if row == "per_head" else toy[:2]
     prompt, n = list(range(3, 24)), 9
     make = lambda impl: PagedDecodeServer(                      # noqa: E731
         net, params, slots=2, num_blocks=17, block_size=8, max_len=64,
@@ -555,7 +687,9 @@ def test_fused_exports_to_a_gathered_importer_and_back():
         rid = a.try_admit(prompt, n)
         while not a.prefill_step(rid, 8):
             pass
-        rid_b = b.import_stream(a.export_stream(rid))
+        payload = a.export_stream(rid)
+        assert payload["geom"] == b._handoff_geometry()
+        rid_b = b.import_stream(payload)
         while not b.done(rid_b):
             b.step()
         assert b.result(rid_b) == whole, (src, dst)

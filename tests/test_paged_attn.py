@@ -326,6 +326,126 @@ def test_kernel_validates_shapes():
 
 
 # ---------------------------------------------------------------------------
+# the shared-row mode: one pool row is key and value (latent attention's
+# absorbed decode), against ``attend_absorbed`` over gathered rows
+# ---------------------------------------------------------------------------
+
+def _latent_attn():
+    from neural_networks_parallel_training_with_mpi_tpu.models.mla import (
+        LatentAttention,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.ops.rope import (
+        RopeScaling,
+    )
+
+    return LatentAttention(
+        d_model=48, n_heads=4, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=12,
+        rope_scaling=RopeScaling(4.0, 16, 4.0, 1.0, 1.0, 1.0, 0.1))
+
+
+# (lens, block_size, max_blocks, pages): lengths that end inside a page and
+# on its border, idle lanes between live ones, the table's full width, and
+# one page a step
+_SHARED_ROW = {
+    "ragged-inside-pages": ([13, 29, 35, 24], 4, 10, 3),
+    "idle-between": ([0, 9, 0, 17, 0], 4, 6, 2),
+    "table-full-width": ([40, 40, 37], 4, 10, 4),
+    "one-page-a-step": ([11, 6, 22], 8, 3, 1),
+    "less-than-a-page": ([3, 1], 8, 4, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHARED_ROW))
+def test_shared_row_mode_is_the_absorbed_form_over_the_pool(case):
+    """``v_pool=None``: the kernel between the two ``mla_absorb`` products
+    against ``LatentAttention.attend_absorbed`` over ``pool[tables]``, the
+    row stored padded to 128 lanes.  Every position no stream holds (the
+    sink, the tail of a stream's last page, whole pages past its length
+    that its table still names) holds huge non-zero values: a key past a
+    length that reached a softmax, or a value row past the last fetched
+    key, would show.  A lane of length 0 walks nothing and reads 0."""
+    lens, bs, mb, pages = _SHARED_ROW[case]
+    attn = _latent_attn()
+    ap = attn.init(prng.init_key(3))
+    rng = np.random.default_rng(len(case))
+    lens = np.asarray(lens, np.int32)
+    b, t_cap, lanes = len(lens), bs * mb, 128
+    nb = 1 + b * mb
+    pool = np.full((nb, bs, lanes), 1e4, np.float32)
+    tables = np.arange(1, nb, dtype=np.int32).reshape(b, mb)
+    tables = rng.permutation(tables.reshape(-1)).reshape(b, mb)
+    rows = rng.normal(size=(b, t_cap, attn.row_dim)).astype(np.float32)
+    for i, ln in enumerate(lens):
+        for t in range(int(ln)):
+            pool[tables[i, t // bs], t % bs, :attn.row_dim] = rows[i, t]
+            pool[tables[i, t // bs], t % bs, attn.row_dim:] = 0.0
+    q_nope = jnp.asarray(rng.normal(size=(b, 1, 4, 8)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(b, 1, 4, 8)), jnp.float32)
+    # the reference: the gathered rows (garbage and all) under the mask
+    got_rows = jnp.asarray(pool)[jnp.asarray(tables)].reshape(
+        b, t_cap, lanes)[..., :attn.row_dim]
+    pos = jnp.asarray(lens - 1)[:, None]
+    mask = jnp.arange(t_cap)[None, None, :] <= pos[:, :, None]
+    want = np.asarray(attn.attend_absorbed(ap, q_nope, q_rope, got_rows,
+                                           mask))
+    w_k, w_v = attn.absorb_weights(ap)
+    q_row = jnp.pad(jnp.concatenate(
+        [attn.absorb_query(w_k, q_nope), q_rope], axis=-1),
+        [(0, 0)] * 3 + [(0, lanes - attn.row_dim)])
+    u = paged_attention(q_row, jnp.asarray(pool), None, jnp.asarray(tables),
+                        jnp.asarray(lens), jnp.asarray(lens - 1),
+                        v_lanes=attn.kv_lora_rank, scale=attn.softmax_scale,
+                        pages=pages)
+    assert u.shape == (b, 1, 4, attn.kv_lora_rank)
+    got = np.asarray(attn.absorb_value(w_v, u))
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert np.isfinite(got).all() and np.all(got[~live] == 0.0)
+
+
+def test_shared_row_mode_feeds_the_mxu_in_the_pools_type_and_validates():
+    """bf16 rows: one fetch a page (no value pool among the operands), the
+    two products of a step take bf16 operands and accumulate in f32; the
+    mode needs ``v_lanes``, the query as wide as the row, and no scales."""
+    import jax
+
+    pool = jnp.zeros((6, 8, 128), jnp.bfloat16)
+    q = jnp.zeros((2, 1, 4, 128), jnp.bfloat16)
+    tables = jnp.zeros((2, 3), jnp.int32)
+    lens = jnp.ones((2,), jnp.int32)
+    fn = lambda q_, p_: paged_attention(                        # noqa: E731
+        q_, p_, None, tables, lens, lens - 1, v_lanes=16, scale=0.3)
+    found, pools_in = [], []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                pools_in.append(sum(v.aval.shape == pool.shape
+                                    for v in eqn.invars))
+            kernel = inside or eqn.primitive.name == "pallas_call"
+            if inside and eqn.primitive.name == "dot_general":
+                found.append((tuple(v.aval.dtype for v in eqn.invars),
+                              eqn.params["preferred_element_type"]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, kernel)
+
+    walk(jax.make_jaxpr(fn)(q, pool).jaxpr, False)
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    assert pools_in == [1]
+    assert len(found) == 2 * 2          # scores and values, two loops
+    assert {d for d, _ in found} == {(bf16, bf16)}
+    assert {acc for _, acc in found} == {f32}
+    with pytest.raises(ValueError, match="needs v_lanes"):
+        paged_attention(q, pool, None, tables, lens, lens - 1)
+    with pytest.raises(ValueError, match="must be the pool row's"):
+        paged_attention(q[..., :64], pool, None, tables, lens, lens - 1,
+                        v_lanes=16)
+    with pytest.raises(ValueError, match="shared-row mode"):
+        paged_attention(q, pool, pool, tables, lens, lens - 1, v_lanes=16)
+
+
+# ---------------------------------------------------------------------------
 # fused == gathered through the serving surface (the token contract)
 # ---------------------------------------------------------------------------
 
@@ -488,6 +608,52 @@ def test_donation_audit_fused_decode_program():
     donated = len(jax.tree_util.tree_leaves(srv.pools)) + 2  # + tokens, pos
     assert rep["n_aliased"] == donated, rep
     assert rep["unaliased_donors"] == 0, rep
+
+
+# the per-head serving programs' lowered text (StableHLO, no locations; the
+# kernel interpreted, so its whole body is in the text) at ``starcoder2-3b``'s
+# shapes and 2 layers, as commit 50eb37a (PR 31) lowered them: ISSUE 32 gave
+# the kernel a second mode beside this one and asked that this one not move
+# (on the chip the programs' compile-cache keys then stay).  A PR that means
+# to change the per-head path re-pins these two.
+_PER_HEAD_TEXT_SHA256 = {
+    "decode": "0faf555c209d3d7b064b0fbfbb2e7604dddb5cd969cc9ed8fe388380f5de2546",
+    "prefill": "cabc201edc343b73a8d541cc1469f5ad52531108661d275710b300207e1671af",
+}
+
+
+def test_per_head_programs_lower_to_the_parents_text():
+    import hashlib
+
+    import jax
+
+    from neural_networks_parallel_training_with_mpi_tpu.serve import paged_kv
+
+    model = Transformer(TransformerConfig(
+        vocab_size=49152, max_seq_len=16384, n_layers=2, d_model=3072,
+        n_heads=24, n_kv_heads=2, d_ff=12288, pos_encoding="rope",
+        rope_theta=999999.44, param_dtype=jnp.bfloat16,
+        compute_dtype=jnp.bfloat16))
+    spec = jax.ShapeDtypeStruct
+    abstract = lambda tree: jax.tree_util.tree_map(            # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+        model, 2305, 16, folded=True)))
+    prefill, step, _, _ = paged_kv._paged_programs(
+        model, 16, 256, 0.0, 0, 1.0, False, "fused")
+    s, mb = 16, 256
+    text = {
+        "decode": step.lower(
+            params, pools, {}, spec((s, mb * 16), jnp.int32),
+            spec((s, mb), jnp.int32), spec((s,), jnp.int32),
+            spec((s,), jnp.bool_), spec((2,), jnp.uint32)).as_text(),
+        "prefill": prefill.lower(
+            params, pools, {}, spec((1, mb), jnp.int32),
+            spec((1,), jnp.int32), spec((1, 512), jnp.int32),
+            spec((), jnp.int32)).as_text()}
+    got = {k: hashlib.sha256(v.encode()).hexdigest() for k, v in text.items()}
+    assert got == _PER_HEAD_TEXT_SHA256
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 """Times the paged-attention kernel's tilings on the chip.
 
     chiprun --chips 1 -- python tools/paged_attn_timing.py [--quick]
+        [--rows per_head,latent]
 
-At ``sc2-3b-serve-code``'s shapes (24 query heads over 2 KV heads of 128,
+Two rows (``--rows``, default both).  ``per_head``: at ``sc2-3b-serve-code``'s shapes (24 query heads over 2 KV heads of 128,
 bf16 pools of 2305 blocks of 16 with the heads folded into the lanes, 256
 blocks a table): the decode step over 16 streams whose lengths are drawn
 like the cell's (mean about 800), the same at the table's full width (the
@@ -18,7 +19,14 @@ not timed here: alone in a loop, XLA lifts ``pool[tables]`` out of it (the
 pool does not change), so a gathered row would leave the gather out; its
 cost is read from the parent's trace, per layer inside the programs
 (``tools/scope_ops.py``).  Also timed: the scatter of a chunk's 512 rows
-into either pool layout.  Results go to ``chiprun_out/paged_attn_timing.json``
+into either pool layout.  ``latent``: at ``ms4-119b-ep4-serve-docqa``'s
+shapes (32 query heads against ONE row that is key and value: 256 lanes of
+``c_kv``, 64 of ``k_rope``, stored 384 wide in bf16 pools of 17409 blocks of
+16; 544 blocks a table), the kernel's shared-row mode in the decode step
+over 32 streams whose lengths are drawn like that cell's (prompts lognormal
+around 2048, mean about 2.7 k), at the table's full width of 8704, and the
+scatter of a 1024-token chunk's rows into the stored layout (PERF.md section
+6, PR 32).  Results go to ``chiprun_out/paged_attn_timing.json``
 and, one JSON line a row, to standard output.  There is no CPU path.
 """
 
@@ -41,6 +49,9 @@ from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import ( 
 
 H, KV, HD, BS, NB, MB = 24, 2, 128, 16, 2305, 256
 T_CAP = BS * MB
+# the latent row: heads, lanes stored / of the value / of the row, geometry
+LAT = dict(heads=32, lanes=384, v_lanes=256, row=320, nb=17409, mb=544,
+           streams=32, scale=0.0884)
 
 
 def timed(fn, args, reps: int) -> float:
@@ -58,41 +69,12 @@ def timed(fn, args, reps: int) -> float:
     return 1e3 * best / reps
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--reps", type=int, default=30)
-    ap.add_argument("--quick", action="store_true",
-                    help="the table's rows and their neighbours only")
-    ap.add_argument("--seed", type=int, default=30)
-    args = ap.parse_args(argv)
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print("paged_attn_timing: no TPU; a CPU timing says nothing",
-              file=sys.stderr)
-        return 3
-    rng = np.random.default_rng(args.seed)
+def time_per_head(args, rng, emit, tables_nb):
+    """The per-head K/V row at ``sc2-3b-serve-code``'s shapes."""
     kp4 = jnp.asarray(rng.normal(size=(NB, BS, KV, HD)), jnp.bfloat16)
     vp4 = jnp.asarray(rng.normal(size=(NB, BS, KV, HD)), jnp.bfloat16)
     kp3, vp3 = kp4.reshape(NB, BS, KV * HD), vp4.reshape(NB, BS, KV * HD)
-    rows = []
-
-    def emit(**row):
-        row["device"] = dev.device_kind
-        rows.append(row)
-        print(json.dumps(row), flush=True)
-
-    def tables_for(lens):
-        """Each stream's live pages scattered over the pool, the rest at
-        the sink."""
-        tables = np.zeros((len(lens), MB), np.int32)
-        free = rng.permutation(np.arange(1, NB))
-        at = 0
-        for i, ln in enumerate(lens):
-            n = -(-int(ln) // BS)
-            tables[i, :n] = free[at:at + n]
-            at += n
-        return jnp.asarray(tables)
-
+    tables_for = lambda lens: tables_nb(lens, NB, MB)        # noqa: E731
     # ---- decode: 16 streams, the cell's lengths (prompt + answer so far)
     prompts = np.clip(rng.lognormal(np.log(768), 0.6, 16), 128, 2048)
     lens = (prompts + rng.uniform(0, 96, 16)).astype(np.int32)
@@ -151,6 +133,96 @@ def main(argv=None) -> int:
         jax.block_until_ready(pool)
         emit(kind="scatter512", layout=name,
              ms=1e3 * (time.perf_counter() - t) / 50)
+
+
+
+def time_latent(args, rng, emit, tables_for):
+    """The latent row at ``ms4-119b-ep4-serve-docqa``'s shapes: the decode
+    step's shared-row walk, and the chunk's scatter into the stored row."""
+    heads, lanes, nb, mb, s_n = (LAT[k] for k in ("heads", "lanes", "nb",
+                                                  "mb", "streams"))
+    pool = jnp.asarray(rng.normal(size=(nb, BS, lanes)), jnp.bfloat16)
+    pool = pool.at[..., LAT["row"]:].set(0)
+    prompts = np.clip(rng.lognormal(np.log(2048), 0.7, s_n), 512, 8192)
+    lens = (prompts + rng.uniform(0, 256, s_n)).astype(np.int32)
+    q = jnp.asarray(rng.normal(size=(s_n, 1, heads, lanes)), jnp.bfloat16)
+    q = q.at[..., LAT["row"]:].set(0)
+
+    def walk(x, t, ln, st, pages):
+        # the kernel's result is v_lanes wide: pad it back to a query so
+        # that calls chain (the pad is what the step's own concat costs)
+        u = paged_attention(x, pool, None, t, ln, st,
+                            v_lanes=LAT["v_lanes"], scale=LAT["scale"],
+                            pages=pages)
+        return jnp.pad(u, [(0, 0)] * 3 + [(0, lanes - LAT["v_lanes"])])
+
+    full = np.full((s_n,), BS * mb, np.int32)
+    for kind, ls in (("latent_decode", lens),
+                     ("latent_decode_full_width", full)):
+        # the full width: 32 x 544 pages are more than the draw below may
+        # hand out once, so every stream walks the same 544 scattered pages
+        tables = (tables_for(ls, nb, mb) if kind == "latent_decode" else
+                  jnp.tile(tables_for(ls[:1], nb, mb), (s_n, 1)))
+        ls_j = jnp.asarray(ls)
+        for pages in ((16, 32, 64) if args.quick else (8, 16, 32, 64, 128)):
+            ms = timed(lambda x, t, ln, st, pages=pages: walk(
+                x, t, ln, st, pages), (q, tables, ls_j, ls_j - 1), args.reps)
+            emit(kind=kind, pages=pages, ms=ms, mean_len=float(ls.mean()),
+                 live_gb_s=int(ls.sum()) * LAT["row"] * 2 / ms / 1e6)
+
+    # ---- the scatter of a 1024-token chunk's rows into the stored row
+    blk = jnp.asarray(rng.integers(1, nb, (1, 1024)), jnp.int32)
+    off = jnp.asarray(rng.integers(0, BS, (1, 1024)), jnp.int32)
+    new = jnp.asarray(rng.normal(size=(1, 1024, lanes)), jnp.bfloat16)
+    fn = jax.jit(lambda p, r: p.at[blk, off].set(r), donate_argnums=0)
+    pool = fn(pool, new)
+    jax.block_until_ready(pool)
+    t = time.perf_counter()
+    for _ in range(50):
+        pool = fn(pool, new)
+    jax.block_until_ready(pool)
+    emit(kind="latent_scatter1024", layout=f"(NB,bs,{lanes})",
+         ms=1e3 * (time.perf_counter() - t) / 50)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--reps", type=int, default=30)
+    ap.add_argument("--quick", action="store_true",
+                    help="the table's rows and their neighbours only")
+    ap.add_argument("--seed", type=int, default=30)
+    ap.add_argument("--rows", default="per_head,latent",
+                    help="which cache rows to time")
+    args = ap.parse_args(argv)
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print("paged_attn_timing: no TPU; a CPU timing says nothing",
+              file=sys.stderr)
+        return 3
+    rng = np.random.default_rng(args.seed)
+    rows = []
+
+    def emit(**row):
+        row["device"] = dev.device_kind
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    def tables_for(lens, nb, mb):
+        """Each stream's live pages scattered over the pool, the rest at
+        the sink."""
+        tables = np.zeros((len(lens), mb), np.int32)
+        free = rng.permutation(np.arange(1, nb))
+        at = 0
+        for i, ln in enumerate(lens):
+            n = -(-int(ln) // BS)
+            tables[i, :n] = free[at:at + n]
+            at += n
+        return jnp.asarray(tables)
+
+    if "per_head" in args.rows:
+        time_per_head(args, rng, emit, tables_for)
+    if "latent" in args.rows:
+        time_latent(args, rng, emit, tables_for)
 
     out = ROOT / "chiprun_out"
     out.mkdir(exist_ok=True)
