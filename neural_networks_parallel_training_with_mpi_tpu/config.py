@@ -200,6 +200,20 @@ class ModelConfig:
     moe_dropless: bool = False
     moe_experts_held: Tuple[int, ...] = ()     # (first, count); () = all
     moe_shared_ff: int = 0
+    moe_score: str = "softmax"                 # softmax | sigmoid
+    moe_routed_scale: float = 1.0
+    # a head width of its own (0: d_model // n_heads), the per-head norm of
+    # q and k, and layers of several kinds: a pattern of window ("L") and
+    # full ("G") attention layers repeated over the depth, the window,
+    # whether full layers are rotated, and leading dense layers (of width
+    # dense_ff) before the expert layers
+    head_width: int = 0
+    qk_norm: bool = False
+    attention_pattern: str = ""
+    sliding_window: int = 0
+    rope_global: bool = True
+    moe_first_dense: int = 0
+    dense_ff: int = 0
     # transformer: fused chunked cross-entropy — evaluate LM head + CE
     # ce_chunk tokens at a time under jax.checkpoint so the (B, T, vocab)
     # f32 logits tensor is never materialized (0 = off).  Loss math is
@@ -773,6 +787,27 @@ def build_argparser() -> argparse.ArgumentParser:
                         "experts held here (default: all)")
     p.add_argument("--moe_shared_ff", type=int, default=0,
                    help="width of the shared expert (0 = none)")
+    p.add_argument("--moe_score", choices=["softmax", "sigmoid"],
+                   default="softmax",
+                   help="the router's score under --moe-dropless (sigmoid: "
+                        "with a stored correction bias in the choice)")
+    p.add_argument("--moe_routed_scale", type=float, default=1.0,
+                   help="factor on the renormalised routed weights")
+    p.add_argument("--head_width", type=int, default=0,
+                   help="width of a head (0 = d_model // n_heads)")
+    _add_bool_flag(p, "qk-norm", False,
+                   "the model's norm over each head of q and k")
+    p.add_argument("--attention_pattern", type=str, default="",
+                   help="kinds of attention layer, repeated over the depth: "
+                        "'L' sees --sliding_window keys, 'G' all (LLLG)")
+    p.add_argument("--sliding_window", type=int, default=0)
+    _add_bool_flag(p, "rope-global", True,
+                   "rotate q and k in 'G' layers too (--no-rope-global: "
+                   "full layers carry no positions)")
+    p.add_argument("--moe_first_dense", type=int, default=0,
+                   help="leading layers with a dense feed-forward of width "
+                        "--dense_ff in place of the expert layer")
+    p.add_argument("--dense_ff", type=int, default=0)
     p.add_argument("--moe_capacity_factor", type=float, default=None,
                    help="per-expert slot count = ceil(factor * group_tokens "
                         "/ n_experts); overflow tokens fall through residual "
@@ -1115,6 +1150,10 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     m.rope_scaling = tuple(float(x) for x in args.rope_scaling.split(",")
                            if x)
     m.moe_dropless = args.moe_dropless
+    for name in ("moe_score", "moe_routed_scale", "head_width", "qk_norm",
+                 "attention_pattern", "sliding_window", "rope_global",
+                 "moe_first_dense", "dense_ff"):
+        setattr(m, name, getattr(args, name))
     m.moe_experts_held = tuple(int(x) for x in
                                args.moe_experts_held.split(",") if x)
     if args.ep > 1:

@@ -52,6 +52,21 @@ def top_k_weights(probs: jax.Array, k: int):
     return top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9), top_i
 
 
+def sigmoid_top_k(gate_params: Pytree, x: jax.Array, k: int):
+    """The sigmoid router (DeepSeek-V3's): scores ``sigmoid(x W)`` in
+    float32 over ALL experts; the k chosen are the k largest of ``score +
+    bias`` (``gate_params["bias"]``, the stored correction bias), and their
+    weights are the chosen SCORES divided by their sum, so the bias takes
+    part in the choice and not in the weight.  ((N, k) weights, (N, k)
+    expert ids, (N, E) scores)."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), gate_params["w"].astype(jnp.float32)))
+    _, top_i = jax.lax.top_k(
+        scores + gate_params["bias"].astype(jnp.float32), k)
+    top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    return top_s / (top_s.sum(-1, keepdims=True) + 1e-20), top_i, scores
+
+
 @dataclass(frozen=True)
 class MoEFFN(Module):
     """Top-1 gated mixture of ``n_experts`` two-layer FFNs.
@@ -321,6 +336,7 @@ class DroplessMoE(Module):
     held: Optional[Tuple[int, int]] = None
     shared_ff: int = 0            # width of the shared expert (0 = none)
     routed_scale: float = 1.0
+    score: str = "softmax"        # softmax | sigmoid (:func:`sigmoid_top_k`)
     impl: str = "auto"
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
@@ -336,6 +352,9 @@ class DroplessMoE(Module):
                              f"{self.top_k}")
         if self.impl not in GROUPED_IMPLS:
             raise ValueError(f"impl must be one of {GROUPED_IMPLS}")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score must be softmax or sigmoid, got "
+                             f"{self.score!r}")
 
     @property
     def span(self) -> Tuple[int, int]:
@@ -353,6 +372,9 @@ class DroplessMoE(Module):
                 "w_in": _uniform(k2, (g, d, f), bd, self.param_dtype),
                 "w_out": _uniform(k3, (g, f, d), bf, self.param_dtype)},
         }
+        if self.score == "sigmoid":
+            out["gate"]["bias"] = jnp.zeros((self.n_experts,),
+                                            self.param_dtype)
         if self.shared_ff:
             s = self.shared_ff
             out["shared"] = {
@@ -371,8 +393,12 @@ class DroplessMoE(Module):
         is not computed here, and the router's ``probs`` / first choices
         for the load-balance term."""
         first, count = self.span
-        probs = router_probs(gate_params, toks)
-        weight, top_i = top_k_weights(probs, self.top_k)
+        if self.score == "sigmoid":
+            weight, top_i, probs = sigmoid_top_k(gate_params, toks,
+                                                 self.top_k)
+        else:
+            probs = router_probs(gate_params, toks)
+            weight, top_i = top_k_weights(probs, self.top_k)
         local = top_i - first
         here = (local >= 0) & (local < count)
         if mask is not None:

@@ -54,6 +54,13 @@ def build_model(cfg: ModelConfig) -> Module:
             moe_dropless=cfg.moe_dropless,
             moe_experts_held=tuple(cfg.moe_experts_held) or None,
             moe_shared_ff=cfg.moe_shared_ff,
+            moe_score=cfg.moe_score,
+            moe_routed_scale=cfg.moe_routed_scale,
+            head_width=cfg.head_width or None, qk_norm=cfg.qk_norm,
+            attention_pattern=cfg.attention_pattern or None,
+            sliding_window=cfg.sliding_window,
+            rope_global=cfg.rope_global,
+            moe_first_dense=cfg.moe_first_dense, dense_ff=cfg.dense_ff,
             ce_chunk=cfg.ce_chunk,
             matmul_dtype=cfg.matmul_dtype,
             matmul_skip=tuple(cfg.matmul_skip),

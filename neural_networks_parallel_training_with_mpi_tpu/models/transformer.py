@@ -32,10 +32,10 @@ def split_qkv(c: "TransformerConfig", qkv: jax.Array):
     so the GQA column layout [q | k | v] cannot drift between them."""
     b, t, _ = qkv.shape
     kvw = c.kv_heads * c.head_dim
-    q = qkv[..., :c.d_model].reshape(b, t, c.n_heads, c.head_dim)
-    k = qkv[..., c.d_model:c.d_model + kvw].reshape(b, t, c.kv_heads,
-                                                    c.head_dim)
-    v = qkv[..., c.d_model + kvw:].reshape(b, t, c.kv_heads, c.head_dim)
+    q = qkv[..., :c.q_dim].reshape(b, t, c.n_heads, c.head_dim)
+    k = qkv[..., c.q_dim:c.q_dim + kvw].reshape(b, t, c.kv_heads,
+                                                c.head_dim)
+    v = qkv[..., c.q_dim + kvw:].reshape(b, t, c.kv_heads, c.head_dim)
     return q, k, v
 
 
@@ -173,6 +173,31 @@ class TransformerConfig:
     moe_dropless: bool = False
     moe_experts_held: Optional[Tuple[int, int]] = None
     moe_shared_ff: int = 0
+    # The router's score under routing without drops: "softmax" over all
+    # experts, or "sigmoid" with a stored correction bias that takes part
+    # in the choice and not in the weight; either way the chosen scores are
+    # divided by their sum, then multiplied by ``moe_routed_scale``.
+    moe_score: str = "softmax"         # softmax | sigmoid
+    moe_routed_scale: float = 1.0
+    # A head width of its own (None: ``d_model // n_heads``): the query
+    # projection is then ``n_heads * head_width`` wide, not ``d_model``.
+    head_width: Optional[int] = None
+    # The model's norm over each head of q and k, one scale vector of
+    # ``head_dim`` for all heads, before any rotation.
+    qk_norm: bool = False
+    # Layers that are not all alike, given as a model's config gives them.
+    # ``attention_pattern`` is repeated over the layers: layer i is
+    # ``pattern[i % len(pattern)]``; "G" attends every position before it,
+    # "L" itself and the ``sliding_window - 1`` before it.  With rotary
+    # positions, ``rope_global=False`` leaves the "G" layers' q and k
+    # unrotated (they carry no positions at all).  The first
+    # ``moe_first_dense`` layers of a model with experts have a dense
+    # feed-forward of width ``dense_ff`` in place of the expert layer.
+    attention_pattern: Optional[str] = None
+    sliding_window: int = 0
+    rope_global: bool = True
+    moe_first_dense: int = 0
+    dense_ff: int = 0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -206,6 +231,65 @@ class TransformerConfig:
                 "routing without drops runs one chip's share without its "
                 "exchange; moe_expert_axis (parallel/expert.py's all-to-all) "
                 "belongs to the capacity layer")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score must be softmax or sigmoid, got "
+                             f"{self.moe_score!r}")
+        if ((self.moe_score != "softmax" or self.moe_routed_scale != 1.0)
+                and not self.moe_dropless):
+            raise ValueError("moe_score and moe_routed_scale belong to "
+                             "routing without drops (moe_dropless)")
+        own = [n for n, on in (("head_width", self.head_width is not None),
+                               ("qk_norm", self.qk_norm),
+                               ("attention_pattern", self.has_layer_kinds))
+               if on]
+        if own and self.attention_kind != "mha":
+            raise ValueError(f"{', '.join(own)}: latent attention owns its "
+                             "projections and has one kind of layer")
+        if self.head_width is not None and self.head_width < 1:
+            raise ValueError(f"head_width {self.head_width} < 1")
+        pat = self.attention_pattern
+        if pat is not None:
+            if not pat or set(pat) - set("LG"):
+                raise ValueError(f"attention_pattern is a string of 'L' and "
+                                 f"'G', got {pat!r}")
+            if "L" in pat and self.sliding_window < 1:
+                raise ValueError("an 'L' layer needs sliding_window >= 1")
+        if self.moe_first_dense:
+            if not 0 < self.moe_first_dense <= self.n_layers \
+                    or self.moe_experts < 1 or self.dense_ff < 1:
+                raise ValueError(
+                    f"moe_first_dense {self.moe_first_dense} needs experts "
+                    f"(moe_experts {self.moe_experts}), a width for the "
+                    f"dense layers (dense_ff {self.dense_ff}) and at most "
+                    f"n_layers {self.n_layers}")
+        if self.has_layer_kinds:
+            if self.scan_layers:
+                raise ValueError("scan_layers stacks layers that are all "
+                                 "alike; this model's are not")
+            if self.attention not in ("auto", "dense"):
+                raise ValueError(
+                    f"layers of several kinds run the dense attention "
+                    f"(the window is in its mask); attention="
+                    f"{self.attention!r} has no window yet")
+
+    # ---- layers that are not all alike ---------------------------------
+    @property
+    def has_layer_kinds(self) -> bool:
+        return self.attention_pattern is not None or self.moe_first_dense > 0
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """Keys layer ``i``'s query sees, itself included (None: all)."""
+        pat = self.attention_pattern
+        return (self.sliding_window
+                if pat and pat[i % len(pat)] == "L" else None)
+
+    def layer_rotary(self, i: int) -> bool:
+        """Whether layer ``i`` rotates q and k by position."""
+        return self.pos_encoding == "rope" and (
+            self.rope_global or self.layer_window(i) is not None)
+
+    def layer_is_moe(self, i: int) -> bool:
+        return self.moe_experts > 0 and i >= self.moe_first_dense
 
     def require_plain_block(self, who: str) -> None:
         """Paths that know the fused-qkv block and the capacity layer only
@@ -213,7 +297,12 @@ class TransformerConfig:
         kinds = [k for k, on in (
             ("latent attention (attention_kind='mla')",
              self.attention_kind != "mha"),
-            ("routing without drops (moe_dropless)", self.moe_dropless))
+            ("routing without drops (moe_dropless)", self.moe_dropless),
+            ("a head width of its own (head_width)",
+             self.head_width is not None),
+            ("the per-head norm of q and k (qk_norm)", self.qk_norm),
+            ("layers of several kinds (attention_pattern / "
+             "moe_first_dense)", self.has_layer_kinds))
             if on]
         if kinds:
             raise ValueError(f"{who} cannot run a block with "
@@ -223,8 +312,16 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width is not None:
+            return self.head_width
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        """Width of the query projection and of the attention's output:
+        ``d_model`` unless the heads have a width of their own."""
+        return self.n_heads * self.head_dim
 
     @property
     def kv_heads(self) -> int:
@@ -236,9 +333,9 @@ class TransformerConfig:
 
     @property
     def qkv_dim(self) -> int:
-        """Fused qkv projection width: d (q) + 2 * kv_heads * head_dim
+        """Fused qkv projection width: q_dim (q) + 2 * kv_heads * head_dim
         (k, v) — reduces to 3 * d_model for classic multi-head."""
-        return self.d_model + 2 * self.kv_heads * self.head_dim
+        return self.q_dim + 2 * self.kv_heads * self.head_dim
 
 
 @dataclass(frozen=True)
@@ -253,13 +350,14 @@ class Transformer(Module):
         c = self.cfg
         return "bf16" if role in c.matmul_skip else c.matmul_dtype
 
-    def _norm(self):
+    def _norm(self, dim: Optional[int] = None):
         """The model's norm over ``d_model`` (block norms and the final
-        one)."""
+        one), or over ``dim`` (a head of q or k)."""
         c = self.cfg
+        dim = c.d_model if dim is None else dim
         if c.norm == "rmsnorm":
-            return RMSNorm(c.d_model, c.norm_eps, c.param_dtype)
-        return LayerNorm(c.d_model, c.norm_eps, c.param_dtype)
+            return RMSNorm(dim, c.norm_eps, c.param_dtype)
+        return LayerNorm(dim, c.norm_eps, c.param_dtype)
 
     def cache_row(self):
         """What one token holds in one layer of a serving cache: pool name
@@ -271,7 +369,10 @@ class Transformer(Module):
             return self._block_modules()["attn"].cache_row()
         return {"k": (c.kv_heads, c.head_dim), "v": (c.kv_heads, c.head_dim)}
 
-    def _block_modules(self):
+    def _block_modules(self, layer: int = 0):
+        """The modules of layer ``layer``: the same for every layer unless
+        the config gives the layers kinds (``layer_is_moe``; the attention's
+        kinds differ in what it does, not in what it holds)."""
         c = self.cfg
         if c.attention_kind == "mla":
             from .mla import LatentAttention
@@ -291,23 +392,29 @@ class Transformer(Module):
                               param_dtype=c.param_dtype,
                               compute_dtype=c.compute_dtype,
                               matmul_dtype=self._mm("qkv"), q_role="qkv"),
-                "attn_out": Linear(c.d_model, c.d_model,
+                "attn_out": Linear(c.q_dim, c.d_model,
                                    use_bias=c.use_bias,
                                    param_dtype=c.param_dtype,
                                    compute_dtype=c.compute_dtype,
                                    matmul_dtype=self._mm("attn_out"),
                                    q_role="attn_out"),
-                "ln2": self._norm(),
             }
-        if c.moe_dropless:
+            if c.qk_norm:
+                mods["q_norm"] = self._norm(c.head_dim)
+                mods["k_norm"] = self._norm(c.head_dim)
+            mods["ln2"] = self._norm()
+        if not c.layer_is_moe(layer):
+            self._dense_ffn_modules(mods)
+        elif c.moe_dropless:
             from .moe import DroplessMoE
 
             mods["moe"] = DroplessMoE(
                 c.d_model, c.d_ff, c.moe_experts, top_k=c.moe_top_k,
                 held=c.moe_experts_held, shared_ff=c.moe_shared_ff,
+                score=c.moe_score, routed_scale=c.moe_routed_scale,
                 param_dtype=c.param_dtype,
                 compute_dtype=c.compute_dtype)
-        elif c.moe_experts > 0:
+        else:
             from .moe import MoEFFN
 
             mods["moe"] = MoEFFN(
@@ -317,29 +424,42 @@ class Transformer(Module):
                 expert_axis=c.moe_expert_axis,
                 router_top_k=c.moe_top_k,
                 param_dtype=c.param_dtype, compute_dtype=c.compute_dtype)
-        else:
-            mods["ff_in"] = Linear(c.d_model, c.d_ff, use_bias=c.use_bias,
-                                   param_dtype=c.param_dtype,
-                                   compute_dtype=c.compute_dtype,
-                                   matmul_dtype=self._mm("ff_in"),
-                                   q_role="ff_in")
-            if c.activation == "swiglu":
-                # gated FFN (Shazeer 2020): silu(x W_gate) * (x W_in),
-                # then W_out — the modern-LM FFN.  A third (d, ff)
-                # projection; pick d_ff ~2/3 of the ungated width for
-                # iso-parameter comparisons.
-                mods["ff_gate"] = Linear(c.d_model, c.d_ff,
-                                         use_bias=c.use_bias,
-                                         param_dtype=c.param_dtype,
-                                         compute_dtype=c.compute_dtype,
-                                         matmul_dtype=self._mm("ff_gate"),
-                                         q_role="ff_gate")
-            mods["ff_out"] = Linear(c.d_ff, c.d_model, use_bias=c.use_bias,
-                                    param_dtype=c.param_dtype,
-                                    compute_dtype=c.compute_dtype,
-                                    matmul_dtype=self._mm("ff_out"),
-                                    q_role="ff_out")
         return mods
+
+    def _dense_ffn_modules(self, mods) -> None:
+        """The two-matrix or gated feed-forward's Linears into ``mods``: of
+        width ``d_ff``, or ``dense_ff`` for a leading dense layer of a model
+        with experts (whose ``d_ff`` is an expert's width)."""
+        c = self.cfg
+        ff = c.dense_ff if c.moe_experts > 0 else c.d_ff
+        mods["ff_in"] = Linear(c.d_model, ff, use_bias=c.use_bias,
+                               param_dtype=c.param_dtype,
+                               compute_dtype=c.compute_dtype,
+                               matmul_dtype=self._mm("ff_in"),
+                               q_role="ff_in")
+        if c.activation == "swiglu":
+            # gated FFN (Shazeer 2020): silu(x W_gate) * (x W_in),
+            # then W_out — the modern-LM FFN.  A third (d, ff)
+            # projection; pick d_ff ~2/3 of the ungated width for
+            # iso-parameter comparisons.
+            mods["ff_gate"] = Linear(c.d_model, ff,
+                                     use_bias=c.use_bias,
+                                     param_dtype=c.param_dtype,
+                                     compute_dtype=c.compute_dtype,
+                                     matmul_dtype=self._mm("ff_gate"),
+                                     q_role="ff_gate")
+        mods["ff_out"] = Linear(ff, c.d_model, use_bias=c.use_bias,
+                                param_dtype=c.param_dtype,
+                                compute_dtype=c.compute_dtype,
+                                matmul_dtype=self._mm("ff_out"),
+                                q_role="ff_out")
+
+    def qk_normed(self, mods, params, q: jax.Array, k: jax.Array):
+        """The per-head norm of q and k (``qk_norm``), before any rotation;
+        shared by the training block and the paged server."""
+        with jax.named_scope("qk_norm"):
+            return (mods["q_norm"].apply(params["q_norm"], q),
+                    mods["k_norm"].apply(params["k_norm"], k))
 
     def quant_roles(self):
         """fp8 delayed-scaling roles (ops.qmm): one activation amax
@@ -384,9 +504,9 @@ class Transformer(Module):
         pos = Embedding(c.max_seq_len, c.d_model, c.param_dtype)
         head = Linear(c.d_model, c.vocab_size, use_bias=False,
                       param_dtype=c.param_dtype, compute_dtype=c.compute_dtype)
-        mods = self._block_modules()
         blocks = []
         for i in range(c.n_layers):
+            mods = self._block_modules(i)
             bkeys = jax.random.split(keys[i], len(mods))
             blocks.append({name: m.init(k) for (name, m), k in zip(mods.items(), bkeys)})
         if c.scan_layers:  # stacked layout: leaves (n_layers, ...)
@@ -402,14 +522,16 @@ class Transformer(Module):
             out["pos"] = pos.init(keys[-2])
         return out
 
-    def _block(self, params, x: jax.Array, qscales=None, collect=False):
-        """One pre-LN block: (params, x) -> (x, aux, qobs); aux is the MoE
+    def _block(self, params, x: jax.Array, qscales=None, collect=False,
+               layer: int = 0):
+        """Layer ``layer``'s pre-LN block: (params, x) -> (x, aux, qobs);
+        aux is the MoE
         load-balance loss for this block (0.0 for a dense FFN), qobs the
         fp8 calibration observations ({role: amax} when ``collect``, {}
         otherwise — ops.qmm delayed scaling; ``qscales`` is the delayed
         amax each Linear reads)."""
         c = self.cfg
-        mods = self._block_modules()
+        mods = self._block_modules(layer)
         qobs = {} if collect else None
         qkw = ({"qscales": qscales, "qobserved": qobs}
                if c.matmul_dtype == "fp8" else {})
@@ -423,6 +545,8 @@ class Transformer(Module):
             h = mods["ln1"].apply(params["ln1"], x)
             qkv = mods["qkv"].apply(params["qkv"], h, **qkw)
             q, k, v = split_qkv(c, qkv)
+            if c.qk_norm:
+                q, k = self.qk_normed(mods, params, q, k)
             # GQA training path: repeat K/V to full query heads so every
             # attention impl (dense/flash/ring/...) sees plain MHA — same
             # math as grouped attention; the bandwidth win is decode-side
@@ -433,10 +557,11 @@ class Transformer(Module):
                 c.attention, q, k, v,
                 axis=c.seq_axis, causal=True, block_q=c.flash_block_q,
                 block_k=c.flash_block_k,
-                rope_theta=(c.rope_theta if c.pos_encoding == "rope"
-                            else None))
+                rope_theta=(c.rope_theta if c.layer_rotary(layer)
+                            else None),
+                window=c.layer_window(layer))
         with jax.named_scope("attn_proj"):
-            out = out.reshape(*out.shape[:2], c.d_model)
+            out = out.reshape(*out.shape[:2], c.q_dim)
             x = x + mods["attn_out"].apply(params["attn_out"], out, **qkw)
         return self._ffn_half(mods, params, x, qkw, qobs)
 
@@ -447,7 +572,7 @@ class Transformer(Module):
         c = self.cfg
         with jax.named_scope("ffn"):
             h = mods["ln2"].apply(params["ln2"], x)
-            if c.moe_experts > 0:
+            if "moe" in mods:
                 ff, aux = mods["moe"].apply(params["moe"], h)
             else:
                 ff = self._ffn(mods, params, h, **qkw)
@@ -515,33 +640,41 @@ class Transformer(Module):
     def fwd_flops(self, x_shape):
         """(B, T) token batch.  qkv/out/ffn/attention matmuls + LM head;
         with MoE, each token runs ``moe_top_k`` expert FFNs plus the
-        router matmul."""
+        router matmul.  Layers of several kinds are counted one by one (a
+        window layer's scores and values over ``min(T, window)`` keys)."""
         c = self.cfg
         b, t = x_shape
-        d, ff, v = c.d_model, c.d_ff, c.vocab_size
-        if c.attention_kind == "mla":
-            # the low-rank projections and the expanded scores + values
-            per_layer = b * t * self._block_modules()[
-                "attn"].fwd_flops_per_token(t)
-        else:
-            per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv (GQA-aware)
-            per_layer += 2.0 * b * t * d * d        # attention out
-            per_layer += 2.0 * (2.0 * b * t * t * d)  # scores + values
-        # FFN in + out per expert; SwiGLU adds the (d, ff) gate matmul
-        gated = c.activation == "swiglu" or c.moe_dropless
-        ffn = 2.0 * ((3.0 if gated else 2.0) * b * t * d * ff)
-        if c.moe_dropless:
-            # of a token's k choices the share that is held here, plus
-            # the shared expert
-            count = (c.moe_experts_held or (0, c.moe_experts))[1]
-            ffn *= c.moe_top_k * count / c.moe_experts
-            ffn += 2.0 * 3.0 * b * t * d * c.moe_shared_ff
-            per_layer += 2.0 * b * t * d * c.moe_experts  # router
-        elif c.moe_experts > 0:
-            ffn *= c.moe_top_k
-            per_layer += 2.0 * b * t * d * c.moe_experts  # router
-        per_layer += ffn
-        return float(c.n_layers * per_layer + 2.0 * b * t * d * v)
+        d, v = c.d_model, c.vocab_size
+        total = 0.0
+        for i in (range(c.n_layers) if c.has_layer_kinds else (0,)):
+            if c.attention_kind == "mla":
+                # the low-rank projections and the expanded scores + values
+                per_layer = b * t * self._block_modules()[
+                    "attn"].fwd_flops_per_token(t)
+            else:
+                keys = min(t, c.layer_window(i) or t)
+                per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv (GQA-aware)
+                per_layer += 2.0 * b * t * c.q_dim * d  # attention out
+                per_layer += 2.0 * (2.0 * b * t * keys * c.q_dim)  # scores + values
+            moe = c.layer_is_moe(i)
+            ff = c.d_ff if moe or not c.moe_experts else c.dense_ff
+            # FFN in + out per expert; SwiGLU adds the (d, ff) gate matmul
+            gated = c.activation == "swiglu" or (moe and c.moe_dropless)
+            ffn = 2.0 * ((3.0 if gated else 2.0) * b * t * d * ff)
+            if moe and c.moe_dropless:
+                # of a token's k choices the share that is held here, plus
+                # the shared expert
+                count = (c.moe_experts_held or (0, c.moe_experts))[1]
+                ffn *= c.moe_top_k * count / c.moe_experts
+                ffn += 2.0 * 3.0 * b * t * d * c.moe_shared_ff
+                per_layer += 2.0 * b * t * d * c.moe_experts  # router
+            elif moe:
+                ffn *= c.moe_top_k
+                per_layer += 2.0 * b * t * d * c.moe_experts  # router
+            total += per_layer + ffn
+        if not c.has_layer_kinds:
+            total *= c.n_layers
+        return float(total + 2.0 * b * t * d * v)
 
     def backbone(self, params, ids: jax.Array, qscales=None,
                  collect=False):
@@ -567,13 +700,19 @@ class Transformer(Module):
         # w.r.t. the differentiated params
         _qs, _collect = qscales, collect
 
-        def block_fn(layer_params, h):
-            return self._block(layer_params, h, _qs, _collect)
+        def block_at(i):
+            """Layer ``i``'s block (the index is read only where the
+            config gives the layers kinds)."""
+            def block_fn(layer_params, h):
+                return self._block(layer_params, h, _qs, _collect, layer=i)
 
-        if c.remat:
-            from .core import make_remat
+            if c.remat:
+                from .core import make_remat
 
-            block_fn = make_remat(c.remat_policy)(block_fn)
+                return make_remat(c.remat_policy)(block_fn)
+            return block_fn
+
+        block_fn = block_at(0)
         aux_total = jnp.zeros((), jnp.float32)
         # block-level roles only (head observes in apply/qloss callers)
         block_roles = [r for r in (self.quant_roles() if collect else ())
@@ -590,8 +729,10 @@ class Transformer(Module):
             (x, aux_total, qobs_total), _ = jax.lax.scan(
                 body, (x, aux_total, qobs_total), params["blocks"])
         else:
-            for layer_params in params["blocks"]:
-                x, aux, obs = block_fn(layer_params, x)
+            for i, layer_params in enumerate(params["blocks"]):
+                # one function for layers that are all alike (traced once)
+                fn = block_at(i) if c.has_layer_kinds else block_fn
+                x, aux, obs = fn(layer_params, x)
                 aux_total = aux_total + aux
                 qobs_total = {r: jnp.maximum(qobs_total[r], obs[r])
                               for r in qobs_total}

@@ -699,13 +699,23 @@ flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)
 # third of the time.  PR 32, the latent row stored 384 lanes wide, one copy
 # a page: 64 pages tie with 128 at the cell's lengths and lose 3 % to them
 # at the table's full width, at half the landing buffer; the untimed rule's
-# 8 pages take 1.6 x as long).  Every other shape walks ``_UNTIMED_KEYS`` key
+# 8 pages take 1.6 x as long.  PR 33, the per-head row of 1024 lanes, a
+# 32 KB page a pool: decode, 64 pages beat 32 by 7 % and 128 by 14 %; under a
+# window of 128 (``"decode_window"``, ``"chunk_window"``) the walk is flat
+# from 5 pages a step up, a chunk's from 13 pages at 64 columns; row tiles of
+# 512 rows over steps of 512 keys, and any tile of 1024 rows, do not fit
+# VMEM with 8 KV heads unrolled; ``(32, 32)`` is 5 % faster than the chunk's
+# ``(16, 64)`` at depth and as fast at start 0: a lead).  Every other shape walks ``_UNTIMED_KEYS`` key
 # positions a step and tiles a chunk at about ``_UNTIMED_ROWS`` query rows
 # a KV head.
 PAGED_TILES = {
     (16, 256, "decode"): (64, 1),
     (16, 256, "chunk"): (32, 64),
     (16, 384, "decode"): (64, 1),
+    (16, 1024, "decode"): (64, 1),
+    (16, 1024, "chunk"): (16, 64),
+    (16, 1024, "decode_window"): (5, 1),
+    (16, 1024, "chunk_window"): (13, 64),
 }
 _UNTIMED_KEYS = 128
 _UNTIMED_ROWS = 256
@@ -714,32 +724,40 @@ _UNTIMED_ROWS = 256
 def paged_tiles(block_size: int, lanes: int, width: int, groups: int,
                 max_blocks: int, pages: Optional[int] = None,
                 tile_cols: Optional[int] = None,
-                quant: bool = False) -> Tuple[int, int]:
+                quant: bool = False,
+                window: Optional[int] = None) -> Tuple[int, int]:
     """``(pages, tile_cols)`` of the paged kernel for this input: an
     explicit size wins, ``None`` comes from the timed table (else the
     untimed rule).  ``pages`` is cut to the table's width, and is 1 for
     int8 pools (``quant``: a 16-row int8 page is half a sublane tile, so
     pages do not stack in one landing buffer); ``tile_cols`` must divide
     ``width`` into tiles whose rows (``tile_cols * groups``) fill whole
-    sublanes, else the chunk is one tile."""
-    kind = "decode" if width == 1 else "chunk"
-    t_pages, t_cols = PAGED_TILES.get(
-        (block_size, lanes, kind),
-        (max(1, _UNTIMED_KEYS // block_size),
-         max(1, _UNTIMED_ROWS // groups)))
-    pages = 1 if quant else max(
-        1, min(t_pages if pages is None else pages, max_blocks))
+    sublanes, else the chunk is one tile.  A walk bounded below by a
+    ``window`` has rows of its own (``"decode_window"``, ``"chunk_window"``);
+    untimed, it takes in one step the pages a tile's rows can see: the
+    window's and the tile's own, and the page the bound cuts."""
+    kind = ("decode" if width == 1 else "chunk") + (
+        "" if window is None else "_window")
+    t_pages, t_cols = PAGED_TILES.get((block_size, lanes, kind),
+                                      (None, max(1, _UNTIMED_ROWS // groups)))
     cols = min(t_cols if tile_cols is None else tile_cols, width)
     fits = [c for c in range(cols, 0, -1)
             if width % c == 0 and (c * groups) % 8 == 0]
-    return pages, (fits[0] if fits else width)
+    cols = fits[0] if fits else width
+    if t_pages is None:
+        t_pages = (max(1, _UNTIMED_KEYS // block_size) if window is None
+                   else -(-(window + cols - 1) // block_size) + 1)
+    pages = 1 if quant else max(
+        1, min(t_pages if pages is None else pages, max_blocks))
+    return pages, cols
 
 
 def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
                        *rest, block_size: int, pages: int,
                        kv_heads: int, groups: int, tile_cols: int,
                        scale: float, quant: bool,
-                       v_lanes: Optional[int] = None):
+                       v_lanes: Optional[int] = None,
+                       window: Optional[int] = None):
     """Grid: (streams, row tiles).  A program owns ``tile_cols`` query
     columns of one stream (all heads: ``tile_cols * groups`` rows a KV
     head) and walks the stream's block table ``pages`` entries a loop step,
@@ -782,7 +800,15 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     hold whatever was there).  A ``len == 0`` lane, and a tile that starts
     at or past ``len`` (the pad columns of a bucketed chunk), walks nothing
     and exits with output 0, the flash kernels' "no contribution"
-    convention; the sink block is never attended."""
+    convention; the sink block is never attended.
+
+    ``window`` bounds the walk from below: a row at position ``p`` sees keys
+    ``p - window + 1 .. p``, so the tile's walk STARTS at the page of its
+    first row's oldest key (``(first - window + 1) // block_size``; table
+    entries before it are never read, and may point at the sink) and every
+    step is masked by position: the first page at the bound, the last at
+    the causal edge.  A decode row at length ``n`` walks pages ``(n -
+    window) // bs .. (n - 1) // bs``."""
     shared = v_lanes is not None
     if shared:
         o_ref, k_buf, sem = rest
@@ -797,15 +823,26 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
     limit = lax.min(ln, first + tile_cols)      # keys its rows can see
     span = pages * block_size
     n_pages = lax.div(limit + block_size - 1, block_size)
-    n_steps = lax.div(limit + span - 1, span)
-    n_full = lax.div(lax.min(first + 1, limit), span)
+    if window is None:
+        n_steps = lax.div(limit + span - 1, span)
+        n_full = lax.div(lax.min(first + 1, limit), span)
+        page_of = lambda j, i: j * pages + i                # noqa: E731
+        key0 = lambda j: j * span                           # noqa: E731
+    else:
+        # the walk's first page; pages, steps and key offsets count from it
+        page0 = lax.div(lax.max(first - (window - 1), 0), block_size)
+        n_pages = n_pages - page0
+        n_steps = lax.div(n_pages + pages - 1, pages)
+        n_full = 0
+        page_of = lambda j, i: page0 + j * pages + i        # noqa: E731
+        key0 = lambda j: page0 * block_size + j * span      # noqa: E731
     rows = tile_cols * groups
     hd = q_ref.shape[-1]
     vd = v_lanes if shared else hd              # lanes of a value row
 
     def page_copies(j, i):
         slot = lax.rem(j, 2)
-        blk = tables_ref[s, j * pages + i]
+        blk = tables_ref[s, page_of(j, i)]
         dst = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
         ops = [pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, dst],
                                      sem.at[slot, 0])]
@@ -858,8 +895,10 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
                 return buf[slot, :, h * block_size:(h + 1) * block_size]
 
             if masked:
-                k0 = j * span
+                k0 = key0(j)
                 keep = (k_off < ln - k0) & (rel <= (first - k0) * groups)
+                if window is not None:
+                    keep &= rel > (first - k0 - window) * groups
                 fetched = v_row < limit - k0
             out = []
             for h, (acc, m, l) in enumerate(carry):
@@ -878,7 +917,9 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_hbm,
                 m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
                 # every walked row sees key 0 in step 0 (its position is
                 # >= 0 and len > 0), so m is finite before the running
-                # exp() can ever see exp(0) garbage
+                # exp() can ever see exp(0) garbage (under a window a row
+                # may see nothing before a later step: what it summed until
+                # then is finite, and exp(m - m_new) = 0 wipes it)
                 p = jnp.exp(sc - m_new)
                 corr = jnp.exp(m - m_new)
                 l_new = corr * l + p.sum(axis=-1, keepdims=True)
@@ -923,6 +964,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
                     scale: Optional[float] = None,
                     pages: Optional[int] = None,
                     tile_cols: Optional[int] = None,
+                    window: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused paged attention: reads K/V straight from the serving block
     pool through per-stream block tables and reduces over each stream's
@@ -962,6 +1004,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
     * ``pages`` / ``tile_cols``: pages a loop step and query columns a row
       tile; ``None`` asks :func:`paged_tiles` (int8 pools walk one page a
       step).
+    * ``window``: a query at position ``p`` sees keys ``p - window + 1 ..
+      p`` only, and the page walk starts at the page of the tile's oldest
+      visible key; ``None`` walks from the stream's first page.
 
     Tables/lengths/starts are traced scalar-prefetch operands: block-table
     churn (admission, growth, eviction) re-runs the SAME compiled kernel
@@ -999,20 +1044,29 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
         interpret = _interpret_default()
     pages, tile_cols = paged_tiles(
         bs, kv_heads * hd, width, n_heads // kv_heads, tables.shape[1],
-        pages, tile_cols, quant=k_scale is not None)
-    return _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
-                                 k_scale, v_scale, pages=pages,
-                                 tile_cols=tile_cols, interpret=interpret,
-                                 v_lanes=v_lanes, scale=scale)
+        pages, tile_cols, quant=k_scale is not None, window=window)
+    if window is None:      # the call's text (and cache key) as it was
+        return _paged_attention_call(
+            q, k_pool, v_pool, tables, lengths, starts, k_scale, v_scale,
+            pages=pages, tile_cols=tile_cols, interpret=interpret,
+            v_lanes=v_lanes, scale=scale)
+    if window < 1:
+        raise ValueError(f"window {window} < 1")
+    return _paged_attention_call(
+        q, k_pool, v_pool, tables, lengths, starts, k_scale, v_scale,
+        pages=pages, tile_cols=tile_cols, interpret=interpret,
+        v_lanes=v_lanes, scale=scale, window=window)
 
 
 # jitted for the flash calls' reason: 30 unrolled layers lower one kernel
 @functools.partial(jax.jit, static_argnames=("pages", "tile_cols",
-                                             "interpret", "v_lanes", "scale"))
+                                             "interpret", "v_lanes", "scale",
+                                             "window"))
 def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
                           k_scale, v_scale, *, pages: int, tile_cols: int,
                           interpret: bool, v_lanes: Optional[int] = None,
-                          scale: Optional[float] = None):
+                          scale: Optional[float] = None,
+                          window: Optional[int] = None):
     s_n, width, n_heads, hd = q.shape
     nb, bs = k_pool.shape[:2]
     lanes = math.prod(k_pool.shape[2:])
@@ -1074,7 +1128,7 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
             _paged_attn_kernel, block_size=bs, pages=pages,
             kv_heads=kv_heads, groups=groups, tile_cols=tile_cols,
             scale=1.0 / (hd ** 0.5) if scale is None else scale,
-            quant=quant, v_lanes=vd if shared else None),
+            quant=quant, v_lanes=vd if shared else None, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (s_n, kv_heads, width * groups, vd), q.dtype),

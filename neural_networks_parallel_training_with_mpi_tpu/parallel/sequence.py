@@ -47,16 +47,21 @@ def _causal_mask(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
 
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
     """Plain full-sequence attention (B, T, H, Dh) — the single-device
     semantics that ring/ulysses must reproduce; also the dense path of
-    models.transformer."""
+    models.transformer.  ``window`` (causal only): a query sees itself and
+    the ``window - 1`` keys before it."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     scores = _block_scores(q, k, scale)
     if causal:
         t_q, t_k = q.shape[1], k.shape[1]
         mask = _causal_mask(jnp.arange(t_q), jnp.arange(t_k))
+        if window is not None:
+            mask &= (jnp.arange(t_k)[None, :]
+                     > jnp.arange(t_q)[:, None] - window)
         scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -519,10 +524,18 @@ def sequence_sharded_attention(impl: str, q, k, v, *, axis: str = "seq",
                                scale: Optional[float] = None,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
-                               rope_theta: Optional[float] = None
+                               rope_theta: Optional[float] = None,
+                               window: Optional[int] = None
                                ) -> jax.Array:
     """``block_q``/``block_k``: the flash kernels' tiling; None derives it
-    from (T, head_dim, dtype) (ops.pallas_kernels.flash_blocks)."""
+    from (T, head_dim, dtype) (ops.pallas_kernels.flash_blocks).
+    ``window``: a causal sliding window, which only the dense
+    implementation's mask has (``auto`` takes it; any other is refused)."""
+    if window is not None:
+        if impl not in ("auto", "dense") or not causal:
+            raise ValueError(f"a sliding window runs the causal dense "
+                             f"attention; attention={impl!r} has none yet")
+        impl = "dense"
     impl = resolve_attention_impl(impl, q.shape[1], head_dim=q.shape[-1],
                                   dtype=q.dtype)
     _note_resolved(impl, q, block_q, block_k)
@@ -542,7 +555,8 @@ def sequence_sharded_attention(impl: str, q, k, v, *, axis: str = "seq",
     # caller's ``attention`` scope (the device trace is read by both)
     with jax.named_scope(f"attn_{impl}"):
         if impl == "dense":
-            return attention_reference(q, k, v, causal=causal, scale=scale)
+            return attention_reference(q, k, v, causal=causal, scale=scale,
+                                       window=window)
         if impl == "dense_blockwise":
             return attention_dense_blockwise(q, k, v, causal=causal,
                                              scale=scale)

@@ -112,6 +112,7 @@ from __future__ import annotations
 
 import base64
 import collections
+import contextlib
 import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -147,6 +148,14 @@ ATTN_IMPLS = ("auto", "gathered", "fused")
 EXPERT_COUNTERS = ("expert_assignments", "expert_tokens_max",
                    "experts_reached", "decode_ticks_counted",
                    "prefill_expert_assignments", "prefill_chunks_counted")
+
+# cumulative attention counters of a model whose layers are of two kinds
+# (window and full), carried like the expert counters: keys the decode
+# ticks' attention had to read, by kind (sum over decoding streams of
+# ``len`` x full layers, of ``min(len, window)`` x window layers), and the
+# pool blocks those streams' visible keys sat in (table entries x layers)
+ATTENTION_COUNTERS = ("full_keys", "window_keys", "full_blocks_held",
+                      "window_blocks_held")
 
 # block 0 is reserved: pad positions and frozen slots write (and gather)
 # here, so a scatter never needs dynamic masking to be allocation-safe
@@ -401,8 +410,27 @@ def stored_rows(row: Dict[str, Tuple[int, ...]],
     return {n: (-(-f // 128) * 128,) for n, f in flat.items()}
 
 
+def layer_windows(model: Transformer) -> Tuple[Optional[int], ...]:
+    """Each layer's window (None: a full layer), from the model's config."""
+    c = model.cfg
+    return tuple(c.layer_window(i) for i in range(c.n_layers))
+
+
+def window_pool_blocks(window: int, slots: int, block_size: int,
+                       prefill_chunk: int) -> int:
+    """Blocks of a WINDOW layer's pool, the sink included.  A stream holds
+    the pages its next query can still see (``window`` positions lie in at
+    most ``ceil((window - 1) / bs) + 1`` pages) and, while one of its chunks
+    is in flight, that chunk's pages; chunks are dispatched one at a time
+    and trimmed behind the window as soon as they are.  Nothing here grows
+    with ``max_len``."""
+    steady = -(-(window - 1) // block_size) + 1
+    return 1 + slots * steady + -(-prefill_chunk // block_size)
+
+
 def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
-                  quant: bool = False, folded: bool = False):
+                  quant: bool = False, folded: bool = False,
+                  window_blocks: Optional[int] = None):
     """Per-layer paged pools, one per entry of the attention's cache row
     (``model.cache_row()``), each ``(num_blocks, block_size, *row)`` —
     :func:`models.generate.init_kv_cache` with the length axis split into
@@ -411,7 +439,9 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
     the lanes, ``(num_blocks, block_size, kv_heads * head_dim)``: the same
     bytes a block row, the shape the kernel DMAs; and the latent row padded
     with zero lanes to whole lane tiles, ``(num_blocks, block_size, 384)``
-    for a row of 320.  ``quant=True`` (per-head K and V only) stores int8
+    for a row of 320.  A window layer's pool has ``window_blocks`` blocks
+    (:func:`window_pool_blocks`) in place of ``num_blocks``.  ``quant=True``
+    (per-head K and V only) stores int8
     codes plus one f32 scale per (block, offset, head), the identical
     scheme the dense cache uses (scales are per position, so paging cannot
     change the numbers)."""
@@ -429,9 +459,14 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
                  **{f"{n}_scale": jnp.ones(lead + r[:-1], jnp.float32)
                     for n, r in row.items()}}
                 for _ in range(c.n_layers)]
-    return [{n: jnp.zeros(lead + r, c.compute_dtype)
+    windows = layer_windows(model)
+    if any(windows) and window_blocks is None:
+        raise ValueError("a model with window layers needs window_blocks "
+                         "(window_pool_blocks)")
+    return [{n: jnp.zeros(((window_blocks if w else num_blocks), block_size)
+                          + r, c.compute_dtype)
              for n, r in stored.items()}
-            for _ in range(c.n_layers)]
+            for w in windows]
 
 
 @functools.lru_cache(maxsize=8)
@@ -462,13 +497,31 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         raise ValueError(
             "kv_quant stores int8 codes of per-head K and V; latent "
             "attention's cache row has no such scheme yet")
+    windows = layer_windows(model)
+    win = next((w for w in windows if w), None)     # the model's one window
+    two_kinds = win is not None     # window layers: a second table
+    if two_kinds and kv_quant:
+        raise ValueError("kv_quant cannot run a model with window layers "
+                         "(attention_pattern 'L') yet: the int8 page walk "
+                         "has no lower bound")
 
-    def gathered_attention(q, kp, vp, tables, positions, ksp, vsp):
+    def kind_scope(window):
+        """``attn_full`` / ``attn_window`` inside ``attn_core``, for a model
+        whose layers are of several kinds (no scope of the other models'
+        programs moves)."""
+        if not c.has_layer_kinds:
+            return contextlib.nullcontext()
+        return jax.named_scope("attn_window" if window else "attn_full")
+
+    def gathered_attention(q, kp, vp, tables, positions, ksp, vsp,
+                           window=None):
         """Attention over each row's whole table width: ``pool[table]``
         materialised (scope ``paged_gather``), then a full-width masked
         scores-softmax-values reduction (scope ``attn_core``).  Same
         values, same order, as the dense cache's (B, T, kv, hd) slab.
-        ``ksp``/``vsp`` are the int8 scale pools or None."""
+        ``ksp``/``vsp`` are the int8 scale pools or None; ``window`` masks
+        the keys behind it as well (their table entries may be the
+        sink's)."""
         b, w = positions.shape
         with jax.named_scope("paged_gather"):
             # (B, MB, bs, kv, hd) -> (B, T_cap, kv, hd), positions in
@@ -478,10 +531,13 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             if ksp is not None:
                 gks = ksp[tables].reshape(b, t_cap, c.kv_heads)
                 gvs = vsp[tables].reshape(b, t_cap, c.kv_heads)
-        with jax.named_scope("attn_core"):
+        with jax.named_scope("attn_core"), kind_scope(window):
             scale = 1.0 / jnp.sqrt(jnp.asarray(c.head_dim, jnp.float32))
             mask = (jnp.arange(t_cap)[None, None, :]
                     <= positions[:, :, None])           # (B, W, T_cap)
+            if window is not None:
+                mask &= (jnp.arange(t_cap)[None, None, :]
+                         > positions[:, :, None] - window)
             if c.kv_heads == c.n_heads:
                 logits = jnp.einsum("bqhd,bkhd->bhqk",
                                     q.astype(jnp.float32),
@@ -521,12 +577,16 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         return blk, off
 
     def dense_attention_half(mods, layer_params, pool, tables, starts, x,
-                             valid, lengths):
+                             valid, lengths, layer=0):
         """``x + Attn(LN(x))`` with per-head K and V rows: the fused qkv
         projection, K/V scattered into the pools, attention gathered or
         fused.  Mirrors ``models.generate._block_chunk`` (the pinned dense
-        math) with the cache axis split into (block, offset)."""
+        math) with the cache axis split into (block, offset).  ``layer``
+        says which kind of layer this is where a model has several: whether
+        q and k are rotated, and a window layer's lower bound (``tables``
+        is then the window kind's)."""
         quant = "k_scale" in pool
+        window = windows[layer]
         # named scopes as in ``Transformer._block`` (the device trace is
         # read by them): ``attention`` is the work, the inner scopes say
         # what implements it here
@@ -535,9 +595,11 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             qkv = mods["qkv"].apply(layer_params["qkv"], h)
             b, w, _ = qkv.shape
             q, k, v = split_qkv(c, qkv)  # q: (B,W,H,hd); k/v: (B,W,KV,hd)
+            if c.qk_norm:
+                q, k = model.qk_normed(mods, layer_params, q, k)
         with jax.named_scope("attention"):
             positions = starts[:, None] + jnp.arange(w)[None, :]  # (B, W)
-            if c.pos_encoding == "rope":
+            if c.layer_rotary(layer):
                 from ..ops.rope import rope_rotate
 
                 q = rope_rotate(q, positions, c.rope_theta)
@@ -564,26 +626,27 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 # dequantize on load.
                 pages, cols = paged_tiles(
                     bs, c.kv_heads * c.head_dim, w, c.n_heads // c.kv_heads,
-                    mb, quant=quant)
+                    mb, quant=quant, window=window)
                 ledger_lib.note("attention", {
                     "impl": "paged", "pages": pages, "tile_cols": cols,
                     "block_size": bs})
-                with jax.named_scope("attn_core"), \
+                with jax.named_scope("attn_core"), kind_scope(window), \
                         jax.named_scope("paged_attention_fused"):
                     out = paged_attention(
                         q, new_kp, new_vp, tables, lengths, starts,
                         k_scale=new_ksp if quant else None,
                         v_scale=new_vsp if quant else None,
-                        pages=pages, tile_cols=cols).astype(x.dtype)
+                        pages=pages, tile_cols=cols,
+                        window=window).astype(x.dtype)
             else:
                 ledger_lib.note("attention", {"impl": "gathered",
                                               "keys": t_cap})
                 out = gathered_attention(
                     q, new_kp, new_vp, tables, positions,
                     new_ksp if quant else None,
-                    new_vsp if quant else None).astype(x.dtype)
+                    new_vsp if quant else None, window).astype(x.dtype)
         with jax.named_scope("attn_proj"):
-            out = out.reshape(b, w, c.d_model)
+            out = out.reshape(b, w, c.q_dim)
             x = x + mods["attn_out"].apply(layer_params["attn_out"], out)
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
@@ -663,8 +726,8 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         return x, {"latent": new_lp}
 
     def block_fwd(layer_params, pool, tables, starts, x, valid, lengths,
-                  decode):
-        """One transformer block over a chunk ``x`` (B, W, D) whose rows
+                  decode, layer=0):
+        """Transformer block ``layer`` over a chunk ``x`` (B, W, D) whose rows
         sit at per-row start positions: the attention half of the model's
         kind writes the chunk's cache rows into the paged pool and reads
         the streams' rows back through the block tables, then the
@@ -674,28 +737,33 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         like the tables so length churn never recompiles.  ``decode``
         (static) picks latent attention's absorbed form.  Returns (x, the
         new pool, the held experts' load (count,) int32 under routing
-        without drops, else None)."""
-        mods = model._block_modules()
+        without drops, else None).  Where the model has window layers
+        ``tables`` is the pair (full kind's, window kind's), and the layer
+        takes its own."""
+        mods = model._block_modules(layer)
+        if two_kinds:
+            tables = tables[1 if windows[layer] else 0]
         if latent:
             x, new_pool = latent_attention_half(
                 mods, layer_params, pool, tables, starts, x, valid, lengths,
                 decode)
         else:
             x, new_pool = dense_attention_half(
-                mods, layer_params, pool, tables, starts, x, valid, lengths)
+                mods, layer_params, pool, tables, starts, x, valid, lengths,
+                layer)
         load = None
         with jax.named_scope("ffn"):
             h = mods["ln2"].apply(layer_params["ln2"], x)
-            if c.moe_dropless:
+            if "moe" not in mods:
+                ff = model._ffn(mods, layer_params, h)
+            elif c.moe_dropless:
                 # pad columns and idle lanes reach no expert (and read
                 # none): the load counts what the traffic asked for
                 live = valid[None, :] & (lengths > 0)[:, None]
                 ff, _, load = mods["moe"].apply(
                     layer_params["moe"], h, mask=live, return_load=True)
-            elif c.moe_experts > 0:
-                ff, _ = mods["moe"].apply(layer_params["moe"], h)
             else:
-                ff = model._ffn(mods, layer_params, h)
+                ff, _ = mods["moe"].apply(layer_params["moe"], h)
             x = x + ff.astype(x.dtype)
         return x, new_pool, load
 
@@ -708,10 +776,21 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         emb_pos = jnp.minimum(starts[:, None] + jnp.arange(w)[None, :],
                               c.max_seq_len - 1)
         x = model.embed(params, ids, emb_pos)
+        if two_kinds and attn_impl == "fused":
+            # the first note of a program wins: name both kinds' walks
+            lanes, groups = c.kv_heads * c.head_dim, c.n_heads // c.kv_heads
+            fp, fc = paged_tiles(bs, lanes, w, groups, mb)
+            wp, wc = paged_tiles(bs, lanes, w, groups, mb, window=win)
+            ledger_lib.note("attention", {
+                "impl": "paged", "pages": fp, "tile_cols": fc,
+                "block_size": bs,
+                "window": {"impl": "paged", "window": win, "pages": wp,
+                           "tile_cols": wc}})
         new_pools, loads = [], []
-        for layer_params, pool in zip(params["blocks"], pools):
+        for i, (layer_params, pool) in enumerate(zip(params["blocks"],
+                                                     pools)):
             x, pool, load = block_fwd(layer_params, pool, tables, starts, x,
-                                      valid, lengths, decode)
+                                      valid, lengths, decode, i)
             new_pools.append(pool)
             loads.append(load)
         return model.head_logits(params, x), new_pools, loads
@@ -721,16 +800,34 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         into the cumulative counters the server carries on the device
         (``EXPERT_COUNTERS``); ``stats`` is ``{}`` for a model without
         routing without drops, and stays so."""
-        if not stats:
+        if "experts" not in stats:
             return stats
-        load = jnp.stack(loads)                               # (L, count)
+        # (L, count); a leading dense layer of such a model has no load
+        load = jnp.stack([ld for ld in loads if ld is not None])
         assigned, busiest = load.sum(), load.max(axis=1).sum()
         zero, one = jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32)
         if decode:
             step = [assigned, busiest, (load > 0).sum(), one, zero, zero]
         else:
             step = [assigned, busiest, zero, zero, assigned, one]
-        return {"experts": stats["experts"]
+        return {**stats, "experts": stats["experts"]
+                + jnp.stack(step).astype(jnp.int32)}
+
+    def count_keys(stats, lengths):
+        """Fold one decode tick's attention into ``ATTENTION_COUNTERS``
+        (a model with window layers; nothing otherwise): ``lengths`` (S,)
+        are the decoding streams' key counts, 0 for a lane that idles."""
+        if "attention" not in stats:
+            return stats
+        n_win = sum(1 for w_ in windows if w_)
+        n_full = len(windows) - n_win
+        seen = jnp.minimum(lengths, win)
+        last = jnp.maximum(lengths - 1, 0) // bs
+        first = (lengths - seen) // bs      # page of the oldest visible key
+        step = [n_full * lengths.sum(), n_win * seen.sum(),
+                n_full * jnp.where(lengths > 0, last + 1, 0).sum(),
+                n_win * jnp.where(lengths > 0, last - first + 1, 0).sum()]
+        return {**stats, "attention": stats["attention"]
                 + jnp.stack(step).astype(jnp.int32)}
 
     def prefill(params, pools, stats, table, start, chunk, true_w):
@@ -768,7 +865,8 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         pos = jnp.where(active, jnp.minimum(pos + 1, cap), pos)
         # the counters come last: the dense programs' results keep their
         # places (and their compile-cache keys)
-        return new_pools, tokens, pos, key, count_load(stats, loads, True)
+        stats = count_keys(count_load(stats, loads, True), lengths)
+        return new_pools, tokens, pos, key, stats
 
     def cow(pools, src, dst):
         """Copy-on-write fork: duplicate block row ``src`` into the
@@ -831,6 +929,10 @@ class _Stream:
     chain_key: Any = None
     registered_tokens: int = 0
     shared_at_admit: int = 0          # matched prefix tokens (stats)
+    # a model with window layers: the window kind's blocks, by the page of
+    # the stream they hold (only pages the next query can still see, and
+    # the chunk in flight)
+    window_pages: Dict[int, int] = field(default_factory=dict)
 
 
 class PagedDecodeServer:
@@ -845,7 +947,7 @@ class PagedDecodeServer:
                  temperature: float = 0.0, top_k: int = 0,
                  top_p: float = 1.0, seed: int = 0,
                  kv_quant: bool = False, attn_impl: str = "auto",
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, prefill_chunk: int = 32):
         c = model.cfg
         self.model, self.params = model, params
         self.slots = int(slots)
@@ -886,18 +988,51 @@ class PagedDecodeServer:
          self._import_fn) = _paged_programs(
             model, self.block_size, self.max_blocks, *self._sampling,
             self.kv_quant, self.attn_impl)
+        # two kinds of cache in one manager: a full layer's pool is what it
+        # was (``num_blocks``, one table a stream, grown on demand); a
+        # window layer's holds, for each stream, the pages its next query
+        # can still see and the chunk in flight, through an allocator and
+        # a table of its own.  Its size follows from the slots, the block
+        # size, the window and the widest chunk (``prefill_chunk``:
+        # ``prefill_step`` takes no wider one), never from ``max_len``, and
+        # by that count it can never be what refuses or evicts a stream.
+        self.window = next((w for w in layer_windows(model) if w), None)
+        self.prefill_chunk = max(1, int(prefill_chunk))
+        self.window_allocator: Optional[BlockAllocator] = None
+        window_blocks = None
+        if self.window is not None:
+            if self.prefix_cache:
+                raise ValueError(
+                    "prefix_cache cannot serve a model with window layers "
+                    "(attention_pattern 'L') yet: a window layer keeps no "
+                    "block of a prefix to share")
+            window_blocks = window_pool_blocks(
+                self.window, self.slots, self.block_size,
+                self.prefill_chunk)
+            self.window_allocator = BlockAllocator(window_blocks)
+            self.window_tables = np.zeros((self.slots, self.max_blocks),
+                                          np.int32)
         self.pools = init_paged_kv(model, self.num_blocks,
                                    self.block_size, quant=self.kv_quant,
-                                   folded=self.attn_impl == "fused")
+                                   folded=self.attn_impl == "fused",
+                                   window_blocks=window_blocks)
         # expert-load counters (EXPERT_COUNTERS): cumulative on the device
         # modulo 2**32, folded into host integers at every fetch; {} for a
         # model that does not route without drops
         self.stats = ({"experts": jnp.zeros((len(EXPERT_COUNTERS),),
                                             jnp.int32)}
                       if c.moe_dropless else {})
-        self._experts_seen = np.zeros((len(EXPERT_COUNTERS),), np.int64)
         self.expert_counters: Dict[str, int] = (
             dict.fromkeys(EXPERT_COUNTERS, 0) if c.moe_dropless else {})
+        # attention counters by kind of layer (ATTENTION_COUNTERS), carried
+        # and folded the same way; {} for a model without window layers
+        self.attention_counters: Dict[str, int] = {}
+        if self.window is not None:
+            self.stats["attention"] = jnp.zeros(
+                (len(ATTENTION_COUNTERS),), jnp.int32)
+            self.attention_counters = dict.fromkeys(ATTENTION_COUNTERS, 0)
+        self._stats_seen = {k: np.zeros(v.shape, np.int64)
+                            for k, v in self.stats.items()}
         self.tokens = jnp.zeros((self.slots, self.t_cap), jnp.int32)
         self.pos = jnp.zeros((self.slots,), jnp.int32)
         self.tables = np.zeros((self.slots, self.max_blocks), np.int32)
@@ -931,6 +1066,55 @@ class PagedDecodeServer:
     def block_utilization(self) -> float:
         cap = self.allocator.capacity
         return self.allocator.used_blocks / cap if cap else 0.0
+
+    def assert_drained(self) -> None:
+        """Every block of every kind back in its allocator."""
+        self.allocator.assert_drained()
+        if self.window_allocator is not None:
+            self.window_allocator.assert_drained()
+
+    # ---- the window kind's blocks ---------------------------------------
+    def _window_span(self, st: _Stream, slot: int, first: int,
+                     last: int) -> None:
+        """Make the stream's window-kind table hold exactly the pages of
+        positions ``first - window + 1 .. last``: what queries at ``first
+        .. last`` can see and will write.  Pages behind are released (their
+        table entries back to the sink) BEFORE the new ones are taken, so a
+        decoding stream never holds more than the window's pages, and the
+        pool's size (:func:`window_pool_blocks`) always covers the ask."""
+        lo = max(first - self.window + 1, 0) // self.block_size
+        hi = last // self.block_size
+        held = st.window_pages          # always a run of neighbouring pages
+        if len(held) == hi - lo + 1 and lo in held and hi in held:
+            return                      # most decode ticks: nothing moves
+        behind = [pg for pg in st.window_pages if pg < lo]
+        if behind:
+            self.window_allocator.release(
+                [st.window_pages.pop(pg) for pg in behind])
+            self.window_tables[slot, behind] = SINK_BLOCK
+        fresh = [pg for pg in range(lo, hi + 1)
+                 if pg not in st.window_pages]
+        if fresh:
+            got = self.window_allocator.alloc(len(fresh))
+            assert got is not None, (
+                "the window pool is sized for every slot's window and one "
+                "chunk in flight; a chunk wider than prefill_chunk?")
+            st.window_pages.update(zip(fresh, got))
+            self.window_tables[slot, fresh] = got
+
+    def _device_tables(self, rows, keep=None):
+        """The block tables of ``rows`` (a slice or all slots) as the
+        programs take them: one array, or the pair (full kind's, window
+        kind's) for a model with window layers.  ``keep`` (slots,) bool
+        masks the other lanes' rows to the sink.  HOST-side copies: see
+        :meth:`prefill_step`."""
+        def one(t):
+            t = t[rows]
+            return jnp.asarray(t.copy() if keep is None else
+                               np.where(keep[:, None], t, SINK_BLOCK))
+        if self.window is None:
+            return one(self.tables)
+        return one(self.tables), one(self.window_tables)
 
     def keys_accounting(self) -> Dict[str, int]:
         """Key-position accounting for the NEXT decode step, from host
@@ -1155,6 +1339,8 @@ class PagedDecodeServer:
         # prefill itself performs (idempotent — see module docstring)
         self.tables[slot, :] = SINK_BLOCK
         self.tables[slot, :len(blocks)] = blocks
+        if self.window is not None:     # taken chunk by chunk, at prefill
+            self.window_tables[slot, :] = SINK_BLOCK
         row = np.zeros((self.t_cap,), np.int32)
         row[:p] = prompt_ids
         self.tokens = self.tokens.at[slot].set(jnp.asarray(row))
@@ -1196,6 +1382,11 @@ class PagedDecodeServer:
             w = min(int(width), remaining)
             if w < 1:
                 raise ValueError(f"prefill width {width} < 1")
+            if self.window is not None:
+                # the window pool holds one chunk of prefill_chunk at most
+                w = min(w, self.prefill_chunk)
+                self._window_span(st, slot, st.prefilled,
+                                  st.prefilled + w - 1)
             # copy-on-write: the FIRST write past the shared boundary
             # lands here when the matched prefix ended mid-block — fork
             # the borrowed partial block (reserved target, one on-device
@@ -1219,7 +1410,7 @@ class PagedDecodeServer:
             # may alias the numpy buffer (and jnp.array's own copy is an
             # async device op), while the host mutates self.tables /
             # self.active in place before the dispatched program has run
-            args = (jnp.asarray(self.tables[slot:slot + 1].copy()),
+            args = (self._device_tables(slice(slot, slot + 1)),
                     jnp.asarray([st.prefilled], jnp.int32),
                     jnp.asarray(chunk),
                     jnp.asarray(w, jnp.int32))
@@ -1227,6 +1418,10 @@ class PagedDecodeServer:
             logits, self.pools, self.stats = self._prefill_fn(
                 self.params, self.pools, self.stats, *args)
         st.prefilled += w
+        if self.window is not None:
+            # behind the window at once: the pool holds ONE chunk beside
+            # the slots' windows, and the next op may be another stream's
+            self._window_span(st, slot, st.prefilled, st.prefilled - 1)
         self._register_prefix(st, final=st.prefilled >= p)
         if st.prefilled < p:
             return False
@@ -1370,6 +1565,10 @@ class PagedDecodeServer:
             st.fork_pending = None
         st.blocks = []
         self.allocator.release(rel)
+        if self.window is not None:
+            self.window_tables[slot, :] = SINK_BLOCK
+            self.window_allocator.release(list(st.window_pages.values()))
+            st.window_pages = {}
         self.active[slot] = False
 
     def evict(self, rid: int):
@@ -1387,6 +1586,13 @@ class PagedDecodeServer:
         return list(st.prompt), st.max_new
 
     # ---- block handoff (disaggregated prefill/decode) -----------------
+    def _refuse_window(self, who: str) -> None:
+        if self.window is not None:
+            raise ValueError(
+                f"{who} cannot hand off a model with window layers "
+                "(attention_pattern 'L') yet: the payload carries one kind "
+                "of block")
+
     def _handoff_geometry(self) -> Dict[str, Any]:
         """The pool facts both sides of a handoff must agree on byte-for-
         byte.  Everything here is static server config, so a mismatch is
@@ -1414,6 +1620,7 @@ class PagedDecodeServer:
         sampled token's K/V is written by its decode step, which runs on
         the importing side) — so exactly ``blocks_for(p)`` block rows
         travel.  Raises for a stream whose prefill is not complete."""
+        self._refuse_window("export_stream")
         st = self._streams[rid]
         slot = self._slot_of[rid]
         p = len(st.prompt)
@@ -1460,6 +1667,7 @@ class PagedDecodeServer:
         are unavailable (nothing consumed — the router retries or falls
         back).  Raises on geometry mismatch or a request this server
         could never hold, mirroring :meth:`try_admit`'s contract."""
+        self._refuse_window("import_stream")
         geom = dict(payload["geom"])
         mine = self._handoff_geometry()
         if geom != mine:
@@ -1569,15 +1777,16 @@ class PagedDecodeServer:
                     assert (int(self._pos_host[slot]) // self.block_size
                             >= st.n_shared), (
                         f"decode would write shared block of rid={rid}")
+                    if self.window is not None:
+                        at = int(self._pos_host[slot])
+                        self._window_span(st, slot, at, at)
             # non-active lanes (free, finished, MID-PREFILL) see an
             # all-sink table: their writes land in the sink and their
             # reads gather garbage that is discarded — so live blocks are
             # written ONLY by prefill chunks and active decode lanes, and
             # parity never rests on a frozen lane recomputing
             # bitwise-identical K/V under a different batch shape
-            masked = np.where(self.active[:, None], self.tables,
-                              SINK_BLOCK)
-            tables = jnp.asarray(masked)
+            tables = self._device_tables(slice(None), keep=self.active)
             active = jnp.asarray(self.active.copy())   # see prefill_step
         with trace_lib.span("decode/submit"):
             (self.pools, self.tokens, self.pos, self.key,
@@ -1602,12 +1811,15 @@ class PagedDecodeServer:
         # counters along: no round trip of their own
         row, stats = jax.device_get((self.tokens[slot], self.stats))
         self._results[rid] = [int(t) for t in np.asarray(row)[:st.target]]
-        if stats:
-            raw = np.asarray(stats["experts"]).astype(np.int64) % (1 << 32)
-            for name, d in zip(EXPERT_COUNTERS,
-                               (raw - self._experts_seen) % (1 << 32)):
-                self.expert_counters[name] += int(d)
-            self._experts_seen = raw
+        for key, names, into in (
+                ("experts", EXPERT_COUNTERS, self.expert_counters),
+                ("attention", ATTENTION_COUNTERS, self.attention_counters)):
+            if key in stats:
+                raw = np.asarray(stats[key]).astype(np.int64) % (1 << 32)
+                for name, d in zip(names,
+                                   (raw - self._stats_seen[key]) % (1 << 32)):
+                    into[name] += int(d)
+                self._stats_seen[key] = raw
         self._release_stream(st, slot)
 
     # ---- results -------------------------------------------------------

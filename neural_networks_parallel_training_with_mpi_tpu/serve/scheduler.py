@@ -486,7 +486,8 @@ class Scheduler:
             block_size=cfg.block_size, max_len=cfg.max_len,
             temperature=cfg.temperature, top_k=cfg.top_k,
             top_p=cfg.top_p, seed=cfg.seed, kv_quant=cfg.kv_quant,
-            attn_impl=cfg.attn_impl, prefix_cache=cfg.prefix_cache)
+            attn_impl=cfg.attn_impl, prefix_cache=cfg.prefix_cache,
+            prefill_chunk=cfg.prefill_chunk)
         self.queue: Deque[Request] = collections.deque()
         self.reqs: Dict[int, Request] = {}      # every request ever seen
         self._srv_rid: Dict[int, int] = {}      # scheduler rid -> server
@@ -522,6 +523,10 @@ class Scheduler:
         # exact as of its tick) for whoever listens to spans
         self.expert_counters: Dict[str, int] = dict(
             self.server.expert_counters)
+        # the same for the attention counters of a model with window and
+        # full layers (paged_kv.ATTENTION_COUNTERS; {} otherwise)
+        self.attention_counters: Dict[str, int] = dict(
+            self.server.attention_counters)
         self.telemetry = _ServeTelemetry(cfg)
         # per-request flow-trace ids must stay unique across the fleet's
         # merged timeline: prefix the scheduler-local rid with this
@@ -649,11 +654,15 @@ class Scheduler:
             with trace_lib.span("retire", tick=self.tick_no) as retire:
                 for srv_rid in finished:
                     done_now.append(self._retire(srv_rid))
-                if finished and self.expert_counters:
+                if finished and (self.expert_counters
+                                 or self.attention_counters):
                     # fresh as of this tick's step: the fetch of a
                     # finished stream brought them
                     self.expert_counters = dict(self.server.expert_counters)
+                    self.attention_counters = dict(
+                        self.server.attention_counters)
                     retire.attrs.update(self.expert_counters)
+                    retire.attrs.update(self.attention_counters)
         self.telemetry.on_tick(self.tick_no, self._snapshot())
         self._gap_wall = time.time()
         self._gap_state = ("sched_bubble" if self._srv_rid
@@ -838,7 +847,7 @@ class Scheduler:
         worker whose control plane died (stdin EOF) — so "exited
         cleanly" always MEANS "leaked no blocks"."""
         out = self.drain()
-        self.server.allocator.assert_drained()
+        self.server.assert_drained()
         return out
 
     # ---- internals -----------------------------------------------------
@@ -1034,6 +1043,7 @@ class Scheduler:
             "padded_keys": self.padded_keys,
             "kernel_keys": self.kernel_keys,
             **self.expert_counters,
+            **self.attention_counters,
             "attended_ratio": (
                 round(self.attended_keys / self.padded_keys, 4)
                 if self.padded_keys else None),

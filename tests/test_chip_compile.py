@@ -420,3 +420,120 @@ def test_latent_routed_prefill_chunk(spec, latent_programs, kernels_compiled):
     assert "while" in text and "moe_experts" in text
     assert "paged_gather" in text and not _latent_pool_moved(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---- window and full layers over two kinds of cache, at the published widths -
+
+WIDE = dict(slots=48, heads=64, kv_heads=8, head_dim=128, window=128,
+            block_size=16, max_blocks=1088, num_blocks=48 * 1088 + 1,
+            chunk=1024)
+
+
+@pytest.mark.parametrize("width,window", [(1, None), (1, 128), (1024, None),
+                                          (1024, 128)],
+                         ids=["decode", "decode_window", "chunk",
+                              "chunk_window"])
+def test_paged_attention_at_the_wide_rows_both_walks(spec, width, window):
+    """The kernel at ``kexaone-236b-ep8-serve-longmix``'s shapes: 64 query
+    heads over 8 KV heads of 128 (a 1024-lane row, a 32 KB page a pool), 48
+    tables of 1088 blocks (209 KB of scalar prefetch), the full walk and the
+    walk bounded below by the window, with the tilings the table holds."""
+    c = WIDE
+    s = c["slots"] if width == 1 else 1
+    lanes = c["kv_heads"] * c["head_dim"]
+    args = [spec((s, width, c["heads"], c["head_dim"]), BF16),
+            spec((c["num_blocks"], 16, lanes), BF16),
+            spec((c["num_blocks"], 16, lanes), BF16),
+            spec((s, c["max_blocks"]), jnp.int32), spec((s,), jnp.int32),
+            spec((s,), jnp.int32)]
+
+    def fn(q, kp, vp, tables, lens, starts):
+        return paged_attention(q, kp, vp, tables, lens, starts,
+                               window=window, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+@pytest.fixture(scope="module")
+def kinds_programs(spec):
+    """The paged server's programs over 2 layers of the model with window and
+    full layers at its published widths (d 6144, 64 heads over 8 KV heads of
+    128, the per-head norm, a dense layer of width 18432 with window
+    attention, then a sparse layer with full attention without positions:
+    128 experts of width 2048 of which 16 are held, sigmoid top 8) and the
+    cell's geometry; ``auto`` resolved as on a TPU."""
+    from unittest import mock
+
+    c = WIDE
+    model = Transformer(TransformerConfig(
+        vocab_size=19200, max_seq_len=262144, n_layers=2, d_model=6144,
+        n_heads=c["heads"], n_kv_heads=c["kv_heads"],
+        head_width=c["head_dim"], d_ff=2048, activation="swiglu",
+        pos_encoding="rope", rope_theta=1e6, norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, qk_norm=True, attention_pattern="LG",
+        sliding_window=c["window"], rope_global=False, moe_experts=128,
+        moe_top_k=8, moe_dropless=True, moe_experts_held=(0, 16),
+        moe_shared_ff=2048, moe_score="sigmoid", moe_routed_scale=2.5,
+        moe_first_dense=1, dense_ff=18432, param_dtype=BF16,
+        compute_dtype=BF16))
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    blocks = paged_kv.window_pool_blocks(c["window"], c["slots"],
+                                         c["block_size"], c["chunk"])
+    assert blocks == 1 + 48 * 9 + 64
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert paged_kv.resolve_attn_impl(model, "auto") == "fused"
+        pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+            model, c["num_blocks"], c["block_size"], folded=True,
+            window_blocks=blocks)))
+        prefill, step, _, _ = paged_kv._paged_programs(
+            model, c["block_size"], c["max_blocks"], 0.0, 0, 1.0, False,
+            "auto")
+    # the window layer's pool: 497 blocks, 32.6 MB a layer, whatever max_len
+    assert [p["k"].shape for p in pools] == [(497, 16, 1024),
+                                             (c["num_blocks"], 16, 1024)]
+    stats = {"experts": spec((len(paged_kv.EXPERT_COUNTERS),), jnp.int32),
+             "attention": spec((len(paged_kv.ATTENTION_COUNTERS),),
+                               jnp.int32)}
+    return params, pools, stats, prefill, step
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_kinds_programs_walk_both_kinds_of_cache_in_place(
+        spec, kinds_programs, kernels_compiled, program):
+    """The decode tick and a 1024-token chunk compile with the paged kernel
+    in both kinds of layer (two kernels lowered a program: the full walk and
+    the bounded one), each under its kind's scope inside ``attn_core``, the
+    per-head norm under ``qk_norm``; nothing pool-shaped is copied,
+    transposed or gathered, of either kind's pool."""
+    import re
+
+    params, pools, stats, prefill, step = kinds_programs
+    c = WIDE
+    s, mb = c["slots"], c["max_blocks"]
+    tabs = lambda n: (spec((n, mb), jnp.int32), spec((n, mb), jnp.int32))  # noqa: E731
+    if program == "decode":
+        lowered = step.lower(
+            params, pools, stats, spec((s, mb * 16), jnp.int32), tabs(s),
+            spec((s,), jnp.int32), spec((s,), jnp.bool_),
+            spec((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(
+            params, pools, stats, tabs(1), spec((1,), jnp.int32),
+            spec((1, c["chunk"]), jnp.int32), spec((), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") >= 2
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for scope in ("attn_core/attn_window/paged_attention_fused",
+                  "attn_core/attn_full/paged_attention_fused", "qk_norm",
+                  "moe_route", "moe_experts", "paged_scatter"):
+        assert scope in text, scope
+    assert "paged_gather" not in text
+    shaped = r"\[(?:497|52225),16,1024\]"
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \S*" + shaped
+                          + r"\S* (?:copy|transpose|gather|copy-start)\(",
+                          line)]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

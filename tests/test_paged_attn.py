@@ -63,9 +63,11 @@ def _drain(srv, rid, prefill_width=16):
 # kernel vs. plain-numpy reference
 # ---------------------------------------------------------------------------
 
-def _np_reference(q, kp, vp, tables, lens, starts, ks=None, vs=None):
+def _np_reference(q, kp, vp, tables, lens, starts, ks=None, vs=None,
+                  window=None):
     """The paged-attention math in plain numpy: gather each stream's live
-    blocks, truncate to its true length, per-row causal softmax."""
+    blocks, truncate to its true length, per-row causal softmax (over the
+    ``window`` keys up to the row's own where one is given)."""
     s_n, w, n_heads, hd = q.shape
     _, bs, kv_heads, _ = kp.shape
     g = n_heads // kv_heads
@@ -88,7 +90,10 @@ def _np_reference(q, kp, vp, tables, lens, starts, ks=None, vs=None):
                 c = h // g
                 sc = (np.asarray(q, np.float32)[s, col, h]
                       @ k[:, c].T) / np.sqrt(hd)
-                sc = np.where(np.arange(ln) <= q_pos, sc, -1e30)
+                seen = np.arange(ln) <= q_pos
+                if window is not None:
+                    seen &= np.arange(ln) > q_pos - window
+                sc = np.where(seen, sc, -1e30)
                 p = np.exp(sc - sc.max())
                 p /= p.sum()
                 out[s, col, h] = p @ v[:, c]
@@ -227,6 +232,92 @@ def test_kernel_walks_pages_and_row_tiles(case):
         jnp.asarray(vp.reshape(nb, bs, kv * hd)), jnp.asarray(tables),
         jnp.asarray(lens), jnp.asarray(starts), pages=pages, tile_cols=cols))
     np.testing.assert_array_equal(folded, got)
+
+
+# (lens, starts or None for decode, width, window, pages, tile_cols) at 4
+# heads over 2 KV heads of 8 lanes and pages of 4: lengths below, at and
+# above the window, bounds inside a page and on its edge
+_WINDOWS = {
+    # decode: shorter than the window, exactly it, one more, many windows,
+    # and a bound that falls on a page's first and last position
+    "decode-around-the-window": ([3, 8, 9, 40, 12, 15, 0, 33], None, 1, 8,
+                                 None, None),
+    # the same with a window that is no multiple of the page, in steps of
+    # two pages: the walk takes several masked steps
+    "decode-odd-window-short-steps": ([5, 7, 8, 23, 38], None, 1, 7, 2,
+                                      None),
+    # a window inside one page
+    "decode-window-in-a-page": ([2, 3, 4, 17], None, 1, 3, None, None),
+    # a chunk longer than the window, at a start that is no multiple of
+    # the page, in four row tiles: every tile starts its own walk
+    "chunk-longer-than-the-window": ([21 + 16], [21], 16, 8, None, 4),
+    # a chunk at position 0 (the bound clamps at the stream's first page)
+    # beside one far into its stream; pad columns past ``len``
+    "chunk-two-streams-pad": ([9, 30 + 11], [0, 30], 16, 8, 2, 4),
+    # one tile for the whole chunk, a window wider than the chunk
+    "chunk-inside-the-window": ([13 + 8], [13], 8, 12, None, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOWS))
+def test_kernel_with_a_window_walks_from_its_bound(case):
+    """The kernel with ``window`` against the numpy reference and against
+    the gathered reduction's mask.  Table entries before the first page a
+    stream's rows can see point at the SINK (as the window kind's allocator
+    leaves them), whose rows hold huge values: a walk that began too early,
+    or a first page not masked by position, would show."""
+    lens, starts, w, window, pages, cols = _WINDOWS[case]
+    heads, kv, hd, bs, mb = 4, 2, 8, 4, 12
+    rng = np.random.default_rng(len(case))
+    nb = 1 + sum(-(-ln // bs) for ln in lens) + 3
+    kp = rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)
+    vp = rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)
+    lens = np.asarray(lens, np.int32)
+    starts = (np.maximum(lens - 1, 0) if starts is None
+              else np.asarray(starts)).astype(np.int32)
+    tables = _scattered_tables(rng, lens, nb, bs, mb)
+    q = jnp.asarray(rng.normal(size=(len(lens), w, heads, hd)), jnp.float32)
+    want = _np_reference(q, kp, vp, tables, lens, starts, window=window)
+    # behind the window: released, the table back at the sink
+    behind = np.maximum(starts - window + 1, 0) // bs
+    for i, n in enumerate(behind):
+        tables[i, :n] = 0
+    kp[0], vp[0] = 1e4, 1e4
+    got = np.asarray(paged_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+        jnp.asarray(lens), jnp.asarray(starts), pages=pages, tile_cols=cols,
+        window=window))
+    live = np.arange(w)[None, :] < (lens - starts)[:, None]     # (S, W)
+    # (pad columns past ``len`` are the caller's to discard)
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    # a window at least as long as every stream is the walk without one
+    if window >= lens.max():
+        plain = np.asarray(paged_attention(
+            q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lens), jnp.asarray(starts)))
+        np.testing.assert_allclose(got[live], plain[live], rtol=2e-5,
+                                   atol=2e-5)
+
+
+def test_window_walks_have_rows_of_their_own_in_the_tile_table():
+    from neural_networks_parallel_training_with_mpi_tpu.ops import (
+        pallas_kernels,
+    )
+
+    tiles = pallas_kernels.paged_tiles
+    # untimed: one step holds what a tile's rows can see (window 128 at
+    # pages of 16: 8 pages and the one the bound cuts)
+    assert tiles(16, 512, 1, 8, 1088, window=128) == (9, 1)
+    assert tiles(8, 32, 16, 2, 64, window=8, tile_cols=4) == (3, 4)
+    assert tiles(8, 32, 16, 1, 64, window=8, tile_cols=4) == (4, 16)
+    # the cell's row was timed for both walks (PERF.md section 6, PR 33)
+    for kind in ("decode", "chunk", "decode_window", "chunk_window"):
+        assert (16, 1024, kind) in pallas_kernels.PAGED_TILES, kind
+    with pytest.raises(ValueError, match="window"):
+        paged_attention(jnp.zeros((1, 1, 2, 8)), jnp.zeros((3, 4, 2, 8)),
+                        jnp.zeros((3, 4, 2, 8)), jnp.zeros((1, 2), jnp.int32),
+                        jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+                        window=0)
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)],

@@ -284,3 +284,208 @@ def test_rope_paged_exact():
     rid = srv.try_admit(prompt, 8)
     assert _drain(srv, rid, prefill_width=4) == _reference(
         model, params, prompt, 8)
+
+
+# ---------------------------------------------------------------------------
+# two kinds of cache in one manager: window layers beside full ones
+# ---------------------------------------------------------------------------
+
+WINDOW = 8
+
+
+def _window_model(**kw):
+    """Two window layers and a full one (pattern ``LLG``), rotary."""
+    return _model(n_layers=3, n_kv_heads=2, pos_encoding="rope",
+                  max_seq_len=512, attention_pattern="LLG",
+                  sliding_window=WINDOW, **kw)
+
+
+def _pool_bytes(srv):
+    return [sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in pool.values()) for pool in srv.pools]
+
+
+def test_window_pools_do_not_grow_with_max_len():
+    """A window layer's pool follows from slots, block size, window and the
+    widest chunk; a full layer's from ``num_blocks``.  Neither reads
+    ``max_len``, and the window pools are within the bound of slots x
+    (window + prefill_chunk + 2 x block_size) positions."""
+    from neural_networks_parallel_training_with_mpi_tpu.serve import paged_kv
+
+    model = _window_model()
+    params = model.init(prng.init_key(0))
+    sizes = {}
+    for max_len in (64, 256, 512):
+        srv = PagedDecodeServer(model, params, slots=4, num_blocks=200,
+                                block_size=4, max_len=max_len,
+                                prefill_chunk=16)
+        sizes[max_len] = _pool_bytes(srv)
+        assert srv.window_tables.shape == srv.tables.shape
+    assert sizes[64] == sizes[256] == sizes[512]
+    window_layer, full_layer = sizes[64][0], sizes[64][2]
+    assert sizes[64][1] == window_layer < full_layer
+    blocks = paged_kv.window_pool_blocks(WINDOW, 4, 4, 16)
+    assert blocks == 1 + 4 * 3 + 4            # sink, 3 pages a slot, a chunk
+    assert (blocks - 1) * 4 <= 4 * (WINDOW + 16 + 2 * 4)
+    row = 2 * 2 * 8 * 4                       # K and V, 2 heads of 8, f32
+    assert window_layer == blocks * 4 * row
+    assert full_layer == 200 * 4 * row
+    # more slots or a wider chunk do move it; a model without window layers
+    # has one kind of cache and no second allocator
+    assert paged_kv.window_pool_blocks(WINDOW, 8, 4, 16) > blocks
+    assert paged_kv.window_pool_blocks(WINDOW, 4, 4, 64) > blocks
+    plain = PagedDecodeServer(_model(), _model().init(prng.init_key(0)),
+                              slots=2, num_blocks=9, block_size=4)
+    assert plain.window is None and plain.window_allocator is None
+
+
+def test_a_stream_of_fifty_windows_holds_a_windows_blocks():
+    """Prefill in chunks longer than the window, then decode to 50 windows:
+    at no point does the stream hold more window-kind blocks than the bound
+    (a window's pages between programs, a chunk's more while one is in
+    flight), its full-kind blocks grow with its length, and the tokens are
+    the gathered path's whatever the kernel does."""
+    model = _window_model()
+    params = model.init(prng.init_key(0))
+    steady = -(-(WINDOW - 1) // 4) + 1                  # 3 pages
+    outs = {}
+    for impl in ("gathered", "fused"):
+        srv = PagedDecodeServer(model, params, slots=2, num_blocks=120,
+                                block_size=4, max_len=50 * WINDOW + 8,
+                                prefill_chunk=16, attn_impl=impl)
+        rid = srv.try_admit(list(range(1, 41)), 50 * WINDOW - 40)
+        st = srv._streams[rid]
+        held = []
+        while not srv.prefill_step(rid, 16):
+            held.append(len(st.window_pages))
+        while not srv.done(rid):
+            held.append(len(st.window_pages))
+            assert srv.window_allocator.used_blocks == len(st.window_pages)
+            srv.step()
+        assert max(held) <= steady and min(held) >= 1
+        assert srv.allocator.used_blocks == 0       # finished and released
+        outs[impl] = srv.result(rid)
+        assert len(outs[impl]) == 50 * WINDOW
+        srv.assert_drained()
+        # the counters: 1 full layer reads every key, 2 window layers 8
+        ticks = 50 * WINDOW - 40 - 1
+        lens = np.arange(41, 41 + ticks)
+        assert srv.attention_counters["full_keys"] == lens.sum()
+        assert srv.attention_counters["window_keys"] == 2 * WINDOW * ticks
+        assert srv.attention_counters["window_blocks_held"] <= (
+            2 * steady * ticks)
+    assert outs["fused"] == outs["gathered"]
+
+
+def test_both_kinds_drain_after_churn_eviction_and_readmission():
+    """Admission, chunked prefill, growth across blocks, an eviction mid
+    decode and mid prefill, re-admission: both allocators drain, an evicted
+    stream's re-run gives the tokens of an undisturbed run, and the window
+    kind never refuses (its pool covers every slot's window and one chunk
+    whatever the full kind's pressure)."""
+    model = _window_model()
+    params = model.init(prng.init_key(0))
+    prompts = [list(range(3, 40)), [7] * 21, list(range(50, 59))]
+
+    def quiet(prompt, n):
+        srv = PagedDecodeServer(model, params, slots=3, num_blocks=60,
+                                block_size=4, max_len=96, prefill_chunk=16)
+        return _drain(srv, srv.try_admit(prompt, n))
+
+    want = [quiet(p, 12) for p in prompts]
+    srv = PagedDecodeServer(model, params, slots=3, num_blocks=60,
+                            block_size=4, max_len=96, prefill_chunk=16)
+    a, b = (srv.try_admit(p, 12) for p in prompts[:2])
+    while not srv.prefill_step(a, 16):
+        pass
+    srv.prefill_step(b, 16)                     # b is mid prefill
+    for _ in range(5):
+        srv.step()
+    assert srv.evict(a) == (prompts[0], 12)     # mid decode
+    assert srv.evict(b) == (prompts[1], 12)     # mid prefill
+    srv.assert_drained()
+    rids = [srv.try_admit(p, 12) for p in prompts]
+    for rid in rids:
+        while not srv.prefill_step(rid, 16):
+            pass
+        # the chunk is trimmed behind the window as soon as it is sent
+        assert len(srv._streams[rid].window_pages) <= 3
+    while not all(srv.done(r) for r in rids):
+        srv.step()
+    assert [srv.result(r) for r in rids] == want
+    srv.assert_drained()
+    assert srv.window_allocator.free_blocks \
+        == srv.window_allocator.capacity
+    # a chunk wider than the server was built for is cut to it, not refused
+    rid = srv.try_admit(list(range(1, 60)), 2)
+    srv.prefill_step(rid, 64)
+    assert srv._streams[rid].prefilled == 16
+    srv.evict(rid)
+    srv.assert_drained()
+
+
+def test_window_table_churn_never_recompiles():
+    """Both kinds' tables are traced operands: admission, growth, the
+    window's release behind itself, eviction and re-admission re-run one
+    decode program and one prefill program a bucket."""
+    model = _window_model()
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=3, num_blocks=60,
+                            block_size=4, max_len=96, prefill_chunk=16)
+    a = srv.try_admit([1] * 30, 20)
+    while not srv.prefill_step(a, 16):
+        pass
+    for _ in range(4):
+        srv.step()
+    n_step, n_prefill = (srv._step_fn._cache_size(),
+                         srv._prefill_fn._cache_size())
+    b = srv.try_admit([9] * 27, 20)
+    while not srv.prefill_step(b, 16):
+        pass
+    for _ in range(10):                 # several pages released behind
+        srv.step()
+    srv.evict(b)
+    c = srv.try_admit([3] * 25, 6)
+    while not srv.prefill_step(c, 16):
+        pass
+    while not (srv.done(a) and srv.done(c)):
+        srv.step()
+    srv.assert_drained()
+    assert srv._step_fn._cache_size() == n_step
+    assert srv._prefill_fn._cache_size() == n_prefill
+
+
+def test_the_ledger_names_both_kinds_walks():
+    """The compile ledger's event of a serving program of a model with
+    window layers says what implements each kind's attention: the full walk,
+    and beside it the walk bounded by the window with its own tiling."""
+    from neural_networks_parallel_training_with_mpi_tpu.utils import (
+        compile_ledger,
+    )
+
+    model = _window_model()
+    params = model.init(prng.init_key(0))
+    led = compile_ledger.Ledger(None)
+    compile_ledger.install(led)
+    try:
+        # a geometry no other test of this file uses: the programs are new
+        srv = PagedDecodeServer(model, params, slots=2, num_blocks=30,
+                                block_size=4, max_len=56, prefill_chunk=16,
+                                attn_impl="fused")
+        _drain(srv, srv.try_admit([5] * 20, 6))
+        gathered = PagedDecodeServer(model, params, slots=2, num_blocks=30,
+                                     block_size=4, max_len=56,
+                                     prefill_chunk=16, attn_impl="gathered")
+        _drain(gathered, gathered.try_admit([5] * 20, 6))
+    finally:
+        compile_ledger.install(None)
+    decode = led.events_for("serve_decode[bs4x14/fused]")
+    assert len(decode) == 1 and decode[0]["attention"] == {
+        "impl": "paged", "pages": 14, "tile_cols": 1, "block_size": 4,
+        "window": {"impl": "paged", "window": WINDOW, "pages": 3,
+                   "tile_cols": 1}}
+    prefill = led.events_for("serve_prefill[bs4x14/fused]")
+    assert prefill and all(e["attention"]["window"]["window"] == WINDOW
+                           for e in prefill)
+    assert led.events_for("serve_decode[bs4x14/gathered]")[0]["attention"] \
+        == {"impl": "gathered", "keys": 56}

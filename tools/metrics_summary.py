@@ -167,7 +167,9 @@ def summarize(records: List[Dict[str, Any]],
         # prefix-cache hit/fork/eviction counters
         for key in ("admitted", "rejected", "evicted", "completed",
                     "tokens_out", "attended_keys", "padded_keys",
-                    "attended_ratio", "walked_keys_share", "prefix_hits",
+                    "attended_ratio", "walked_keys_share", "full_keys",
+                    "window_keys", "full_blocks_held",
+                    "window_blocks_held", "prefix_hits",
                     "prefix_misses",
                     "prefix_hit_tokens", "prefix_hit_rate",
                     "shared_blocks", "cow_forks", "cache_evictions",
@@ -273,6 +275,13 @@ def serving_lines(summary: Dict[str, Any]) -> List[str]:
                 f"  walked keys share: {st['walked_keys_share']:.3f} of "
                 "the padded width (1.0 = gathered; below it the paged "
                 "kernel ran)")
+        if "window_keys" in st:
+            lines.append(
+                f"  keys read by kind of layer: {st.get('full_keys')} full"
+                f" / {st['window_keys']} window; blocks held "
+                f"{st.get('full_blocks_held')} / "
+                f"{st.get('window_blocks_held')} (decode ticks, summed "
+                "over layers)")
         if "prefix_hits" in st:
             rate = st.get("prefix_hit_rate")
             lines.append(

@@ -2,9 +2,9 @@
 """Times the paged-attention kernel's tilings on the chip.
 
     chiprun --chips 1 -- python tools/paged_attn_timing.py [--quick]
-        [--rows per_head,latent]
+        [--rows per_head,latent,wide]
 
-Two rows (``--rows``, default both).  ``per_head``: at ``sc2-3b-serve-code``'s shapes (24 query heads over 2 KV heads of 128,
+Three rows (``--rows``, default all).  ``per_head``: at ``sc2-3b-serve-code``'s shapes (24 query heads over 2 KV heads of 128,
 bf16 pools of 2305 blocks of 16 with the heads folded into the lanes, 256
 blocks a table): the decode step over 16 streams whose lengths are drawn
 like the cell's (mean about 800), the same at the table's full width (the
@@ -26,7 +26,12 @@ shapes (32 query heads against ONE row that is key and value: 256 lanes of
 over 32 streams whose lengths are drawn like that cell's (prompts lognormal
 around 2048, mean about 2.7 k), at the table's full width of 8704, and the
 scatter of a 1024-token chunk's rows into the stored layout (PERF.md section
-6, PR 32).  Results go to ``chiprun_out/paged_attn_timing.json``
+6, PR 32).  ``wide``: at ``kexaone-236b-ep8-serve-longmix``'s shapes (64 query
+heads over 8 KV heads of 128: a 1024-lane row, a 32 KB page a pool; 48
+streams whose lengths are drawn like that cell's, mean about 5 k; 1088
+blocks a table), the decode step and a 1024-token chunk, each as the FULL
+walk and as the walk bounded below by a window of 128 (PERF.md section 6,
+PR 33).  Results go to ``chiprun_out/paged_attn_timing.json``
 and, one JSON line a row, to standard output.  There is no CPU path.
 """
 
@@ -185,13 +190,77 @@ def time_latent(args, rng, emit, tables_for):
          ms=1e3 * (time.perf_counter() - t) / 50)
 
 
+def time_wide(args, rng, emit, tables_for):
+    """The per-head row of 1024 lanes at ``kexaone-236b-ep8-serve-longmix``'s
+    shapes: the full walk and the walk under a window of 128, decode and a
+    1024-token chunk."""
+    heads, kv, hd, nb, mb, s_n, win = 64, 8, 128, 52225, 1088, 48, 128
+    lanes = kv * hd
+    # 1.7 GB a pool: made on the device and handed to the programs as
+    # arguments (a closure would bake each into every program as a constant)
+    kp, vp = (jax.random.normal(jax.random.PRNGKey(i), (nb, BS, lanes),
+                                jnp.bfloat16) for i in (1, 2))
+    prompts = np.clip(rng.lognormal(np.log(4096), 0.7, s_n), 1024, 16384)
+    lens = (prompts + rng.uniform(0, 512, s_n)).astype(np.int32)
+    tables, lens_j = tables_for(lens, nb, mb), jnp.asarray(lens)
+    q = jnp.asarray(rng.normal(size=(s_n, 1, heads, hd)), jnp.bfloat16)
+    row = 2 * lanes * 2                     # K and V bytes of one key
+
+    def walk(x, k_, v_, t, ln, st, **kw):
+        return paged_attention(x, k_, v_, t, ln, st, **kw)
+
+    def timed_or_refused(fn, a, reps):
+        """ms, or None where the chip's compiler refuses the tiling (a row
+        tile of 1024 rows does not fit VMEM beside its landing buffers)."""
+        try:
+            return timed(fn, a, reps)
+        except Exception as e:                              # noqa: BLE001
+            print(f"refused: {str(e)[:160]}", file=sys.stderr)
+            return None
+
+    for pages in ((32, 64) if args.quick else (16, 32, 64, 128)):
+        ms = timed(lambda x, *a, pages=pages: walk(x, *a, pages=pages),
+                   (q, kp, vp, tables, lens_j, lens_j - 1), args.reps)
+        emit(kind="wide_decode", pages=pages, ms=ms,
+             mean_len=float(lens.mean()),
+             live_gb_s=int(lens.sum()) * row / ms / 1e6)
+    for pages in ((9, 16) if args.quick else (3, 5, 9, 12, 16)):
+        ms = timed(lambda x, *a, pages=pages: walk(
+            x, *a, pages=pages, window=win),
+            (q, kp, vp, tables, lens_j, lens_j - 1), args.reps)
+        emit(kind="wide_decode_window", pages=pages, ms=ms, window=win,
+             live_gb_s=int(np.minimum(lens, win).sum()) * row / ms / 1e6)
+    q = jnp.asarray(rng.normal(size=(1, 1024, heads, hd)), jnp.bfloat16)
+    reps = max(4, args.reps // 4)
+    for start in ((4096,) if args.quick else (0, 4096, 12288)):
+        ln = jnp.asarray([start + 1024], jnp.int32)
+        st = jnp.asarray([start], jnp.int32)
+        tbl = tables_for([start + 1024], nb, mb)
+        for pages, cols in (((32, 64), (64, 64)) if args.quick else
+                            ((16, 64), (32, 32), (32, 64), (64, 32),
+                             (64, 64), (32, 128))):
+            ms = timed_or_refused(lambda x, *a, pages=pages, cols=cols: walk(
+                x, *a, pages=pages, tile_cols=cols),
+                (q, kp, vp, tbl, ln, st), reps)
+            emit(kind="wide_chunk", start=start, pages=pages,
+                 tile_cols=cols, ms=ms)
+        for pages, cols in (((13, 64), (17, 128)) if args.quick else
+                            ((6, 32), (11, 32), (7, 64), (13, 64),
+                             (16, 64), (17, 128))):
+            ms = timed_or_refused(lambda x, *a, pages=pages, cols=cols: walk(
+                x, *a, pages=pages, tile_cols=cols, window=win),
+                (q, kp, vp, tbl, ln, st), reps)
+            emit(kind="wide_chunk_window", start=start, pages=pages,
+                 tile_cols=cols, window=win, ms=ms)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=30)
     ap.add_argument("--quick", action="store_true",
                     help="the table's rows and their neighbours only")
     ap.add_argument("--seed", type=int, default=30)
-    ap.add_argument("--rows", default="per_head,latent",
+    ap.add_argument("--rows", default="per_head,latent,wide",
                     help="which cache rows to time")
     args = ap.parse_args(argv)
     dev = jax.devices()[0]
@@ -223,6 +292,8 @@ def main(argv=None) -> int:
         time_per_head(args, rng, emit, tables_for)
     if "latent" in args.rows:
         time_latent(args, rng, emit, tables_for)
+    if "wide" in args.rows:
+        time_wide(args, rng, emit, tables_for)
 
     out = ROOT / "chiprun_out"
     out.mkdir(exist_ok=True)
