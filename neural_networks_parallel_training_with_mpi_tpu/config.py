@@ -304,6 +304,11 @@ class TrainConfig:
     #                    are even
     grad_reduction: str = "global_mean"
     # cross-replica weight-update sharding (arXiv 2004.13336):
+    #   replicated - (default) all-reduce the gradient tree, update the
+    #             whole state on every chip; on a TPU data mesh of more
+    #             than one chip each matrix's all-reduce is its own and
+    #             starts beside the backward pass
+    #             (dp.exchange_overlap_options)
     #   zero1   - flat-buffer form: ravel the whole tree into one padded
     #             f32 buffer sharded over the data axes (shard_map DP /
     #             DP x seq paths)

@@ -428,6 +428,7 @@ class Trainer:
                 lambda: self.model.init(prng.init_key(cfg.seed)))
             self.update_plan = us_lib.plan_updates(
                 dummy, dp.data_axis_size(self.mesh))
+        exchange_options: Dict[str, Any] = {}  # the plain-DP branch's only
         if self.pipeline:
             from ..parallel import pipeline as pp
 
@@ -536,6 +537,12 @@ class Trainer:
                 with_accuracy=(cfg.loss == "cross_entropy"),
                 example_batch=example)
         else:
+            # the replicated update on a TPU data mesh of more than one
+            # chip: each matrix's all-reduce beside the backward pass
+            # (nothing elsewhere).  The options go to the outermost jit:
+            # the step's own, or the scan's under --steps_per_dispatch k
+            if cfg.update_sharding == "replicated":
+                exchange_options = dp.exchange_overlap_options(self.mesh)
             self.train_step = dp.make_train_step(
                 self.model, self.optimizer, self.mesh, loss_name=train_loss,
                 grad_reduction=cfg.grad_reduction,
@@ -543,7 +550,10 @@ class Trainer:
                 update_sharding=cfg.update_sharding,
                 grad_clip=cfg.grad_clip if step_clips else 0.0,
                 with_metrics=self.telemetry_metrics,
-                update_plan=self.update_plan)
+                update_plan=self.update_plan,
+                compiler_options=(exchange_options
+                                  if int(cfg.steps_per_dispatch) <= 1
+                                  else None))
             self.eval_step = dp.make_eval_step(
                 self.model, self.mesh, loss_name=cfg.loss,
                 with_accuracy=(cfg.loss == "cross_entropy"))
@@ -629,7 +639,8 @@ class Trainer:
             # donate the carried state: the caller always discards the old
             # one, and k>1 exists to cut overhead, not add copies
             self.multi_step = ledger_lib.instrument(
-                jax.jit(multi, donate_argnums=0),
+                jax.jit(multi, donate_argnums=0,
+                        compiler_options=exchange_options or None),
                 f"multi_step[{self.layout_tag},k={self.k_dispatch}]")
         # distributed tracing (train/trace.py): install the span tracer
         # + compile ledger for this process.  Validates the flag combo

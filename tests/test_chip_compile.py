@@ -537,3 +537,95 @@ def test_kinds_programs_walk_both_kinds_of_cache_in_place(
                           line)]
     assert not moved, moved
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# --- the data-parallel train step over the four described chips --------------
+
+
+def _entry_all_reduces(compiled_text: str):
+    """``(in line, beside a product)``: the byte sizes of the all-reduces
+    the entry computation runs as instructions of their own, and the number
+    it runs as ``async-collective-start`` fusions (the form in which this
+    compiler puts a collective inside a product; the ``-done`` half is not
+    counted)."""
+    import re
+
+    entry = compiled_text[compiled_text.index("\nENTRY "):]
+    in_line = []
+    for shape in re.findall(r"= (\S+) all-reduce\(", entry):
+        dims = re.match(r"f32\[([\d,]*)\]", shape)
+        n = 1
+        for d in (dims.group(1).split(",") if dims and dims.group(1) else []):
+            n *= int(d)
+        in_line.append(4 * n if dims else 0)   # a tuple: the vectors' one
+    fused = len(re.findall(r"%async-collective-start\S* = .* fusion\(",
+                           entry))
+    return in_line, fused
+
+
+@pytest.mark.parametrize("with_options", [False, True])
+def test_dp4_step_all_reduces_beside_the_products(topo, with_options):
+    """``gpt2m-train-dp4``'s step (gpt2-medium's widths at 2 layers, AdamW,
+    ``--ce_chunk 256``, the flash kernels, 4 sequences a chip) on a data
+    mesh of the four described chips.  As the compiler takes it, the
+    combiner merges the leaves into a few all-reduces that run in line
+    with nothing beside them; under ``dp.exchange_overlap_options`` every
+    matrix keeps an all-reduce of its own and most of them run inside
+    weight-gradient products.  In line stay the leaves whose gradients are
+    made last and the one all-reduce the vectors and scalars share
+    (PERF.md section 6, PR 34)."""
+    from unittest import mock
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from neural_networks_parallel_training_with_mpi_tpu.ops import optim
+    from neural_networks_parallel_training_with_mpi_tpu.parallel import (
+        data_parallel as dp,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.train.state import (
+        TrainState,
+    )
+
+    model = Transformer(TransformerConfig(
+        vocab_size=50257, max_seq_len=1024, n_layers=2, d_model=1024,
+        n_heads=16, d_ff=4096, pos_encoding="learned", ce_chunk=256,
+        attention="auto", param_dtype=jnp.float32, compute_dtype=BF16))
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), dp.DATA_AXES)
+    options = dp.exchange_overlap_options(mesh)
+    assert options    # a TPU data mesh of four
+    opt = optim.adamw(3e-4, weight_decay=0.1)
+    step = dp.make_train_step(
+        model, opt, mesh, loss_name="cross_entropy",
+        compiler_options=options if with_options else None)
+    params = jax.eval_shape(lambda: model.init(prng.init_key(0)))
+
+    def placed(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    whole, rows = NamedSharding(mesh, P()), NamedSharding(mesh,
+                                                          P(dp.DATA_AXES))
+    state = placed(TrainState(step=jax.ShapeDtypeStruct((), jnp.int32),
+                              params=params,
+                              opt_state=jax.eval_shape(opt.init, params),
+                              qstate=()), whole)
+    batch = placed({"x": jax.ShapeDtypeStruct((16, 1024), jnp.int32),
+                    "y": jax.ShapeDtypeStruct((16, 1024), jnp.int32),
+                    "mask": jax.ShapeDtypeStruct((16,), jnp.float32)}, rows)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = step.lower(state, batch)
+    assert "flash_bwd_dkv" in lowered.as_text()
+    in_line, fused = _entry_all_reduces(lowered.compile().as_text())
+    # 2 layers x (qkv, attn_out, ff_in, ff_out) + table, positions, head
+    matrices = [x for x in jax.tree_util.tree_leaves(params)
+                if 4 * x.size >= dp.ALL_REDUCE_COMBINE_BYTES]
+    assert len(matrices) == 11
+    if not with_options:
+        assert fused == 0 and len(in_line) <= 4, (in_line, fused)
+        return
+    big = [b for b in in_line if b >= dp.ALL_REDUCE_COMBINE_BYTES]
+    assert fused >= 6, (in_line, fused)
+    assert len(big) + fused == len(matrices), (in_line, fused)
+    assert len(in_line) - len(big) <= 1, in_line

@@ -78,6 +78,29 @@ def _param_leaves(t):
 # ------------------------------------------------------------- the plan
 
 
+# the one-device train step of _lm_cfg under AdamW, lowered by commit
+# 5672ad5 (PR 33): PR 34's compiler options are for a TPU data mesh of more
+# than one chip, so on a mesh of one ``gpt2m-train-b4``'s program and its
+# compile-cache key stay.  A PR that means to change the plain
+# data-parallel step re-pins this.
+_ONE_DEVICE_STEP_SHA256 = (
+    "a64367fefa364308f18be776d9a927564250c398acf3adebdfe1932d41c7e533")
+
+
+def test_one_device_step_lowers_to_the_parents_text():
+    import hashlib
+
+    c = dataclasses.replace(_lm_cfg("replicated", mesh=MeshConfig(data=1)),
+                            optimizer="adamw")
+    t = Trainer(c, mesh=make_mesh(MeshConfig(data=1),
+                                  devices=jax.devices()[:1]))
+    t.init_state()
+    batch = next(iter(t.loader.epoch(0)))
+    text = t.train_step.lower(t.state, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _ONE_DEVICE_STEP_SHA256
+
+
 def test_plan_largest_dim_and_tiny_fallback():
     params = {"w": jnp.zeros((48, 2048)), "e": jnp.zeros((4096, 16)),
               "b": jnp.zeros((64,)), "s": jnp.zeros(())}
