@@ -214,6 +214,24 @@ class ModelConfig:
     rope_global: bool = True
     moe_first_dense: int = 0
     dense_ff: int = 0
+    # a state-space mixer beside the attention in every block (models/ssm.py;
+    # ssm_heads 0: none), its shapes and the tile of its chunked recurrence,
+    # and the model's fixed multipliers (1: none)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
     # transformer: fused chunked cross-entropy — evaluate LM head + CE
     # ce_chunk tokens at a time under jax.checkpoint so the (B, T, vocab)
     # f32 logits tensor is never materialized (0 = off).  Loss math is
@@ -519,6 +537,14 @@ class TrainConfig:
         return TrainConfig(**d)
 
 
+# the model's fixed multipliers that are one number each (ModelConfig fields
+# and flags of the same names)
+SCALAR_MULTIPLIERS = ("embedding_multiplier", "lm_head_multiplier",
+                      "attention_in_multiplier", "attention_out_multiplier",
+                      "key_multiplier", "ssm_in_multiplier",
+                      "ssm_out_multiplier")
+
+
 def _add_bool_flag(p: argparse.ArgumentParser, name: str, default: bool, help: str) -> None:
     p.add_argument(f"--{name}", dest=name.replace("-", "_"), action="store_true",
                    default=default, help=help)
@@ -813,6 +839,26 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="leading layers with a dense feed-forward of width "
                         "--dense_ff in place of the expert layer")
     p.add_argument("--dense_ff", type=int, default=0)
+    p.add_argument("--ssm_heads", type=int, default=0,
+                   help="heads of a state-space mixer beside the attention "
+                        "in every block (models/ssm.py; 0 = none)")
+    p.add_argument("--ssm_head_dim", type=int, default=0)
+    p.add_argument("--ssm_state", type=int, default=0,
+                   help="the mixer's state a head and channel")
+    p.add_argument("--ssm_groups", type=int, default=1,
+                   help="groups of heads that share the mixer's B and C")
+    p.add_argument("--ssm_conv", type=int, default=4,
+                   help="taps of the mixer's causal convolution")
+    p.add_argument("--ssm_chunk", type=int, default=128,
+                   help="tile of the mixer's chunked recurrence")
+    for name in SCALAR_MULTIPLIERS:
+        p.add_argument(f"--{name}", type=float, default=1.0)
+    p.add_argument("--ssm_multipliers", type=str, default="",
+                   help="five comma-separated factors on the segments "
+                        "z,xs,B,C,dt of the mixer's projection")
+    p.add_argument("--mlp_multipliers", type=str, default="",
+                   help="two comma-separated factors: on the gated "
+                        "feed-forward's gate and on its output")
     p.add_argument("--moe_capacity_factor", type=float, default=None,
                    help="per-expert slot count = ceil(factor * group_tokens "
                         "/ n_experts); overflow tokens fall through residual "
@@ -1161,6 +1207,13 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
         setattr(m, name, getattr(args, name))
     m.moe_experts_held = tuple(int(x) for x in
                                args.moe_experts_held.split(",") if x)
+    for name in ("ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
+                 "ssm_conv", "ssm_chunk", *SCALAR_MULTIPLIERS):
+        setattr(m, name, getattr(args, name))
+    for name in ("ssm_multipliers", "mlp_multipliers"):
+        given = tuple(float(x) for x in getattr(args, name).split(",") if x)
+        if given:
+            setattr(m, name, given)
     if args.ep > 1:
         # expert-sharded MoE: route token slots over the 'expert' axis
         cfg.model.moe_expert_axis = "expert"

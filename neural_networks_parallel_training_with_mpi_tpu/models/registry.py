@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..config import ModelConfig
+from ..config import SCALAR_MULTIPLIERS, ModelConfig
 from .convnet import ConvNet
 from .mlp import MLP
 from .core import Module
@@ -61,6 +61,12 @@ def build_model(cfg: ModelConfig) -> Module:
             sliding_window=cfg.sliding_window,
             rope_global=cfg.rope_global,
             moe_first_dense=cfg.moe_first_dense, dense_ff=cfg.dense_ff,
+            ssm_heads=cfg.ssm_heads, ssm_head_dim=cfg.ssm_head_dim,
+            ssm_state=cfg.ssm_state, ssm_groups=cfg.ssm_groups,
+            ssm_conv=cfg.ssm_conv, ssm_chunk=cfg.ssm_chunk,
+            **{name: getattr(cfg, name) for name in SCALAR_MULTIPLIERS},
+            ssm_multipliers=tuple(cfg.ssm_multipliers),
+            mlp_multipliers=tuple(cfg.mlp_multipliers),
             ce_chunk=cfg.ce_chunk,
             matmul_dtype=cfg.matmul_dtype,
             matmul_skip=tuple(cfg.matmul_skip),

@@ -48,6 +48,13 @@ def repeat_kv(c: "TransformerConfig", kv: jax.Array) -> jax.Array:
     return jnp.repeat(kv, groups, axis=2)
 
 
+def scaled(x: jax.Array, multiplier: float) -> jax.Array:
+    """``x`` times one of the model's fixed multipliers, in ``x``'s type; a
+    multiplier of 1 adds no operation (the programs of a model without
+    multipliers are what they were)."""
+    return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
@@ -198,6 +205,34 @@ class TransformerConfig:
     rope_global: bool = True
     moe_first_dense: int = 0
     dense_ff: int = 0
+    # A state-space mixer beside the attention in every block (models/ssm.py,
+    # Falcon-H1's hybrid block): both read the same normed input and their
+    # outputs are added, ``x + a_out Attn(a_in h) + s_out Mixer(s_in h)``.
+    # ``ssm_heads`` 0 is no mixer; the other five are its shapes (heads of
+    # ``ssm_head_dim``, ``ssm_groups`` groups of B and C, a state of
+    # ``ssm_state`` a channel, ``ssm_conv`` taps) and ``ssm_chunk`` the tile
+    # of its chunked recurrence (an implementation's, not mathematics).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # The model's fixed multipliers (muP): on the embedded tokens and on the
+    # logits; on the attention's input, on its key projection (before the
+    # rotation) and on its output; on the mixer's input and output, and on
+    # the five segments [z | xs | B | C | dt] of its projection; on the gated
+    # feed-forward's gate and on its output.  All 1: today's models, whose
+    # programs hold no operation for them.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -272,6 +307,41 @@ class TransformerConfig:
                     f"(the window is in its mask); attention="
                     f"{self.attention!r} has no window yet")
 
+        if self.has_mixer:
+            if min(self.ssm_head_dim, self.ssm_state, self.ssm_groups,
+                   self.ssm_conv, self.ssm_chunk) < 1:
+                raise ValueError(
+                    "a mixer (ssm_heads) needs ssm_head_dim, ssm_state, "
+                    "ssm_groups, ssm_conv and ssm_chunk of at least 1")
+            if self.attention_kind != "mha" or self.matmul_dtype != "bf16":
+                raise ValueError(
+                    "the mixer sits beside the fused-qkv attention and "
+                    "outside the quantized-matmul seam: attention_kind "
+                    "must be 'mha' and matmul_dtype 'bf16'")
+            if self.attention not in ("auto", "dense", "flash"):
+                raise ValueError(
+                    f"the mixer's recurrence runs over a whole sequence; "
+                    f"attention={self.attention!r} shards it")
+        if (tuple(self.mlp_multipliers) != (1.0, 1.0)
+                and self.activation != "swiglu"):
+            raise ValueError("mlp_multipliers scale the gate and the output "
+                             "of the gated feed-forward (activation="
+                             "'swiglu')")
+
+    # ---- a mixer beside the attention, and the multipliers ---------------
+    @property
+    def has_mixer(self) -> bool:
+        return self.ssm_heads > 0
+
+    @property
+    def has_multipliers(self) -> bool:
+        return any(m != 1.0 for m in (
+            self.embedding_multiplier, self.lm_head_multiplier,
+            self.attention_in_multiplier, self.attention_out_multiplier,
+            self.key_multiplier, self.ssm_in_multiplier,
+            self.ssm_out_multiplier, *self.ssm_multipliers,
+            *self.mlp_multipliers))
+
     # ---- layers that are not all alike ---------------------------------
     @property
     def has_layer_kinds(self) -> bool:
@@ -302,7 +372,11 @@ class TransformerConfig:
              self.head_width is not None),
             ("the per-head norm of q and k (qk_norm)", self.qk_norm),
             ("layers of several kinds (attention_pattern / "
-             "moe_first_dense)", self.has_layer_kinds))
+             "moe_first_dense)", self.has_layer_kinds),
+            ("recurrent state: a state-space mixer beside the attention "
+             "(ssm_heads)", self.has_mixer),
+            ("the model's multipliers (embedding_multiplier, "
+             "key_multiplier, ...)", self.has_multipliers))
             if on]
         if kinds:
             raise ValueError(f"{who} cannot run a block with "
@@ -369,6 +443,14 @@ class Transformer(Module):
             return self._block_modules()["attn"].cache_row()
         return {"k": (c.kv_heads, c.head_dim), "v": (c.kv_heads, c.head_dim)}
 
+    def state_row(self):
+        """What one STREAM holds in one layer beside its cache rows: store
+        name -> (shape, type); empty for a model without a mixer.  The paged
+        server keeps it in a second store, one row a slot (models/ssm.py)."""
+        if not self.cfg.has_mixer:
+            return {}
+        return self._block_modules()["ssm"].state_row()
+
     def _block_modules(self, layer: int = 0):
         """The modules of layer ``layer``: the same for every layer unless
         the config gives the layers kinds (``layer_is_moe``; the attention's
@@ -402,6 +484,16 @@ class Transformer(Module):
             if c.qk_norm:
                 mods["q_norm"] = self._norm(c.head_dim)
                 mods["k_norm"] = self._norm(c.head_dim)
+            if c.has_mixer:
+                from .ssm import Mamba2Mixer
+
+                mods["ssm"] = Mamba2Mixer(
+                    c.d_model, c.ssm_heads, c.ssm_head_dim, c.ssm_state,
+                    n_groups=c.ssm_groups, d_conv=c.ssm_conv,
+                    chunk=c.ssm_chunk,
+                    multipliers=tuple(c.ssm_multipliers),
+                    norm_eps=c.norm_eps, param_dtype=c.param_dtype,
+                    compute_dtype=c.compute_dtype)
             mods["ln2"] = self._norm()
         if not c.layer_is_moe(layer):
             self._dense_ffn_modules(mods)
@@ -454,6 +546,28 @@ class Transformer(Module):
                                 matmul_dtype=self._mm("ff_out"),
                                 q_role="ff_out")
 
+    def scaled_qkv(self, mods, params, h: jax.Array, **qkw):
+        """The fused qkv projection of ``attention_in_multiplier * h``, split,
+        the keys times ``key_multiplier`` (before any norm or rotation);
+        shared by the training block and the paged server."""
+        c = self.cfg
+        qkv = mods["qkv"].apply(params["qkv"],
+                                scaled(h, c.attention_in_multiplier), **qkw)
+        q, k, v = split_qkv(c, qkv)
+        return q, scaled(k, c.key_multiplier), v
+
+    def mixer_half(self, mods, params, h: jax.Array, run):
+        """``ssm_out_multiplier * Mixer(ssm_in_multiplier * h)`` under scope
+        ``ssm``; ``run(mixer, its params, its input)`` picks the form (a
+        sequence, a prefill chunk, a decode tick) and returns (output, new
+        state or None).  Shared by the training block and the paged
+        server."""
+        c = self.cfg
+        with jax.named_scope("ssm"):
+            y, state = run(mods["ssm"], params["ssm"],
+                           scaled(h, c.ssm_in_multiplier))
+            return scaled(y, c.ssm_out_multiplier), state
+
     def qk_normed(self, mods, params, q: jax.Array, k: jax.Array):
         """The per-head norm of q and k (``qk_norm``), before any rotation;
         shared by the training block and the paged server."""
@@ -487,12 +601,13 @@ class Transformer(Module):
         delayed-scaling context (qscales/qobserved) to the Linears."""
         c = self.cfg
         if c.activation == "swiglu":
-            gate = jax.nn.silu(mods["ff_gate"].apply(params["ff_gate"], h,
-                                                     **qkw))
-            return mods["ff_out"].apply(
+            m_gate, m_out = c.mlp_multipliers
+            gate = jax.nn.silu(scaled(
+                mods["ff_gate"].apply(params["ff_gate"], h, **qkw), m_gate))
+            return scaled(mods["ff_out"].apply(
                 params["ff_out"],
                 gate * mods["ff_in"].apply(params["ff_in"], h, **qkw),
-                **qkw)
+                **qkw), m_out)
         h = mods["ff_in"].apply(params["ff_in"], h, **qkw)
         h = ACTIVATIONS[c.activation](h)
         return mods["ff_out"].apply(params["ff_out"], h, **qkw)
@@ -543,8 +658,7 @@ class Transformer(Module):
             return self._ffn_half(mods, params, x, qkw, qobs)
         with jax.named_scope("attn_proj"):
             h = mods["ln1"].apply(params["ln1"], x)
-            qkv = mods["qkv"].apply(params["qkv"], h, **qkw)
-            q, k, v = split_qkv(c, qkv)
+            q, k, v = self.scaled_qkv(mods, params, h, **qkw)
             if c.qk_norm:
                 q, k = self.qk_normed(mods, params, q, k)
             # GQA training path: repeat K/V to full query heads so every
@@ -562,7 +676,14 @@ class Transformer(Module):
                 window=c.layer_window(layer))
         with jax.named_scope("attn_proj"):
             out = out.reshape(*out.shape[:2], c.q_dim)
-            x = x + mods["attn_out"].apply(params["attn_out"], out, **qkw)
+            x = x + scaled(
+                mods["attn_out"].apply(params["attn_out"], out, **qkw),
+                c.attention_out_multiplier)
+        if c.has_mixer:
+            # beside the attention, from the same normed input, added
+            x = x + self.mixer_half(
+                mods, params, h,
+                lambda mixer, p, u: (mixer.apply(p, u), None))[0]
         return self._ffn_half(mods, params, x, qkw, qobs)
 
     def _ffn_half(self, mods, params, x: jax.Array, qkw, qobs):
@@ -613,7 +734,8 @@ class Transformer(Module):
         with jax.named_scope("embed"):
             x = Embedding(c.vocab_size, c.d_model, c.param_dtype).apply(
                 params["embed"], ids)
-            return self.add_pos(params, x, positions)
+            return self.add_pos(params, scaled(x, c.embedding_multiplier),
+                                positions)
 
     def final_norm(self, params, x: jax.Array) -> jax.Array:
         """The pre-head LayerNorm — the non-vocab half of
@@ -635,7 +757,8 @@ class Transformer(Module):
                             matmul_dtype=self._mm("head"),
                             q_role="head").apply(params["head"], x,
                                                  qscales=qscales)
-            return logits.astype(jnp.float32)
+            return scaled(logits.astype(jnp.float32),
+                           c.lm_head_multiplier)
 
     def fwd_flops(self, x_shape):
         """(B, T) token batch.  qkv/out/ffn/attention matmuls + LM head;
@@ -656,6 +779,9 @@ class Transformer(Module):
                 per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv (GQA-aware)
                 per_layer += 2.0 * b * t * c.q_dim * d  # attention out
                 per_layer += 2.0 * (2.0 * b * t * keys * c.q_dim)  # scores + values
+                if c.has_mixer:
+                    per_layer += b * t * self._block_modules()[
+                        "ssm"].fwd_flops_per_token()
             moe = c.layer_is_moe(i)
             ff = c.d_ff if moe or not c.moe_experts else c.dense_ff
             # FFN in + out per expert; SwiGLU adds the (d, ff) gate matmul

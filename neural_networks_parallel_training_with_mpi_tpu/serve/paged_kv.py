@@ -19,6 +19,15 @@ blocks:
   normed and rotated before it is written.  Allocation, block tables,
   copy-on-write, export / import and the handoff geometry read the row
   and nothing else of the attention.
+* **State that is not paged** (a model with a state-space mixer beside its
+  attention, ``models/ssm.py``): per layer, one row a SLOT of the model's
+  **state row** (``Transformer.state_row()``: the mixer's convolution tail
+  and its float32 state), in a second store beside the pools
+  (:func:`init_paged_state`).  A row is a function of its stream, not of
+  positions: fixed in size, overwritten in place by every prefill chunk and
+  decode tick, never shared; it is zeroed on the device at admission,
+  held bit for bit by idle lanes and pad columns, and goes with the slot
+  (DESIGN.md says why it is no kind of page).
 * **Block tables**: per slot, ``(max_blocks,)`` int32 indices into the
   pool, host-owned (a tiny traced argument each step — never a
   recompile).  Unallocated entries point at the reserved **sink block
@@ -122,7 +131,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import _quantize_kv, _sample
-from ..models.transformer import Transformer, split_qkv
+from ..models.transformer import Transformer, scaled
 from ..ops.pallas_kernels import paged_attention, paged_tiles
 from ..train import trace as trace_lib
 from ..utils import compile_ledger as ledger_lib
@@ -156,6 +165,16 @@ EXPERT_COUNTERS = ("expert_assignments", "expert_tokens_max",
 # pool blocks those streams' visible keys sat in (table entries x layers)
 ATTENTION_COUNTERS = ("full_keys", "window_keys", "full_blocks_held",
                       "window_blocks_held")
+
+# cumulative counters of a model with recurrent state (a state-space mixer
+# beside the attention, models/ssm.py), carried like the expert counters:
+# state rows a decode tick updated (decoding streams x mixer layers), true
+# prompt columns a prefill chunk ran through the chunked recurrence (columns
+# x mixer layers), and the two counts of what was folded, under the names
+# EXPERT_COUNTERS gives them (a program that carried both sets would count
+# the same ticks and chunks in both)
+SSM_COUNTERS = ("ssm_state_updates", "ssm_prefill_tokens",
+                "decode_ticks_counted", "prefill_chunks_counted")
 
 # block 0 is reserved: pad positions and frozen slots write (and gather)
 # here, so a scatter never needs dynamic masking to be allocation-safe
@@ -469,6 +488,45 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
             for w in windows]
 
 
+def init_paged_state(model: Transformer, slots: int):
+    """The store that is NOT paged, beside the pools: per layer, one row a
+    slot of every entry of the model's state row (``model.state_row()``:
+    store name -> (shape, type); a mixer's convolution tail and its float32
+    state), zeros.  ``[]`` for a model without recurrent state.  A row
+    belongs to a slot for as long as a stream holds it: zeroed at admission,
+    carried by prefill chunks and decode ticks, gone with the slot."""
+    row = model.state_row()
+    if not row:
+        return []
+    return [{n: jnp.zeros((slots,) + shape, dtype)
+             for n, (shape, dtype) in row.items()}
+            for _ in range(model.cfg.n_layers)]
+
+
+def refuse_recurrent(model: Transformer, who: str, why: str) -> None:
+    """What needs a snapshot of recurrent state that does not exist yet
+    refuses a model that has such state, by name."""
+    if model.cfg.has_mixer:
+        raise ValueError(
+            f"{who} cannot serve a model with recurrent state (a "
+            f"state-space mixer beside the attention: ssm_heads) yet: "
+            f"{why}")
+
+
+@functools.lru_cache(maxsize=8)
+def _state_reset_program(model: Transformer):
+    """Zero one slot's rows of the state store in every layer (``slot`` is
+    a traced scalar: admissions never recompile; the store is donated, so
+    the rows are zeroed in place)."""
+    def reset(state, slot):
+        return jax.tree_util.tree_map(
+            lambda s: jax.lax.dynamic_update_slice_in_dim(
+                s, jnp.zeros((1,) + s.shape[1:], s.dtype), slot, 0), state)
+
+    return ledger_lib.instrument(jax.jit(reset, donate_argnums=(0,)),
+                                 "serve_state_reset")
+
+
 @functools.lru_cache(maxsize=8)
 def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                     temperature: float, top_k: int, top_p: float,
@@ -504,6 +562,10 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         raise ValueError("kv_quant cannot run a model with window layers "
                          "(attention_pattern 'L') yet: the int8 page walk "
                          "has no lower bound")
+    recurrent = c.has_mixer     # a second store: one state row a slot
+    if kv_quant:
+        refuse_recurrent(model, "kv_quant", "int8 K and V beside a float32 "
+                         "state was never compared with the reference")
 
     def kind_scope(window):
         """``attn_full`` / ``attn_window`` inside ``attn_core``, for a model
@@ -578,7 +640,8 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
 
     def dense_attention_half(mods, layer_params, pool, tables, starts, x,
                              valid, lengths, layer=0):
-        """``x + Attn(LN(x))`` with per-head K and V rows: the fused qkv
+        """``x + Attn(LN(x))`` (and the normed input, for a mixer beside
+        it) with per-head K and V rows: the fused qkv
         projection, K/V scattered into the pools, attention gathered or
         fused.  Mirrors ``models.generate._block_chunk`` (the pinned dense
         math) with the cache axis split into (block, offset).  ``layer``
@@ -592,9 +655,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         # what implements it here
         with jax.named_scope("attn_proj"):
             h = mods["ln1"].apply(layer_params["ln1"], x)
-            qkv = mods["qkv"].apply(layer_params["qkv"], h)
-            b, w, _ = qkv.shape
-            q, k, v = split_qkv(c, qkv)  # q: (B,W,H,hd); k/v: (B,W,KV,hd)
+            # q: (B,W,H,hd); k/v: (B,W,KV,hd)
+            q, k, v = model.scaled_qkv(mods, layer_params, h)
+            b, w = q.shape[:2]
             if c.qk_norm:
                 q, k = model.qk_normed(mods, layer_params, q, k)
         with jax.named_scope("attention"):
@@ -647,11 +710,13 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                     new_vsp if quant else None, window).astype(x.dtype)
         with jax.named_scope("attn_proj"):
             out = out.reshape(b, w, c.q_dim)
-            x = x + mods["attn_out"].apply(layer_params["attn_out"], out)
+            x = x + scaled(
+                mods["attn_out"].apply(layer_params["attn_out"], out),
+                c.attention_out_multiplier)
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
             new_pool.update(k_scale=new_ksp, v_scale=new_vsp)
-        return x, new_pool
+        return x, new_pool, h
 
     def latent_attention_half(mods, layer_params, pool, tables, starts, x,
                               valid, lengths, decode):
@@ -726,7 +791,7 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         return x, {"latent": new_lp}
 
     def block_fwd(layer_params, pool, tables, starts, x, valid, lengths,
-                  decode, layer=0):
+                  decode, layer=0, state=None):
         """Transformer block ``layer`` over a chunk ``x`` (B, W, D) whose rows
         sit at per-row start positions: the attention half of the model's
         kind writes the chunk's cache rows into the paged pool and reads
@@ -737,9 +802,15 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         like the tables so length churn never recompiles.  ``decode``
         (static) picks latent attention's absorbed form.  Returns (x, the
         new pool, the held experts' load (count,) int32 under routing
-        without drops, else None).  Where the model has window layers
-        ``tables`` is the pair (full kind's, window kind's), and the layer
-        takes its own."""
+        without drops, else None, the new state rows).  Where the model
+        has window layers ``tables`` is the pair (full kind's, window
+        kind's), and the layer takes its own.  Where it has a mixer beside
+        the attention, ``state`` holds this layer's state rows of the
+        chunk's B streams: the mixer reads the attention's normed input,
+        its output is added to the same residual, and it writes to the
+        second store what the attention writes to its pages: a chunk carries
+        the state over its true columns, a tick over its decoding lanes
+        (``lengths > 0``), everything else holds."""
         mods = model._block_modules(layer)
         if two_kinds:
             tables = tables[1 if windows[layer] else 0]
@@ -748,9 +819,18 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 mods, layer_params, pool, tables, starts, x, valid, lengths,
                 decode)
         else:
-            x, new_pool = dense_attention_half(
+            x, new_pool, h = dense_attention_half(
                 mods, layer_params, pool, tables, starts, x, valid, lengths,
                 layer)
+        if recurrent:
+            if decode:
+                run = lambda mixer, p, u: mixer.apply_step(     # noqa: E731
+                    p, u, state, lengths > 0)
+            else:
+                run = lambda mixer, p, u: mixer.apply_chunk(    # noqa: E731
+                    p, u, state, valid)
+            mix, state = model.mixer_half(mods, layer_params, h, run)
+            x = x + mix
         load = None
         with jax.named_scope("ffn"):
             h = mods["ln2"].apply(layer_params["ln2"], x)
@@ -765,10 +845,10 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             else:
                 ff, _ = mods["moe"].apply(layer_params["moe"], h)
             x = x + ff.astype(x.dtype)
-        return x, new_pool, load
+        return x, new_pool, load, state
 
     def forward(params, pools, tables, starts, ids, valid, lengths,
-                decode):
+                decode, state=None):
         # clamp pad columns' embedding positions into range (their
         # outputs are discarded; learned positional tables have no row
         # past max_seq_len)
@@ -786,14 +866,16 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 "block_size": bs,
                 "window": {"impl": "paged", "window": win, "pages": wp,
                            "tile_cols": wc}})
-        new_pools, loads = [], []
+        new_pools, loads, new_state = [], [], []
         for i, (layer_params, pool) in enumerate(zip(params["blocks"],
                                                      pools)):
-            x, pool, load = block_fwd(layer_params, pool, tables, starts, x,
-                                      valid, lengths, decode, i)
+            x, pool, load, rows = block_fwd(
+                layer_params, pool, tables, starts, x, valid, lengths,
+                decode, i, state[i] if recurrent else None)
             new_pools.append(pool)
             loads.append(load)
-        return model.head_logits(params, x), new_pools, loads
+            new_state.append(rows)
+        return model.head_logits(params, x), new_pools, loads, new_state
 
     def count_load(stats, loads, decode):
         """Fold one program's expert loads (a (count,) int32 per layer)
@@ -830,19 +912,42 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         return {**stats, "attention": stats["attention"]
                 + jnp.stack(step).astype(jnp.int32)}
 
-    def prefill(params, pools, stats, table, start, chunk, true_w):
+    def count_state(stats, work, decode):
+        """Fold one program's work at the mixers into ``SSM_COUNTERS`` (a
+        model with recurrent state; nothing otherwise): ``work`` is the
+        tick's decoding streams or the chunk's true columns."""
+        if "ssm" not in stats:
+            return stats
+        work = work.astype(jnp.int32) * c.n_layers
+        zero, one = jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32)
+        step = [work, zero, one, zero] if decode else [zero, work, zero, one]
+        return {**stats, "ssm": stats["ssm"] + jnp.stack(step)}
+
+    # ``state`` and ``slot``: the second store of a model with recurrent
+    # state and the chunk's stream's place in it (None otherwise)
+    def prefill(params, pools, state, stats, table, slot, start, chunk,
+                true_w):
         # chunk (1, W_bucket) int32; logits for ALL columns return and
         # the caller indexes the true last position (same contract as
         # the dense server's bucketed prefill).  attendable keys after
         # this chunk's writes: everything up to start + true_w (pad
         # columns wrote to the sink, which is past every length)
         valid = jnp.arange(chunk.shape[1]) < true_w
-        logits, new_pools, loads = forward(params, pools, table, start,
-                                           chunk, valid, start + true_w,
-                                           False)
-        return logits, new_pools, count_load(stats, loads, False)
+        # the stream's rows of the second store, carried over the chunk
+        rows = jax.tree_util.tree_map(
+            lambda s: jax.lax.dynamic_slice_in_dim(s, slot, 1, 0),
+            state) if recurrent else None
+        logits, new_pools, loads, rows = forward(
+            params, pools, table, start, chunk, valid, start + true_w, False,
+            rows)
+        if recurrent:
+            state = jax.tree_util.tree_map(
+                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
+                    s, r, slot, 0), state, rows)
+        return logits, new_pools, state, count_state(
+            count_load(stats, loads, False), true_w, False)
 
-    def step(params, pools, stats, tokens, tables, pos, active, key):
+    def step(params, pools, state, stats, tokens, tables, pos, active, key):
         s = tokens.shape[0]
         cap = tokens.shape[1] - 1
         ids = jnp.take_along_axis(tokens, pos[:, None], axis=1)  # (S, 1)
@@ -850,9 +955,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         # inactive lanes carry length 0, so the fused kernel walks ZERO
         # of their blocks (the gathered path computes-and-discards them)
         lengths = jnp.where(active, pos + 1, 0)
-        logits, new_pools, loads = forward(params, pools, tables, pos, ids,
-                                           jnp.ones((1,), bool), lengths,
-                                           True)
+        logits, new_pools, loads, state = forward(
+            params, pools, tables, pos, ids, jnp.ones((1,), bool), lengths,
+            True, state)
         with jax.named_scope("sample"):
             nxt, key = _sample(logits[:, 0], temperature, key, top_k,
                                top_p)
@@ -865,8 +970,30 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         pos = jnp.where(active, jnp.minimum(pos + 1, cap), pos)
         # the counters come last: the dense programs' results keep their
         # places (and their compile-cache keys)
-        stats = count_keys(count_load(stats, loads, True), lengths)
-        return new_pools, tokens, pos, key, stats
+        stats = count_state(
+            count_keys(count_load(stats, loads, True), lengths),
+            active.sum(), True)
+        return new_pools, state, tokens, pos, key, stats
+
+    # a model with recurrent state hands its second store through both
+    # programs (donated: the rows are updated in place); every other model's
+    # programs take and return what they did, and lower to the text they had
+    donate_prefill, donate_step = (1, 2, 3), (1, 2, 3, 4, 6)
+    if not recurrent:
+        with_state = (prefill, step)
+
+        def prefill(params, pools, stats, table, start, chunk, true_w):
+            logits, new_pools, _, stats = with_state[0](
+                params, pools, None, stats, table, None, start, chunk,
+                true_w)
+            return logits, new_pools, stats
+
+        def step(params, pools, stats, tokens, tables, pos, active, key):
+            new_pools, _, tokens, pos, key, stats = with_state[1](
+                params, pools, None, stats, tokens, tables, pos, active, key)
+            return new_pools, tokens, pos, key, stats
+
+        donate_prefill, donate_step = (1, 2), (1, 2, 3, 5)
 
     def cow(pools, src, dst):
         """Copy-on-write fork: duplicate block row ``src`` into the
@@ -899,9 +1026,10 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     # values are runtime data, so the ledger stays flat.
     tag = (f"bs{bs}x{mb}" + ("/int8" if kv_quant else "")
            + f"/{attn_impl}")
-    return (ledger_lib.instrument(jax.jit(prefill, donate_argnums=(1, 2)),
-                                  f"serve_prefill[{tag}]"),
-            ledger_lib.instrument(jax.jit(step, donate_argnums=(1, 2, 3, 5)),
+    return (ledger_lib.instrument(
+                jax.jit(prefill, donate_argnums=donate_prefill),
+                f"serve_prefill[{tag}]"),
+            ledger_lib.instrument(jax.jit(step, donate_argnums=donate_step),
                                   f"serve_decode[{tag}]"),
             ledger_lib.instrument(jax.jit(cow, donate_argnums=(0,)),
                                   f"serve_cow[{tag}]"),
@@ -960,6 +1088,10 @@ class PagedDecodeServer:
         self.t_cap = self.max_blocks * self.block_size
         self.num_blocks = int(num_blocks)
         self.prefix_cache = bool(prefix_cache)
+        if self.prefix_cache:
+            refuse_recurrent(model, "prefix_cache", "a shared prefix's "
+                             "blocks hold its keys and values, and no "
+                             "snapshot of the state at its end")
         self.prefix = PrefixIndex()
         self.allocator = BlockAllocator(
             self.num_blocks,
@@ -1016,6 +1148,15 @@ class PagedDecodeServer:
                                    self.block_size, quant=self.kv_quant,
                                    folded=self.attn_impl == "fused",
                                    window_blocks=window_blocks)
+        # state that is not paged, in the same manager: a model with a
+        # mixer beside its attention keeps, per layer, one row a SLOT (the
+        # convolution's tail and the float32 state), zeroed on the device
+        # when a stream is admitted to the slot, carried by its prefill
+        # chunks and decode ticks, and gone with the slot.  [] otherwise.
+        self.state = init_paged_state(model, self.slots)
+        self.state_rows: Dict[int, int] = {}    # slot -> the rid it holds
+        self._reset_fn = (_state_reset_program(model) if self.state
+                          else None)
         # expert-load counters (EXPERT_COUNTERS): cumulative on the device
         # modulo 2**32, folded into host integers at every fetch; {} for a
         # model that does not route without drops
@@ -1031,6 +1172,11 @@ class PagedDecodeServer:
             self.stats["attention"] = jnp.zeros(
                 (len(ATTENTION_COUNTERS),), jnp.int32)
             self.attention_counters = dict.fromkeys(ATTENTION_COUNTERS, 0)
+        # the mixers' counters (SSM_COUNTERS), the same way
+        self.ssm_counters: Dict[str, int] = {}
+        if self.state:
+            self.stats["ssm"] = jnp.zeros((len(SSM_COUNTERS),), jnp.int32)
+            self.ssm_counters = dict.fromkeys(SSM_COUNTERS, 0)
         self._stats_seen = {k: np.zeros(v.shape, np.int64)
                             for k, v in self.stats.items()}
         self.tokens = jnp.zeros((self.slots, self.t_cap), jnp.int32)
@@ -1068,10 +1214,15 @@ class PagedDecodeServer:
         return self.allocator.used_blocks / cap if cap else 0.0
 
     def assert_drained(self) -> None:
-        """Every block of every kind back in its allocator."""
+        """Every block of every kind back in its allocator, and no state
+        row held by a stream."""
         self.allocator.assert_drained()
         if self.window_allocator is not None:
             self.window_allocator.assert_drained()
+        if self.state_rows:
+            raise AssertionError(
+                "state leak: rows of the recurrent state still held after "
+                f"quiesce (slot -> rid): {dict(sorted(self.state_rows.items()))}")
 
     # ---- the window kind's blocks ---------------------------------------
     def _window_span(self, st: _Stream, slot: int, first: int,
@@ -1341,6 +1492,8 @@ class PagedDecodeServer:
         self.tables[slot, :len(blocks)] = blocks
         if self.window is not None:     # taken chunk by chunk, at prefill
             self.window_tables[slot, :] = SINK_BLOCK
+        if self.state:
+            self._reset_state(slot, rid)
         row = np.zeros((self.t_cap,), np.int32)
         row[:p] = prompt_ids
         self.tokens = self.tokens.at[slot].set(jnp.asarray(row))
@@ -1348,6 +1501,14 @@ class PagedDecodeServer:
         self._pos_host[slot] = 0
         self.active[slot] = False
         return rid
+
+    def _reset_state(self, slot: int, rid: int) -> None:
+        """A stream admitted to ``slot`` starts from a zero state and an
+        empty convolution tail, whatever the slot's last stream left: one
+        small program on the device (no fetch, and none a tick), ordered
+        before the stream's first chunk by the store it donates."""
+        self.state = self._reset_fn(self.state, jnp.asarray(slot, jnp.int32))
+        self.state_rows[slot] = rid
 
     def prefill_remaining(self, rid: int) -> int:
         """Prompt tokens not yet prefilled (0 = stream is decoding)."""
@@ -1410,13 +1571,19 @@ class PagedDecodeServer:
             # may alias the numpy buffer (and jnp.array's own copy is an
             # async device op), while the host mutates self.tables /
             # self.active in place before the dispatched program has run
-            args = (self._device_tables(slice(slot, slot + 1)),
-                    jnp.asarray([st.prefilled], jnp.int32),
+            table = self._device_tables(slice(slot, slot + 1))
+            args = (jnp.asarray([st.prefilled], jnp.int32),
                     jnp.asarray(chunk),
                     jnp.asarray(w, jnp.int32))
         with trace_lib.span("prefill/submit"):
-            logits, self.pools, self.stats = self._prefill_fn(
-                self.params, self.pools, self.stats, *args)
+            if self.state:
+                (logits, self.pools, self.state,
+                 self.stats) = self._prefill_fn(
+                    self.params, self.pools, self.state, self.stats, table,
+                    jnp.asarray(slot, jnp.int32), *args)
+            else:
+                logits, self.pools, self.stats = self._prefill_fn(
+                    self.params, self.pools, self.stats, table, *args)
         st.prefilled += w
         if self.window is not None:
             # behind the window at once: the pool holds ONE chunk beside
@@ -1569,6 +1736,8 @@ class PagedDecodeServer:
             self.window_tables[slot, :] = SINK_BLOCK
             self.window_allocator.release(list(st.window_pages.values()))
             st.window_pages = {}
+        # the state row goes with the slot (its next stream zeroes it)
+        self.state_rows.pop(slot, None)
         self.active[slot] = False
 
     def evict(self, rid: int):
@@ -1587,11 +1756,15 @@ class PagedDecodeServer:
 
     # ---- block handoff (disaggregated prefill/decode) -----------------
     def _refuse_window(self, who: str) -> None:
+        """A handoff's payload is one kind of block: a model with window
+        layers, or with recurrent state beside its blocks, is refused."""
         if self.window is not None:
             raise ValueError(
                 f"{who} cannot hand off a model with window layers "
                 "(attention_pattern 'L') yet: the payload carries one kind "
                 "of block")
+        refuse_recurrent(self.model, who, "the payload carries blocks of "
+                         "keys and values, and no snapshot of the state")
 
     def _handoff_geometry(self) -> Dict[str, Any]:
         """The pool facts both sides of a handoff must agree on byte-for-
@@ -1789,10 +1962,16 @@ class PagedDecodeServer:
             tables = self._device_tables(slice(None), keep=self.active)
             active = jnp.asarray(self.active.copy())   # see prefill_step
         with trace_lib.span("decode/submit"):
-            (self.pools, self.tokens, self.pos, self.key,
-             self.stats) = self._step_fn(
-                self.params, self.pools, self.stats, self.tokens, tables,
-                self.pos, active, self.key)
+            if self.state:
+                (self.pools, self.state, self.tokens, self.pos, self.key,
+                 self.stats) = self._step_fn(
+                    self.params, self.pools, self.state, self.stats,
+                    self.tokens, tables, self.pos, active, self.key)
+            else:
+                (self.pools, self.tokens, self.pos, self.key,
+                 self.stats) = self._step_fn(
+                    self.params, self.pools, self.stats, self.tokens, tables,
+                    self.pos, active, self.key)
         finished = []
         with trace_lib.span("decode/finish"):
             for rid, slot in list(self._slot_of.items()):
@@ -1813,7 +1992,8 @@ class PagedDecodeServer:
         self._results[rid] = [int(t) for t in np.asarray(row)[:st.target]]
         for key, names, into in (
                 ("experts", EXPERT_COUNTERS, self.expert_counters),
-                ("attention", ATTENTION_COUNTERS, self.attention_counters)):
+                ("attention", ATTENTION_COUNTERS, self.attention_counters),
+                ("ssm", SSM_COUNTERS, self.ssm_counters)):
             if key in stats:
                 raw = np.asarray(stats[key]).astype(np.int64) % (1 << 32)
                 for name, d in zip(names,

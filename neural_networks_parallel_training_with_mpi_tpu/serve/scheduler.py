@@ -527,6 +527,9 @@ class Scheduler:
         # full layers (paged_kv.ATTENTION_COUNTERS; {} otherwise)
         self.attention_counters: Dict[str, int] = dict(
             self.server.attention_counters)
+        # and for the counters of a model with recurrent state
+        # (paged_kv.SSM_COUNTERS; {} otherwise)
+        self.ssm_counters: Dict[str, int] = dict(self.server.ssm_counters)
         self.telemetry = _ServeTelemetry(cfg)
         # per-request flow-trace ids must stay unique across the fleet's
         # merged timeline: prefix the scheduler-local rid with this
@@ -655,14 +658,17 @@ class Scheduler:
                 for srv_rid in finished:
                     done_now.append(self._retire(srv_rid))
                 if finished and (self.expert_counters
-                                 or self.attention_counters):
+                                 or self.attention_counters
+                                 or self.ssm_counters):
                     # fresh as of this tick's step: the fetch of a
                     # finished stream brought them
                     self.expert_counters = dict(self.server.expert_counters)
                     self.attention_counters = dict(
                         self.server.attention_counters)
+                    self.ssm_counters = dict(self.server.ssm_counters)
                     retire.attrs.update(self.expert_counters)
                     retire.attrs.update(self.attention_counters)
+                    retire.attrs.update(self.ssm_counters)
         self.telemetry.on_tick(self.tick_no, self._snapshot())
         self._gap_wall = time.time()
         self._gap_state = ("sched_bubble" if self._srv_rid
@@ -1044,6 +1050,7 @@ class Scheduler:
             "kernel_keys": self.kernel_keys,
             **self.expert_counters,
             **self.attention_counters,
+            **self.ssm_counters,
             "attended_ratio": (
                 round(self.attended_keys / self.padded_keys, 4)
                 if self.padded_keys else None),

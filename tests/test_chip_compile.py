@@ -539,6 +539,90 @@ def test_kinds_programs_walk_both_kinds_of_cache_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# ---- the programs of falconh1-34b-pp12-serve-chat, whole -------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_cell_programs_fit_the_chip(spec, kernels_compiled, program):
+    """The decode tick (64 slots) and a 512-column prefill chunk of the
+    cell's own configuration (``benchmark/configs/falcon-h1-34b-pp12.json``:
+    6 layers at the published widths, the whole vocabulary; the mix's 8193
+    blocks of 16 and 192 table entries a stream), with the state store beside
+    the pools, compile for the described chip under its 15.75 GB.  Counted
+    here (PR 35): the tick 13.77 GB (10.51 of weights, 1.61 of pool, 1.61 of
+    state, 25 MB of temporaries), the chunk 14.33 GB (its 535 MB of float32
+    logits for every column among them).  The mixer's scopes are in the
+    compiled text, the tick's update of a layer's state is one fusion that
+    reads the store once, and neither a pool nor the state is copied."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.harness import common
+
+    cell = common.load_cell("falconh1-34b-pp12-serve-chat")
+    model = cell["model"]["family"].program_model(cell["model"])
+    geo = cell["job"]["serve_config"]
+    s, bs = geo["slots"], geo["block_size"]
+    mb = geo["max_len"] // bs
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    assert paged_kv.resolve_attn_impl(model, "auto") == "fused"
+    pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+        model, geo["num_blocks"], bs, folded=True)))
+    state = abstract(jax.eval_shape(
+        lambda: paged_kv.init_paged_state(model, s)))
+    assert [(v["conv"].shape, v["ssm"].shape, v["ssm"].dtype)
+            for v in state] == [((64, 3, 5120), (64, 32, 128, 256),
+                                 jnp.float32)] * 6
+    prefill, step, _, _ = paged_kv._paged_programs(
+        model, bs, mb, 0.0, 0, 1.0, False, "auto")
+    stats = {"ssm": spec((len(paged_kv.SSM_COUNTERS),), jnp.int32)}
+    if program == "decode":
+        lowered = step.lower(
+            params, pools, state, stats, spec((s, mb * bs), jnp.int32),
+            spec((s, mb), jnp.int32), spec((s,), jnp.int32),
+            spec((s,), jnp.bool_), spec((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(
+            params, pools, state, stats, spec((1, mb), jnp.int32),
+            spec((), jnp.int32), spec((1,), jnp.int32),
+            spec((1, geo["prefill_chunk"]), jnp.int32), spec((), jnp.int32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 13.5e9 < total < (14.0e9 if program == "decode" else 14.6e9), total
+    assert total < 15.75e9
+    text = compiled.as_text()
+    scopes = ("ssm_in", "ssm_conv", "ssm_gate_norm", "ssm_out",
+              "ssm_update" if program == "decode" else "ssm_scan",
+              "attn_core/paged_attention_fused", "paged_scatter")
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+    assert "paged_gather" not in text
+    # (the convolution's tails, 2 MB a layer, the compiler does lay out anew)
+    shaped = r"\[(?:8193,16,512|64,32,128,256)\]"
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \S*" + shaped
+                          + r"\S* (?:copy|transpose|gather|copy-start)\(",
+                          line)]
+    assert not moved, moved
+    if program == "decode":
+        # one fusion a layer makes the new state and the state's read
+        # (S . C) from one pass over the store
+        both = [line for line in text.splitlines()
+                if "/ssm_update/" in line and " fusion(" in line
+                and "f32[64,32,128,256]" in line.split(" fusion(")[0]]
+        assert len(both) == 6, len(both)
+        assert all("f32[64,32,128]" in line.split(" fusion(")[0]
+                   for line in both)
+
+
 # --- the data-parallel train step over the four described chips --------------
 
 

@@ -489,3 +489,310 @@ def test_the_ledger_names_both_kinds_walks():
                            for e in prefill)
     assert led.events_for("serve_decode[bs4x14/gathered]")[0]["attention"] \
         == {"impl": "gathered", "keys": 56}
+
+
+# ---------------------------------------------------------------------------
+# state that is not paged: a mixer beside the attention (models/ssm.py)
+# ---------------------------------------------------------------------------
+
+def _hybrid(dtype="float32"):
+    """The toy of tests/test_ssm_hybrid_model.py with seeded weights from its
+    family: (program model, program params, the family's model dict, the
+    reference's tensors)."""
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.families import ssm_attn_parallel as ref
+    from benchmark.harness import weights
+
+    model = {"vocab_size": 96, "d_model": 48, "n_layers": 3, "n_heads": 4,
+             "n_kv_heads": 2, "head_dim": 16, "d_ff": 80, "d_ssm": 48,
+             "ssm_heads": 4, "ssm_head_dim": 12, "ssm_state": 16,
+             "ssm_groups": 2, "ssm_conv": 4, "ssm_chunk": 8,
+             "max_seq_len": 128, "rms_eps": 1e-5, "rope_theta": 1e11,
+             "embedding_multiplier": 5.657, "lm_head_multiplier": 0.0625,
+             "attention_in_multiplier": 0.8,
+             "attention_out_multiplier": 0.3, "key_multiplier": 0.11,
+             "ssm_in_multiplier": 0.25, "ssm_out_multiplier": 0.35,
+             "ssm_multipliers": [0.354, 0.25, 0.177, 0.5, 0.354],
+             "mlp_multipliers": [0.177, 0.4], "param_dtype": dtype,
+             "compute_dtype": dtype, "family": ref, "config": "toy"}
+    maker = weights.Maker(model, 5)
+    outer, layers = maker.outer(), maker.layers()
+    return (ref.program_model(model), ref.to_program(model, outer, layers),
+            model, (outer, layers))
+
+
+def _hybrid_server(net, params, **kw):
+    base = dict(slots=3, num_blocks=40, block_size=4, max_len=64,
+                prefill_chunk=8)
+    base.update(kw)
+    return PagedDecodeServer(net, params, **base)
+
+
+def _record_prefill_logits(srv, into):
+    """Keep every prefill chunk's logits (the program returns them for all
+    columns; the server reads the last true one)."""
+    inner = srv._prefill_fn
+
+    def recording(*args):
+        out = inner(*args)
+        into.append(np.asarray(out[0][0], np.float32))
+        return out
+
+    srv._prefill_fn = recording
+
+
+def _state_rows(srv, slot):
+    import jax
+
+    return [{n: np.asarray(v[slot]) for n, v in layer.items()}
+            for layer in jax.device_get(srv.state)]
+
+
+# float32: program and reference differ in the order of float32 sums (the
+# chunked recurrence against the step-by-step one, the gathered attention
+# against the blocked one): 2e-5 of the logits' scale, observed 7e-7.
+# bfloat16: every projection rounds its operands and its result to 8 bits of
+# mantissa (2^-9 = 2e-3 each), some ten of them a layer over three layers,
+# and the mixer's chunk products round ``xs``, ``B``, ``C`` and the masked
+# decays once more: 3e-2 of the scale, observed 1.6e-2; a state that is stale
+# moves the logits by more than 1e-1 of it (the planted fault of the next
+# test).
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+def test_chunked_prefill_then_decode_against_the_full_forward(dtype, tol):
+    """A prompt of 21 in chunks of 8 (8, 8 and 5 true columns of a bucket
+    of 8), then 9 decode ticks through ``PagedDecodeServer`` with a stranger
+    beside it: the logits of every prompt position, and of the positions
+    that predicted the served tokens, against the reference's full forward
+    pass over prompt + served tokens."""
+    import jax
+
+    net, params, model, (outer, layers) = _hybrid(dtype)
+    ref = model["family"]
+    srv = _hybrid_server(net, params)
+    chunks = []
+    _record_prefill_logits(srv, chunks)
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(0, 96, size=21).tolist()
+    other = srv.try_admit(rng.integers(0, 96, size=6).tolist(), 30)
+    while not srv.prefill_step(other, 8):
+        pass
+    chunks.clear()
+    rid = srv.try_admit(prompt, 10)
+    while not srv.prefill_step(rid, 8):
+        srv.step()                      # the stranger decodes between chunks
+    assert [c.shape[0] for c in chunks] == [8, 8, 8]
+    while not srv.done(rid):
+        srv.step()
+    served = srv.result(rid)
+    assert served[:21] == prompt and len(served) == 31
+    with jax.default_matmul_precision("highest"):
+        f32 = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+            lambda a: a.astype(jnp.float32), t)
+        x = ref.embed(model, f32(outer), np.asarray([served]))
+        for i, p in enumerate(layers):
+            x = ref.block(model, f32(p), x, i)
+        want = np.asarray(ref.head_logits(model, f32(outer), x))[0]
+    scale = np.abs(want).max()
+    got = np.concatenate([chunks[0], chunks[1], chunks[2][:5]])
+    assert np.abs(got - want[:21]).max() <= tol * scale
+    # each served token is the reference's best at its position, or lies
+    # within the tolerance of it (greedy; rounding may swap near ties)
+    for t in range(21, 31):
+        row = want[t - 1]
+        assert row.max() - row[served[t]] <= 2 * tol * scale, t
+    assert srv.ssm_counters["ssm_prefill_tokens"] == 3 * (6 + 21)
+
+
+def test_a_slot_admitted_again_starts_from_zero_state(monkeypatch):
+    """One slot, two streams one after the other: the second's first chunk
+    leaves the state a fresh server's leaves, bit for bit, and its tokens
+    are a fresh server's.  With the reset skipped (the planted fault) the
+    state after that chunk is another one and the logits move by more than
+    a tenth of their scale."""
+    net, params, _model, _t = _hybrid()
+    first, second = list(range(5, 30)), list(range(40, 52))
+
+    def second_after_first(srv, logits):
+        _drain(srv, srv.try_admit(first, 6), 8)
+        srv.assert_drained()
+        rid = srv.try_admit(second, 6)
+        assert srv._slot_of[rid] == 0 and srv.state_rows == {0: rid}
+        _record_prefill_logits(srv, logits)
+        srv.prefill_step(rid, 8)
+        rows = _state_rows(srv, 0)
+        return rows, _drain(srv, rid, 8)
+
+    fresh = _hybrid_server(net, params, slots=1)
+    rid = fresh.try_admit(second, 6)
+    want_logits = []
+    _record_prefill_logits(fresh, want_logits)
+    fresh.prefill_step(rid, 8)
+    want_rows, want = _state_rows(fresh, 0), _drain(fresh, rid, 8)
+
+    logits = []
+    rows, got = second_after_first(_hybrid_server(net, params, slots=1),
+                                   logits)
+    assert got == want
+    for mine, theirs in zip(rows, want_rows):
+        for name in mine:
+            assert (mine[name] == theirs[name]).all(), name
+    assert (logits[0] == want_logits[0]).all()
+
+    def skipped(self, slot, rid):       # the fault: the row is not zeroed
+        self.state_rows[slot] = rid
+
+    monkeypatch.setattr(PagedDecodeServer, "_reset_state", skipped)
+    logits = []
+    rows, _ = second_after_first(_hybrid_server(net, params, slots=1), logits)
+    assert not (rows[0]["ssm"] == want_rows[0]["ssm"]).all()
+    moved = np.abs(logits[0] - want_logits[0]).max()
+    assert moved > 0.1 * np.abs(want_logits[0]).max()
+
+
+def test_idle_lanes_and_pad_columns_leave_state_and_tail_as_they_were():
+    """A decode tick moves the decoding lane's rows alone: a lane that is
+    mid prefill and a free lane with planted rows keep state and tail bit
+    for bit; a prefill chunk moves its own slot's rows alone; and what a
+    chunk's pad columns hold does not reach state, tail or pool."""
+    import jax
+
+    net, params, _model, _t = _hybrid()
+    srv = _hybrid_server(net, params)
+    a = srv.try_admit(list(range(1, 12)), 20)
+    while not srv.prefill_step(a, 8):
+        pass
+    b = srv.try_admit(list(range(20, 45)), 5)
+    srv.prefill_step(b, 8)                      # mid prefill: 8 of 25
+    assert (srv._slot_of[a], srv._slot_of[b]) == (0, 1)
+    srv.state = jax.tree_util.tree_map(         # the free lane, planted
+        lambda s: s.at[2].set(0.25), srv.state)
+    before = [_state_rows(srv, s) for s in range(3)]
+    for _ in range(3):
+        srv.step()
+    after = [_state_rows(srv, s) for s in range(3)]
+    for layer in range(3):
+        for name in ("conv", "ssm"):
+            assert not (after[0][layer][name]
+                        == before[0][layer][name]).all()
+            for idle in (1, 2):
+                assert (after[idle][layer][name]
+                        == before[idle][layer][name]).all(), (idle, name)
+    srv.prefill_step(b, 8)                      # 16 of 25: b's rows alone
+    later = [_state_rows(srv, s) for s in range(3)]
+    for layer in range(3):
+        assert not (later[1][layer]["ssm"] == after[1][layer]["ssm"]).all()
+        for other in (0, 2):
+            for name in ("conv", "ssm"):
+                assert (later[other][layer][name]
+                        == after[other][layer][name]).all()
+
+    def one_chunk(pad_id):
+        """A chunk of 5 true columns in a bucket of 8 whose pad columns
+        hold ``pad_id``, through the prefill program itself."""
+        one = _hybrid_server(net, params, slots=1)
+        rid = one.try_admit([3, 1, 4, 1, 5], 2)
+        chunk = np.full((1, 8), pad_id, np.int32)
+        chunk[0, :5] = [3, 1, 4, 1, 5]
+        _logits, one.pools, one.state, one.stats = one._prefill_fn(
+            one.params, one.pools, one.state, one.stats,
+            one._device_tables(slice(0, 1)), jnp.asarray(0, jnp.int32),
+            jnp.asarray([0], jnp.int32), jnp.asarray(chunk),
+            jnp.asarray(5, jnp.int32))
+        blocks = one._streams[rid].blocks
+        return _state_rows(one, 0), [
+            np.asarray(pool["k"])[blocks] for pool in one.pools]
+
+    (rows0, pools0), (rows9, pools9) = one_chunk(0), one_chunk(77)
+    for r0, r9, p0, p9 in zip(rows0, rows9, pools0, pools9):
+        assert (r0["ssm"] == r9["ssm"]).all()
+        assert (r0["conv"] == r9["conv"]).all() and (p0 == p9).all()
+    # the tail holds the last three TRUE inputs, not the bucket's last three
+    assert np.abs(rows0[0]["conv"]).min() > 0
+
+
+def test_evicted_and_readmitted_the_state_is_rebuilt_by_prefill():
+    """A stream evicted mid decode and admitted again prefills its prompt
+    anew from a zero state: the logits of its chunks and its tokens are
+    those of the undisturbed run, bit for bit; the state row went with the
+    slot and came back with the admission."""
+    net, params, _model, _t = _hybrid()
+    prompt = list(range(7, 26))
+    quiet = _hybrid_server(net, params)
+    want_logits = []
+    _record_prefill_logits(quiet, want_logits)
+    want = _drain(quiet, quiet.try_admit(prompt, 12), 8)
+
+    srv = _hybrid_server(net, params)
+    logits = []
+    _record_prefill_logits(srv, logits)
+    other = srv.try_admit([9] * 5, 40)          # takes slot 0
+    while not srv.prefill_step(other, 8):
+        pass
+    logits.clear()
+    rid = srv.try_admit(prompt, 12)
+    while not srv.prefill_step(rid, 8):
+        pass
+    for _ in range(4):
+        srv.step()
+    slot = srv._slot_of[rid]
+    assert srv.evict(rid) == (prompt, 12) and slot not in srv.state_rows
+    logits.clear()
+    again = srv.try_admit(prompt, 12)
+    assert srv.state_rows[srv._slot_of[again]] == again
+    got = _drain(srv, again, 8)
+    assert got == want
+    assert len(logits) == len(want_logits) == 3
+    # the true columns (a pad column attends whatever the sink holds)
+    for mine, theirs, true in zip(logits, want_logits, (8, 8, 3)):
+        assert (mine[:true] == theirs[:true]).all()
+
+
+def test_a_drained_server_holds_no_state_row(tmp_path):
+    """``quiesce`` evicts everything and proves it: no block of any kind and
+    no row of the state store is held; a row left behind is named.  The
+    mixers' counters ride the ``retire`` spans and the serve records."""
+    from neural_networks_parallel_training_with_mpi_tpu.serve import (
+        Scheduler, ServeConfig,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.train import (
+        trace as trace_lib,
+    )
+
+    net, params, _model, _t = _hybrid()
+    spans = []
+    listener = lambda n, t, d, a: spans.append((n, dict(a or {})))  # noqa: E731
+    tracer = trace_lib.start_run(str(tmp_path))
+    trace_lib.add_listener(listener)
+    try:
+        sched = Scheduler(net, params, ServeConfig(
+            slots=3, block_size=4, num_blocks=40, max_len=64,
+            prefill_chunk=8))
+        rng = np.random.default_rng(1)
+        for n in (13, 30, 7, 22, 9):
+            sched.submit(rng.integers(0, 96, size=n).tolist(), 8)
+        for _ in range(14):
+            sched.tick()
+        assert sched.server.state_rows
+        sched.quiesce()
+        sched.server.assert_drained()
+        assert not sched.server.state_rows
+    finally:
+        trace_lib.remove_listener(listener)
+        trace_lib.stop_run(tracer)
+    stamped = [a for n, a in spans
+               if n == "retire" and "ssm_state_updates" in a]
+    assert stamped and set(stamped[-1]) >= {
+        "ssm_state_updates", "ssm_prefill_tokens", "decode_ticks_counted",
+        "prefill_chunks_counted"}
+    snap = sched._snapshot()
+    assert snap["ssm_state_updates"] == stamped[-1]["ssm_state_updates"] > 0
+    # 3 mixer layers: a tick updates 3 rows a decoding stream
+    assert snap["ssm_state_updates"] % 3 == 0
+    sched.server.state_rows[1] = 99
+    with pytest.raises(AssertionError, match="state leak.*1: 99"):
+        sched.server.assert_drained()

@@ -169,7 +169,8 @@ def summarize(records: List[Dict[str, Any]],
                     "tokens_out", "attended_keys", "padded_keys",
                     "attended_ratio", "walked_keys_share", "full_keys",
                     "window_keys", "full_blocks_held",
-                    "window_blocks_held", "prefix_hits",
+                    "window_blocks_held", "ssm_state_updates",
+                    "ssm_prefill_tokens", "prefix_hits",
                     "prefix_misses",
                     "prefix_hit_tokens", "prefix_hit_rate",
                     "shared_blocks", "cow_forks", "cache_evictions",
@@ -282,6 +283,12 @@ def serving_lines(summary: Dict[str, Any]) -> List[str]:
                 f"{st.get('full_blocks_held')} / "
                 f"{st.get('window_blocks_held')} (decode ticks, summed "
                 "over layers)")
+        if "ssm_state_updates" in st:
+            lines.append(
+                f"  recurrent state: {st['ssm_state_updates']} rows updated "
+                f"by decode ticks, {st.get('ssm_prefill_tokens')} prompt "
+                "columns through the chunked recurrence (each summed over "
+                "the mixer layers)")
         if "prefix_hits" in st:
             rate = st.get("prefix_hit_rate")
             lines.append(
