@@ -357,8 +357,8 @@ class InprocReplica(ReplicaHandle):
         for frid, lrid in list(self._local.items()):
             fin = lrid in done_local
             if not fin:
-                # injected single-token streams retire inside inject()
-                # and never appear in a tick's done list
+                # requests a drain landed and retired outside a tick
+                # never appear in a tick's done list
                 try:
                     fin = self.sched.done(lrid)
                 except KeyError:

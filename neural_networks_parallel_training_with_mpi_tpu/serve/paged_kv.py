@@ -88,6 +88,22 @@ loop performs zero per-token device syncs — the discipline the trainer's
 monitor uses, taken to its limit (see the satellite fix in
 ``models/serve.py``).
 
+**The contract of ``step()`` (and of ``Scheduler.tick()``), in four lines.**
+A rid is reported when its row is on the host, and only then is its result
+readable.  The row is waited for behind one queued program: the step that
+makes a stream's last token takes the row (``serve_take``: a copy into a
+buffer of its own, a copy to the host started behind it) and frees the slot
+and the blocks at once; :meth:`PagedDecodeServer.land` waits for it after the
+next chunk or step has been dispatched, so the wait never drains the device's
+queue.  Nothing is in flight once nothing is dispatched: with no stream left
+active or prefilling, or no program dispatched since the last landing, every
+row lands at once (a single stream, a drained scheduler, ``drain`` /
+``quiesce`` / ``close``).  ``t_done`` is the landing, never a dispatch.  The
+rest of a request's boundary is one program each: an admission
+(``serve_admit``: token row, position and, for a model with state, the
+slot's zeroed state rows, in place) and a prefill's first token
+(``serve_first_token``, one a prefill bucket).
+
 **Prefix caching + copy-on-write** (``prefix_cache=True``): real chat
 traffic shares system prompts, and the block-table indirection above is
 one refcount away from sharing the identical prefix K/V across streams
@@ -149,11 +165,12 @@ ATTN_IMPLS = ("auto", "gathered", "fused")
 
 # cumulative expert-load counters of a model that routes without drops
 # (models.moe.DroplessMoE), carried on the device beside the pools as one
-# int32 vector and brought to the host in the fetch ``_finish`` makes
-# anyway: choices that fell on held experts (all programs), the busiest
-# held expert's count summed over programs and layers, held experts with
-# at least one token summed over decode ticks and layers, decode ticks,
-# and the first of these over prefill chunks alone with their count
+# int32 vector and brought to the host with a finished stream's row (a copy
+# taken by ``_finish``, folded by ``land``): choices that fell on held
+# experts (all programs), the busiest held expert's count summed over
+# programs and layers, held experts with at least one token summed over
+# decode ticks and layers, decode ticks, and the first of these over prefill
+# chunks alone with their count
 EXPERT_COUNTERS = ("expert_assignments", "expert_tokens_max",
                    "experts_reached", "decode_ticks_counted",
                    "prefill_expert_assignments", "prefill_chunks_counted")
@@ -514,17 +531,62 @@ def refuse_recurrent(model: Transformer, who: str, why: str) -> None:
 
 
 @functools.lru_cache(maxsize=8)
-def _state_reset_program(model: Transformer):
-    """Zero one slot's rows of the state store in every layer (``slot`` is
-    a traced scalar: admissions never recompile; the store is donated, so
-    the rows are zeroed in place)."""
-    def reset(state, slot):
-        return jax.tree_util.tree_map(
-            lambda s: jax.lax.dynamic_update_slice_in_dim(
-                s, jnp.zeros((1,) + s.shape[1:], s.dtype), slot, 0), state)
+def _boundary_programs(tag: str, temperature: float, top_k: int,
+                       top_p: float, *server):
+    """The three small programs of a request's boundary, beside the four of
+    :func:`_paged_programs` and cached like them, a set a kind of server
+    (``server``: the rest of that cache's key, so that a server's ledger
+    events do not depend on which servers ran before it); none of them
+    touches a pool.  ``slot`` is a traced scalar everywhere: one compile
+    each, and ``first_token`` one a prefill bucket.
 
-    return ledger_lib.instrument(jax.jit(reset, donate_argnums=(0,)),
-                                 "serve_state_reset")
+    ``take``: a finished stream's row and the counters as of the program
+    just dispatched, as buffers of their own: the next step donates
+    ``tokens`` and ``stats``, and these are read on the host one program
+    later (:meth:`PagedDecodeServer.land`)."""
+    def take(tokens, stats, slot):
+        return (jax.lax.dynamic_index_in_dim(tokens, slot, 0, keepdims=False),
+                jax.tree_util.tree_map(jnp.copy, stats))
+
+    def admit(tokens, pos, state, row, where):
+        """An admission as one program: the slot's token row, its position
+        ``at`` (0; an imported stream's prompt length), and under ``fresh``
+        zeros in the slot's rows of the state store of a model that has one
+        (``[]`` otherwise); ``where`` is ``[slot, at, fresh]``, one upload.
+        All three arrays are donated and updated in place: no copy of the
+        store."""
+        slot, at, fresh = where[0], where[1], where[2] != 0
+        tokens = jax.lax.dynamic_update_slice(tokens, row[None],
+                                              (slot, jnp.zeros_like(slot)))
+        pos = jax.lax.dynamic_update_slice(pos, at[None], (slot,))
+
+        def reset(s):
+            held = jax.lax.dynamic_slice_in_dim(s, slot, 1, 0)
+            return jax.lax.dynamic_update_slice_in_dim(
+                s, jnp.where(fresh, jnp.zeros_like(held), held), slot, 0)
+
+        return tokens, pos, jax.tree_util.tree_map(reset, state)
+
+    def first_token(logits, tokens, pos, key, where):
+        """A prefill's last chunk hands over to decode as one program: the
+        first token sampled from the chunk's logits at its last true column
+        ``col`` (one compile a prefill bucket), written at ``(slot, at)``,
+        the position set to ``at``; ``where`` is ``[slot, col, at]``."""
+        slot, col, at = where[0], where[1], where[2]
+        tok, key = _sample(
+            jax.lax.dynamic_index_in_dim(logits, col, 1, keepdims=False),
+            temperature, key, top_k, top_p)
+        tokens = jax.lax.dynamic_update_slice(tokens, tok[:, None],
+                                              (slot, at))
+        pos = jax.lax.dynamic_update_slice(pos, at[None], (slot,))
+        return tokens, pos, key
+
+    return (ledger_lib.instrument(jax.jit(take), f"serve_take[{tag}]"),
+            ledger_lib.instrument(jax.jit(admit, donate_argnums=(0, 1, 2)),
+                                  f"serve_admit[{tag}]"),
+            ledger_lib.instrument(
+                jax.jit(first_token, donate_argnums=(1, 2)),
+                f"serve_first_token[{tag}]"))
 
 
 @functools.lru_cache(maxsize=8)
@@ -1063,6 +1125,16 @@ class _Stream:
     window_pages: Dict[int, int] = field(default_factory=dict)
 
 
+@dataclass
+class _Row:
+    """A finished stream's row on its way to the host (taken, not landed)."""
+    rid: int
+    target: int
+    row: Any                          # device (t_cap,) int32, its own buffer
+    stats: Any                        # the counters' copy taken with it
+    after: int                        # model programs dispatched by then
+
+
 class PagedDecodeServer:
     """Slot server over a paged KV pool: same host contract as the dense
     ``DecodeServer`` (submit/step/done/result), plus the paged-runtime
@@ -1120,6 +1192,9 @@ class PagedDecodeServer:
          self._import_fn) = _paged_programs(
             model, self.block_size, self.max_blocks, *self._sampling,
             self.kv_quant, self.attn_impl)
+        self._take_fn, self._admit_fn, self._first_fn = _boundary_programs(
+            f"bs{self.block_size}x{self.max_blocks}", *self._sampling, model,
+            self.block_size, self.max_blocks, self.kv_quant, self.attn_impl)
         # two kinds of cache in one manager: a full layer's pool is what it
         # was (``num_blocks``, one table a stream, grown on demand); a
         # window layer's holds, for each stream, the pages its next query
@@ -1155,8 +1230,6 @@ class PagedDecodeServer:
         # chunks and decode ticks, and gone with the slot.  [] otherwise.
         self.state = init_paged_state(model, self.slots)
         self.state_rows: Dict[int, int] = {}    # slot -> the rid it holds
-        self._reset_fn = (_state_reset_program(model) if self.state
-                          else None)
         # expert-load counters (EXPERT_COUNTERS): cumulative on the device
         # modulo 2**32, folded into host integers at every fetch; {} for a
         # model that does not route without drops
@@ -1189,6 +1262,14 @@ class PagedDecodeServer:
         self._streams: Dict[int, _Stream] = {}
         self._slot_of: Dict[int, int] = {}
         self._results: Dict[int, List[int]] = {}
+        # finished streams' rows between their take and their landing
+        # (:meth:`land`), and the count of model programs dispatched (chunks
+        # and steps) that says whether one is queued behind a row
+        self._in_flight: List[_Row] = []
+        self._programs = 0
+        self._programs_at_land = 0
+        self.rows_landed = 0          # rows brought to the host
+        self.rows_landed_behind = 0   # ... with a later program queued
         if c.scan_layers:
             params = dict(params)
             stacked = params["blocks"]
@@ -1492,23 +1573,32 @@ class PagedDecodeServer:
         self.tables[slot, :len(blocks)] = blocks
         if self.window is not None:     # taken chunk by chunk, at prefill
             self.window_tables[slot, :] = SINK_BLOCK
-        if self.state:
-            self._reset_state(slot, rid)
         row = np.zeros((self.t_cap,), np.int32)
         row[:p] = prompt_ids
-        self.tokens = self.tokens.at[slot].set(jnp.asarray(row))
-        self.pos = self.pos.at[slot].set(0)
-        self._pos_host[slot] = 0
+        self._place(slot, row, 0,
+                    fresh=bool(self.state and self._reset_state(slot, rid)))
         self.active[slot] = False
         return rid
 
-    def _reset_state(self, slot: int, rid: int) -> None:
+    def _reset_state(self, slot: int, rid: int) -> bool:
         """A stream admitted to ``slot`` starts from a zero state and an
-        empty convolution tail, whatever the slot's last stream left: one
-        small program on the device (no fetch, and none a tick), ordered
-        before the stream's first chunk by the store it donates."""
-        self.state = self._reset_fn(self.state, jnp.asarray(slot, jnp.int32))
+        empty convolution tail, whatever the slot's last stream left: books
+        the row and says so to the admission's program (:meth:`_place`),
+        which zeroes the rows on the device in the call that writes the
+        token row (no fetch, no program of its own), ordered before the
+        stream's first chunk by the store it donates."""
         self.state_rows[slot] = rid
+        return True
+
+    def _place(self, slot: int, row: np.ndarray, at: int,
+               fresh: bool = False) -> None:
+        """The device's side of an admission, one program (``serve_admit``):
+        ``row`` into the slot's token row, the position to ``at``, and under
+        ``fresh`` zeros into the slot's state rows."""
+        self.tokens, self.pos, self.state = self._admit_fn(
+            self.tokens, self.pos, self.state, row,
+            np.asarray([slot, at, fresh], np.int32))
+        self._pos_host[slot] = at
 
     def prefill_remaining(self, rid: int) -> int:
         """Prompt tokens not yet prefilled (0 = stream is decoding)."""
@@ -1584,6 +1674,7 @@ class PagedDecodeServer:
             else:
                 logits, self.pools, self.stats = self._prefill_fn(
                     self.params, self.pools, self.stats, table, *args)
+            self._programs += 1
         st.prefilled += w
         if self.window is not None:
             # behind the window at once: the pool holds ONE chunk beside
@@ -1593,11 +1684,9 @@ class PagedDecodeServer:
         if st.prefilled < p:
             return False
         with trace_lib.span("prefill/first_token"):
-            t, tk, tp = self._sampling
-            first_row, self.key = _sample(logits[:, w - 1], t, self.key,
-                                          tk, tp)
-            self.tokens = self.tokens.at[slot, p].set(first_row[0])
-            self.pos = self.pos.at[slot].set(p)
+            self.tokens, self.pos, self.key = self._first_fn(
+                logits, self.tokens, self.pos, self.key,
+                np.asarray([slot, w - 1, p], np.int32))
             self._pos_host[slot] = p
             self.active[slot] = st.max_new > 1
             if st.max_new <= 1:
@@ -1717,7 +1806,7 @@ class PagedDecodeServer:
         return short
 
     def _release_stream(self, st: _Stream, slot: int) -> None:
-        """THE single stream-release path (_finish and evict both land
+        """THE single stream-release path (_finish and evict both end
         here): zero the table to the sink FIRST — the next step's
         frozen-lane write must go to the sink, never into a block
         someone else holds — then drop one reference per block through
@@ -1910,9 +1999,7 @@ class PagedDecodeServer:
         row = np.zeros((self.t_cap,), np.int32)
         row[:p] = prompt_ids
         row[p] = int(payload["first_token"])
-        self.tokens = self.tokens.at[slot].set(jnp.asarray(row))
-        self.pos = self.pos.at[slot].set(p)
-        self._pos_host[slot] = p
+        self._place(slot, row, p)
         self.active[slot] = max_new > 1
         self.prompt_tokens_admitted += p
         self.handoffs_imported += 1
@@ -1925,13 +2012,23 @@ class PagedDecodeServer:
 
     # ---- decode --------------------------------------------------------
     def step(self) -> List[int]:
-        """One batched decode step across all slots; returns the rids
-        that finished this step.  Completion comes from host-side
-        position counters — no device fetch.  Raises
-        :class:`BlockExhausted` when a stream's next write has no block
-        (call :meth:`ensure_blocks` / evict first)."""
+        """:meth:`dispatch` one decode step, then :meth:`land`: returns the
+        rids whose rows reached the host in this call.  A stream whose last
+        token this step makes is reported by the NEXT call while other
+        streams run, and by this one when none does."""
+        self.dispatch()
+        return self.land()
+
+    def dispatch(self) -> None:
+        """One batched decode step across all slots, dispatched and not
+        waited for.  Completion comes from host-side position counters — no
+        device fetch: a stream whose last token this step makes has its row
+        taken (:meth:`_finish`) and its slot and blocks released at once;
+        :meth:`land` reports it.  Raises :class:`BlockExhausted` when a
+        stream's next write has no block (call :meth:`ensure_blocks` / evict
+        first)."""
         if not self.active.any():
-            return []
+            return
         # the three child spans split the scheduler's ``decode`` span
         # where the device can fall idle (train/trace.py vocabulary)
         with trace_lib.span("decode/prepare"):
@@ -1972,7 +2069,7 @@ class PagedDecodeServer:
                  self.stats) = self._step_fn(
                     self.params, self.pools, self.stats, self.tokens, tables,
                     self.pos, active, self.key)
-        finished = []
+            self._programs += 1
         with trace_lib.span("decode/finish"):
             for rid, slot in list(self._slot_of.items()):
                 if not self.active[slot]:
@@ -1980,16 +2077,57 @@ class PagedDecodeServer:
                 self._pos_host[slot] += 1
                 if self._pos_host[slot] + 1 >= self._streams[rid].target:
                     self._finish(rid)
-                    finished.append(rid)
-        return finished
 
     def _finish(self, rid: int) -> None:
+        """The program just dispatched makes ``rid``'s last token.  Take the
+        row: one small program that copies ``tokens[slot]`` and the counters
+        into buffers of their own (the next step donates both arrays) and a
+        copy to the host started behind it; nothing waits.  The slot and the
+        blocks go back at once: the device runs its programs in the order
+        they were dispatched, so whoever gets them next writes after this
+        read, which is what lets an admission overwrite a slot at all.  The
+        rid is reported when the row is on the host (:meth:`land`)."""
         st = self._streams.pop(rid)
         slot = self._slot_of.pop(rid)
-        # the one fetch a finished stream costs brings the expert-load
-        # counters along: no round trip of their own
-        row, stats = jax.device_get((self.tokens[slot], self.stats))
-        self._results[rid] = [int(t) for t in np.asarray(row)[:st.target]]
+        row, stats = self._take_fn(self.tokens, self.stats, np.int32(slot))
+        for leaf in jax.tree_util.tree_leaves((row, stats)):
+            leaf.copy_to_host_async()
+        self._in_flight.append(_Row(rid, st.target, row, stats,
+                                    self._programs))
+        self._release_stream(st, slot)
+
+    def land(self, every: bool = False) -> List[int]:
+        """Wait for the rows that have a later program queued behind them,
+        fold the counters that came along, and return their rids (results
+        readable from here on).  The lag is one program: a row taken behind
+        the step just dispatched stays in flight until a chunk or a step has
+        been dispatched behind it, so the host's wait never drains the
+        device's queue.  Where nothing will be queued behind (no stream left
+        active or prefilling, or no program dispatched since the last call),
+        and under ``every`` (a caller about to stop ticking), everything in
+        flight lands now: nothing is in flight once nothing is dispatched."""
+        every = (every or not self._streams
+                 or self._programs == self._programs_at_land)
+        self._programs_at_land = self._programs
+        behind = [r for r in self._in_flight if r.after < self._programs]
+        due = self._in_flight if every else behind
+        if not due:
+            return []
+        self._in_flight = [] if every else [
+            r for r in self._in_flight if r.after >= self._programs]
+        # the wait: with a program queued behind, the healthy state
+        with trace_lib.span("land"):
+            got = jax.device_get([(r.row, r.stats) for r in due])
+        for r, (row, stats) in zip(due, got):
+            self._results[r.rid] = [int(t) for t in row[:r.target]]
+            self._fold_stats(stats)
+        self.rows_landed += len(due)
+        self.rows_landed_behind += len(behind)
+        return [r.rid for r in due]
+
+    def _fold_stats(self, stats) -> None:
+        """The device's cumulative counters (modulo 2**32) into the host's
+        integers; the fetch of a finished stream's row brought them."""
         for key, names, into in (
                 ("experts", EXPERT_COUNTERS, self.expert_counters),
                 ("attention", ATTENTION_COUNTERS, self.attention_counters),
@@ -2000,15 +2138,21 @@ class PagedDecodeServer:
                                    (raw - self._stats_seen[key]) % (1 << 32)):
                     into[name] += int(d)
                 self._stats_seen[key] = raw
-        self._release_stream(st, slot)
 
     # ---- results -------------------------------------------------------
     def done(self, rid: int) -> bool:
+        """True once ``rid``'s row is on the host (not while it is in
+        flight: :meth:`land`)."""
         if rid in self._results:
             return True
-        if rid in self._streams:
+        if self.holds(rid) or any(r.rid == rid for r in self._in_flight):
             return False
         raise KeyError(f"request {rid}: unknown or already consumed")
+
+    def holds(self, rid: int) -> bool:
+        """``rid`` still runs here: prefilling or decoding, its slot and
+        blocks not yet released."""
+        return rid in self._streams
 
     def result(self, rid: int) -> List[int]:
         """Prompt + generated ids for a finished request (pops it)."""

@@ -8,11 +8,16 @@ top of the library loop the repo had before this subsystem:
   ``queue_depth``; beyond that requests are REJECTED (counted, and the
   caller told), because an unbounded queue just converts overload into
   unbounded latency.
-* **Per-tick admit/retire**: every :meth:`Scheduler.tick` retires
-  finished streams, admits from the queue head while a slot + the
-  prompt's blocks + the token budget allow, runs at most one chunked
-  prefill chunk, and advances all decoding streams one batched step —
-  requests join and leave mid-flight, never stalling the batch.
+* **Per-tick admit/retire**: every :meth:`Scheduler.tick` admits from
+  the queue head while a slot + the prompt's blocks + the token budget
+  allow, runs at most one chunked prefill chunk, advances all decoding
+  streams one batched step, and then retires the streams whose rows have
+  reached the host — requests join and leave mid-flight, never stalling
+  the batch.  A stream that finishes frees its slot and blocks in the tick
+  of its last step and is returned by the NEXT tick, after that tick's
+  programs are queued (``PagedDecodeServer.land``: the host waits for a
+  finished row one program behind, never with the device's queue empty);
+  by the same tick when nothing else runs.  ``t_done`` is the landing.
   Admission is head-of-line (no skip-ahead): simple, and what makes the
   no-starvation property provable — the queue head cannot be bypassed
   forever by luckier requests.
@@ -518,9 +523,9 @@ class Scheduler:
         self.walked_keys = 0
         # expert-load counters of a model that routes without drops
         # (paged_kv.EXPERT_COUNTERS; {} otherwise): cumulative, as of the
-        # server's last fetch of a finished stream, stamped on the
-        # ``retire`` span of every tick that finished one (so a stamp is
-        # exact as of its tick) for whoever listens to spans
+        # step behind which the newest landed row was taken, stamped on the
+        # ``retire`` span of every tick that landed one for whoever listens
+        # to spans
         self.expert_counters: Dict[str, int] = dict(
             self.server.expert_counters)
         # the same for the attention counters of a model with window and
@@ -621,10 +626,9 @@ class Scheduler:
 
     # ---- the service loop ----------------------------------------------
     def tick(self) -> List[int]:
-        """One scheduler tick: retire/admit/prefill/decode.  Returns the
-        rids completed during this tick."""
+        """One scheduler tick: admit/prefill/decode/land/retire.  Returns
+        the rids whose results reached the host during this tick."""
         self.tick_no += 1
-        done_now: List[int] = []
         self._leave_gap()
         tracer = trace_lib.active()
         if tracer is not None and self._gap_state is not None:
@@ -635,8 +639,9 @@ class Scheduler:
         with trace_lib.span("admit", tick=self.tick_no):
             self._admit()
         with trace_lib.span("prefill", tick=self.tick_no):
-            done_now += self._prefill_tick()
-        if self.server.any_active():
+            self._prefill_tick()
+        decoding = self.server.any_active()
+        if decoding:
             with trace_lib.span("decode", tick=self.tick_no):
                 self._grow_or_evict()
                 # flow step at a stream's FIRST decode tick: the arrow
@@ -653,22 +658,11 @@ class Scheduler:
                 self.padded_keys += acct["padded_keys"]
                 self.kernel_keys += acct["kernel_keys"]
                 self.walked_keys += acct["walked_keys"]
-                finished = self.server.step()
-            with trace_lib.span("retire", tick=self.tick_no) as retire:
-                for srv_rid in finished:
-                    done_now.append(self._retire(srv_rid))
-                if finished and (self.expert_counters
-                                 or self.attention_counters
-                                 or self.ssm_counters):
-                    # fresh as of this tick's step: the fetch of a
-                    # finished stream brought them
-                    self.expert_counters = dict(self.server.expert_counters)
-                    self.attention_counters = dict(
-                        self.server.attention_counters)
-                    self.ssm_counters = dict(self.server.ssm_counters)
-                    retire.attrs.update(self.expert_counters)
-                    retire.attrs.update(self.attention_counters)
-                    retire.attrs.update(self.ssm_counters)
+                self.server.dispatch()
+        # with this tick's chunk and step queued on the device, wait for
+        # the rows taken a program earlier (a tick that only lands rows
+        # opens no ``decode`` span)
+        done_now = self._land(opened=decoding)
         self.telemetry.on_tick(self.tick_no, self._snapshot())
         self._gap_wall = time.time()
         self._gap_state = ("sched_bubble" if self._srv_rid
@@ -677,6 +671,31 @@ class Scheduler:
             self._gap_mirror = trace_lib.annotation(self._gap_state)
             self._gap_mirror.__enter__()
         return done_now
+
+    def _land(self, every: bool = False, opened: bool = False) -> List[int]:
+        """Bring the finished streams' rows that are due to the host
+        (``PagedDecodeServer.land``: those with a later program queued
+        behind them; all of them once nothing is, or under ``every``) and
+        retire their requests: ``t_done`` is the landing, never a dispatch.
+        The ``retire`` span (opened for a decode tick too, ``opened``)
+        carries the server's cumulative counters, fresh as of the step
+        behind which the newest landed row was taken."""
+        landed = self.server.land(every)
+        if not (landed or opened):
+            return []
+        with trace_lib.span("retire", tick=self.tick_no) as retire:
+            done = [self._retire(srv_rid) for srv_rid in landed]
+            if landed:
+                self.expert_counters = dict(self.server.expert_counters)
+                self.attention_counters = dict(
+                    self.server.attention_counters)
+                self.ssm_counters = dict(self.server.ssm_counters)
+                retire.attrs.update(
+                    self.expert_counters, **self.attention_counters,
+                    **self.ssm_counters,
+                    rows_landed=self.server.rows_landed,
+                    rows_landed_behind=self.server.rows_landed_behind)
+        return done
 
     def _leave_gap(self) -> None:
         if self._gap_mirror is not None:
@@ -698,6 +717,7 @@ class Scheduler:
 
     def close(self) -> None:
         self._leave_gap()
+        self._land(every=True)      # no tick follows: nothing stays in flight
         self.telemetry.close(self.tick_no, self._snapshot())
         if self._tracer is not None:
             trace_lib.stop_run(self._tracer)
@@ -764,9 +784,8 @@ class Scheduler:
                        rid=rid, stage="inject", tick=self.tick_no)
         if trace_lib.active() is not None:
             self._flow_to_decode.append(rid)
-        if self.server.done(srv_rid):
-            # degenerate single-token handoff: already complete
-            self._retire(srv_rid)
+        # (a degenerate single-token handoff is already complete: its row is
+        # in flight, and the next tick reports it like any other)
         return rid
 
     def tokens_at_risk(self) -> int:
@@ -779,6 +798,9 @@ class Scheduler:
         total = 0
         for rid, srv_rid in self._srv_rid.items():
             req = self.reqs[rid]
+            if not self.server.holds(srv_rid):  # finished, its row in flight
+                total += len(req.prompt) + req.max_new
+                continue
             st = self.server._streams[srv_rid]
             slot = self.server._slot_of[srv_rid]
             prefilled, p = st.prefilled, len(req.prompt)
@@ -801,6 +823,7 @@ class Scheduler:
         tests/test_serve_sched.py).  Completed-but-unconsumed results
         stay readable via :meth:`result`."""
         out: List[Dict[str, Any]] = []
+        self._land(every=True)      # finished is finished: not handed back
         for rid in list(self._srv_rid):
             srv_rid = self._srv_rid.pop(rid)
             self._sched_rid.pop(srv_rid)
@@ -864,9 +887,9 @@ class Scheduler:
         block-granular upper bound) instead of charged per stream —
         otherwise a token budget would reject admissions whose residency
         the cache already holds."""
-        raw = sum(len(r.prompt) + r.max_new
-                  for rid, r in self.reqs.items()
-                  if rid in self._srv_rid)
+        raw = sum(len(self.reqs[rid].prompt) + self.reqs[rid].max_new
+                  for rid, srv_rid in self._srv_rid.items()
+                  if self.server.holds(srv_rid))
         return max(0, raw - self.server.shared_token_discount())
 
     def _admit(self) -> None:
@@ -907,12 +930,11 @@ class Scheduler:
                            rid=req.rid, stage="admit",
                            prompt_tokens=p, tick=self.tick_no)
 
-    def _prefill_tick(self) -> List[int]:
+    def _prefill_tick(self) -> None:
         """At most one prefill chunk per tick (interleaving: decoding
         streams advance every tick regardless of admission work)."""
-        done_now: List[int] = []
         if not self._prefilling:
-            return done_now
+            return
         rid = self._prefilling[0]
         srv_rid = self._srv_rid[rid]
         trace_lib.flow("req", f"{self._flow_prefix}{rid}", "t",
@@ -923,9 +945,10 @@ class Scheduler:
             req.t_first = self.now()
             if trace_lib.active() is not None:
                 self._flow_to_decode.append(rid)
-            if self.server.done(srv_rid):   # single-token request
-                done_now.append(self._retire(srv_rid))
-            elif self.cfg.role == "prefill" and not req.unified:
+            # (a single-token request is finished here: its row is taken,
+            # and the tick's landing reports it)
+            if (self.cfg.role == "prefill" and not req.unified
+                    and self.server.holds(srv_rid)):
                 # disaggregated handoff: the stream leaves this replica
                 # at the prefill->decode boundary.  Export FIRST (read-
                 # only), then release — under prefix_cache the owned
@@ -933,7 +956,6 @@ class Scheduler:
                 # release parks them cached-free and the content stays
                 # resident for future prefix hits
                 self._export_handoff(rid, srv_rid)
-        return done_now
 
     def _export_handoff(self, rid: int, srv_rid: int) -> None:
         req = self.reqs[rid]
@@ -966,7 +988,8 @@ class Scheduler:
             self._evict(victim)
 
     def _pick_victim(self) -> Optional[int]:
-        inflight = [self.reqs[rid] for rid in self._srv_rid]
+        inflight = [self.reqs[rid] for rid, srv_rid in self._srv_rid.items()
+                    if self.server.holds(srv_rid)]
         if len(inflight) <= 1:
             return None
         key = lambda r: (r.deadline, r.t_submit, r.rid)   # noqa: E731
@@ -1048,6 +1071,10 @@ class Scheduler:
             "attended_keys": self.attended_keys,
             "padded_keys": self.padded_keys,
             "kernel_keys": self.kernel_keys,
+            # finished streams' rows brought to the host, and those of them
+            # that had a later program queued behind the wait
+            "rows_landed": self.server.rows_landed,
+            "rows_landed_behind": self.server.rows_landed_behind,
             **self.expert_counters,
             **self.attention_counters,
             **self.ssm_counters,

@@ -34,16 +34,19 @@ Span vocabulary (the fixed vocabulary the report tool groups by):
 ``ckpt``        a checkpoint save call (sync write or async staging)
 ``ckpt_write``  the async writer thread's actual disk write
 ``rollback``    anomaly/SDC rollback: restore + re-place
-``admit`` / ``prefill`` / ``decode`` / ``retire``
-                the serving scheduler's tick phases (serve/scheduler.py)
+``admit`` / ``prefill`` / ``decode`` / ``land`` / ``retire``
+                the serving scheduler's tick phases (serve/scheduler.py);
+                ``land`` (serve/paged_kv.py ``land``) is the host's wait
+                for finished streams' rows, behind the tick's programs:
+                its length is the device's lead, not a loss
 ``prefill/prepare`` / ``prefill/submit`` / ``prefill/first_token``
                 inside ``prefill`` (serve/paged_kv.py ``prefill_step``):
                 host bookkeeping and uploads, the program call, the
-                eager sampling of the first token on the last chunk
+                first token's one program on the last chunk
 ``decode/prepare`` / ``decode/submit`` / ``decode/finish``
-                inside ``decode`` (``PagedServer.step``): block supply
-                checks and uploads, the program call, the position loop
-                and the blocking token reads of finished streams
+                inside ``decode`` (``PagedDecodeServer.dispatch``): block
+                supply checks and uploads, the program call, the position
+                loop and the take of finished streams' rows (no wait)
 ``queue_wait``  serving inter-tick gap with requests queued but no slot
 ``sched_bubble``
                 serving inter-tick gap with decoding streams in flight

@@ -31,7 +31,8 @@ will be priced in):
 ==================  =====================================================
 ``step``            productive step compute: dispatch/fetch host cost
                     plus the async pipeline in flight between them, and
-                    the serving tick phases (admit/prefill/decode/retire)
+                    the serving tick phases (admit/prefill/decode/land/
+                    retire)
 ``compile``         ledger-observed XLA compiles (``compile:<n>`` spans)
 ``data_stall``      host batch assembly / loader waits (``load``)
 ``ckpt``            checkpoint save + the async writer's disk time
@@ -87,6 +88,9 @@ CATEGORIES = ("step", "compile", "data_stall", "ckpt", "rollback", "eval",
 SPAN_CATEGORY = {
     "dispatch": "step", "fetch": "step",
     "admit": "step", "prefill": "step", "decode": "step", "retire": "step",
+    # the scheduler's wait for a finished stream's row: the device is
+    # running the programs queued behind it
+    "land": "step",
     "load": "data_stall",
     "eval": "eval",
     "ckpt": "ckpt", "ckpt_write": "ckpt",

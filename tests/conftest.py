@@ -203,3 +203,73 @@ def mesh1(devices):
     from neural_networks_parallel_training_with_mpi_tpu.config import MeshConfig
 
     return make_mesh(MeshConfig(data=1), devices=devices[:1])
+
+
+@pytest.fixture
+def staggered_batch():
+    """``run(net, params, requests, callers, **serve_config)``: a closed loop
+    of ``callers`` callers over one ``Scheduler`` (each sends its next
+    ``(prompt, max_new)`` of ``requests``, ``max_new`` 2 or more, when its
+    last was reported), so
+    streams finish at different ticks while others run, their rows land a
+    program behind, and their slots are admitted again with a row in flight.
+    Checks, for every request: it is reported in the tick after its last
+    step while another stream runs (in that same tick when none does), and
+    its tokens are those the same request gets alone on a fresh scheduler
+    (one stream: the row lands in the tick of its last step, with nothing
+    behind it); ``rows_landed_behind`` counts every row but those.  Returns
+    the scheduler, closed, for the caller's own counters."""
+    from neural_networks_parallel_training_with_mpi_tpu.serve import (
+        Scheduler, ServeConfig,
+    )
+
+    def alone(net, params, prompt, n, cfg):
+        sched = Scheduler(net, params, ServeConfig(**cfg))
+        rid = sched.submit(prompt, n)
+        sched.run_until_drained()
+        toks = sched.result(rid)
+        assert (sched.server.rows_landed,
+                sched.server.rows_landed_behind) == (1, 0)
+        sched.close()
+        return toks
+
+    def run(net, params, requests, callers, **cfg):
+        sched = Scheduler(net, params, ServeConfig(**cfg))
+        srv = sched.server
+        todo = list(requests)
+        waiting, sent, served = [None] * callers, {}, {}
+        taken_at = {}       # rid -> tick whose step made its last token
+        unqueued = 0        # rows landed with nothing queued behind them
+        for _ in range(10_000):
+            for c in range(callers):
+                if waiting[c] is None and todo:
+                    prompt, n = todo.pop(0)
+                    waiting[c] = sched.submit(prompt, n)
+                    sent[waiting[c]] = (prompt, n)
+            if not any(w is not None for w in waiting):
+                break
+            reported = sched.tick()
+            running = [r for r, s in sched._srv_rid.items() if srv.holds(s)]
+            for rid, s in sched._srv_rid.items():
+                if not srv.holds(s):        # taken, still in flight
+                    taken_at.setdefault(rid, sched.tick_no)
+            for rid in reported:
+                if rid in taken_at:
+                    assert sched.tick_no == taken_at.pop(rid) + 1, rid
+                else:   # taken and landed in this tick: nothing runs on
+                    assert not running, (rid, running)
+                    unqueued += 1
+                served[rid] = sched.result(rid)
+                waiting[waiting.index(rid)] = None
+                assert sched.stats(rid).t_done is not None
+        assert not todo and not taken_at and len(served) == len(requests)
+        assert not srv._in_flight
+        assert (srv.rows_landed, srv.rows_landed_behind) == (
+            len(requests), len(requests) - unqueued)
+        srv.assert_drained()
+        sched.close()
+        for rid, (prompt, n) in sent.items():
+            assert served[rid] == alone(net, params, prompt, n, cfg), rid
+        return sched
+
+    return run

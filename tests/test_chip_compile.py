@@ -623,6 +623,66 @@ def test_hybrid_cell_programs_fit_the_chip(spec, kernels_compiled, program):
                    for line in both)
 
 
+def test_hybrid_cell_boundary_programs_update_in_place(spec):
+    """The three programs of a request's boundary (``serve_admit``,
+    ``serve_first_token`` for a 512-column chunk's float32 logits,
+    ``serve_take``) at the cell's own sizes, for the described chip: the
+    admission zeroes one slot's rows of the 1.61 GB state store in place (the
+    store is aliased to its output and no layer's state is copied: the cell
+    has 1.2 GB of room, not 1.6), the first token reads one column of the
+    chunk's 268 MB of logits without a copy of them, and all three are
+    small beside any model program."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.harness import common
+
+    cell = common.load_cell("falconh1-34b-pp12-serve-chat")
+    model = cell["model"]["family"].program_model(cell["model"])
+    geo = cell["job"]["serve_config"]
+    s, bs = geo["slots"], geo["block_size"]
+    t_cap = geo["max_len"] // bs * bs
+    state = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: paged_kv.init_paged_state(model, s)))
+    store = sum(x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(state))
+    assert 1.6e9 < store < 1.63e9
+    take, admit, first = paged_kv._boundary_programs(
+        "compile-test", 0.0, 0, 1.0, model, bs, geo["max_len"] // bs)
+    tokens, pos = spec((s, t_cap), jnp.int32), spec((s,), jnp.int32)
+    where = spec((3,), jnp.int32)
+    stats = {"ssm": spec((len(paged_kv.SSM_COUNTERS),), jnp.int32)}
+
+    compiled = admit.lower(tokens, pos, state, spec((t_cap,), jnp.int32),
+                           where).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > store
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    moved = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if re.search(r"= \S*\[64,32,128,256\]\S* (?:copy|copy-start)\(",
+                          line)]
+    assert not moved, moved
+
+    logits = spec((1, geo["prefill_chunk"], cell["model"]["vocab_size"]),
+                  jnp.float32)
+    compiled = first.lower(logits, tokens, pos, spec((2,), jnp.uint32),
+                           where).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 20
+
+    compiled = take.lower(tokens, stats, spec((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 0       # buffers of their own
+    assert mem.output_size_in_bytes < 1 << 20
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # --- the data-parallel train step over the four described chips --------------
 
 

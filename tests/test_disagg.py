@@ -333,3 +333,43 @@ def test_decode_pool_death_reprefills_unified_on_prefill_pool(lm):
         _drained(pre)
     finally:
         _close(router, pre, dec)
+
+
+# ---------------------------------------------------------------------------
+# the request boundary (ISSUE 36) across the handoff
+# ---------------------------------------------------------------------------
+
+def test_single_token_requests_and_rows_in_flight_cross_the_handoff(lm):
+    """Requests of one token finish on the prefill role (their row is taken
+    at the prefill and reported by that tick's landing: no export of a
+    stream that is gone), the others are exported, imported through the
+    admission's one program (row and position in one call) and finished on
+    the decode role with their rows landing a program behind; every token
+    equals the unified scheduler's, and both roles drain."""
+    import numpy as np
+
+    model, params = lm
+    rng = np.random.default_rng(9)
+    jobs = [(rng.integers(0, V, int(p)).tolist(), int(m))
+            for p, m in ((5, 1), (17, 6), (3, 1), (9, 2), (12, 9), (20, 3))]
+    ref = _reference(model, params, jobs)
+    pre = InprocReplica(_sched(model, params, role="prefill", replica=0),
+                        name="pre-0")
+    dec = InprocReplica(_sched(model, params, role="decode", replica=1),
+                        name="dec-0")
+    router = FleetRouter([pre, dec], queue_depth=64)
+    try:
+        rids = [router.submit(p, m) for p, m in jobs]
+        assert all(r is not None for r in rids)
+        _drive(router, rids)
+        for rid, want in zip(rids, ref):
+            assert router.result(rid) == want
+        assert router.handoffs == sum(1 for _p, m in jobs if m > 1)
+        assert pre.sched.handed_off == router.handoffs
+        assert pre.sched.server.rows_landed == 2        # the single tokens
+        assert dec.sched.server.rows_landed == router.handoffs
+        assert not pre.sched.server._in_flight
+        assert not dec.sched.server._in_flight
+        _drained(pre, dec)
+    finally:
+        _close(router, pre, dec)

@@ -467,3 +467,19 @@ def test_the_schedulers_records_carry_both_kinds_of_counter(toy, tmp_path):
         [sys.executable, str(ROOT / "tools" / "metrics_summary.py"),
          str(tmp_path)], capture_output=True, text=True, check=True).stdout
     assert "keys read by kind of layer" in out
+
+
+def test_rows_landing_a_program_behind_serve_the_same_tokens(
+        toy, staggered_batch):
+    """Window and full layers over two kinds of pool under the request
+    boundary of ISSUE 36: a finished stream's blocks of both kinds go back
+    at its take, its slot is admitted again with the row in flight, and
+    every request's tokens are those it gets alone; both pools drain."""
+    net, params = toy[0], toy[1]
+    rng = np.random.default_rng(3)
+    requests = [(rng.integers(0, 96, size=int(rng.integers(2, 40))).tolist(),
+                 int(rng.integers(2, 16))) for _ in range(7)]
+    sched = staggered_batch(net, params, requests, 2, slots=2, num_blocks=40,
+                            block_size=4, max_len=80, prefill_chunk=16)
+    assert sched.attention_counters == sched.server.attention_counters
+    assert sched.attention_counters["window_keys"] > 0

@@ -596,7 +596,10 @@ def test_latent_table_churn_and_growth_never_recompile_under_fused(toy):
         while not (srv.done(a) and srv.done(c)):
             srv.step()
         srv.allocator.assert_drained()
-        assert len(led.events) == n_events
+        # (the first stream to finish compiles the take of its row: one
+        # program, whichever slot; nothing of the churn compiled)
+        assert [e["name"] for e in led.events[n_events:]] \
+            == ["serve_take[bs8x7]"]
     finally:
         compile_ledger.install(None)
     decode = led.events_for("serve_decode[bs8x7/fused]")
@@ -781,3 +784,27 @@ def test_config_refuses_what_the_block_cannot_be():
     with pytest.raises(ValueError, match="belongs to the capacity layer"):
         TransformerConfig(moe_experts=4, moe_dropless=True,
                           moe_expert_axis="expert")
+
+
+@pytest.mark.parametrize("impl", ["gathered", "fused"])
+def test_rows_landing_a_program_behind_serve_the_same_tokens(
+        toy, staggered_batch, impl):
+    """The latent row and the routing without drops under the request
+    boundary of ISSUE 36: eight requests over three callers, rows landing
+    behind the next tick's programs, slots admitted again meanwhile: every
+    request's tokens are those it gets alone, and the expert counters that
+    ride on the landed rows still count every program."""
+    net, params = toy[0], toy[1]
+    rng = np.random.default_rng(2)
+    requests = [(rng.integers(0, 96, size=int(rng.integers(2, 30))).tolist(),
+                 int(rng.integers(2, 14))) for _ in range(8)]
+    sched = staggered_batch(net, params, requests, 3, slots=3, block_size=8,
+                            num_blocks=33, max_len=64, prefill_chunk=8,
+                            attn_impl=impl)
+    counters = sched.expert_counters
+    chunks = sum(-(-len(p) // 8) for p, _ in requests)
+    assert counters["prefill_chunks_counted"] == chunks
+    # every decode tick but those after the last landed row's take (none:
+    # the drain's last row lands behind nothing, with the last tick's count)
+    assert counters["decode_ticks_counted"] > 0
+    assert counters == sched.server.expert_counters

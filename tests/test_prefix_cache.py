@@ -473,3 +473,23 @@ def test_cache_hit_cow_and_eviction_add_no_compiles(tmp_path):
     finally:
         sched.close()
     assert trace_lib.active() is None
+
+
+def test_rows_landing_a_program_behind_share_prefixes_all_the_same(
+        staggered_batch):
+    """The prefix cache under the request boundary of ISSUE 36: a finished
+    stream's blocks are released at the take of its row (owned prompt blocks
+    park cached-free, shared ones drop a reference) while the row is still
+    in flight, later arrivals hit them, and every request's tokens are those
+    it gets alone; the refcounts drain."""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, VOCAB, 18).tolist()
+    requests = [(shared + rng.integers(0, VOCAB, int(k)).tolist(), int(n))
+                for k, n in zip(rng.integers(0, 8, 8), rng.integers(2, 10, 8))]
+    sched = staggered_batch(model, params, requests, 2, slots=2,
+                            num_blocks=40, block_size=8, prefill_chunk=8,
+                            prefix_cache=True)
+    stats = sched.server.prefix_stats()
+    assert stats["prefix_hits"] >= 6 and stats["prefix_hit_tokens"] >= 6 * 16

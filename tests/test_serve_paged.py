@@ -796,3 +796,157 @@ def test_a_drained_server_holds_no_state_row(tmp_path):
     sched.server.state_rows[1] = 99
     with pytest.raises(AssertionError, match="state leak.*1: 99"):
         sched.server.assert_drained()
+
+
+# ---------------------------------------------------------------------------
+# the request boundary (ISSUE 36): a finished row lands one program behind
+# ---------------------------------------------------------------------------
+
+def _two_streams(a_new=3, b_new=12):
+    """``a`` and ``b`` prefilled side by side in slots 0 and 1."""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=2, num_blocks=24,
+                            block_size=8)
+    a = srv.try_admit([1, 2, 3], a_new)
+    b = srv.try_admit([9, 8, 7, 6, 5], b_new)
+    for rid in (a, b):
+        while not srv.prefill_step(rid, 16):
+            pass
+    return model, params, srv, a, b
+
+
+def test_step_reports_a_row_one_step_later_while_others_run():
+    """``a``'s last token is made by step 2: that call reports nothing, the
+    slot and the blocks are free when it returns, ``done`` says no (and does
+    not raise) while the row is in flight, and step 3 reports ``a``.  ``b``
+    is alone at its last step and comes back from that very call."""
+    model, params, srv, a, b = _two_streams()
+    used = srv.allocator.used_blocks
+    assert srv.step() == []
+    assert srv.step() == [] and not srv.done(a) and not srv.holds(a)
+    assert srv.free_slots() == 1 and srv.allocator.used_blocks == used - 1
+    assert [r.rid for r in srv._in_flight] == [a]
+    assert srv.step() == [a] and srv.done(a)
+    assert srv.result(a) == _dense_reference(model, params, [1, 2, 3], 3)
+    out = []
+    while not out:
+        out = srv.step()
+    assert out == [b] and not srv._in_flight
+    assert srv.result(b) == _dense_reference(model, params,
+                                             [9, 8, 7, 6, 5], 12)
+    assert (srv.rows_landed, srv.rows_landed_behind) == (2, 1)
+    srv.assert_drained()
+    with pytest.raises(KeyError):
+        srv.done(a)
+
+
+def test_a_slot_admitted_again_does_not_disturb_the_row_in_flight():
+    """``a``'s slot is taken by ``c`` while ``a``'s row is in flight: the
+    admission overwrites the slot's token row and position, ``c``'s chunk
+    and first token are written, and ``a`` still lands with its own tokens
+    (the take ran before them on the device, into a buffer of its own)."""
+    model, params, srv, a, b = _two_streams()
+    srv.step(); srv.step()                      # a's row taken, in flight
+    slot = 0
+    c = srv.try_admit([4] * 11, 6)
+    assert srv._slot_of[c] == slot and not srv.done(a)
+    while not srv.prefill_step(c, 16):
+        pass
+    assert srv.step() == [a]
+    assert srv.result(a) == _dense_reference(model, params, [1, 2, 3], 3)
+    while not (srv.done(b) and srv.done(c)):
+        srv.step()
+    assert srv.result(c) == _dense_reference(model, params, [4] * 11, 6)
+    assert srv.result(b) == _dense_reference(model, params,
+                                             [9, 8, 7, 6, 5], 12)
+    srv.assert_drained()
+
+
+def test_evicting_the_last_stream_leaves_nothing_to_wait_behind():
+    """With ``b`` evicted nothing is active: ``step`` dispatches no program
+    and lands ``a`` at once (no later program behind it: not counted as
+    ``behind``); a step with nothing in flight and nothing active is a
+    no-op."""
+    model, params, srv, a, b = _two_streams()
+    srv.step(); srv.step()
+    srv.evict(b)
+    assert srv.step() == [a]
+    assert (srv.rows_landed, srv.rows_landed_behind) == (1, 0)
+    assert srv.result(a) == _dense_reference(model, params, [1, 2, 3], 3)
+    assert srv.step() == [] and srv.land() == [] and srv.land(every=True) == []
+    srv.assert_drained()
+
+
+def test_a_mid_prefill_stream_does_not_hold_a_row_in_flight_for_ever():
+    """``b`` is admitted and never prefilled: ``a``'s row stays in flight
+    after its last step (a chunk of ``b`` could still be queued behind it),
+    and the next ``step``, which dispatches nothing, lands it."""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=2, num_blocks=24,
+                            block_size=8)
+    a = srv.try_admit([1, 2, 3], 2)
+    b = srv.try_admit([9, 8, 7, 6, 5], 4)
+    while not srv.prefill_step(a, 16):
+        pass
+    assert srv.step() == [] and not srv.done(a)
+    assert srv.step() == [a]
+    assert (srv.rows_landed, srv.rows_landed_behind) == (1, 0)
+    # a chunk dispatched behind the row counts
+    c = srv.try_admit([3, 3, 3], 2)
+    while not srv.prefill_step(c, 16):
+        pass
+    assert srv.step() == []
+    srv.prefill_step(b, 4)
+    assert srv.land() == [c]
+    assert (srv.rows_landed, srv.rows_landed_behind) == (2, 1)
+
+
+def test_a_single_token_request_is_taken_at_its_prefill():
+    """``max_new`` 1: the prefill's first token is the whole answer, the row
+    is taken behind the chunk and lands with the next call."""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=2, num_blocks=24,
+                            block_size=8)
+    rid = srv.try_admit([4, 5, 6], 1)
+    assert srv.prefill_step(rid, 16)
+    assert not srv.done(rid) and srv.free_slots() == 2
+    assert srv.step() == [rid]
+    assert srv.result(rid) == _dense_reference(model, params, [4, 5, 6], 1)
+
+
+def test_the_first_token_program_samples_as_the_eager_lines_did():
+    """The program that ends a prefill (``serve_first_token``) against the
+    lines it replaced, written out here: the same token from the same key,
+    the same next key, the same row and position, at a temperature and a
+    ``top_k`` that make the key matter."""
+    import jax
+
+    from neural_networks_parallel_training_with_mpi_tpu.models.generate import (
+        _sample,
+    )
+
+    model = _model()
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=2, num_blocks=24,
+                            block_size=8, temperature=0.9, top_k=12, seed=3)
+    seen = []
+    prefill = srv._prefill_fn
+    srv._prefill_fn = lambda *a: seen.append(prefill(*a)) or seen[-1]
+    prompt = [5, 9, 11, 13, 2, 2, 7]
+    srv.try_admit([1, 2], 5)
+    rid = srv.try_admit(prompt, 5)
+    slot, p = srv._slot_of[rid], len(prompt)
+    key = srv.key
+    tokens = np.asarray(srv.tokens).copy()
+    assert srv.prefill_step(rid, 16)
+    logits = seen[-1][0]
+    first, next_key = _sample(logits[:, p - 1], 0.9, key, 12, 1.0)
+    tokens[slot, p] = int(first[0])
+    assert (np.asarray(srv.tokens) == tokens).all()
+    assert (np.asarray(srv.pos) == [0, p]).all()
+    assert (jax.random.key_data(srv.key)
+            == jax.random.key_data(next_key)).all()
+    assert int(first[0]) != int(np.asarray(logits)[0, p - 1].argmax())

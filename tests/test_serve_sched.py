@@ -541,3 +541,177 @@ def test_drain_with_prefix_cache_refcounts_drain():
     sched.server.allocator.assert_drained()   # refcounts all zero
     assert {d["rid"] for d in drained} <= {r1, r2}
     sched.close()
+
+
+# ---- the request boundary: rows land one program behind (ISSUE 36) ---------
+
+def _boundary_sched(**kw):
+    model = _model()
+    params = model.init(prng.init_key(0))
+    cfg = dict(slots=4, num_blocks=40, block_size=8, prefill_chunk=8)
+    cfg.update(kw)
+    return model, params, Scheduler(model, params, ServeConfig(**cfg))
+
+
+@pytest.fixture
+def spans(tmp_path):
+    """Every span closed while the test runs, ``(name, attrs)`` (a tracer is
+    what makes the listeners hear them)."""
+    from neural_networks_parallel_training_with_mpi_tpu.train import (
+        trace as trace_lib,
+    )
+
+    heard = []
+    listener = lambda n, t, d, a: heard.append((n, dict(a or {})))  # noqa: E731
+    tracer = trace_lib.start_run(str(tmp_path / "trace"))
+    trace_lib.add_listener(listener)
+    yield heard
+    trace_lib.remove_listener(listener)
+    trace_lib.stop_run(tracer)
+
+
+def test_a_rid_is_reported_a_tick_after_its_last_step_or_in_it():
+    """``a`` needs 4 tokens and ``b`` 9: ``a``'s last step is dispatched in
+    tick 3 (its first token and its first step came in tick 1), its slot and
+    blocks are free at once, and ``tick()`` returns it in tick 4, behind
+    that tick's step; ``b`` is alone when its last step is dispatched and
+    comes back in that same tick."""
+    model, params, sched = _boundary_sched()
+    a = sched.submit([1, 2, 3], 4)
+    b = sched.submit([5, 6], 9)
+    seen = {}
+    while sched.pending() or sched.in_flight():
+        free = sched.server.free_slots()
+        for rid in sched.tick():
+            seen[rid] = (sched.tick_no, free)
+    # a: prefilled in tick 1, steps in ticks 1-3; b: prefilled in tick 2,
+    # steps in ticks 2-9
+    assert seen[a][0] == 4 and seen[b][0] == 9
+    # a's slot was free before the tick that reported it began
+    assert seen[a][1] == 3
+    assert sched.result(a) == _reference(model, params, [1, 2, 3], 4)
+    assert sched.result(b) == _reference(model, params, [5, 6], 9)
+    assert (sched.server.rows_landed, sched.server.rows_landed_behind) \
+        == (2, 1)
+    assert sched.stats(a).t_done <= sched.stats(b).t_done
+
+
+def test_rows_landed_behind_on_a_scripted_schedule(staggered_batch, spans):
+    """Twelve requests over three callers: every row lands with a program
+    queued behind it but those taken in a tick that left nothing running
+    (the drain's last, and a moment when all three callers wait at once);
+    the snapshot and the ``retire`` span carry both counters, and ``land``
+    is a span of its own."""
+    model = _model()
+    params = model.init(prng.init_key(0))
+    rng = np.random.default_rng(4)
+    requests = [(rng.integers(0, VOCAB, int(rng.integers(1, 20))).tolist(),
+                 int(rng.integers(2, 12))) for _ in range(12)]
+    sched = staggered_batch(model, params, requests, 3, slots=3,
+                            num_blocks=40, block_size=8, prefill_chunk=8)
+    snap = sched._snapshot()
+    assert snap["rows_landed"] == 12
+    # (the fixture holds the count to the ticks it saw; here, its size)
+    assert 9 <= snap["rows_landed_behind"] <= 11
+    stamped = [a for n, a in spans if n == "retire" and "rows_landed" in a]
+    # (the fixture's one-stream schedulers stamp theirs after this one's)
+    stamped = stamped[:[a["rows_landed"] for a in stamped].index(12) + 1]
+    assert stamped[-1]["rows_landed_behind"] == snap["rows_landed_behind"]
+    assert [a["rows_landed"] for a in stamped] == sorted(
+        a["rows_landed"] for a in stamped)
+    # the staggered run's ``land`` spans (the alone runs land one each)
+    assert sum(1 for n, _ in spans if n == "land") >= len(stamped)
+
+
+def test_a_tick_that_only_lands_rows_opens_no_decode_span(spans):
+    """``a`` finishes while ``b`` is still mid prefill: the next tick runs
+    one chunk of ``b`` and no step, lands ``a`` behind the chunk, and opens
+    ``retire`` but no ``decode``."""
+    model, params, sched = _boundary_sched(prefill_chunk=4)
+    a = sched.submit([1, 2, 3], 3)
+    sched.tick()                    # a prefilled, first token, step 1 of 2
+    b = sched.submit(list(range(1, 25)), 4)      # six chunks of prefill
+    assert sched.tick() == []       # b's chunk 1, a's last step: row taken
+    assert not sched.server.any_active()
+    spans.clear()
+    assert sched.tick() == [a]      # b's chunk 2, no step: a lands behind it
+    names = [n for n, _ in spans]
+    assert "decode" not in names and "land" in names and "retire" in names
+    assert (sched.server.rows_landed, sched.server.rows_landed_behind) \
+        == (1, 1)
+    sched.run_until_drained()
+    assert sched.result(a) == _reference(model, params, [1, 2, 3], 3)
+    assert sched.result(b) == _reference(model, params,
+                                         list(range(1, 25)), 4)
+
+
+@pytest.mark.parametrize("how", ["run_until_drained", "drain", "quiesce",
+                                 "close", "evict"])
+def test_nothing_stays_in_flight_once_nothing_is_dispatched(how):
+    """A row taken in the last tick before the caller stops ticking is
+    landed by what the caller does next, and its request is complete, not
+    handed back: ``drain`` / ``quiesce`` / ``close``; ``run_until_drained``
+    ends with none in flight; and after an eviction of the last running
+    stream the next tick lands it all the same."""
+    model, params, sched = _boundary_sched()
+    a = sched.submit([1, 2, 3], 3)
+    b = sched.submit([7, 8, 9, 10], 12)
+    for _ in range(2):
+        assert sched.tick() == []
+    # a's last step was dispatched in tick 2; b runs on
+    assert [r.rid for r in sched.server._in_flight] == [sched._srv_rid[a]]
+    assert not sched.done(a)
+    if how == "run_until_drained":
+        assert sched.run_until_drained() == [a, b]
+    elif how in ("drain", "quiesce"):
+        back = getattr(sched, how)()
+        assert [d["rid"] for d in back] == [b]
+    elif how == "close":
+        sched.close()
+    else:
+        sched._evict(b)                 # back to the head of the queue
+        assert sched.tick() == [a]      # behind b's new prefill chunk
+    assert not sched.server._in_flight
+    assert sched.done(a) and sched.stats(a).t_done is not None
+    assert sched.result(a) == _reference(model, params, [1, 2, 3], 3)
+    if how not in ("evict", "close"):   # b runs on under these two
+        sched.server.assert_drained()
+
+
+def test_a_second_scheduler_after_prewarm_records_no_compile(tmp_path):
+    """``prewarm`` runs whole requests through a throwaway scheduler, so the
+    boundary's three programs (``serve_admit``, one ``serve_first_token`` a
+    prefill bucket, ``serve_take``) compile there with the chunk and the
+    step: the scheduler that serves records none."""
+    from neural_networks_parallel_training_with_mpi_tpu.serve import prewarm
+    from neural_networks_parallel_training_with_mpi_tpu.train import (
+        trace as trace_lib,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.utils import (
+        compile_ledger,
+    )
+
+    model = _model(d_ff=72)     # programs no other test of this file made
+    params = model.init(prng.init_key(0))
+    make = lambda: Scheduler(model, params, ServeConfig(     # noqa: E731
+        slots=3, num_blocks=40, block_size=8, prefill_chunk=16))
+    tracer = trace_lib.start_run(str(tmp_path))
+    try:
+        ledger = compile_ledger.active()
+        prewarm(make, prompt_lens=(1, 30))
+        warm = [e["name"] for e in ledger.events]
+        sched = make()
+        rng = np.random.default_rng(0)
+        rids = [sched.submit(rng.integers(0, VOCAB, p).tolist(), n)
+                for p, n in ((1, 1), (7, 5), (16, 2), (30, 9), (11, 3))]
+        sched.run_until_drained()
+        assert all(sched.done(r) for r in rids)
+        sched.close()
+        after = [e["name"] for e in ledger.events][len(warm):]
+    finally:
+        trace_lib.stop_run(tracer)
+    assert after == []
+    count = lambda stem: sum(n.startswith(stem) for n in warm)  # noqa: E731
+    assert count("serve_admit[") == 1 and count("serve_take[") == 1
+    assert count("serve_first_token[") == count("serve_prefill[") == 2
+    assert count("serve_decode[") == 1

@@ -247,3 +247,21 @@ def test_the_state_row_and_the_counts(toy):
     assert net.fwd_flops((2, 10)) - plain.fwd_flops((2, 10)) \
         == 3 * 20 * per_token
     assert ref.recurrence_flops(MODEL) == per_token - 2 * 48 * 212
+
+
+def test_rows_landing_a_program_behind_serve_the_same_tokens(
+        toy, staggered_batch):
+    """Recurrent state beside the pages under the request boundary of ISSUE
+    36: the admission's one program zeroes the slot's state rows in place
+    while the last stream's row is still in flight, and every request's
+    tokens are those it gets alone on a fresh scheduler; no state row is
+    left held."""
+    net, params = toy[0], toy[1]
+    rng = np.random.default_rng(4)
+    requests = [(rng.integers(0, 96, size=int(rng.integers(2, 30))).tolist(),
+                 int(rng.integers(2, 12))) for _ in range(7)]
+    sched = staggered_batch(net, params, requests, 2, slots=2, num_blocks=33,
+                            block_size=8, max_len=64, prefill_chunk=8)
+    assert sched.ssm_counters == sched.server.ssm_counters
+    assert sched.ssm_counters["ssm_prefill_tokens"] == 3 * sum(
+        len(p) for p, _ in requests)
