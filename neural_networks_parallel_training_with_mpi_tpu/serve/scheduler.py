@@ -61,6 +61,7 @@ import collections
 import json
 import math
 import os
+import sys
 import time
 from dataclasses import dataclass
 from typing import Any, Deque, Dict, List, Optional
@@ -561,6 +562,10 @@ class Scheduler:
         # installed: their flow gets its decode point in the next decode
         # span (a point per phase change, not per stream per tick)
         self._flow_to_decode: List[int] = []
+        # one lap a tick, from a tick's start to the next one's (the
+        # caller's time between ticks included): a tick that ran long is
+        # recorded with where it stood (train/trace.py "Laps and stalls")
+        self._laps = trace_lib.LapWatch("serve_tick")
 
     # ---- client surface ------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int,
@@ -629,6 +634,7 @@ class Scheduler:
         """One scheduler tick: admit/prefill/decode/land/retire.  Returns
         the rids whose results reached the host during this tick."""
         self.tick_no += 1
+        self._laps.lap(self.tick_no, self.now())
         self._leave_gap()
         tracer = trace_lib.active()
         if tracer is not None and self._gap_state is not None:
@@ -718,6 +724,8 @@ class Scheduler:
     def close(self) -> None:
         self._leave_gap()
         self._land(every=True)      # no tick follows: nothing stays in flight
+        for line in self._laps.end():
+            log(line, every_process=True, file=sys.stderr)
         self.telemetry.close(self.tick_no, self._snapshot())
         if self._tracer is not None:
             trace_lib.stop_run(self._tracer)
@@ -1075,6 +1083,9 @@ class Scheduler:
             # that had a later program queued behind the wait
             "rows_landed": self.server.rows_landed,
             "rows_landed_behind": self.server.rows_landed_behind,
+            # ticks that ran long, and their seconds over the median tick
+            "stalls": self._laps.stalls,
+            "stall_s": round(self._laps.stall_s, 6),
             **self.expert_counters,
             **self.attention_counters,
             **self.ssm_counters,

@@ -428,13 +428,17 @@ _ACTIVE: Optional["Telemetry"] = None
 
 def emergency_dump(reason: str) -> Optional[str]:
     """Best-effort postmortem dump from wherever the process is dying
-    (utils.faults' injected crash, the watchdog's hang handler).
+    (utils.faults' injected crash, the watchdog's hang handler), and the
+    span tracer's last write (train/trace.py ``flush``).
 
     Deliberately does NOT drain the lag queue: on the hang path the queued
     futures are exactly what is stuck, and a ``device_get`` here would
     block the watchdog's exit forever.  The dump carries what was already
     fetched — which under the lag-2 discipline is everything up to ~2
     dispatches before the stall."""
+    # the tracer's pending spans first, telemetry on or off: these paths end
+    # in ``os._exit``, where no exit hook hands them to the file
+    trace_lib.flush()
     t = _ACTIVE
     if t is None or not t.enabled:
         return None
@@ -467,6 +471,10 @@ class Telemetry:
         self.enabled = bool(cfg.telemetry_dir)
         self.dir = cfg.telemetry_dir
         self.kind = kind
+        # the step loop's lap watch, set by the loop that owns one
+        # (``Trainer.fit``): its counters ride every record
+        self.laps: Optional[trace_lib.LapWatch] = None
+        self._stalls_seen = 0
         # the heartbeat/rollup role tag: "train" for the LM trainer's
         # kind="step" stream, else the kind itself ("rl", "serve")
         self.role = "train" if kind == "step" else kind
@@ -614,6 +622,17 @@ class Telemetry:
                                     fires=cum - self.skipped_total,
                                     grad_norm=rec.get("grad_norm"))
             self.skipped_total = cum
+        laps = self.laps
+        if laps is not None:
+            # steps that ran long and their seconds over the median step
+            # (train/trace.py "Laps and stalls"), each new one an event
+            rec["stalls"] = laps.stalls
+            rec["stall_s"] = round(laps.stall_s, 6)
+            new = laps.stalls - self._stalls_seen
+            self._stalls_seen = laps.stalls
+            if new:
+                for stall in list(laps.records)[-new:]:
+                    self.recorder.event("stall", stall["n"], **stall)
         self.last_record = rec
         self.recorder.record(rec)
         if self._jsonl is not None:

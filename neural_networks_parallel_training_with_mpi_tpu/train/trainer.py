@@ -1301,6 +1301,10 @@ class Trainer:
         watchdog = HangWatchdog(
             cfg.hang_timeout or None,
             on_timeout=lambda: telemetry_lib.emergency_dump("hang"))
+        # one lap a dispatch, marked ahead of the loader's ``next()``: a
+        # step that ran long is recorded with where it stood
+        # (train/trace.py "Laps and stalls")
+        laps = self.telemetry.laps = trace_lib.LapWatch("train_step")
         # anomaly policy (DESIGN.md §6): consumes the per-step loss
         # futures at a fixed lag of two dispatches, so its device_get only
         # ever waits on a step whose successor is already submitted — one
@@ -1397,7 +1401,8 @@ class Trainer:
                             for i, b in enumerate(self.loader.epoch(
                                 epoch, start_step=epoch_start_step)))
                     # each next() is a "load" span (host batch assembly)
-                    dispatches = trace_lib.traced_iter("load", dispatches)
+                    dispatches = trace_lib.traced_iter(
+                        "load", dispatches, before=lambda: laps.lap(step))
                     for batch, n_steps, rows in dispatches:
                         if shutdown.requested:
                             break
@@ -1574,6 +1579,8 @@ class Trainer:
             # generator would otherwise park its loader thread until GC
             if dispatches is not None and hasattr(dispatches, "close"):
                 dispatches.close()
+            for line in laps.end():
+                log(line, every_process=True, file=sys.stderr)
             exc = sys.exc_info()[1]
             if exc is not None:
                 # abnormal exit (anomaly abort, crash): the flight

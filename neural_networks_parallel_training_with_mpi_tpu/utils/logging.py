@@ -28,9 +28,10 @@ def is_leader() -> bool:
     return jax.process_index() == 0
 
 
-def log(msg: str, *, every_process: bool = False) -> None:
+def log(msg: str, *, every_process: bool = False,
+        file: Optional[TextIO] = None) -> None:
     if every_process or is_leader():
-        print(msg, flush=True)
+        print(msg, file=file, flush=True)
 
 
 class MetricsLogger:

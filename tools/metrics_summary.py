@@ -174,10 +174,18 @@ def summarize(records: List[Dict[str, Any]],
                     "prefix_misses",
                     "prefix_hit_tokens", "prefix_hit_rate",
                     "shared_blocks", "cow_forks", "cache_evictions",
-                    "blocks_saved", "cached_free_blocks"):
+                    "blocks_saved", "cached_free_blocks",
+                    "stalls", "stall_s"):
             if key in last:
                 tick_stats[key] = last[key]
         out["serving_ticks"] = tick_stats
+    # kind="step" records carry the step loop's cumulative stall counters
+    # (train/trace.py LapWatch): the run's figure is the last record's
+    step_recs = [r for r in records if r.get("kind") == "step"
+                 and "stalls" in r]
+    if step_recs:
+        out["step_stalls"] = {"stalls": step_recs[-1]["stalls"],
+                              "stall_s": step_recs[-1].get("stall_s")}
     # kind="alert" records (train.telemetry EMA z-score anomalies,
     # serve/scheduler.py SLO burn rate): count by name + the last few,
     # so a triage pass sees WHAT fired without grepping the stream
@@ -240,6 +248,13 @@ def summarize(records: List[Dict[str, Any]],
     return out
 
 
+def _stalls_line(counters: Dict[str, Any], what: str) -> str:
+    return (f"  STALLS: {counters['stalls']} {what} ran long, "
+            f"{counters.get('stall_s') or 0.0:.3f} s over the median "
+            "(the trace's `stall` spans say where: "
+            "tools/trace_report.py)")
+
+
 def serving_lines(summary: Dict[str, Any]) -> List[str]:
     """The serving view: request-latency percentiles + tick/pool/prefix-
     cache state — shared by the full render and ``--serve``."""
@@ -271,6 +286,8 @@ def serving_lines(summary: Dict[str, Any]) -> List[str]:
                 f"{st.get('padded_keys')} padded "
                 f"({st['attended_ratio']:.3f} "
                 "— the fused kernel's skipped work)")
+        if st.get("stalls"):
+            lines.append(_stalls_line(st, "ticks"))
         if st.get("walked_keys_share") is not None:
             lines.append(
                 f"  walked keys share: {st['walked_keys_share']:.3f} of "
@@ -338,6 +355,8 @@ def render_text(summary: Dict[str, Any], records: List[Dict[str, Any]],
     if summary.get("skipped_updates"):
         lines.append(f"  skipped updates: {summary['skipped_updates']} "
                      "(guarded steps rejected — see postmortem/events)")
+    if (summary.get("step_stalls") or {}).get("stalls"):
+        lines.append(_stalls_line(summary["step_stalls"], "steps"))
     for t in summary.get("topology_changes", []):
         bs = t.get("batch_size") or [None, None]
         ac = t.get("accum_steps") or [None, None]

@@ -11,7 +11,11 @@ per process × incarnation) and writes:
   relaunched shows both incarnations of every rank with the relaunch
   gap visible between them;
 * a text summary — per-phase time share per (process, incarnation),
-  per-request flow-point counts, the DROPPED-span count from each
+  per-request flow-point counts, one ``STALL`` row per lap that ran long
+  (``stall`` spans, train/trace.py "Laps and stalls": loop, lap, wall,
+  excess, where it stood, the cause and the OS readings behind it; on the
+  timeline each lies on a ``stalls`` track of its own), the DROPPED-span
+  count from each
   bounded tracer's footer (a truncated track is flagged TRUNCATED
   instead of reading as a quiet tail), and the compile ledger rollup
   (compiles, recompiles, total compile seconds, what changed).
@@ -124,6 +128,18 @@ _META_KEYS = ("kind", "name", "t", "dur", "p", "run", "inc", "thread",
               "id", "fph")
 
 
+# what a ``stall`` span says of its lap (train/trace.py "Laps and stalls"),
+# in the order the stalls table prints it
+_STALL_KEYS = ("loop", "n", "excess_s", "where", "where_s", "cause", "cpu_s",
+               "cpu_other_s", "run_delay_s", "nvcsw", "nivcsw", "majflt",
+               "gc_s", "trace_write_s", "compiles", "loadavg", "psi_cpu",
+               "psi_io", "psi_mem")
+
+
+def _is_stall(rec: Dict[str, Any]) -> bool:
+    return rec.get("kind") == "span" and rec.get("name") == "stall"
+
+
 def to_chrome(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
     """Chrome trace-event JSON: one Chrome 'process' per (run, process,
     incarnation) group, named so Perfetto's track labels carry the
@@ -142,7 +158,8 @@ def to_chrome(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
                        "args": {"name": f"proc {p} / incarnation {inc}"
                                         f" [{run}]"}})
         for r in recs:
-            thread = r.get("thread", "main")
+            # a stall lies over its whole lap's spans: a track of its own
+            thread = ("stalls" if _is_stall(r) else r.get("thread", "main"))
             tkey = (key, thread)
             if tkey not in tids:
                 tids[tkey] = sum(1 for (k, _t) in tids if k == key)
@@ -204,7 +221,9 @@ def summarize(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
     groups = _groups(spans)
     for key in sorted(set(groups) | set(flow_groups) | set(dropped)):
         run, p, inc = key
-        recs = groups.get(key, [])
+        # a stall repeats its lap's seconds: listed apart, not a phase
+        stalls = [r for r in groups.get(key, []) if _is_stall(r)]
+        recs = [r for r in groups.get(key, []) if not _is_stall(r)]
         starts = [float(r["t"]) for r in recs]
         ends = [float(r["t"]) + float(r.get("dur", 0.0)) for r in recs]
         wall = max(ends) - min(starts) if recs else 0.0
@@ -227,6 +246,9 @@ def summarize(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
             "t_last": round(max(ends), 6) if ends else None,
             "wall_s": round(wall, 6),
             "phases": phases,
+            "stalls": [{"t": r.get("t"), "wall_s": r.get("dur"),
+                        **{k: r.get(k) for k in _STALL_KEYS}}
+                       for r in stalls],
         })
     # relaunch gaps: for each (run, process), the quiet time between one
     # incarnation's last span and the next incarnation's first
@@ -268,6 +290,28 @@ def summarize(data: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
     return out
 
 
+def _stall_row(st: Dict[str, Any]) -> str:
+    def num(key, fmt=".2f"):
+        return "n/a" if st.get(key) is None else format(st[key], fmt)
+
+    row = (f"  STALL {st.get('loop')} {st.get('n')}: {num('wall_s')}s wall, "
+           f"{num('excess_s')}s over the median, in {st.get('where')} "
+           f"{num('where_s')}s, cause {st.get('cause')}; cpu {num('cpu_s')}s"
+           f", other threads {num('cpu_other_s')}s, run delay "
+           f"{num('run_delay_s')}s, switches {st.get('nvcsw')} voluntary / "
+           f"{st.get('nivcsw')} involuntary, {st.get('majflt')} major "
+           f"faults, gc {num('gc_s')}s, trace write {num('trace_write_s')}s"
+           f", {st.get('compiles')} compile(s)")
+    psi = ", ".join(f"{label} {st[key]:g}%" for key, label in
+                    (("psi_cpu", "cpu"), ("psi_io", "io"),
+                     ("psi_mem", "memory")) if st.get(key) is not None)
+    if psi:
+        row += f"; pressure {psi}"
+    if st.get("loadavg") is not None:
+        row += f"; load {st['loadavg']:g}"
+    return row
+
+
 def render_text(summary: Dict[str, Any]) -> str:
     lines: List[str] = []
     runs = summary.get("runs", [])
@@ -289,6 +333,8 @@ def render_text(summary: Dict[str, Any]) -> str:
                      else f"  {100 * ph['share']:5.1f}%")
             lines.append(f"  {name:<16} {ph['count']:>6}x  "
                          f"{ph['total_s']:>10.3f}s{share}")
+        for st in g.get("stalls", []):
+            lines.append(_stall_row(st))
     for gap in summary.get("relaunch_gaps", []):
         lines.append(f"relaunch gap: proc {gap['process']} incarnation "
                      f"{gap['from_incarnation']} -> "
