@@ -102,7 +102,10 @@ row lands at once (a single stream, a drained scheduler, ``drain`` /
 rest of a request's boundary is one program each: an admission
 (``serve_admit``: token row, position and, for a model with state, the
 slot's zeroed state rows, in place) and a prefill's first token
-(``serve_first_token``, one a prefill bucket).
+(``serve_first_token``: sampled from the one row of logits the prompt's last
+chunk returns, so one compile a server).  A prefill chunk runs the head on
+the column that is read and on no other: the last true column of a prompt's
+last chunk; a chunk that is not the last runs no head at all.
 
 **Prefix caching + copy-on-write** (``prefix_cache=True``): real chat
 traffic shares system prompts, and the block-table indirection above is
@@ -538,7 +541,7 @@ def _boundary_programs(tag: str, temperature: float, top_k: int,
     (``server``: the rest of that cache's key, so that a server's ledger
     events do not depend on which servers ran before it); none of them
     touches a pool.  ``slot`` is a traced scalar everywhere: one compile
-    each, and ``first_token`` one a prefill bucket.
+    each.
 
     ``take``: a finished stream's row and the counters as of the program
     just dispatched, as buffers of their own: the next step donates
@@ -569,13 +572,12 @@ def _boundary_programs(tag: str, temperature: float, top_k: int,
 
     def first_token(logits, tokens, pos, key, where):
         """A prefill's last chunk hands over to decode as one program: the
-        first token sampled from the chunk's logits at its last true column
-        ``col`` (one compile a prefill bucket), written at ``(slot, at)``,
-        the position set to ``at``; ``where`` is ``[slot, col, at]``."""
-        slot, col, at = where[0], where[1], where[2]
-        tok, key = _sample(
-            jax.lax.dynamic_index_in_dim(logits, col, 1, keepdims=False),
-            temperature, key, top_k, top_p)
+        first token sampled from ``logits``, the ``(1, V)`` row the chunk
+        program made at its last true column (whatever the chunk's bucket:
+        one compile), written at ``(slot, at)``, the position set to ``at``;
+        ``where`` is ``[slot, at]``."""
+        slot, at = where[0], where[1]
+        tok, key = _sample(logits, temperature, key, top_k, top_p)
         tokens = jax.lax.dynamic_update_slice(tokens, tok[:, None],
                                               (slot, at))
         pos = jax.lax.dynamic_update_slice(pos, at[None], (slot,))
@@ -937,7 +939,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             new_pools.append(pool)
             loads.append(load)
             new_state.append(rows)
-        return model.head_logits(params, x), new_pools, loads, new_state
+        # the last block's hidden state: each program applies the head to
+        # the columns it reads
+        return x, new_pools, loads, new_state
 
     def count_load(stats, loads, decode):
         """Fold one program's expert loads (a (count,) int32 per layer)
@@ -988,10 +992,12 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     # ``state`` and ``slot``: the second store of a model with recurrent
     # state and the chunk's stream's place in it (None otherwise)
     def prefill(params, pools, state, stats, table, slot, start, chunk,
-                true_w):
-        # chunk (1, W_bucket) int32; logits for ALL columns return and
-        # the caller indexes the true last position (same contract as
-        # the dense server's bucketed prefill).  attendable keys after
+                true_w, last):
+        # chunk (1, W_bucket) int32; the (1, V) float32 logits of the last
+        # true column return, and only under ``last`` (the chunk is its
+        # prompt's last: the one column a first token is sampled from);
+        # any other chunk runs no head and returns zeros.  ``last`` is a
+        # traced scalar: one program a bucket.  attendable keys after
         # this chunk's writes: everything up to start + true_w (pad
         # columns wrote to the sink, which is past every length)
         valid = jnp.arange(chunk.shape[1]) < true_w
@@ -999,9 +1005,14 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         rows = jax.tree_util.tree_map(
             lambda s: jax.lax.dynamic_slice_in_dim(s, slot, 1, 0),
             state) if recurrent else None
-        logits, new_pools, loads, rows = forward(
+        x, new_pools, loads, rows = forward(
             params, pools, table, start, chunk, valid, start + true_w, False,
             rows)
+        logits = jax.lax.cond(
+            last,
+            lambda h: model.head_logits(params, h)[:, 0],
+            lambda h: jnp.zeros((1, c.vocab_size), jnp.float32),
+            jax.lax.dynamic_slice_in_dim(x, true_w - 1, 1, 1))
         if recurrent:
             state = jax.tree_util.tree_map(
                 lambda s, r: jax.lax.dynamic_update_slice_in_dim(
@@ -1017,9 +1028,10 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         # inactive lanes carry length 0, so the fused kernel walks ZERO
         # of their blocks (the gathered path computes-and-discards them)
         lengths = jnp.where(active, pos + 1, 0)
-        logits, new_pools, loads, state = forward(
+        x, new_pools, loads, state = forward(
             params, pools, tables, pos, ids, jnp.ones((1,), bool), lengths,
             True, state)
+        logits = model.head_logits(params, x)
         with jax.named_scope("sample"):
             nxt, key = _sample(logits[:, 0], temperature, key, top_k,
                                top_p)
@@ -1044,10 +1056,11 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
     if not recurrent:
         with_state = (prefill, step)
 
-        def prefill(params, pools, stats, table, start, chunk, true_w):
+        def prefill(params, pools, stats, table, start, chunk, true_w,
+                    last):
             logits, new_pools, _, stats = with_state[0](
                 params, pools, None, stats, table, None, start, chunk,
-                true_w)
+                true_w, last)
             return logits, new_pools, stats
 
         def step(params, pools, stats, tokens, tables, pos, active, key):
@@ -1270,6 +1283,8 @@ class PagedDecodeServer:
         self._programs_at_land = 0
         self.rows_landed = 0          # rows brought to the host
         self.rows_landed_behind = 0   # ... with a later program queued
+        self.prefill_chunks = 0       # chunk programs dispatched
+        self.prefill_heads = 0        # ... whose head ran (a prompt's last)
         if c.scan_layers:
             params = dict(params)
             stacked = params["blocks"]
@@ -1655,8 +1670,11 @@ class PagedDecodeServer:
             # the chunk as one fresh numpy row (a list of a thousand Python
             # ints costs 2 ms to hand over, with the device idle behind it
             # whenever a finished stream's fetch has just drained the queue)
-            chunk = np.zeros((1, prefill_bucket(w)), np.int32)
+            bucket = prefill_bucket(w)
+            chunk = np.zeros((1, bucket), np.int32)
             chunk[0, :w] = st.prompt[st.prefilled:st.prefilled + w]
+            # the prompt's last chunk is the one whose head runs
+            last = st.prefilled + w >= p
             # the device gets a HOST-side copy: on the CPU backend asarray
             # may alias the numpy buffer (and jnp.array's own copy is an
             # async device op), while the host mutates self.tables /
@@ -1664,8 +1682,9 @@ class PagedDecodeServer:
             table = self._device_tables(slice(slot, slot + 1))
             args = (jnp.asarray([st.prefilled], jnp.int32),
                     jnp.asarray(chunk),
-                    jnp.asarray(w, jnp.int32))
-        with trace_lib.span("prefill/submit"):
+                    jnp.asarray(w, jnp.int32),
+                    jnp.asarray(last, jnp.bool_))
+        with trace_lib.span("prefill/submit", bucket=bucket, head=int(last)):
             if self.state:
                 (logits, self.pools, self.state,
                  self.stats) = self._prefill_fn(
@@ -1675,18 +1694,20 @@ class PagedDecodeServer:
                 logits, self.pools, self.stats = self._prefill_fn(
                     self.params, self.pools, self.stats, table, *args)
             self._programs += 1
+            self.prefill_chunks += 1
+            self.prefill_heads += int(last)
         st.prefilled += w
         if self.window is not None:
             # behind the window at once: the pool holds ONE chunk beside
             # the slots' windows, and the next op may be another stream's
             self._window_span(st, slot, st.prefilled, st.prefilled - 1)
-        self._register_prefix(st, final=st.prefilled >= p)
-        if st.prefilled < p:
+        self._register_prefix(st, final=last)
+        if not last:
             return False
         with trace_lib.span("prefill/first_token"):
             self.tokens, self.pos, self.key = self._first_fn(
                 logits, self.tokens, self.pos, self.key,
-                np.asarray([slot, w - 1, p], np.int32))
+                np.asarray([slot, p], np.int32))
             self._pos_host[slot] = p
             self.active[slot] = st.max_new > 1
             if st.max_new <= 1:

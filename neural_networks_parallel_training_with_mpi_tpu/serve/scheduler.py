@@ -1083,6 +1083,10 @@ class Scheduler:
             # that had a later program queued behind the wait
             "rows_landed": self.server.rows_landed,
             "rows_landed_behind": self.server.rows_landed_behind,
+            # prefill chunk programs dispatched, and those of them whose
+            # head ran (a prompt's last chunk)
+            "prefill_chunks": self.server.prefill_chunks,
+            "prefill_heads": self.server.prefill_heads,
             # ticks that ran long, and their seconds over the median tick
             "stalls": self._laps.stalls,
             "stall_s": round(self._laps.stall_s, 6),

@@ -355,19 +355,24 @@ def test_prefill_in_chunks_then_decode_through_the_latent_cache(toy, impl):
         counters["decode_ticks_counted"] * 2 * 4)
     assert counters["expert_assignments"] >= counters[
         "prefill_expert_assignments"] > 0
-    # the prefill program's own logits, one chunk of a 30-token prompt
+    # the prefill program's own logits, one chunk of a 30-token prompt: the
+    # program returns the row of its last true column, so each position is
+    # read as the last of its prefix (the same chunk, ``true_w`` shorter)
     srv = PagedDecodeServer(net, params, slots=2, num_blocks=17,
                             block_size=8, max_len=64, attn_impl=impl)
     rid = srv.try_admit(prompts[2], 4)
     slot = srv._slot_of[rid]
-    logits, _pools, _stats = srv._prefill_fn(
-        srv.params, srv.pools, srv.stats,
-        jnp.asarray(srv.tables[slot:slot + 1].copy()),
-        jnp.asarray([0], jnp.int32),
-        jnp.asarray([prompts[2] + [0, 0]], jnp.int32),
-        jnp.asarray(30, jnp.int32))
+    rows = []
+    for true_w in range(1, 31):
+        row, srv.pools, srv.stats = srv._prefill_fn(
+            srv.params, srv.pools, srv.stats,
+            jnp.asarray(srv.tables[slot:slot + 1].copy()),
+            jnp.asarray([0], jnp.int32),
+            jnp.asarray([prompts[2] + [0, 0]], jnp.int32),
+            jnp.asarray(true_w, jnp.int32), jnp.asarray(True))
+        rows.append(row)
     want = reference_logits(outer, layers, jnp.asarray([prompts[2]]))
-    assert close(logits[:, :30], want)
+    assert close(jnp.stack(rows, axis=1), want)
 
 
 def dense_toy():
@@ -699,12 +704,14 @@ def test_fused_exports_to_a_gathered_importer_and_back(toy, row):
 
 
 # the latent programs' lowered text (StableHLO, no locations) of the toy
-# below, as commit 678b5f3 (PR 29) lowered it: ISSUE 30 changed the per-head
-# path beside it and asked that this one not move.  A PR that means to change
-# the latent path re-pins these two.
+# below.  ``decode`` as commit 678b5f3 (PR 29) lowered it: ISSUE 30 changed
+# the per-head path beside it and asked that this one not move.  ``prefill``
+# re-pinned by PR 38 (the chunk's head on its last true column alone, under
+# ``last``: ISSUE 38 asked that ``decode`` stay byte for byte).  A PR that
+# means to change the latent path re-pins what it moves.
 _LATENT_TEXT_SHA256 = {
     "decode": "26a4c660ac11aa7dd14e6f7b4ef8cfc6ea785bd766cc8324d7b2cdd1dd0f5b35",
-    "prefill": "27629a94daeb2f438af5ee16cc87d2351c9155bf0d4f5339a66f247799390cae",
+    "prefill": "ac7e7ef331dc5467e605e463da3618ae1e1647f2d51abc150f1b1555b4e2c708",
 }
 
 
@@ -721,7 +728,7 @@ def test_latent_programs_lower_to_the_parents_text(toy):
         "prefill": srv._prefill_fn.lower(
             srv.params, srv.pools, srv.stats, jnp.asarray(srv.tables[:1]),
             jnp.zeros((1,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
-            jnp.asarray(5, jnp.int32)).as_text()}
+            jnp.asarray(5, jnp.int32), jnp.asarray(True)).as_text()}
     got = {k: hashlib.sha256(v.encode()).hexdigest() for k, v in text.items()}
     assert got == _LATENT_TEXT_SHA256
 

@@ -705,11 +705,13 @@ def test_donation_audit_fused_decode_program():
 # kernel interpreted, so its whole body is in the text) at ``starcoder2-3b``'s
 # shapes and 2 layers, as commit 50eb37a (PR 31) lowered them: ISSUE 32 gave
 # the kernel a second mode beside this one and asked that this one not move
-# (on the chip the programs' compile-cache keys then stay).  A PR that means
-# to change the per-head path re-pins these two.
+# (on the chip the programs' compile-cache keys then stay).  ``prefill`` was
+# re-pinned by PR 38 (the chunk's head on its last true column alone, under
+# ``last``), which left ``decode`` byte for byte.  A PR that means to change
+# the per-head path re-pins what it moves.
 _PER_HEAD_TEXT_SHA256 = {
     "decode": "0faf555c209d3d7b064b0fbfbb2e7604dddb5cd969cc9ed8fe388380f5de2546",
-    "prefill": "cabc201edc343b73a8d541cc1469f5ad52531108661d275710b300207e1671af",
+    "prefill": "98e5d7fefbdfb23e9aa8634718d1ee1e04ea6a0c9b2219903f3ca7fba4eb3cb4",
 }
 
 
@@ -742,7 +744,7 @@ def test_per_head_programs_lower_to_the_parents_text():
         "prefill": prefill.lower(
             params, pools, {}, spec((1, mb), jnp.int32),
             spec((1,), jnp.int32), spec((1, 512), jnp.int32),
-            spec((), jnp.int32)).as_text()}
+            spec((), jnp.int32), spec((), jnp.bool_)).as_text()}
     got = {k: hashlib.sha256(v.encode()).hexdigest() for k, v in text.items()}
     assert got == _PER_HEAD_TEXT_SHA256
 

@@ -143,7 +143,7 @@ def paged_names():
     prefill = srv._prefill_fn.lower(
         srv.params, srv.pools, srv.stats, jnp.asarray(srv.tables[:1]),
         jnp.zeros((1,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
-        jnp.asarray(5, jnp.int32)).compile()
+        jnp.asarray(5, jnp.int32), jnp.asarray(True)).compile()
     return {"step": _op_names(step), "prefill": _op_names(prefill)}
 
 

@@ -534,8 +534,9 @@ def _hybrid_server(net, params, **kw):
 
 
 def _record_prefill_logits(srv, into):
-    """Keep every prefill chunk's logits (the program returns them for all
-    columns; the server reads the last true one)."""
+    """Keep every prefill chunk's logits: the ``(V,)`` row of its last true
+    column where the chunk was its prompt's last, zeros otherwise (the
+    program runs the head on the one column the server reads)."""
     inner = srv._prefill_fn
 
     def recording(*args):
@@ -544,6 +545,24 @@ def _record_prefill_logits(srv, into):
         return out
 
     srv._prefill_fn = recording
+
+
+def _prefix_logits(srv, prompt, width, between=lambda: None):
+    """The logits at every position of ``prompt``, through the server:
+    position ``n - 1`` is the last true column of the last chunk of the
+    prefix ``prompt[:n]``, so each prefix is prefilled as a request of its
+    own (one token, gone at once); ``between`` runs between its chunks."""
+    rows, out, inner = [], [], srv._prefill_fn
+    _record_prefill_logits(srv, rows)
+    for n in range(1, len(prompt) + 1):
+        rid = srv.try_admit(prompt[:n], 1)
+        while not srv.prefill_step(rid, width):
+            assert not rows[-1].any()   # not the last chunk: no head ran
+            between()
+        out.append(rows[-1])
+        srv.land(every=True)
+    srv._prefill_fn = inner
+    return np.stack(out)
 
 
 def _state_rows(srv, slot):
@@ -566,26 +585,29 @@ def _state_rows(srv, slot):
 def test_chunked_prefill_then_decode_against_the_full_forward(dtype, tol):
     """A prompt of 21 in chunks of 8 (8, 8 and 5 true columns of a bucket
     of 8), then 9 decode ticks through ``PagedDecodeServer`` with a stranger
-    beside it: the logits of every prompt position, and of the positions
-    that predicted the served tokens, against the reference's full forward
-    pass over prompt + served tokens."""
+    beside it: the logits of every prompt position (each the last true
+    column of a prefix's last chunk, the stranger decoding between chunks),
+    and of the positions that predicted the served tokens, against the
+    reference's full forward pass over prompt + served tokens."""
     import jax
 
     net, params, model, (outer, layers) = _hybrid(dtype)
     ref = model["family"]
     srv = _hybrid_server(net, params)
-    chunks = []
-    _record_prefill_logits(srv, chunks)
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, 96, size=21).tolist()
-    other = srv.try_admit(rng.integers(0, 96, size=6).tolist(), 30)
+    other = srv.try_admit(rng.integers(0, 96, size=6).tolist(), 56)
     while not srv.prefill_step(other, 8):
         pass
-    chunks.clear()
+    got = _prefix_logits(srv, prompt, 8, between=srv.step)
+    chunks = []
+    _record_prefill_logits(srv, chunks)
     rid = srv.try_admit(prompt, 10)
     while not srv.prefill_step(rid, 8):
         srv.step()                      # the stranger decodes between chunks
-    assert [c.shape[0] for c in chunks] == [8, 8, 8]
+    assert [c.shape for c in chunks] == [(96,)] * 3
+    assert not chunks[0].any() and not chunks[1].any()
+    assert (chunks[2] == got[20]).all()
     while not srv.done(rid):
         srv.step()
     served = srv.result(rid)
@@ -598,14 +620,15 @@ def test_chunked_prefill_then_decode_against_the_full_forward(dtype, tol):
             x = ref.block(model, f32(p), x, i)
         want = np.asarray(ref.head_logits(model, f32(outer), x))[0]
     scale = np.abs(want).max()
-    got = np.concatenate([chunks[0], chunks[1], chunks[2][:5]])
     assert np.abs(got - want[:21]).max() <= tol * scale
     # each served token is the reference's best at its position, or lies
     # within the tolerance of it (greedy; rounding may swap near ties)
     for t in range(21, 31):
         row = want[t - 1]
         assert row.max() - row[served[t]] <= 2 * tol * scale, t
-    assert srv.ssm_counters["ssm_prefill_tokens"] == 3 * (6 + 21)
+    # three mixer layers: the stranger, the 21 prefixes, the prompt
+    assert srv.ssm_counters["ssm_prefill_tokens"] == 3 * (
+        6 + sum(range(1, 22)) + 21)
 
 
 def test_a_slot_admitted_again_starts_from_zero_state(monkeypatch):
@@ -641,7 +664,9 @@ def test_a_slot_admitted_again_starts_from_zero_state(monkeypatch):
     for mine, theirs in zip(rows, want_rows):
         for name in mine:
             assert (mine[name] == theirs[name]).all(), name
-    assert (logits[0] == want_logits[0]).all()
+    # the first chunk (8 of 12) ran no head; the last one's row is read
+    assert not logits[0].any() and want_logits[1].any()
+    assert (logits[1] == want_logits[1]).all()
 
     def skipped(self, slot, rid):       # the fault: the row is not zeroed
         self.state_rows[slot] = rid
@@ -650,8 +675,8 @@ def test_a_slot_admitted_again_starts_from_zero_state(monkeypatch):
     logits = []
     rows, _ = second_after_first(_hybrid_server(net, params, slots=1), logits)
     assert not (rows[0]["ssm"] == want_rows[0]["ssm"]).all()
-    moved = np.abs(logits[0] - want_logits[0]).max()
-    assert moved > 0.1 * np.abs(want_logits[0]).max()
+    moved = np.abs(logits[1] - want_logits[1]).max()
+    assert moved > 0.1 * np.abs(want_logits[1]).max()
 
 
 def test_idle_lanes_and_pad_columns_leave_state_and_tail_as_they_were():
@@ -702,7 +727,7 @@ def test_idle_lanes_and_pad_columns_leave_state_and_tail_as_they_were():
             one.params, one.pools, one.state, one.stats,
             one._device_tables(slice(0, 1)), jnp.asarray(0, jnp.int32),
             jnp.asarray([0], jnp.int32), jnp.asarray(chunk),
-            jnp.asarray(5, jnp.int32))
+            jnp.asarray(5, jnp.int32), jnp.asarray(True))
         blocks = one._streams[rid].blocks
         return _state_rows(one, 0), [
             np.asarray(pool["k"])[blocks] for pool in one.pools]
@@ -717,7 +742,7 @@ def test_idle_lanes_and_pad_columns_leave_state_and_tail_as_they_were():
 
 def test_evicted_and_readmitted_the_state_is_rebuilt_by_prefill():
     """A stream evicted mid decode and admitted again prefills its prompt
-    anew from a zero state: the logits of its chunks and its tokens are
+    anew from a zero state: the logits of its last chunk and its tokens are
     those of the undisturbed run, bit for bit; the state row went with the
     slot and came back with the admission."""
     net, params, _model, _t = _hybrid()
@@ -747,9 +772,9 @@ def test_evicted_and_readmitted_the_state_is_rebuilt_by_prefill():
     got = _drain(srv, again, 8)
     assert got == want
     assert len(logits) == len(want_logits) == 3
-    # the true columns (a pad column attends whatever the sink holds)
-    for mine, theirs, true in zip(logits, want_logits, (8, 8, 3)):
-        assert (mine[:true] == theirs[:true]).all()
+    # the last chunk's one row, which every earlier chunk's state reaches
+    assert not logits[0].any() and not logits[1].any()
+    assert want_logits[2].any() and (logits[2] == want_logits[2]).all()
 
 
 def test_a_drained_server_holds_no_state_row(tmp_path):
@@ -942,11 +967,193 @@ def test_the_first_token_program_samples_as_the_eager_lines_did():
     key = srv.key
     tokens = np.asarray(srv.tokens).copy()
     assert srv.prefill_step(rid, 16)
-    logits = seen[-1][0]
-    first, next_key = _sample(logits[:, p - 1], 0.9, key, 12, 1.0)
+    logits = seen[-1][0]                # (1, V): the last true column's
+    first, next_key = _sample(logits, 0.9, key, 12, 1.0)
     tokens[slot, p] = int(first[0])
     assert (np.asarray(srv.tokens) == tokens).all()
     assert (np.asarray(srv.pos) == [0, p]).all()
     assert (jax.random.key_data(srv.key)
             == jax.random.key_data(next_key)).all()
-    assert int(first[0]) != int(np.asarray(logits)[0, p - 1].argmax())
+    assert int(first[0]) != int(np.asarray(logits)[0].argmax())
+
+
+# ---------------------------------------------------------------------------
+# the chunk's head (ISSUE 38): one column, and only a prompt's last chunk
+# ---------------------------------------------------------------------------
+
+FAMILIES = ["per_head", "latent", "window", "recurrent"]
+
+
+def _family_toy(family):
+    """One toy a kind of cache row: per-head K and V, the latent row (with
+    routing without drops), window and full layers over two pools, and the
+    recurrent state beside the pool; float32."""
+    if family == "recurrent":
+        return _hybrid()[:2]
+    if family == "latent":
+        import sys
+        from pathlib import Path
+
+        root = Path(__file__).resolve().parents[1]
+        if str(root) not in sys.path:
+            sys.path.insert(0, str(root))
+        from benchmark.families import mla_moe
+
+        rope = {"beta_fast": 4, "beta_slow": 1, "factor": 4,
+                "llama_4_scaling_beta": 0.1, "mscale": 1,
+                "mscale_all_dim": 1, "original_max_position_embeddings": 16}
+        net = mla_moe.program_model({
+            "vocab_size": 96, "d_model": 48, "n_layers": 2, "n_heads": 4,
+            "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+            "qk_rope_head_dim": 8, "v_head_dim": 12, "expert_ff": 24,
+            "shared_experts": 1, "experts_total": 8, "experts_first": 2,
+            "experts_held": 4, "top_k": 2, "routed_scale": 1,
+            "max_seq_len": 64, "rms_eps": 1e-6, "rope_theta": 10000,
+            "rope": rope, "param_dtype": "float32",
+            "compute_dtype": "float32", "family": mla_moe, "config": "toy"})
+    elif family == "window":
+        net = _window_model()
+    else:
+        net = _model(n_kv_heads=2, pos_encoding="rope")
+    return net, net.init(prng.init_key(0))
+
+
+def _family_server(net, params, **kw):
+    return PagedDecodeServer(net, params, slots=3, num_blocks=40,
+                             block_size=4, max_len=64, prefill_chunk=8, **kw)
+
+
+def _device_state(srv):
+    import jax
+
+    return [np.asarray(x) for x in jax.tree_util.tree_leaves(
+        (srv.pools, srv.state, srv.stats))]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_chunk_returns_its_last_true_columns_row_or_none(family):
+    """A prompt of 21 in chunks of 8 (the last one 5 true columns of a
+    bucket of 8): the last chunk's ``(1, V)`` row is the full forward's
+    logits at the last prompt position, the two before it return zeros; the
+    same chunks with every one told it is the last return the forward's
+    rows at positions 7, 15 and 20, and after each chunk pools, state and
+    counters are the same bit for bit whether the head ran or not."""
+    net, params = _family_toy(family)
+    vocab = net.cfg.vocab_size
+    prompt = np.random.default_rng(2).integers(0, vocab, size=21).tolist()
+    want = np.asarray(net.apply(params, jnp.asarray([prompt])), np.float32)[0]
+    tol = 2e-5 * np.abs(want).max()
+
+    served, forced = (_family_server(net, params) for _ in range(2))
+    inner = forced._prefill_fn
+    forced._prefill_fn = lambda *a: inner(*a[:-1], jnp.asarray(True))
+    a, b = [], []
+    _record_prefill_logits(served, a)
+    _record_prefill_logits(forced, b)
+    rids = [srv.try_admit(prompt, 4) for srv in (served, forced)]
+    for chunk in range(3):
+        done = [srv.prefill_step(rid, 8)
+                for srv, rid in zip((served, forced), rids)]
+        assert done == [chunk == 2] * 2
+        for mine, theirs in zip(_device_state(served),
+                                _device_state(forced)):
+            assert (mine == theirs).all()
+    assert [r.shape for r in a + b] == [(vocab,)] * 6
+    assert not a[0].any() and not a[1].any()
+    assert np.abs(a[2] - want[20]).max() <= tol
+    for row, at in zip(b, (7, 15, 20)):
+        assert np.abs(row - want[at]).max() <= tol, at
+    assert (a[2] == b[2]).all()
+    assert (served.prefill_chunks, served.prefill_heads) == (3, 1)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_multi_chunk_prompt_beside_a_stranger_serves_greedy_tokens(family):
+    """Three chunks with a stranger decoding between them, then decode: the
+    served tokens are the full forward's greedy tokens (each the best of its
+    row, or within rounding of it)."""
+    net, params = _family_toy(family)
+    vocab = net.cfg.vocab_size
+    rng = np.random.default_rng(4)
+    prompt = rng.integers(0, vocab, size=21).tolist()
+    srv = _family_server(net, params)
+    other = srv.try_admit(rng.integers(0, vocab, size=6).tolist(), 30)
+    while not srv.prefill_step(other, 8):
+        pass
+    rid = srv.try_admit(prompt, 6)
+    while not srv.prefill_step(rid, 8):
+        srv.step()
+    while not srv.done(rid):
+        srv.step()
+    served = srv.result(rid)
+    assert served[:21] == prompt and len(served) == 27
+    want = np.asarray(net.apply(params, jnp.asarray([served])), np.float32)[0]
+    for t in range(21, 27):
+        row = want[t - 1]
+        assert row.max() - row[served[t]] <= 4e-5 * np.abs(want).max(), t
+
+
+def test_one_first_token_program_a_server_and_one_chunk_program_a_bucket():
+    """Prompts that draw three buckets (8, 16, 32), one of them in two
+    chunks (a chunk that is its prompt's last and one that is not, in the
+    same bucket): the first-token program compiled once, the chunk program
+    once a bucket, and nothing more on a second pass."""
+    # a model and a geometry no other test uses: the caches start empty
+    model = _model(max_seq_len=80)
+    params = model.init(prng.init_key(0))
+    srv = PagedDecodeServer(model, params, slots=2, num_blocks=40,
+                            block_size=4, max_len=76)
+    assert srv._first_fn._cache_size() == 0
+    for _ in range(2):
+        for n, width in ((5, 32), (11, 32), (27, 32), (60, 32)):
+            _drain(srv, srv.try_admit(list(range(1, n + 1)), 3), width)
+        assert srv._first_fn._cache_size() == 1
+        assert srv._prefill_fn._cache_size() == 3
+    assert (srv.prefill_chunks, srv.prefill_heads) == (10, 8)
+
+
+def test_the_serve_records_count_chunks_and_heads(tmp_path):
+    """A 3-chunk and a 1-chunk prompt: ``prefill_chunks`` 4 and
+    ``prefill_heads`` 2 in the final ``kind="serve"`` record, ``bucket=``
+    and ``head=`` on every ``prefill/submit`` span, and the summary tool's
+    line."""
+    import json
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from neural_networks_parallel_training_with_mpi_tpu.serve import (
+        Scheduler, ServeConfig,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.train import (
+        trace as trace_lib,
+    )
+
+    model = _model()
+    params = model.init(prng.init_key(0))
+    spans = []
+    listener = lambda n, t, d, a: spans.append((n, dict(a or {})))  # noqa: E731
+    tracer = trace_lib.start_run(str(tmp_path / "trace"))
+    trace_lib.add_listener(listener)
+    sched = Scheduler(model, params, ServeConfig(
+        slots=2, num_blocks=40, block_size=4, max_len=64, prefill_chunk=8,
+        telemetry_dir=str(tmp_path), metrics_every=1))
+    try:
+        rids = [sched.submit(list(range(1, n + 1)), 4) for n in (20, 6)]
+        sched.run_until_drained()
+        assert all(len(sched.result(r)) for r in rids)
+    finally:
+        sched.close()
+        trace_lib.remove_listener(listener)
+        trace_lib.stop_run(tracer)
+    final = [r for r in map(json.loads, open(tmp_path / "metrics.jsonl"))
+             if r.get("kind") == "serve" and r.get("final")][-1]
+    assert (final["prefill_chunks"], final["prefill_heads"]) == (4, 2)
+    submits = sorted((a["bucket"], a["head"]) for n, a in spans
+                     if n == "prefill/submit")
+    assert submits == [(8, 0), (8, 0), (8, 1), (8, 1)]
+    root = Path(__file__).resolve().parents[1]
+    out = subprocess.run(
+        [sys.executable, str(root / "tools" / "metrics_summary.py"),
+         str(tmp_path)], capture_output=True, text=True, check=True).stdout
+    assert "prefill chunks: 4, the head ran in 2" in out

@@ -680,9 +680,9 @@ def test_nothing_stays_in_flight_once_nothing_is_dispatched(how):
 
 def test_a_second_scheduler_after_prewarm_records_no_compile(tmp_path):
     """``prewarm`` runs whole requests through a throwaway scheduler, so the
-    boundary's three programs (``serve_admit``, one ``serve_first_token`` a
-    prefill bucket, ``serve_take``) compile there with the chunk and the
-    step: the scheduler that serves records none."""
+    boundary's three programs (``serve_admit``, ``serve_first_token``,
+    ``serve_take``: one each, whatever the prefill buckets) compile there
+    with the chunks and the step: the scheduler that serves records none."""
     from neural_networks_parallel_training_with_mpi_tpu.serve import prewarm
     from neural_networks_parallel_training_with_mpi_tpu.train import (
         trace as trace_lib,
@@ -713,5 +713,5 @@ def test_a_second_scheduler_after_prewarm_records_no_compile(tmp_path):
     assert after == []
     count = lambda stem: sum(n.startswith(stem) for n in warm)  # noqa: E731
     assert count("serve_admit[") == 1 and count("serve_take[") == 1
-    assert count("serve_first_token[") == count("serve_prefill[") == 2
-    assert count("serve_decode[") == 1
+    assert count("serve_first_token[") == count("serve_decode[") == 1
+    assert count("serve_prefill[") == 2

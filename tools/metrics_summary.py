@@ -175,6 +175,7 @@ def summarize(records: List[Dict[str, Any]],
                     "prefix_hit_tokens", "prefix_hit_rate",
                     "shared_blocks", "cow_forks", "cache_evictions",
                     "blocks_saved", "cached_free_blocks",
+                    "prefill_chunks", "prefill_heads",
                     "stalls", "stall_s"):
             if key in last:
                 tick_stats[key] = last[key]
@@ -306,6 +307,11 @@ def serving_lines(summary: Dict[str, Any]) -> List[str]:
                 f"by decode ticks, {st.get('ssm_prefill_tokens')} prompt "
                 "columns through the chunked recurrence (each summed over "
                 "the mixer layers)")
+        if st.get("prefill_chunks"):
+            chunks, heads = st["prefill_chunks"], st.get("prefill_heads", 0)
+            lines.append(
+                f"  prefill chunks: {chunks}, the head ran in {heads} (a "
+                f"prompt's last chunk; {heads / chunks:.3f})")
         if "prefix_hits" in st:
             rate = st.get("prefix_hit_rate")
             lines.append(
