@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils import compile_ledger as ledger_lib
 from .core import ACTIVATIONS, Module, Pytree, _uniform
 
 
@@ -278,17 +279,50 @@ class MoEFFN(Module):
 GROUPED_IMPLS = ("auto", "ragged", "gmm")
 
 
+# the weight tile the VMEM budget of the grouped matmul was timed at
+# (``tools/moe_grouped_timing.py``)
+GMM_TILE_K, GMM_TILE_N = 2048, 1024
+
+
+def _dividing_tile(dim: int, cap: int) -> int:
+    """``dim`` where it fits under ``cap``; else the largest multiple of 128
+    at most ``cap`` that divides it, so that no remainder tile runs (``cap``
+    itself where none does)."""
+    if dim <= cap:
+        return dim
+    for t in range(cap - cap % 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return cap
+
+
 def gmm_tiling(m: int, k: int, n: int):
-    """Row / contraction / column tile of the Pallas grouped matmul at a
-    problem size, from the chip timing (``tools/moe_grouped_timing.py``,
-    PERF.md): few rows (a decode tick's 128) want a short row tile, so that
-    an expert with one token multiplies 16 rows and not 128, a prefill
-    chunk's 4096 rows want 256; the weight tiles are as wide as VMEM takes
-    (2048 x 1024), because the product is bound by reading them."""
+    """Row / contraction / column tile of the Pallas grouped matmul of ``m``
+    sorted rows by weights of (k, n), from the chip timing
+    (``tools/moe_grouped_timing.py``, PERF.md).
+
+    Where one group's whole (k, n) fits the VMEM of the timed weight tile,
+    the tile is the whole matrix: one contraction step, no remainder to mask,
+    and consecutive visits of a group keep its block, so each reached group's
+    weights are read once.  Otherwise the timed tile, each side shrunk to a
+    multiple of 128 that divides its dimension.  The row tile follows ``m``:
+    a decode tick's 128 rows want 16, so that an expert with one token
+    multiplies 16 rows and not 128, a prefill chunk's 4096 want 256; a
+    visit costs more than the rows padding it, so 512 rows of 8 an expert
+    still want 128."""
     tm = 256 if m >= 4096 else (128 if m >= 512 else 16)
     while m % tm:
         tm //= 2
-    return tm, min(k, 2048), min(n, 1024)
+    if k * n <= GMM_TILE_K * GMM_TILE_N:
+        return tm, k, n
+    return tm, _dividing_tile(k, GMM_TILE_K), _dividing_tile(n, GMM_TILE_N)
+
+
+def resolve_grouped_impl(impl: str) -> str:
+    """``auto`` -> the Pallas kernel on a TPU, ``ragged_dot`` elsewhere."""
+    if impl == "auto":
+        return "gmm" if jax.default_backend() == "tpu" else "ragged"
+    return impl
 
 
 def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
@@ -297,9 +331,7 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
     int32 -> (M, N) float32: row r of group g times ``w[g]``.  Rows past
     ``group_sizes.sum()`` belong to no group; what comes back there is
     unspecified (the caller masks it)."""
-    if impl == "auto":
-        impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
-    if impl == "gmm":
+    if resolve_grouped_impl(impl) == "gmm":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         return gmm(xs, w, group_sizes, preferred_element_type=jnp.float32,
@@ -411,9 +443,20 @@ class DroplessMoE(Module):
 
     def experts_ffn(self, ep: Pytree, xs: jax.Array,
                     sizes: jax.Array) -> jax.Array:
-        """Sorted rows (M, d) -> (M, d) float32 through each row's expert."""
+        """Sorted rows (M, d) -> (M, d) float32 through each row's expert.
+        The program's compile-ledger event says what implements the grouped
+        products and, for the kernel, the tiles of gate / up (``in``) and of
+        the down product (``out``)."""
         cdt = self.compute_dtype
         xs = xs.astype(cdt)
+        impl = resolve_grouped_impl(self.impl)
+        if impl == "gmm":
+            m, (_, d, f) = xs.shape[0], ep["w_gate"].shape
+            ledger_lib.note("experts", {"impl": "gmm", "tiling": {
+                "in": list(gmm_tiling(m, d, f)),
+                "out": list(gmm_tiling(m, f, d))}})
+        else:
+            ledger_lib.note("experts", {"impl": impl})
         gate = grouped_matmul(xs, ep["w_gate"].astype(cdt), sizes, self.impl)
         up = grouped_matmul(xs, ep["w_in"].astype(cdt), sizes, self.impl)
         h = (jax.nn.silu(gate) * up).astype(cdt)

@@ -28,7 +28,9 @@ from neural_networks_parallel_training_with_mpi_tpu.ops.pallas_kernels import (
     flash_attention, paged_attention,
 )
 from neural_networks_parallel_training_with_mpi_tpu.serve import paged_kv
-from neural_networks_parallel_training_with_mpi_tpu.utils import prng
+from neural_networks_parallel_training_with_mpi_tpu.utils import (
+    compile_ledger, prng,
+)
 
 # big_lm widths and the serving geometry are chip_smoke.py's
 BATCH, SEQ, HEADS, HEAD_DIM, D_MODEL = 8, 1024, 16, 64, 1024
@@ -640,11 +642,14 @@ def test_yarn_cell_programs_fit_the_chip_and_walk_both_pools(
     layers at the published widths, all 64 experts of each, the whole
     vocabulary; the mix's 35841 blocks of 16 for the two full layers and
     4225 for each of the six window layers) compile for the described chip
-    under its 15.75 GB.  Counted here (PR 39): the tick 10.79 GB (7.59 of
-    weights, 2.35 of full-layer pool, 0.83 of window pools, 13 MB of
-    temporaries), the chunk 10.94 GB (166 MB of temporaries).  Both kinds
-    of layer run the paged kernel over their own pool in place, each under
-    its kind's scope, and no pool is copied, transposed or gathered."""
+    under its 15.75 GB.  Counted here: the tick 10.79 GB (7.59 of weights,
+    2.35 of full-layer pool, 0.83 of window pools, 13 MB of temporaries),
+    the chunk 10.94 GB (166 MB of temporaries), under whole-expert tiles as
+    under the split ones before them.  Both kinds of layer run the
+    paged kernel over their own pool in place, each under its kind's scope,
+    and no pool is copied, transposed or gathered.  The program's
+    compile-ledger notes name the grouped product's tiles: one weight tile
+    an expert."""
     import re
     import sys
     from pathlib import Path
@@ -675,16 +680,26 @@ def test_yarn_cell_programs_fit_the_chip_and_walk_both_pools(
              "attention": spec((len(paged_kv.ATTENTION_COUNTERS),),
                                jnp.int32)}
     tabs = lambda n: (spec((n, mb), jnp.int32), spec((n, mb), jnp.int32))  # noqa: E731
-    if program == "decode":
-        lowered = step.lower(
-            params, pools, stats, spec((s, mb * bs), jnp.int32), tabs(s),
-            spec((s,), jnp.int32), spec((s,), jnp.bool_),
-            spec((2,), jnp.uint32))
-    else:
-        lowered = prefill.lower(
-            params, pools, stats, tabs(1), spec((1,), jnp.int32),
-            spec((1, geo["prefill_chunk"]), jnp.int32), spec((), jnp.int32),
-            spec((), jnp.bool_))
+    ledger = compile_ledger.Ledger(None)
+    compile_ledger.install(ledger)
+    try:
+        with ledger.collecting_notes() as notes:
+            if program == "decode":
+                lowered = step.lower(
+                    params, pools, stats, spec((s, mb * bs), jnp.int32),
+                    tabs(s), spec((s,), jnp.int32), spec((s,), jnp.bool_),
+                    spec((2,), jnp.uint32))
+            else:
+                lowered = prefill.lower(
+                    params, pools, stats, tabs(1), spec((1,), jnp.int32),
+                    spec((1, geo["prefill_chunk"]), jnp.int32),
+                    spec((), jnp.int32), spec((), jnp.bool_))
+    finally:
+        compile_ledger.install(None)
+    # whole-expert weight tiles, rows a tile as the chip timed them
+    tm = 128 if program == "decode" else 256
+    assert notes["experts"] == {"impl": "gmm", "tiling": {
+        "in": [tm, 2304, 896], "out": [tm, 896, 2304]}}
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes

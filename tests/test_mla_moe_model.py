@@ -613,6 +613,8 @@ def test_latent_table_churn_and_growth_never_recompile_under_fused(toy):
     prefill = led.events_for("serve_prefill[bs8x7/fused]")
     assert prefill and all(e["attention"] == {"impl": "gathered", "keys": 56}
                            for e in prefill)
+    # the grouped expert products say what implements them (the CPU's)
+    assert all(e["experts"] == {"impl": "ragged"} for e in decode + prefill)
     with tempfile.TemporaryDirectory() as tdir:
         served, _ = serve(net, params, [list(range(1, 20))], 12, max_len=64,
                           attn_impl="fused", telemetry_dir=tdir,
