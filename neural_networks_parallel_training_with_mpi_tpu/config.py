@@ -214,6 +214,17 @@ class ModelConfig:
     rope_global: bool = True
     moe_first_dense: int = 0
     dense_ff: int = 0
+    # the norm of q and k over the whole projection (qk_norm_whole), the
+    # block's norms on each half's output (post_norm), and the Gated DeltaNet
+    # of the 'D' layers of attention_pattern (models/deltanet.py; gdn_heads 0:
+    # none): its shapes and its beta's range
+    qk_norm_whole: bool = False
+    post_norm: bool = False
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_neg_eigval: bool = False
     # a state-space mixer beside the attention in every block (models/ssm.py;
     # ssm_heads 0: none), its shapes and the tile of its chunked recurrence,
     # and the model's fixed multipliers (1: none)
@@ -829,8 +840,9 @@ def build_argparser() -> argparse.ArgumentParser:
     _add_bool_flag(p, "qk-norm", False,
                    "the model's norm over each head of q and k")
     p.add_argument("--attention_pattern", type=str, default="",
-                   help="kinds of attention layer, repeated over the depth: "
-                        "'L' sees --sliding_window keys, 'G' all (LLLG)")
+                   help="kinds of layer, repeated over the depth: 'L' sees "
+                        "--sliding_window keys, 'G' all (LLLG), 'D' a Gated "
+                        "DeltaNet in place of attention (DDDG)")
     p.add_argument("--sliding_window", type=int, default=0)
     _add_bool_flag(p, "rope-global", True,
                    "rotate q and k in 'G' layers too (--no-rope-global: "
@@ -839,6 +851,21 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="leading layers with a dense feed-forward of width "
                         "--dense_ff in place of the expert layer")
     p.add_argument("--dense_ff", type=int, default=0)
+    _add_bool_flag(p, "qk-norm-whole", False,
+                   "with --qk-norm: one norm over the whole q projection and "
+                   "one over the whole k projection")
+    _add_bool_flag(p, "post-norm", False,
+                   "the block's norms on each half's output: x + norm(F(x))")
+    p.add_argument("--gdn_heads", type=int, default=0,
+                   help="heads of the Gated DeltaNet of the 'D' layers of "
+                        "--attention_pattern (models/deltanet.py; 0 = none)")
+    p.add_argument("--gdn_key_dim", type=int, default=0)
+    p.add_argument("--gdn_value_dim", type=int, default=0)
+    p.add_argument("--gdn_conv", type=int, default=4,
+                   help="taps of the Gated DeltaNet's causal convolutions")
+    _add_bool_flag(p, "gdn-neg-eigval", False,
+                   "beta in (0, 2) in place of (0, 1): a transition may have "
+                   "a negative eigenvalue")
     p.add_argument("--ssm_heads", type=int, default=0,
                    help="heads of a state-space mixer beside the attention "
                         "in every block (models/ssm.py; 0 = none)")
@@ -1203,7 +1230,9 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     m.moe_dropless = args.moe_dropless
     for name in ("moe_score", "moe_routed_scale", "head_width", "qk_norm",
                  "attention_pattern", "sliding_window", "rope_global",
-                 "moe_first_dense", "dense_ff"):
+                 "moe_first_dense", "dense_ff", "qk_norm_whole", "post_norm",
+                 "gdn_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv",
+                 "gdn_neg_eigval"):
         setattr(m, name, getattr(args, name))
     m.moe_experts_held = tuple(int(x) for x in
                                args.moe_experts_held.split(",") if x)

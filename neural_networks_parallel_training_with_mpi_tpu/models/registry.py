@@ -61,6 +61,10 @@ def build_model(cfg: ModelConfig) -> Module:
             sliding_window=cfg.sliding_window,
             rope_global=cfg.rope_global,
             moe_first_dense=cfg.moe_first_dense, dense_ff=cfg.dense_ff,
+            qk_norm_whole=cfg.qk_norm_whole, post_norm=cfg.post_norm,
+            gdn_heads=cfg.gdn_heads, gdn_key_dim=cfg.gdn_key_dim,
+            gdn_value_dim=cfg.gdn_value_dim, gdn_conv=cfg.gdn_conv,
+            gdn_neg_eigval=cfg.gdn_neg_eigval,
             ssm_heads=cfg.ssm_heads, ssm_head_dim=cfg.ssm_head_dim,
             ssm_state=cfg.ssm_state, ssm_groups=cfg.ssm_groups,
             ssm_conv=cfg.ssm_conv, ssm_chunk=cfg.ssm_chunk,

@@ -190,18 +190,34 @@ class TransformerConfig:
     # projection is then ``n_heads * head_width`` wide, not ``d_model``.
     head_width: Optional[int] = None
     # The model's norm over each head of q and k, one scale vector of
-    # ``head_dim`` for all heads, before any rotation.
+    # ``head_dim`` for all heads, before any rotation; under
+    # ``qk_norm_whole`` one norm over the whole q projection and one over the
+    # whole k projection, a scale a lane (Olmo 2's).
     qk_norm: bool = False
+    qk_norm_whole: bool = False
+    # Where the block's two norms sit: before each half (``x + F(norm(x))``,
+    # pre-norm) or, under ``post_norm``, on each half's output (``x +
+    # norm(F(x))``, Olmo 2's order).
+    post_norm: bool = False
     # Layers that are not all alike, given as a model's config gives them.
     # ``attention_pattern`` is repeated over the layers: layer i is
     # ``pattern[i % len(pattern)]``; "G" attends every position before it,
-    # "L" itself and the ``sliding_window - 1`` before it.  The first
+    # "L" itself and the ``sliding_window - 1`` before it, and "D" has no
+    # attention: a Gated DeltaNet (models/deltanet.py) mixes its tokens in
+    # its place, of ``gdn_heads`` heads with keys of ``gdn_key_dim`` and
+    # values of ``gdn_value_dim``, a convolution of ``gdn_conv`` taps, beta
+    # in (0, 2) under ``gdn_neg_eigval`` (else (0, 1)).  The first
     # ``moe_first_dense`` layers of a model with experts have a dense
     # feed-forward of width ``dense_ff`` in place of the expert layer.
     attention_pattern: Optional[str] = None
     sliding_window: int = 0
     moe_first_dense: int = 0
     dense_ff: int = 0
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_neg_eigval: bool = False
     # The rotation of each kind of layer under rotary positions, as the
     # model's config gives it: (the "L" layers', the "G" layers'), each an
     # ``ops.rope.Rotary`` (plain at its theta, or YaRN at it) or None (the
@@ -299,20 +315,38 @@ class TransformerConfig:
                              "routing without drops (moe_dropless)")
         own = [n for n, on in (("head_width", self.head_width is not None),
                                ("qk_norm", self.qk_norm),
+                               ("post_norm", self.post_norm),
                                ("attention_pattern", self.has_layer_kinds))
                if on]
         if own and self.attention_kind != "mha":
             raise ValueError(f"{', '.join(own)}: latent attention owns its "
                              "projections and has one kind of layer")
+        if self.qk_norm_whole and not self.qk_norm:
+            raise ValueError("qk_norm_whole says how wide the norm of q and "
+                             "k is: it needs qk_norm")
         if self.head_width is not None and self.head_width < 1:
             raise ValueError(f"head_width {self.head_width} < 1")
         pat = self.attention_pattern
         if pat is not None:
-            if not pat or set(pat) - set("LG"):
-                raise ValueError(f"attention_pattern is a string of 'L' and "
-                                 f"'G', got {pat!r}")
+            if not pat or set(pat) - set("LGD"):
+                raise ValueError(f"attention_pattern is a string of 'L', 'G' "
+                                 f"and 'D', got {pat!r}")
             if "L" in pat and self.sliding_window < 1:
                 raise ValueError("an 'L' layer needs sliding_window >= 1")
+        if self.has_delta_layers != (self.gdn_heads > 0):
+            raise ValueError(
+                "a 'D' layer of attention_pattern is a Gated DeltaNet: it "
+                "needs gdn_heads >= 1, and gdn_heads needs a 'D' layer")
+        if self.has_delta_layers:
+            if min(self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv) < 1:
+                raise ValueError(
+                    "'D' layers need gdn_key_dim, gdn_value_dim and gdn_conv "
+                    "of at least 1")
+            if self.has_mixer or self.matmul_dtype != "bf16":
+                raise ValueError(
+                    "'D' layers hold recurrent state of their own and sit "
+                    "outside the quantized-matmul seam: ssm_heads must be 0 "
+                    "and matmul_dtype 'bf16'")
         if self.moe_first_dense:
             if not 0 < self.moe_first_dense <= self.n_layers \
                     or self.moe_experts < 1 or self.dense_ff < 1:
@@ -371,11 +405,42 @@ class TransformerConfig:
     def has_layer_kinds(self) -> bool:
         return self.attention_pattern is not None or self.moe_first_dense > 0
 
-    def layer_window(self, i: int) -> Optional[int]:
-        """Keys layer ``i``'s query sees, itself included (None: all)."""
+    def _kind(self, i: int) -> str:
         pat = self.attention_pattern
-        return (self.sliding_window
-                if pat and pat[i % len(pat)] == "L" else None)
+        return pat[i % len(pat)] if pat else "G"
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """Keys layer ``i``'s query sees, itself included (None: all, or no
+        attention at all)."""
+        return self.sliding_window if self._kind(i) == "L" else None
+
+    @property
+    def has_delta_layers(self) -> bool:
+        return "D" in (self.attention_pattern or "")
+
+    def layer_is_delta(self, i: int) -> bool:
+        """Layer ``i`` mixes its tokens by a Gated DeltaNet, not attention."""
+        return self._kind(i) == "D"
+
+    def layer_has_cache(self, i: int) -> bool:
+        """Layer ``i`` attends, so it holds keys and values (a page pool)."""
+        return not self.layer_is_delta(i)
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer holds a state a stream carries between calls."""
+        return self.has_mixer or self.has_delta_layers
+
+    def layer_is_recurrent(self, i: int) -> bool:
+        """Layer ``i`` holds such a state (a state row a slot)."""
+        return self.has_mixer or self.layer_is_delta(i)
+
+    def recurrent_kind(self) -> str:
+        """What holds the recurrent state, as the refusals name it."""
+        if self.has_delta_layers:
+            return ("delta-rule layers in place of attention "
+                    "(attention_pattern 'D', gdn_heads)")
+        return "a state-space mixer beside the attention (ssm_heads)"
 
     def layer_rotary(self, i: int) -> Optional[Rotary]:
         """Layer ``i``'s rotation of q and k (None: no positions)."""
@@ -403,8 +468,9 @@ class TransformerConfig:
              "moe_first_dense)", self.has_layer_kinds),
             ("a rotation by kind of layer (rope_kinds: YaRN or none)",
              self.rope_kinds is not None),
-            ("recurrent state: a state-space mixer beside the attention "
-             "(ssm_heads)", self.has_mixer),
+            (f"recurrent state: {self.recurrent_kind()}",
+             self.has_recurrent_state),
+            ("a norm on each half's output (post_norm)", self.post_norm),
             ("the model's multipliers (embedding_multiplier, "
              "key_multiplier, ...)", self.has_multipliers))
             if on]
@@ -473,20 +539,39 @@ class Transformer(Module):
             return self._block_modules()["attn"].cache_row()
         return {"k": (c.kv_heads, c.head_dim), "v": (c.kv_heads, c.head_dim)}
 
-    def state_row(self):
-        """What one STREAM holds in one layer beside its cache rows: store
-        name -> (shape, type); empty for a model without a mixer.  The paged
-        server keeps it in a second store, one row a slot (models/ssm.py)."""
-        if not self.cfg.has_mixer:
+    def state_row(self, layer: Optional[int] = None):
+        """What one STREAM holds in layer ``layer`` (None: the first layer
+        that holds any) beside its cache rows: store name -> (shape, type);
+        empty for a layer without recurrent state.  The paged server keeps
+        it in a second store, one row a slot (models/ssm.py,
+        models/deltanet.py)."""
+        c = self.cfg
+        if layer is None:
+            layer = next((i for i in range(c.n_layers)
+                          if c.layer_is_recurrent(i)), 0)
+        if not c.layer_is_recurrent(layer):
             return {}
-        return self._block_modules()["ssm"].state_row()
+        mods = self._block_modules(layer)
+        return mods["gdn" if "gdn" in mods else "ssm"].state_row()
 
     def _block_modules(self, layer: int = 0):
         """The modules of layer ``layer``: the same for every layer unless
         the config gives the layers kinds (``layer_is_moe``; the attention's
-        kinds differ in what it does, not in what it holds)."""
+        kinds differ in what it does, not in what it holds; a 'D' layer has
+        a Gated DeltaNet in place of the attention's projections)."""
         c = self.cfg
-        if c.attention_kind == "mla":
+        if c.layer_is_delta(layer):
+            from .deltanet import GatedDeltaNet
+
+            mods = {"ln1": self._norm(),
+                    "gdn": GatedDeltaNet(
+                        c.d_model, c.gdn_heads, c.gdn_key_dim,
+                        c.gdn_value_dim, d_conv=c.gdn_conv,
+                        allow_neg_eigval=c.gdn_neg_eigval,
+                        norm_eps=c.norm_eps, param_dtype=c.param_dtype,
+                        compute_dtype=c.compute_dtype),
+                    "ln2": self._norm()}
+        elif c.attention_kind == "mla":
             from .mla import LatentAttention
 
             mods = {"ln1": self._norm(),
@@ -511,7 +596,10 @@ class Transformer(Module):
                                    matmul_dtype=self._mm("attn_out"),
                                    q_role="attn_out"),
             }
-            if c.qk_norm:
+            if c.qk_norm_whole:
+                mods["q_norm"] = self._norm(c.q_dim)
+                mods["k_norm"] = self._norm(c.kv_heads * c.head_dim)
+            elif c.qk_norm:
                 mods["q_norm"] = self._norm(c.head_dim)
                 mods["k_norm"] = self._norm(c.head_dim)
             if c.has_mixer:
@@ -599,11 +687,42 @@ class Transformer(Module):
             return scaled(y, c.ssm_out_multiplier), state
 
     def qk_normed(self, mods, params, q: jax.Array, k: jax.Array):
-        """The per-head norm of q and k (``qk_norm``), before any rotation;
-        shared by the training block and the paged server."""
+        """The norm of q and k (``qk_norm``: each head, or under
+        ``qk_norm_whole`` the whole projection), before any rotation; shared
+        by the training block and the paged server."""
         with jax.named_scope("qk_norm"):
-            return (mods["q_norm"].apply(params["q_norm"], q),
-                    mods["k_norm"].apply(params["k_norm"], k))
+            if not self.cfg.qk_norm_whole:
+                return (mods["q_norm"].apply(params["q_norm"], q),
+                        mods["k_norm"].apply(params["k_norm"], k))
+            whole = lambda n, x: mods[n].apply(                 # noqa: E731
+                params[n], x.reshape(*x.shape[:-2], -1)).reshape(x.shape)
+            return whole("q_norm", q), whole("k_norm", k)
+
+    def norm_in(self, mods, params, name: str, x: jax.Array) -> jax.Array:
+        """A half's input: ``norm(x)`` (pre-norm), or ``x`` itself under
+        ``post_norm``; shared by the training block and the paged server."""
+        if self.cfg.post_norm:
+            return x
+        return mods[name].apply(params[name], x)
+
+    def norm_out(self, mods, params, name: str, y: jax.Array) -> jax.Array:
+        """A half's output before the residual add: ``y`` itself
+        (pre-norm), or ``norm(y)`` under ``post_norm``."""
+        if not self.cfg.post_norm:
+            return y
+        return mods[name].apply(params[name], y)
+
+    def delta_half(self, mods, params, x: jax.Array, run):
+        """A 'D' layer's first half under scope ``gdn``: ``x +
+        norm(GDN(x))`` (or ``x + GDN(norm(x))`` pre-norm); ``run(mixer, its
+        params, its input)`` picks the form (a sequence, a prefill chunk, a
+        decode tick) and returns (output, new state or None).  Shared by the
+        training block and the paged server."""
+        with jax.named_scope("gdn"):
+            y, state = run(mods["gdn"], params["gdn"],
+                           self.norm_in(mods, params, "ln1", x))
+            return (x + self.norm_out(mods, params, "ln1", y).astype(x.dtype),
+                    state)
 
     def quant_roles(self):
         """fp8 delayed-scaling roles (ops.qmm): one activation amax
@@ -686,8 +805,12 @@ class Transformer(Module):
         if c.attention_kind == "mla":
             x = self._latent_attention(mods, params, x)
             return self._ffn_half(mods, params, x, qkw, qobs)
+        if c.layer_is_delta(layer):
+            x, _ = self.delta_half(
+                mods, params, x, lambda mixer, p, u: (mixer.apply(p, u), None))
+            return self._ffn_half(mods, params, x, qkw, qobs)
         with jax.named_scope("attn_proj"):
-            h = mods["ln1"].apply(params["ln1"], x)
+            h = self.norm_in(mods, params, "ln1", x)
             q, k, v = self.scaled_qkv(mods, params, h, **qkw)
             if c.qk_norm:
                 q, k = self.qk_normed(mods, params, q, k)
@@ -707,9 +830,9 @@ class Transformer(Module):
                 window=c.layer_window(layer))
         with jax.named_scope("attn_proj"):
             out = out.reshape(*out.shape[:2], c.q_dim)
-            x = x + scaled(
+            x = x + self.norm_out(mods, params, "ln1", scaled(
                 mods["attn_out"].apply(params["attn_out"], out, **qkw),
-                c.attention_out_multiplier)
+                c.attention_out_multiplier))
         if c.has_mixer:
             # beside the attention, from the same normed input, added
             x = x + self.mixer_half(
@@ -723,13 +846,14 @@ class Transformer(Module):
         layer, or routing without drops."""
         c = self.cfg
         with jax.named_scope("ffn"):
-            h = mods["ln2"].apply(params["ln2"], x)
+            h = self.norm_in(mods, params, "ln2", x)
             if "moe" in mods:
                 ff, aux = mods["moe"].apply(params["moe"], h)
             else:
                 ff = self._ffn(mods, params, h, **qkw)
                 aux = jnp.zeros((), jnp.float32)
-            return x + ff.astype(x.dtype), aux, (qobs or {})
+            return (x + self.norm_out(mods, params, "ln2", ff).astype(x.dtype),
+                    aux, (qobs or {}))
 
     def _latent_attention(self, mods, params, x: jax.Array) -> jax.Array:
         """``x + LatentAttn(norm(x))`` over a whole causal sequence from
@@ -805,6 +929,10 @@ class Transformer(Module):
                 # the low-rank projections and the expanded scores + values
                 per_layer = b * t * self._block_modules()[
                     "attn"].fwd_flops_per_token(t)
+            elif c.layer_is_delta(i):
+                # the Gated DeltaNet in place of the attention
+                per_layer = b * t * self._block_modules(i)[
+                    "gdn"].fwd_flops_per_token()
             else:
                 keys = min(t, c.layer_window(i) or t)
                 per_layer = 2.0 * b * t * d * c.qkv_dim  # qkv (GQA-aware)

@@ -707,7 +707,10 @@ flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)
 # VMEM with 8 KV heads unrolled; ``(32, 32)`` is 5 % faster than the chunk's
 # ``(16, 64)`` at depth and as fast at start 0: a lead).  Every other shape walks ``_UNTIMED_KEYS`` key
 # positions a step and tiles a chunk at about ``_UNTIMED_ROWS`` query rows
-# a KV head.
+# a KV head, and at no more rows than ``_UNTIMED_TILE`` lanes of a pool row
+# allow: 512 rows of 1024 lanes is the widest tile that compiled and was
+# timed, and 256 rows of the 3840-lane row of 30 KV heads ask 137 MB of the
+# 128 MB of VMEM (a described v5e's compile of a 512-column chunk).
 PAGED_TILES = {
     (16, 256, "decode"): (64, 1),
     (16, 256, "chunk"): (32, 64),
@@ -719,6 +722,7 @@ PAGED_TILES = {
 }
 _UNTIMED_KEYS = 128
 _UNTIMED_ROWS = 256
+_UNTIMED_TILE = 512 * 1024
 
 
 def paged_tiles(block_size: int, lanes: int, width: int, groups: int,
@@ -738,8 +742,9 @@ def paged_tiles(block_size: int, lanes: int, width: int, groups: int,
     window's and the tile's own, and the page the bound cuts."""
     kind = ("decode" if width == 1 else "chunk") + (
         "" if window is None else "_window")
+    rows = min(_UNTIMED_ROWS, _UNTIMED_TILE // lanes)
     t_pages, t_cols = PAGED_TILES.get((block_size, lanes, kind),
-                                      (None, max(1, _UNTIMED_ROWS // groups)))
+                                      (None, max(1, rows // groups)))
     cols = min(t_cols if tile_cols is None else tile_cols, width)
     fits = [c for c in range(cols, 0, -1)
             if width % c == 0 and (c * groups) % 8 == 0]

@@ -20,14 +20,17 @@ blocks:
   copy-on-write, export / import and the handoff geometry read the row
   and nothing else of the attention.
 * **State that is not paged** (a model with a state-space mixer beside its
-  attention, ``models/ssm.py``): per layer, one row a SLOT of the model's
-  **state row** (``Transformer.state_row()``: the mixer's convolution tail
-  and its float32 state), in a second store beside the pools
-  (:func:`init_paged_state`).  A row is a function of its stream, not of
-  positions: fixed in size, overwritten in place by every prefill chunk and
-  decode tick, never shared; it is zeroed on the device at admission,
-  held bit for bit by idle lanes and pad columns, and goes with the slot
-  (DESIGN.md says why it is no kind of page).
+  attention, ``models/ssm.py``, or with Gated DeltaNet layers in place of
+  attention, ``models/deltanet.py``): per layer that holds it, one row a
+  SLOT of the model's **state row** (``Transformer.state_row(layer)``: the
+  mixer's convolution tail and its float32 state), in a second store beside
+  the pools (:func:`init_paged_state`).  A layer without attention holds no
+  pool, and one without recurrent state no row: the two stores are lists
+  over the layers that hold each, in layer order.  A row is a function of
+  its stream, not of positions: fixed in size, overwritten in place by every
+  prefill chunk and decode tick, never shared; it is zeroed on the device at
+  admission, held bit for bit by idle lanes and pad columns, and goes with
+  the slot (DESIGN.md says why it is no kind of page).
 * **Block tables**: per slot, ``(max_blocks,)`` int32 indices into the
   pool, host-owned (a tiny traced argument each step — never a
   recompile).  Unallocated entries point at the reserved **sink block
@@ -187,12 +190,13 @@ ATTENTION_COUNTERS = ("full_keys", "window_keys", "full_blocks_held",
                       "window_blocks_held")
 
 # cumulative counters of a model with recurrent state (a state-space mixer
-# beside the attention, models/ssm.py), carried like the expert counters:
-# state rows a decode tick updated (decoding streams x mixer layers), true
-# prompt columns a prefill chunk ran through the chunked recurrence (columns
-# x mixer layers), and the two counts of what was folded, under the names
-# EXPERT_COUNTERS gives them (a program that carried both sets would count
-# the same ticks and chunks in both)
+# beside the attention, models/ssm.py, or delta-rule layers,
+# models/deltanet.py), carried like the expert counters: state rows a decode
+# tick updated (decoding streams x layers that hold state), true prompt
+# columns a prefill chunk ran through the chunked recurrence (columns x
+# layers that hold state), and the two counts of what was folded, under the
+# names EXPERT_COUNTERS gives them (a program that carried both sets would
+# count the same ticks and chunks in both)
 SSM_COUNTERS = ("ssm_state_updates", "ssm_prefill_tokens",
                 "decode_ticks_counted", "prefill_chunks_counted")
 
@@ -475,9 +479,17 @@ def stored_rows(row: Dict[str, Tuple[int, ...]],
 
 
 def layer_windows(model: Transformer) -> Tuple[Optional[int], ...]:
-    """Each layer's window (None: a full layer), from the model's config."""
+    """Each layer's window (None: a full layer, or one without attention),
+    from the model's config."""
     c = model.cfg
     return tuple(c.layer_window(i) for i in range(c.n_layers))
+
+
+def attention_windows(model: Transformer) -> Tuple[Optional[int], ...]:
+    """The windows of the layers that attend (and hold a pool), in order."""
+    c = model.cfg
+    return tuple(c.layer_window(i) for i in range(c.n_layers)
+                 if c.layer_has_cache(i))
 
 
 def window_pool_blocks(window: int, slots: int, block_size: int,
@@ -495,8 +507,9 @@ def window_pool_blocks(window: int, slots: int, block_size: int,
 def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
                   quant: bool = False, folded: bool = False,
                   window_blocks: Optional[int] = None):
-    """Per-layer paged pools, one per entry of the attention's cache row
-    (``model.cache_row()``), each ``(num_blocks, block_size, *row)`` —
+    """Paged pools of every layer that attends, one per entry of the
+    attention's cache row (``model.cache_row()``), each ``(num_blocks,
+    block_size, *row)`` —
     :func:`models.generate.init_kv_cache` with the length axis split into
     (block, offset).  ``folded`` (the fused kernel's layout,
     :func:`stored_rows`) stores a per-head row with its heads folded into
@@ -513,6 +526,7 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
     row = model.cache_row()
     lead = (num_blocks, block_size)
     stored = stored_rows(row, folded)
+    windows = attention_windows(model)
     if quant:
         if set(row) != {"k", "v"}:
             raise ValueError(
@@ -522,8 +536,7 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
                     for n, r in stored.items()},
                  **{f"{n}_scale": jnp.ones(lead + r[:-1], jnp.float32)
                     for n, r in row.items()}}
-                for _ in range(c.n_layers)]
-    windows = layer_windows(model)
+                for _ in windows]
     if any(windows) and window_blocks is None:
         raise ValueError("a model with window layers needs window_blocks "
                          "(window_pool_blocks)")
@@ -534,28 +547,27 @@ def init_paged_kv(model: Transformer, num_blocks: int, block_size: int,
 
 
 def init_paged_state(model: Transformer, slots: int):
-    """The store that is NOT paged, beside the pools: per layer, one row a
-    slot of every entry of the model's state row (``model.state_row()``:
-    store name -> (shape, type); a mixer's convolution tail and its float32
-    state), zeros.  ``[]`` for a model without recurrent state.  A row
-    belongs to a slot for as long as a stream holds it: zeroed at admission,
-    carried by prefill chunks and decode ticks, gone with the slot."""
-    row = model.state_row()
-    if not row:
-        return []
+    """The store that is NOT paged, beside the pools: per layer that holds
+    recurrent state, one row a slot of every entry of its state row
+    (``model.state_row(layer)``: store name -> (shape, type); a mixer's
+    convolution tail and its float32 state), zeros.  ``[]`` for a model
+    without recurrent state.  A row belongs to a slot for as long as a
+    stream holds it: zeroed at admission, carried by prefill chunks and
+    decode ticks, gone with the slot."""
+    c = model.cfg
     return [{n: jnp.zeros((slots,) + shape, dtype)
-             for n, (shape, dtype) in row.items()}
-            for _ in range(model.cfg.n_layers)]
+             for n, (shape, dtype) in model.state_row(i).items()}
+            for i in range(c.n_layers) if c.layer_is_recurrent(i)]
 
 
 def refuse_recurrent(model: Transformer, who: str, why: str) -> None:
     """What needs a snapshot of recurrent state that does not exist yet
     refuses a model that has such state, by name."""
-    if model.cfg.has_mixer:
+    c = model.cfg
+    if c.has_recurrent_state:
         raise ValueError(
-            f"{who} cannot serve a model with recurrent state (a "
-            f"state-space mixer beside the attention: ssm_heads) yet: "
-            f"{why}")
+            f"{who} cannot serve a model with recurrent state "
+            f"({c.recurrent_kind()}) yet: {why}")
 
 
 @functools.lru_cache(maxsize=8)
@@ -651,7 +663,13 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         raise ValueError("kv_quant cannot run a model with window layers "
                          "(attention_pattern 'L') yet: the int8 page walk "
                          "has no lower bound")
-    recurrent = c.has_mixer     # a second store: one state row a slot
+    recurrent = c.has_recurrent_state   # a second store: a row a slot
+    # where each layer's pool and state rows sit in the two stores (a layer
+    # without attention holds no pool, one without state no row)
+    pool_of = {i: j for j, i in enumerate(
+        i for i in range(c.n_layers) if c.layer_has_cache(i))}
+    state_of = {i: j for j, i in enumerate(
+        i for i in range(c.n_layers) if c.layer_is_recurrent(i))}
     if kv_quant:
         refuse_recurrent(model, "kv_quant", "int8 K and V beside a float32 "
                          "state was never compared with the reference")
@@ -743,7 +761,7 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         # read by them): ``attention`` is the work, the inner scopes say
         # what implements it here
         with jax.named_scope("attn_proj"):
-            h = mods["ln1"].apply(layer_params["ln1"], x)
+            h = model.norm_in(mods, layer_params, "ln1", x)
             # q: (B,W,H,hd); k/v: (B,W,KV,hd)
             q, k, v = model.scaled_qkv(mods, layer_params, h)
             b, w = q.shape[:2]
@@ -797,9 +815,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                     new_vsp if quant else None, window).astype(x.dtype)
         with jax.named_scope("attn_proj"):
             out = out.reshape(b, w, c.q_dim)
-            x = x + scaled(
+            x = x + model.norm_out(mods, layer_params, "ln1", scaled(
                 mods["attn_out"].apply(layer_params["attn_out"], out),
-                c.attention_out_multiplier)
+                c.attention_out_multiplier))
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
             new_pool.update(k_scale=new_ksp, v_scale=new_vsp)
@@ -897,18 +915,13 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         its output is added to the same residual, and it writes to the
         second store what the attention writes to its pages: a chunk carries
         the state over its true columns, a tick over its decoding lanes
-        (``lengths > 0``), everything else holds."""
+        (``lengths > 0``), everything else holds.  A delta-rule layer ('D')
+        has no attention and no pool (``pool`` None): its Gated DeltaNet
+        mixes the tokens in the attention's place and carries ``state`` the
+        same way."""
         mods = model._block_modules(layer)
         if two_kinds:
             tables = tables[1 if windows[layer] else 0]
-        if latent:
-            x, new_pool = latent_attention_half(
-                mods, layer_params, pool, tables, starts, x, valid, lengths,
-                decode)
-        else:
-            x, new_pool, h = dense_attention_half(
-                mods, layer_params, pool, tables, starts, x, valid, lengths,
-                layer)
         if recurrent:
             if decode:
                 run = lambda mixer, p, u: mixer.apply_step(     # noqa: E731
@@ -916,11 +929,23 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             else:
                 run = lambda mixer, p, u: mixer.apply_chunk(    # noqa: E731
                     p, u, state, valid)
+        if c.layer_is_delta(layer):
+            new_pool = None
+            x, state = model.delta_half(mods, layer_params, x, run)
+        elif latent:
+            x, new_pool = latent_attention_half(
+                mods, layer_params, pool, tables, starts, x, valid, lengths,
+                decode)
+        else:
+            x, new_pool, h = dense_attention_half(
+                mods, layer_params, pool, tables, starts, x, valid, lengths,
+                layer)
+        if c.has_mixer:
             mix, state = model.mixer_half(mods, layer_params, h, run)
             x = x + mix
         load = None
         with jax.named_scope("ffn"):
-            h = mods["ln2"].apply(layer_params["ln2"], x)
+            h = model.norm_in(mods, layer_params, "ln2", x)
             if "moe" not in mods:
                 ff = model._ffn(mods, layer_params, h)
             elif c.moe_dropless:
@@ -931,7 +956,8 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                     layer_params["moe"], h, mask=live, return_load=True)
             else:
                 ff, _ = mods["moe"].apply(layer_params["moe"], h)
-            x = x + ff.astype(x.dtype)
+            x = x + model.norm_out(mods, layer_params, "ln2",
+                                   ff).astype(x.dtype)
         return x, new_pool, load, state
 
     def forward(params, pools, tables, starts, ids, valid, lengths,
@@ -954,14 +980,17 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 "window": {"impl": "paged", "window": win, "pages": wp,
                            "tile_cols": wc}})
         new_pools, loads, new_state = [], [], []
-        for i, (layer_params, pool) in enumerate(zip(params["blocks"],
-                                                     pools)):
+        for i, layer_params in enumerate(params["blocks"]):
+            held = state_of.get(i)
             x, pool, load, rows = block_fwd(
-                layer_params, pool, tables, starts, x, valid, lengths,
-                decode, i, state[i] if recurrent else None)
-            new_pools.append(pool)
+                layer_params, pools[pool_of[i]] if i in pool_of else None,
+                tables, starts, x, valid, lengths, decode, i,
+                None if held is None else state[held])
+            if i in pool_of:
+                new_pools.append(pool)
+            if held is not None:
+                new_state.append(rows)
             loads.append(load)
-            new_state.append(rows)
         # the last block's hidden state: each program applies the head to
         # the columns it reads
         return x, new_pools, loads, new_state
@@ -990,8 +1019,9 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         are the decoding streams' key counts, 0 for a lane that idles."""
         if "attention" not in stats:
             return stats
-        n_win = sum(1 for w_ in windows if w_)
-        n_full = len(windows) - n_win
+        attend = attention_windows(model)
+        n_win = sum(1 for w_ in attend if w_)
+        n_full = len(attend) - n_win
         seen = jnp.minimum(lengths, win)
         last = jnp.maximum(lengths - 1, 0) // bs
         first = (lengths - seen) // bs      # page of the oldest visible key
@@ -1007,7 +1037,7 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         tick's decoding streams or the chunk's true columns."""
         if "ssm" not in stats:
             return stats
-        work = work.astype(jnp.int32) * c.n_layers
+        work = work.astype(jnp.int32) * len(state_of)
         zero, one = jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32)
         step = [work, zero, one, zero] if decode else [zero, work, zero, one]
         return {**stats, "ssm": stats["ssm"] + jnp.stack(step)}
@@ -1240,7 +1270,7 @@ class PagedDecodeServer:
         # size, the window and the widest chunk (``prefill_chunk``:
         # ``prefill_step`` takes no wider one), never from ``max_len``, and
         # by that count it can never be what refuses or evicts a stream.
-        self._windows = layer_windows(model)
+        self._windows = attention_windows(model)
         self.window = next((w for w in self._windows if w), None)
         self.prefill_chunk = max(1, int(prefill_chunk))
         self.window_allocator: Optional[BlockAllocator] = None

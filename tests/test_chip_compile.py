@@ -719,6 +719,124 @@ def test_yarn_cell_programs_fit_the_chip_and_walk_both_pools(
     assert not moved, moved
 
 
+# ---- the programs of olmohybrid-7b-pp2-serve-chat, whole --------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_delta_rule_cell_programs_fit_the_chip(spec, kernels_compiled,
+                                               program):
+    """The decode tick (64 slots) and a 512-column prefill chunk of the
+    cell's own configuration (``benchmark/configs/olmo-hybrid-7b-pp2.json``:
+    16 layers ``D D D G`` at the published widths, the whole vocabulary; the
+    mix's 4097 blocks of 16 and 192 table entries a stream) compile for the
+    described chip under its 15.75 GB.  A pool exists for each of the 4 full
+    layers alone (4.03 GB) and a state row store for each of the 12
+    delta-rule layers alone (1.75 GB, each row of S laid 48 x 384 so that
+    the chip pads nothing); no pool for a 'D' layer would leave room for
+    the 12 x 1.0 GB those would take.  Counted here: the tick 14.09 GB
+    (8.20 of weights, 107 MB of temporaries), the chunk 14.19 GB (212 MB).
+    The Gated DeltaNet's scopes are in the compiled text, neither a pool nor
+    the state is copied, and the tick writes out nothing of the state's
+    shape but the state (a key or query laid out as a stored row would be
+    another 141 MB a layer to write and read)."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.harness import common
+
+    cell = common.load_cell("olmohybrid-7b-pp2-serve-chat")
+    model = cell["model"]["family"].program_model(cell["model"])
+    geo = cell["job"]["serve_config"]
+    s, bs = geo["slots"], geo["block_size"]
+    mb = geo["max_len"] // bs
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+        model, geo["num_blocks"], bs, folded=True)))
+    state = abstract(jax.eval_shape(
+        lambda: paged_kv.init_paged_state(model, s)))
+    c = model.cfg
+    assert [i for i in range(16) if c.layer_has_cache(i)] == [3, 7, 11, 15]
+    assert [(p["k"].shape, p["v"].shape) for p in pools] == [
+        ((4097, 16, 3840), (4097, 16, 3840))] * 4
+    assert [(v["conv"].shape, v["state"].shape, v["state"].dtype)
+            for v in state] == [((64, 3, 11520), (64, 30, 48, 384),
+                                 jnp.float32)] * 12
+    prefill, step, _, _ = paged_kv._paged_programs(
+        model, bs, mb, 0.0, 0, 1.0, False, "fused")
+    stats = {"ssm": spec((len(paged_kv.SSM_COUNTERS),), jnp.int32)}
+    if program == "decode":
+        lowered = step.lower(
+            params, pools, state, stats, spec((s, mb * bs), jnp.int32),
+            spec((s, mb), jnp.int32), spec((s,), jnp.int32),
+            spec((s,), jnp.bool_), spec((2,), jnp.uint32))
+    else:
+        lowered = prefill.lower(
+            params, pools, state, stats, spec((1, mb), jnp.int32),
+            spec((), jnp.int32), spec((1,), jnp.int32),
+            spec((1, geo["prefill_chunk"]), jnp.int32), spec((), jnp.int32),
+            spec((), jnp.bool_))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 14.0e9 < total < 14.8e9 < 15.75e9, total
+    assert mem.alias_size_in_bytes > 5.7e9     # pools and state in place
+    text = compiled.as_text()
+    scopes = ("gdn_in", "gdn_conv", "gdn_gate_norm", "gdn_out",
+              "gdn_update" if program == "decode" else "gdn_scan",
+              "attn_core/attn_full/paged_attention_fused", "paged_scatter",
+              "qk_norm")
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+    assert "paged_gather" not in text
+    shaped = r"\[(?:4097,16,3840|64,30,48,384)\]"
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \S*" + shaped
+                          + r"\S* (?:copy|transpose|gather|copy-start)\(",
+                          line)]
+    assert not moved, moved
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    written = [line.strip()[:160] for line in entry.splitlines()
+               if re.search(r"= \S*" + shaped + r"\S* (?:reshape|broadcast)\(",
+                            line)]
+    assert not written, written
+
+
+def test_delta_rule_chunk_runs_every_product_in_float32(spec):
+    """The chunked rule of a 512-column chunk at the cell's head widths (30
+    heads, keys of 96, values of 192), as the chip's compiler leaves it:
+    every product (``K K^T``, ``Q K^T``, the triangular solve's, and the
+    four with the carried state) keeps both operands in float32.  Under
+    the TPU's default precision they would be rounded to bfloat16 for one
+    pass, and the state with them, where the configuration keeps it in
+    float32."""
+    import re
+
+    from neural_networks_parallel_training_with_mpi_tpu.models.deltanet import (
+        GatedDeltaNet,
+    )
+
+    f32 = lambda *shape: spec(shape, jnp.float32)      # noqa: E731
+    b, t, h = 1, 512, 30
+    text = _compiled_text(
+        GatedDeltaNet(3840, h, 96, 192)._scan, f32(b, t, h, 96),
+        f32(b, t, h, 96), f32(b, t, h, 192), f32(b, t, h), f32(b, t, h),
+        f32(b, h, 96, 192))
+    products = [line for line in text.splitlines()
+                if re.search(r"= \S+ (?:convolution|dot)\(", line)]
+    assert len(products) >= 7, products
+    low = [line.strip()[:160] for line in products
+           if "operand_precision={highest,highest}" not in line]
+    assert not low, low
+
+
 def test_hybrid_cell_boundary_programs_update_in_place(spec):
     """The three programs of a request's boundary (``serve_admit``,
     ``serve_first_token`` for the one row of float32 logits a prompt's last
