@@ -17,8 +17,7 @@ One token's pass, with ``H`` heads, keys of ``dk`` and values of ``dv``:
 * the state ``S`` (``dk x dv`` a head, float32):
   ``S_t = alpha_t (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T``,
   ``o_t = S_t^T q_t``; as a tick computes it: ``S' = alpha S``, ``u = beta (v
-  - S'^T k)``, ``S = S' + k u^T``, ``o = S^T q`` (the state read once and
-  written once);
+  - S'^T k)``, ``S = S' + k u^T``, ``o = S^T q``;
 * ``o <- RMSNorm_dv(o) * silu(g)`` a head (one learned weight of ``dv`` for
   all heads), then ``o W_out``.
 
@@ -48,7 +47,11 @@ a tile, not mathematics: 64, as FLA's kernels.  Everything from the
 convolution's output on is float32, and every product of the rule asks for
 ``HIGHEST`` precision (a TPU's default rounds float32 operands to bfloat16 for
 one pass, which would keep the state at bfloat16's precision through a
-prefill).  The tick (scope ``gdn_update``) is the rule as it stands.
+prefill).  The tick (scope ``gdn_update``) is the rule as it stands, float32
+on the VPU: in XLA one pass over a layer's state for ``S^T k`` and ``S^T q``
+and one that writes ``S'`` (it needs ``u``, which needs ``S^T k``); on the
+paged server's fused path one Pallas pass that reads the state once and
+writes it once (``ops.pallas_kernels.delta_rule_step``).
 """
 
 from __future__ import annotations
@@ -60,6 +63,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_kernels import delta_rule_block, delta_rule_step
+from ..utils import compile_ledger as ledger_lib
 from .core import Linear, Module, Pytree, RMSNorm
 
 F32 = jnp.float32
@@ -292,10 +297,15 @@ class GatedDeltaNet(Module):
                                 jnp.ones((u.shape[1],), bool))[0]
 
     def apply_step(self, params: Pytree, u: jax.Array,
-                   state: Dict[str, jax.Array], active: jax.Array):
+                   state: Dict[str, jax.Array], active: jax.Array,
+                   fused: bool = False):
         """One token a stream: ``u`` (S, 1, d), ``state`` with a leading S,
         ``active`` (S,) bool -> (output (S, 1, d), the new state).  A lane
-        that is not active keeps its state and its tail bit for bit."""
+        that is not active keeps its state and its tail bit for bit.
+        ``fused`` (the paged server's fused path) runs the update as one
+        Pallas pass over each stream's state
+        (``ops.pallas_kernels.delta_rule_step``); else XLA's two passes
+        below.  The program's compile-ledger event says which ran."""
         xqkv, gate, b, a = self._project(params, u)
         with jax.named_scope("gdn_conv"):
             window = jnp.concatenate(
@@ -306,38 +316,53 @@ class GatedDeltaNet(Module):
                              state["conv"])
         with jax.named_scope("gdn_update"):
             beta, g = self._gates(params, b[:, 0], a[:, 0])    # (S, H)
-            k1, q1, v1 = k[:, 0], q[:, 0], v[:, 0]
-            f, dv = self.fold, self.value_dim
-            decay = jnp.exp(g)[..., None]                       # (S, H, 1)
-            lane_row = jax.lax.broadcasted_iota(jnp.int32, (f * dv,), 0) // dv
-
-            def spread(x):
-                """(S, H, dk) -> (S, H, dk/f, 1 or f dv), x[i] in the lanes
-                of a stored row that hold S[i]: a select over the lanes of
-                a broadcast, which the compiler fuses into the pass over the
-                state (a reshape of a broadcast it would write out)."""
-                x = x.reshape(x.shape[:2] + (self.key_dim // f, f))
-                out = x[..., :1]
-                for j in range(1, f):
-                    out = jnp.where(lane_row == j, x[..., j:j + 1], out)
-                return out
-
-            def gather(r):      # (S, H, f dv) -> (S, H, dv): the f rows' sum
-                return r.reshape(r.shape[:2] + (f, dv)).sum(-2)
-
             old = state["state"]                        # (S, H, dk/f, f dv)
-            # one pass reads the state for both products, S^T k and S^T q;
-            # o = S'^T q = alpha S^T q + (k . q) u for S' = alpha S + k u^T
-            sk = gather((old * spread(k1)).sum(-2))
-            sq = gather((old * spread(q1)).sum(-2))
-            u1 = beta[..., None] * (v1 - decay * sk)            # (S, H, dv)
-            o = decay * sq + (k1 * q1).sum(-1, keepdims=True) * u1
-            # the second writes S'; a lane that is not active keeps S
-            s = jnp.where(active[:, None, None, None],
-                          old * decay[..., None]
-                          + spread(k1) * jnp.tile(u1, f)[..., None, :], old)
+            if fused:
+                ledger_lib.note("gdn_update", {
+                    "impl": "pallas",
+                    "block": [delta_rule_block(*old.shape), self.n_heads]})
+                s, o = delta_rule_step(old, k[:, 0], q[:, 0], v[:, 0], beta,
+                                       jnp.exp(g), active)
+            else:
+                ledger_lib.note("gdn_update", {"impl": "xla"})
+                s, o = self._update(old, k[:, 0], q[:, 0], v[:, 0], beta, g,
+                                    active)
         return (self._finish(params, o[:, None], gate),
                 {"conv": tail, "state": s})
+
+    def _update(self, old, k1, q1, v1, beta, g, active):
+        """The tick's rule in XLA: ``old`` (S, H, dk/f, f dv), ``k1``,
+        ``q1`` (S, H, dk), ``v1`` (S, H, dv), ``beta``, ``g`` (S, H) ->
+        (the new state, ``o`` (S, H, dv))."""
+        f, dv = self.fold, self.value_dim
+        decay = jnp.exp(g)[..., None]                           # (S, H, 1)
+        lane_row = jax.lax.broadcasted_iota(jnp.int32, (f * dv,), 0) // dv
+
+        def spread(x):
+            """(S, H, dk) -> (S, H, dk/f, 1 or f dv), x[i] in the lanes of a
+            stored row that hold S[i]: a select over the lanes of a
+            broadcast, which the compiler fuses into the pass over the
+            state (a reshape of a broadcast it would write out)."""
+            x = x.reshape(x.shape[:2] + (self.key_dim // f, f))
+            out = x[..., :1]
+            for j in range(1, f):
+                out = jnp.where(lane_row == j, x[..., j:j + 1], out)
+            return out
+
+        def gather(r):          # (S, H, f dv) -> (S, H, dv): the f rows' sum
+            return r.reshape(r.shape[:2] + (f, dv)).sum(-2)
+
+        # one pass reads the state for both products, S^T k and S^T q;
+        # o = S'^T q = alpha S^T q + (k . q) u for S' = alpha S + k u^T
+        sk = gather((old * spread(k1)).sum(-2))
+        sq = gather((old * spread(q1)).sum(-2))
+        u1 = beta[..., None] * (v1 - decay * sk)                # (S, H, dv)
+        o = decay * sq + (k1 * q1).sum(-1, keepdims=True) * u1
+        # the second writes S'; a lane that is not active keeps S
+        s = jnp.where(active[:, None, None, None],
+                      old * decay[..., None]
+                      + spread(k1) * jnp.tile(u1, f)[..., None, :], old)
+        return s, o
 
     # ---- counts ---------------------------------------------------------
     def recurrence_flops_per_token(self) -> float:

@@ -11,6 +11,9 @@ provides:
   the FlashAttention recurrence.  Causal blocks above the diagonal are
   skipped entirely (the fori_loop upper bound shrinks per q-block), saving
   ~2x FLOPs at long T.  O(T) memory per head instead of O(T^2).
+* **delta_rule_step** — the gated delta rule's decode tick over a store of
+  float32 state rows, each stream's state read once and written once, in
+  place.
 
 The kernels run in interpreter mode on CPU (tests, SURVEY.md §4's fake-device
 strategy) and compiled through Mosaic on TPU.  The backward pass of
@@ -1149,3 +1152,156 @@ def _paged_attention_call(q, k_pool, v_pool, tables, lengths, starts,
     out = out.reshape(s_n, kv_heads, width, groups, vd)
     out = out.transpose(0, 2, 1, 3, 4).reshape(s_n, width, n_heads, vd)
     return out[..., :v_lanes] if shared and vd != v_lanes else out
+
+
+# ==========================================================================
+# Delta rule: a decode tick's state update (models/deltanet.py, gdn_update)
+# ==========================================================================
+
+# State bytes a grid step of ``delta_rule_step`` holds at most: whole slots
+# of every head, at least one.  A block is read once and written once, each
+# double-buffered, so VMEM holds four of them.
+DELTA_RULE_BLOCK_BYTES = 4 << 20
+
+
+def delta_rule_block(slots: int, heads: int, rows: int, lanes: int) -> int:
+    """Slots a grid step of :func:`delta_rule_step` updates: as many whole
+    slots of all ``heads`` as ``DELTA_RULE_BLOCK_BYTES`` of float32 state
+    rows ``(rows, lanes)`` hold, at least one and at most ``slots``."""
+    per_slot = heads * rows * lanes * 4
+    return max(1, min(slots, DELTA_RULE_BLOCK_BYTES // per_slot))
+
+
+def _delta_rule_kernel(active_ref, gates_ref, s_ref, kq_ref, v_ref, s_out,
+                       o_ref, *, slots: int, heads: int, fold: int, dv: int,
+                       total: int):
+    """One grid step: ``slots`` streams, every head, each head's state
+    block ``(dk/fold, fold·dv)`` read once and written once.
+
+    ``kq_ref`` (slots, dk/fold, 2·heads·fold) holds the keys then the
+    queries with a stored row's ``fold`` entries in lanes side by side:
+    lane ``h·fold + j`` of row ``r`` is ``k[h, fold·r + j]``.  A lane of it
+    broadcast over the lanes of the stored row that hold ``S[fold·r + j]``
+    (lane ``l`` holds row ``l // dv``) is ``k`` spread over the state;
+    the state times it summed over the rows is each of the ``fold`` rows'
+    part of ``S^T k``, and the ``fold`` parts summed by rolls of ``dv``
+    lanes leave ``S^T k`` in every part: the ``u`` each part's update
+    takes.  ``active_ref`` (streams,) and ``gates_ref`` (3·streams·heads:
+    ``beta``, the decay, ``k . q``) are scalars in SMEM; ``v_ref`` and
+    ``o_ref`` (slots, heads, fold·dv) are a head's value row tiled
+    ``fold`` times and its output, every part the same."""
+    rows, lanes = s_ref.shape[-2:]
+    part = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) // dv
+
+    def spread(kq, col):
+        out = jnp.broadcast_to(kq[:, col:col + 1], (rows, lanes))
+        for j in range(1, fold):
+            out = jnp.where(part == j, kq[:, col + j:col + j + 1], out)
+        return out
+
+    def gather(r):
+        out = r
+        for j in range(1, fold):
+            out = out + pltpu.roll(r, j * dv, 1)
+        return out
+
+    for i in range(slots):
+        # the last block's rows past the streams are read and not written
+        at = jnp.minimum(pl.program_id(0) * slots + i, total - 1)
+        live = active_ref[at] != 0
+        kq = kq_ref[i]
+        for h in range(heads):
+            beta = gates_ref[at * heads + h]
+            decay = gates_ref[(total + at) * heads + h]
+            kdq = gates_ref[(2 * total + at) * heads + h]
+            s = s_ref[i, h]
+            ks = spread(kq, h * fold)
+            sk = gather((s * ks).sum(0, keepdims=True))
+            sq = gather((s * spread(kq, (heads + h) * fold)).sum(
+                0, keepdims=True))
+            u = beta * (v_ref[i, h:h + 1, :] - decay * sk)
+            o_ref[i, h:h + 1, :] = decay * sq + kdq * u
+
+            @pl.when(live)
+            def _():
+                s_out[i, h] = decay * s + ks * u
+
+            @pl.when(jnp.logical_not(live))
+            def _():
+                s_out[i, h] = s
+
+
+def delta_rule_step(state: jax.Array, k: jax.Array, q: jax.Array,
+                    v: jax.Array, beta: jax.Array, decay: jax.Array,
+                    active: jax.Array, *, block: Optional[int] = None,
+                    interpret: Optional[bool] = None):
+    """One decode tick of the gated delta rule over a store of state rows,
+    in place: ``S' = decay S + k u^T`` with ``u = beta (v - decay S^T k)``,
+    and ``o = S'^T q = decay S^T q + (k . q) u``, every product float32 on
+    the VPU.  A stream's state is read once and written once: XLA's form
+    (models/deltanet.py) reads it a second time, since the pass that writes
+    ``S'`` needs ``u``, which needs the reduction ``S^T k`` of the same
+    state.
+
+    * ``state``: (streams, heads, dk/fold, fold·dv) float32, ``dk x dv`` a
+      head with ``fold`` rows side by side in a stored row; it is aliased
+      to the new state (donate it, and the update is in place).
+    * ``k``, ``q``: (streams, heads, dk); ``v``: (streams, heads, dv);
+      ``beta``, ``decay``: (streams, heads); all float32.
+    * ``active``: (streams,) bool; a stream that is not active keeps its
+      state bit for bit.
+    * ``block``: streams a grid step, every head (None:
+      :func:`delta_rule_block`); a last block that is cut short reads
+      past the streams and writes nothing there.
+
+    Returns (the new state, ``o`` (streams, heads, dv) float32)."""
+    if interpret is None:
+        interpret = _interpret_default()
+    s_n, heads, rows, lanes = state.shape
+    if block is None:
+        block = delta_rule_block(s_n, heads, rows, lanes)
+    return _delta_rule_call(state, k, q, v, beta, decay, active,
+                            block=block, interpret=interpret)
+
+
+# jitted for the flash calls' reason: 12 unrolled layers lower one kernel
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _delta_rule_call(state, k, q, v, beta, decay, active, *, block: int,
+                     interpret: bool):
+    s_n, heads, rows, lanes = state.shape
+    dk, dv = k.shape[-1], v.shape[-1]
+    fold = dk // rows
+
+    def lanes_of(x):    # (S, H, dk) -> (S, dk/fold, H·fold)
+        return x.reshape(s_n, heads, rows, fold).transpose(0, 2, 1, 3) \
+            .reshape(s_n, rows, heads * fold)
+
+    kq = jnp.concatenate([lanes_of(k), lanes_of(q)], axis=-1)
+    gates = jnp.stack([beta, decay, (k * q).sum(-1)]).reshape(-1)
+    blk = lambda *shape: pl.BlockSpec(                       # noqa: E731
+        (block,) + shape, lambda g, *_: (g,) + (0,) * len(shape))
+    # the state block in and out, double-buffered, and the small blocks
+    # beside it (each lane- and sublane-padded)
+    state_blk = block * heads * rows * lanes * 4
+    small = 4 * block * (rows * max(2 * heads * fold, 128)
+                         + 2 * -(-heads // 8) * 8 * lanes) * 4
+    new, o = pl.pallas_call(
+        functools.partial(_delta_rule_kernel, slots=block, heads=heads,
+                          fold=fold, dv=dv, total=s_n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(s_n, block),),
+            in_specs=[blk(heads, rows, lanes),
+                      blk(rows, 2 * heads * fold), blk(heads, lanes)],
+            out_specs=[blk(heads, rows, lanes), blk(heads, lanes)]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((s_n, heads, lanes), jnp.float32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=int(min(100 << 20, max(
+                32 << 20, 4 * state_blk + small + (8 << 20))))),
+        interpret=interpret,
+        name="delta_rule_step",
+    )(active.astype(jnp.int32), gates, state, kq, jnp.tile(v, fold))
+    return new, o[..., :dv]

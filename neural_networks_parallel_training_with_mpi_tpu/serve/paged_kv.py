@@ -924,8 +924,11 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
             tables = tables[1 if windows[layer] else 0]
         if recurrent:
             if decode:
+                # the delta rule's tick takes its kernel on the fused path
+                fused = ({"fused": attn_impl == "fused"}
+                         if c.layer_is_delta(layer) else {})
                 run = lambda mixer, p, u: mixer.apply_step(     # noqa: E731
-                    p, u, state, lengths > 0)
+                    p, u, state, lengths > 0, **fused)
             else:
                 run = lambda mixer, p, u: mixer.apply_chunk(    # noqa: E731
                     p, u, state, valid)

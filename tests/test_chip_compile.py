@@ -809,6 +809,88 @@ def test_delta_rule_cell_programs_fit_the_chip(spec, kernels_compiled,
     assert not written, written
 
 
+def test_delta_rule_tick_updates_each_state_in_one_kernel_pass(
+        spec, kernels_compiled):
+    """The decode tick of ``olmohybrid-7b-pp2-serve-chat`` (the cell's own
+    configuration and geometry, as above) updates each delta-rule layer's
+    store, (64, 30, 48, 384) float32, in one Pallas call under
+    ``gdn_update`` (``delta_rule_step``) that reads it once and writes it
+    once: the stored state is an operand of that call and of nothing
+    else, and the call's output is the store, aliased in place.  The
+    program aliases what XLA's form of the tick aliased (5,780,079,616 B:
+    the pools, the stores and the convolution's tails) and fits the chip
+    as above: 14.04 GB, 55 MB of temporaries (XLA's form: 14.08 GB, 99
+    MB).  The compile-ledger event names what implements the update and
+    its block: one slot of all 30 heads a grid step."""
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmark.harness import common
+
+    cell = common.load_cell("olmohybrid-7b-pp2-serve-chat")
+    model = cell["model"]["family"].program_model(cell["model"])
+    geo = cell["job"]["serve_config"]
+    s, bs = geo["slots"], geo["block_size"]
+    mb = geo["max_len"] // bs
+    abstract = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(lambda: model.init(prng.init_key(0))))
+    pools = abstract(jax.eval_shape(lambda: paged_kv.init_paged_kv(
+        model, geo["num_blocks"], bs, folded=True)))
+    state = abstract(jax.eval_shape(
+        lambda: paged_kv.init_paged_state(model, s)))
+    assert paged_kv.resolve_attn_impl(model, "auto") == "fused"
+    _, step, _, _ = paged_kv._paged_programs(
+        model, bs, mb, 0.0, 0, 1.0, False, "auto")
+    stats = {"ssm": spec((len(paged_kv.SSM_COUNTERS),), jnp.int32)}
+    ledger = compile_ledger.Ledger(None)
+    compile_ledger.install(ledger)
+    try:
+        with ledger.collecting_notes() as notes:
+            lowered = step.lower(
+                params, pools, state, stats, spec((s, mb * bs), jnp.int32),
+                spec((s, mb), jnp.int32), spec((s,), jnp.int32),
+                spec((s,), jnp.bool_), spec((2,), jnp.uint32))
+    finally:
+        compile_ledger.install(None)
+    assert notes["gdn_update"] == {"impl": "pallas", "block": [1, 30]}
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 14.0e9 < total < 14.8e9 < 15.75e9, total
+    assert mem.alias_size_in_bytes >= 5_780_079_616
+    assert mem.temp_size_in_bytes < 99e6
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")].splitlines()
+    stores = [m.group(1) for line in entry for m in [re.match(
+        r"\s*%(\S+) = f32\[64,30,48,384\]\S* parameter\(", line)] if m]
+    assert len(stores) == 12, stores
+    for name in stores:
+        readers = [line.strip() for line in entry
+                   if re.search(r"[(,] ?%" + re.escape(name) + r"[,)]",
+                                line)]
+        assert len(readers) == 1, readers
+        (call,) = readers
+        assert 'custom_call_target="tpu_custom_call"' in call, call[:160]
+        assert re.search(r'op_name="[^"]*/gdn_update/[^"]*delta_rule_step',
+                         call), call[:160]
+        assert call.split(" custom-call(")[0].split(" = ")[1].startswith(
+            "(f32[64,30,48,384]"), call[:160]
+    kernels = [line for line in entry if " custom-call(" in line
+               and "/gdn_update/" in line and "delta_rule_step" in line]
+    assert len(kernels) == 12, len(kernels)
+    # the kernel's first output is its third operand, the store
+    for call in kernels:
+        assert "output_to_operand_aliasing={{0}: (2, {})}" in call, \
+            call[:200]
+
+
 def test_delta_rule_chunk_runs_every_product_in_float32(spec):
     """The chunked rule of a 512-column chunk at the cell's head widths (30
     heads, keys of 96, values of 192), as the chip's compiler leaves it:

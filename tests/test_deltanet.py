@@ -180,3 +180,44 @@ def test_the_state_row_and_the_counts():
     assert ref.CHUNK == GatedDeltaNet(D, H, DK, DV).chunk == 64
     assert ref.recurrence_flops(MODEL) == GatedDeltaNet(
         D, H, DK, DV).recurrence_flops_per_token()
+
+
+@pytest.mark.parametrize("slots,heads,dk,dv,block", [
+    (64, 30, 96, 192, None),    # olmohybrid-7b-pp2's layer, its own block
+    (6, 4, 64, 128, None),      # a fold of 1: a row of S is a stored row
+    (5, 3, 96, 192, 2),         # the last block cut short
+    (7, 2, 16, 64, 3),          # a fold of 2 over 8 stored rows
+])
+def test_the_tick_kernel_is_the_xla_form(slots, heads, dk, dv, block):
+    """The Pallas tick (``ops.pallas_kernels.delta_rule_step``, interpreted
+    here) against the XLA form it replaces (``GatedDeltaNet._update``):
+    random states, unit keys, ``beta`` in (0, 2), log decays down to
+    ``-e^0.5``, a random set of idle streams.  The new state and ``o``
+    within ``TIGHT`` of the largest value; an idle stream's state bit for
+    bit."""
+    from neural_networks_parallel_training_with_mpi_tpu.ops import (
+        pallas_kernels as pk,
+    )
+
+    mixer = GatedDeltaNet(heads * dk, heads, dk, dv)
+    f = mixer.fold
+    assert f == (1 if dv == 128 else 2)
+    ks = jax.random.split(jax.random.PRNGKey(slots * heads + dk), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    state = jax.random.normal(ks[0], (slots, heads, dk // f, f * dv))
+    k = unit(jax.random.normal(ks[1], (slots, heads, dk)))
+    q = unit(jax.random.normal(ks[2], (slots, heads, dk))) * dk ** -0.5
+    v = jax.random.normal(ks[3], (slots, heads, dv))
+    beta = jax.random.uniform(ks[4], (slots, heads), minval=0.0, maxval=2.0)
+    g = -jnp.exp(jax.random.uniform(ks[5], (slots, heads), minval=-3.0,
+                                    maxval=0.5))
+    active = jax.random.bernoulli(ks[6], 0.7, (slots,)).at[0].set(False)
+    assert bool(active.any())
+    want_s, want_o = mixer._update(state, k, q, v, beta, g, active)
+    got_s, got_o = pk.delta_rule_step(state, k, q, v, beta, jnp.exp(g),
+                                      active, block=block, interpret=True)
+    assert got_s.shape == state.shape and got_o.shape == (slots, heads, dv)
+    assert close(got_s, want_s) and close(got_o, want_o)
+    idle = ~np.asarray(active)
+    assert (np.asarray(got_s)[idle] == np.asarray(state)[idle]).all()
+    assert not (np.asarray(got_s)[~idle] == np.asarray(state)[~idle]).all()
