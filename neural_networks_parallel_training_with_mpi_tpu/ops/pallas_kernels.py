@@ -18,9 +18,9 @@ provides:
 The kernels run in interpreter mode on CPU (tests, SURVEY.md §4's fake-device
 strategy) and compiled through Mosaic on TPU.  The backward pass of
 flash_attention is also Pallas: the forward additionally emits the per-row
-logsumexp, and two backward kernels (dq; dk+dv) recompute the probability
-blocks from (q, k, lse) in VMEM — the standard FlashAttention-2 backward
-split, no (T, T) buffer anywhere.  ``_blocked_attention_reference`` keeps
+logsumexp, and one backward kernel recomputes each causal tile pair's
+probability block from (q, k, lse) in VMEM once and takes dq, dk and dv
+from it — no (T, T) buffer anywhere.  ``_blocked_attention_reference`` keeps
 the same math in plain JAX as the cross-check for tests.
 """
 
@@ -316,8 +316,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
 def _kernel_geometry(b: int, t: int, h: int, d: int, block_q: int,
                      block_k: int, itemsize: int):
-    """What the three ``pallas_call``s share: the layout plan, the block
-    specs by role, and the compiler parameters."""
+    """What the two ``pallas_call``s share: the layout plan, the block
+    specs by role, and the VMEM they need besides the backward's scratch."""
     pack, folded = _fold_plan(h, d)
     g, cols, w = (b, h // pack, pack * d) if folded else (b * h, 1, d)
     mem = {"memory_space": pltpu.VMEM}
@@ -334,16 +334,19 @@ def _kernel_geometry(b: int, t: int, h: int, d: int, block_q: int,
         "stat_rows": pl.BlockSpec((1, 1, pack, t),
                                   lambda g_, c, i: (g_, c, 0, 0), **mem),
     }
-    # every grid step is independent; the scoped-VMEM limit follows the
-    # tile: the resident rows (double-buffered, lane-padded) plus the f32
+    # the resident rows (double-buffered, lane-padded) plus the f32
     # score-sized temporaries of one loop step, per head of the column
     resident = 8 * t * max(w, 128) * itemsize
     tiles = 8 * pack * block_q * block_k * 4
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel"),
-        vmem_limit_bytes=int(min(100 << 20,
-                                 max(32 << 20, resident + tiles))))
-    return pack, folded, (g, cols, w), specs, params
+    return pack, folded, (g, cols, w), specs, resident + tiles
+
+
+def _compiler_params(vmem: int, last: str):
+    """The scoped-VMEM limit follows the tile; the first two grid axes are
+    independent, the last is ``last`` ("parallel" or "arbitrary")."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", last),
+        vmem_limit_bytes=int(min(100 << 20, max(32 << 20, vmem))))
 
 
 def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
@@ -367,7 +370,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
 def _flash_forward_call(q, k, v, *, mask: str, block_q: int, block_k: int,
                         interpret: bool):
     b, t, h, d = q.shape
-    pack, folded, (g, cols, w), specs, params = _kernel_geometry(
+    pack, folded, (g, cols, w), specs, vmem = _kernel_geometry(
         b, t, h, d, block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
                                block_k=block_k, seq_len=t, mask=mask,
@@ -381,7 +384,7 @@ def _flash_forward_call(q, k, v, *, mask: str, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((g, t, cols * w), q.dtype),
             jax.ShapeDtypeStruct((g, cols, pack, t), jnp.float32),
         ],
-        compiler_params=params,
+        compiler_params=_compiler_params(vmem, "parallel"),
         interpret=interpret,
         name="flash_fwd",
     )(*(_to_kernel(x, folded) for x in (q, k, v)))
@@ -429,86 +432,53 @@ def _blocked_attention_reference(q, k, v, causal: bool, block_k: int):
 
 
 # --------------------------------------------------------------------------
-# Backward kernels (FlashAttention-2 split: one kernel accumulates dq over
-# k-blocks, one accumulates dk/dv over q-blocks; p is recomputed from
-# (q, k, lse), delta = rowsum(do * o) is precomputed outside).  As in the
-# forward: operands in the dtype they arrive in, f32 scores, lse, delta and
+# Backward kernel (one pass over the causal tile pairs: p and ds are
+# recomputed once a pair from (q, k, lse) and feed dq, dk and dv alike;
+# delta = rowsum(do * o) is precomputed outside).  As in the forward:
+# operands in the dtype they arrive in, f32 scores, lse, delta and
 # accumulators; p and ds are cast only as the operand of the next product,
-# and the softmax scale lands on the (block, D) accumulators, once.
+# and the softmax scale lands on the accumulators, once.
 # --------------------------------------------------------------------------
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q: int, block_k: int, seq_len: int,
-                         mask: str, scale: float, pack: int, head_dim: int):
-    """Grid: (G, columns, T // block_q).  q/do/dq blocks (1, block_q, W);
-    k/v whole rows (1, T, W); lse/delta blocks (1, 1, pack, block_q)
-    float32 (see _flash_fwd_kernel for the layout)."""
-    qi = pl.program_id(2)
-    q, s_scale = _fold_scale(q_ref[0], scale)
-    do = do_ref[0]
-    w = q.shape[-1]
-    lanes = _head_lanes(pack, head_dim, block_q)
-    full, hi = _row_bounds(mask, qi, block_q, block_k, seq_len // block_k)
-    rel = (lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-           - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
-    heads = []
-    for i, m in enumerate(lanes):
-        lse = lse_ref[0, 0, i][:, None]                   # (Bq, 1) f32
-        if mask == "causal_exclusive":
-            # no-key rows carry lse = NEG_INF; every score they see is the
-            # mask fill, so exp(fill - 0) is the 0 their gradient is, where
-            # exp(fill - NEG_INF) would be 1
-            lse = jnp.where(lse > (NEG_INF * 0.5), lse, 0.0)
-        heads.append((_only(m, q), _only(m, do), lse,
-                      delta_ref[0, 0, i][:, None]))
-
-    def step(masked: bool):
-        def body(j, dq_accs):
-            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
-            k = k_ref[0, rows, :]
-            v = v_ref[0, rows, :]
-            keep = (_keep(mask, rel, qi * block_q, j * block_k)
-                    if masked else None)
-            out = []
-            for (qh, doh, lse, delta), dq_acc in zip(heads, dq_accs):
-                s = _dot(qh, k, _NT)
-                if s_scale is not None:
-                    s = s * s_scale
-                if masked:
-                    s = jnp.where(keep, s, NEG_INF)
-                p = jnp.exp(s - lse)                      # (Bq, Bk)
-                ds = p * (_dot(doh, v, _NT) - delta)
-                out.append(dq_acc + _dot(ds.astype(k.dtype), k, _NN))
-            return tuple(out)
-        return body
-
-    dqs = (jnp.zeros((block_q, w), jnp.float32),) * pack
-    if mask == "none":
-        dqs = lax.fori_loop(0, hi, step(False), dqs)
-    else:
-        dqs = _two_loops(0, full, hi, step(False), step(True), dqs)
-    dq_ref[0] = (_own_lanes(lanes, dqs) * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, block_k: int,
-                          seq_len: int, mask: str, scale: float, pack: int,
-                          head_dim: int):
-    """Grid: (G, columns, T // block_k).  k/v/dk/dv blocks (1, block_k, W);
-    q/do whole rows (1, T, W); lse/delta whole rows (1, 1, pack, T) float32.
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dqt_ref, *, block_q: int,
+                      block_k: int, seq_len: int, mask: str, scale: float,
+                      pack: int, head_dim: int):
+    """Grid: (G, columns, T // block_k), the k-blocks in order.  k/v/dk/dv
+    blocks (1, block_k, W); q/do/dq whole rows (1, T, W); lse/delta whole
+    rows (1, 1, pack, T) float32; ``dqt_ref`` a (T // block_q, W, block_q)
+    f32 scratch that holds dq^T of the column's rows across the k-blocks.
 
     Works on the TRANSPOSED tile, keys down and queries across: ``s^T = k
     q^T`` comes out of the MXU that way, ``p^T`` and ``ds^T`` are then the
-    left operands of plain products, and lse/delta are read as the
-    (1, block_q) lane rows they are stored as — no transposed-operand
-    product and no row-to-column relayout in the loop."""
+    right or left operands of plain products, and lse/delta are read as
+    the (1, block_q) lane rows they are stored as — no transposed-operand
+    product and no row-to-column relayout in the loop.  dq is accumulated
+    transposed, ``dq_h^T += k_h^T ds_h^T`` into head h's rows of the
+    scratch (k^T made once a k-block), summed over the k-blocks in order,
+    and written once, transposed back, after the last."""
     kj = pl.program_id(2)
+
+    @pl.when(kj == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros_like(dqt_ref)
+
     v = v_ref[0]                                          # (Bk, W)
-    k, s_scale = _fold_scale(k_ref[0], scale)
+    k_in = k_ref[0]
+    k, s_scale = _fold_scale(k_in, scale)
     w = k.shape[-1]
     lanes = _head_lanes(pack, head_dim, block_k)
     ks = [_only(m, k) for m in lanes]
     vs = [_only(m, v) for m in lanes]
+    # k_h^T (D, Bk) of each head, once a k-block, as the product of the
+    # identity's rows that pick the head's lanes with k^T: exact for bf16
+    # operands, and no transpose op, which XLA's CPU backend (where the
+    # kernel is interpreted) folds into the dq product as a bf16
+    # transposed-operand product it cannot run
+    pick = (lax.broadcasted_iota(jnp.int32, (head_dim, w), 1)
+            - lax.broadcasted_iota(jnp.int32, (head_dim, w), 0))
+    kts = [_dot((pick == n * head_dim).astype(k_in.dtype), k_in,
+                _NT).astype(k_in.dtype) for n in range(pack)]
     num_q = seq_len // block_q
     lo, full = _col_bounds(mask, kj, block_q, block_k, num_q)
     # k_local - q_local on the transposed tile
@@ -523,13 +493,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             keep = (_keep(mask, rel, i * block_q, kj * block_k)
                     if masked else None)
             out = []
-            for n, (kh, vh, (dk_acc, dv_acc)) in enumerate(
-                    zip(ks, vs, carry)):
+            for n, (kh, vh, kth, (dk_acc, dv_acc)) in enumerate(
+                    zip(ks, vs, kts, carry)):
                 # slice from the refs (Mosaic lowers pl.ds ref reads;
                 # value-level lax.dynamic_slice has no TPU lowering rule)
                 lse = lse_ref[0, 0, n:n + 1, pl.ds(start, block_q)]
                 delta = delta_ref[0, 0, n:n + 1, pl.ds(start, block_q)]
-                if mask == "causal_exclusive":            # see the dq kernel
+                if mask == "causal_exclusive":
+                    # no-key rows carry lse = NEG_INF; every score they see
+                    # is the mask fill, so exp(fill - 0) is the 0 their
+                    # gradient is, where exp(fill - NEG_INF) would be 1
                     lse = jnp.where(lse > (NEG_INF * 0.5), lse, 0.0)
                 st = _dot(kh, q, _NT)                     # (Bk, Bq) f32
                 if s_scale is not None:
@@ -538,8 +511,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     st = jnp.where(keep, st, NEG_INF)
                 pt = jnp.exp(st - lse)                    # lse: (1, Bq)
                 dv_acc = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
-                dst = pt * (_dot(vh, do, _NT) - delta)
-                dk_acc = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
+                dst = (pt * (_dot(vh, do, _NT) - delta)).astype(q.dtype)
+                dk_acc = dk_acc + _dot(dst, q, _NN)
+                rows = slice(n * head_dim, (n + 1) * head_dim)
+                dqt_ref[i, rows, :] += _dot(kth, dst, _NN)  # (D, Bq)
                 out.append((dk_acc, dv_acc))
             return tuple(out)
         return body
@@ -554,6 +529,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  * scale).astype(dk_ref.dtype)
     dv_ref[0] = _own_lanes(lanes, [dv for _, dv in carry]).astype(
         dv_ref.dtype)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _():
+        def write(i, c):
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            dq_ref[0, rows, :] = (dqt_ref[i].T * scale).astype(dq_ref.dtype)
+            return c
+        lax.fori_loop(0, num_q, write, 0)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool,
@@ -575,13 +558,13 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool,
 def _flash_backward_call(q, k, v, out, lse, g, g_lse, *, mask: str,
                          block_q: int, block_k: int, interpret: bool):
     b, t, h, d = q.shape
-    pack, folded, (grp, cols, w), specs, params = _kernel_geometry(
+    pack, folded, (grp, cols, w), specs, vmem = _kernel_geometry(
         b, t, h, d, block_q, block_k, q.dtype.itemsize)
     # delta_i = sum_j p_ij * dp_ij = rowsum(do * o): one fused elementwise
-    # reduce in XLA over the model's own layout, shared by both kernels.
+    # reduce in XLA over the model's own layout.
     #
     # A cotangent on the lse OUTPUT (flash_attention_with_lse) folds into
-    # the same kernels: d lse_i / d s_ij = p_ij, so
+    # the same kernel: d lse_i / d s_ij = p_ij, so
     # ds_ij = p_ij * (dp_ij - delta_i + g_lse_i) — i.e. shift delta by
     # -g_lse and nothing else changes (dv is lse-independent).
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
@@ -590,34 +573,23 @@ def _flash_backward_call(q, k, v, out, lse, g, g_lse, *, mask: str,
         delta = delta - g_lse.astype(jnp.float32)
     stats = (lse.reshape(grp, cols, pack, t),
              delta.reshape(grp, cols, pack, t))
-    qh, kh, vh, doh = (_to_kernel(x, folded) for x in (q, k, v, g))
-
-    row = dict(block_q=block_q, block_k=block_k, seq_len=t, mask=mask,
-               scale=1.0 / (d ** 0.5), pack=pack, head_dim=d)
     like = lambda x: jax.ShapeDtypeStruct((grp, t, cols * w), x.dtype)
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **row),
-        grid=(grp, cols, t // block_q),
-        in_specs=[specs["q_blk"], specs["rows"], specs["rows"],
-                  specs["q_blk"], specs["stat_blk"], specs["stat_blk"]],
-        out_specs=specs["q_blk"],
-        out_shape=like(q),
-        compiler_params=params,
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qh, kh, vh, doh, *stats)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **row),
+    # dq^T of the column's rows stays in VMEM across the k-blocks
+    dqt = t * max(w, 128) * 4
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q,
+                          block_k=block_k, seq_len=t, mask=mask,
+                          scale=1.0 / (d ** 0.5), pack=pack, head_dim=d),
         grid=(grp, cols, t // block_k),
         in_specs=[specs["rows"], specs["k_blk"], specs["k_blk"],
                   specs["rows"], specs["stat_rows"], specs["stat_rows"]],
-        out_specs=[specs["k_blk"], specs["k_blk"]],
-        out_shape=[like(k), like(v)],
-        compiler_params=params,
+        out_specs=[specs["rows"], specs["k_blk"], specs["k_blk"]],
+        out_shape=[like(q), like(k), like(v)],
+        scratch_shapes=[pltpu.VMEM((t // block_q, w, block_q), jnp.float32)],
+        compiler_params=_compiler_params(vmem + dqt, "arbitrary"),
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qh, kh, vh, doh, *stats)
+        name="flash_bwd",
+    )(*(_to_kernel(x, folded) for x in (q, k, v, g)), *stats)
     return tuple(_from_kernel(x, b, h, folded) for x in (dq, dk, dv))
 
 

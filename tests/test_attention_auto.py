@@ -195,7 +195,7 @@ def test_train_step_compile_event_names_the_attention(tmp_path, mesh8):
 
 def test_scope_report_lists_attn_flash_and_its_kernels(tmp_path):
     """``tools/scope_report.py`` on a toy trace: the by-scope table holds
-    ``attention`` for both passes, and under ``attn_flash`` the three
+    ``attention`` for both passes, and under ``attn_flash`` the two
     kernels' self time beside what XLA does around them."""
     import importlib.util
     import pathlib
@@ -217,8 +217,7 @@ def test_scope_report_lists_attn_flash_and_its_kernels(tmp_path):
     bwd = (step + "transpose(jvp(attention))/attn_flash/"
            "jit(_flash_backward_call)/")
     ops = [(fwd + "transpose", 0, 2), (fwd + "flash_fwd/pallas_call", 2, 10),
-           (bwd + "flash_bwd_dq/pallas_call", 20, 12),
-           (bwd + "flash_bwd_dkv/pallas_call", 32, 16),
+           (bwd + "flash_bwd/pallas_call", 20, 28),
            (bwd + "reduce_sum", 48, 1),
            (step + "jvp(attn_proj)/dot_general", 49, 1),
            (step + "jvp(ffn)/dot_general", 50, 30)]
@@ -230,8 +229,8 @@ def test_scope_report_lists_attn_flash_and_its_kernels(tmp_path):
     ns = 1e-9                   # the writer's times are nanoseconds
     got = report.attention_by_impl(obs)
     assert set(got) == {"attn_flash"}
-    want = {"total": 41 * ns, "flash_fwd": 10 * ns, "flash_bwd_dq": 12 * ns,
-            "flash_bwd_dkv": 16 * ns, "around": 3 * ns}
+    want = {"total": 41 * ns, "flash_fwd": 10 * ns, "flash_bwd": 28 * ns,
+            "around": 3 * ns}
     assert got["attn_flash"] == pytest.approx(want)
     from benchmark.reducers import scopes
 

@@ -268,7 +268,7 @@ def _kernel_dots(jaxpr, inside=False):
 @pytest.mark.parametrize("head_dim", [64, 128])
 def test_flash_kernels_feed_the_mxu_bf16(head_dim):
     """The guard against the upcast coming back: with bf16 inputs no
-    product inside the three kernels has an f32 operand (head_dim 64 folds
+    product inside the two kernels has an f32 operand (head_dim 64 folds
     its power-of-two scale into the operand, 128 scales the f32 scores),
     and every product accumulates in f32."""
     x = jnp.zeros((1, 64, 2, head_dim), jnp.bfloat16)
@@ -279,9 +279,10 @@ def test_flash_kernels_feed_the_mxu_bf16(head_dim):
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
     dots = _kernel_dots(jaxpr.jaxpr)
-    # fwd 2, dq 3, dkv 4 products, in two loops each (off and on the
-    # diagonal), per head of the 128-lane column (two of width 64 share one)
-    assert len(dots) == 2 * (2 + 3 + 4) * (128 // head_dim)
+    # fwd 2 and bwd 5 products, in two loops each (off and on the
+    # diagonal), and the backward's k^T once a k-block, per head of the
+    # 128-lane column (two of width 64 share one)
+    assert len(dots) == (2 * (2 + 5) + 1) * (128 // head_dim)
     assert set(dots) == {(jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16))}
     # and f32 inputs stay f32 products (what the CPU tests feed)
     x32 = x.astype(jnp.float32)
@@ -366,3 +367,84 @@ def test_heads_on_lanes_equal_heads_major(mode, monkeypatch):
     for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), folded, major):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+# ---- the backward: one kernel yields dq, dk and dv -------------------------
+
+def _dense_with_lse(q, k, v, mode):
+    """Dense attention and its per-row logsumexp in f32 under any of the
+    kernels' three masks, and which rows have a key at all."""
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    pos = jnp.arange(t)
+    keep = {"none": jnp.ones((t, t), bool),
+            "causal": pos[None, :] <= pos[:, None],
+            "causal_exclusive": pos[None, :] < pos[:, None]}[mode]
+    s = jnp.where(keep[None, None], s, -1e30)
+    live = keep.any(-1)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)               # (B, H, T)
+    p = jnp.exp(s - lse[..., None]) * live[None, None, :, None]
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return out, lse.reshape(-1, t), jnp.tile(live, q.shape[0] * q.shape[2])
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+@pytest.mark.parametrize("blocks", [(128, 256), (256, 128)])
+@pytest.mark.parametrize("mode", ["none", "causal", "causal_exclusive"])
+@pytest.mark.parametrize("heads,head_dim", [
+    (4, 64),        # two heads a 128-lane column
+    (2, 128),       # one head a column
+    (2, 96),        # heads-major
+])
+def test_fused_backward_matches_dense_vjp(heads, head_dim, mode, blocks,
+                                          with_lse):
+    """dq, dk and dv of the one backward kernel against autodiff of dense
+    attention, with and without a cotangent on the lse output (it rides
+    the kernel as a shift of delta), over several q- and k-blocks a
+    column, the blocks rectangular both ways."""
+    rng = np.random.default_rng(head_dim + len(mode) + blocks[0])
+    b, t = 1, 512
+    q, k, v, w = (jnp.asarray(rng.standard_normal((b, t, heads, head_dim)),
+                              jnp.float32) for _ in range(4))
+    w_lse = jnp.asarray(rng.standard_normal((b * heads, t)), jnp.float32)
+    bq, bk = blocks
+    live = _dense_with_lse(q, k, v, mode)[2].reshape(b * heads, t)
+
+    def loss(fn):
+        def f(q_, k_, v_):
+            out, lse = fn(q_, k_, v_)
+            total = jnp.sum(out * w)
+            if with_lse:
+                total += jnp.sum(jnp.where(live, lse * w_lse, 0.0))
+            return total
+        return f
+
+    got = jax.grad(loss(lambda *a: pk.flash_attention_with_lse(
+        *a, True, bq, bk, True, mask_mode=mode)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: _dense_with_lse(*a, mode)[:2]),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, a, bb in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
+                                   rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+def test_flash_grad_is_two_pallas_calls():
+    """The gradient of a flash call runs two kernels: the forward, and one
+    backward that yields dq, dk and dv."""
+    x = jnp.zeros((1, 256, 4, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, 128, 128, True).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+
+    def names(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += names(sub)
+        return found
+
+    assert sorted(names(jaxpr.jaxpr)) == ["flash_bwd", "flash_fwd"]

@@ -2,12 +2,15 @@
 ``parallel.sequence.AUTO_FLASH_MIN_SEQ`` and ``ops.pallas_kernels.FLASH_BLOCKS``
 come from.
 
-    chiprun --chips 1 -- python tools/flash_block_sweep.py
+    python tools/flash_block_sweep.py [--shapes A,B]     # on a TPU host
 
-For each shape it times forward + backward (grad of a sum over q, k, v;
-causal; bf16) of: the XLA dense path (``attention_reference``, what ``auto``
-picked before), this repo's kernels at every ``block_q x block_k`` of
-``BLOCKS``, and, as a yardstick that is not shipped, JAX's own
+For each shape (every one of ``SHAPES``, or the labels ``--shapes`` names)
+it times forward + backward (grad of a sum over q, k, v; causal; bf16) of:
+the XLA dense path (``attention_reference``, what ``auto`` picked before),
+this repo's kernels at every ``block_q x block_k`` of ``BLOCKS`` (and, for
+each, the backward alone: ``flash_<bq>x<bk>_bwd_ms``, the one backward
+kernel with the ``delta`` reduce and the layout around it, from the
+forward's residuals), and, as a yardstick that is not shipped, JAX's own
 ``jax.experimental.pallas.ops.tpu.flash_attention`` at its default tiling and
 at 512s.  Ours is timed from and to the model's ``(B, T, H, D)`` layout; the
 library kernel is handed its own ``(B, H, T, D)`` layout.  ``flash_hm_*`` is
@@ -19,14 +22,18 @@ The shapes: the training cells' (B 4, T 1024, H 16 x 64), ``starcoder2-3b``'s he
 half-filled lanes cost), and T 512 / 2048 / 4096 at head_dim 64 (either side
 of the table's row, and whether the derived tiling holds at longer T).
 
-Artifact: ``FLASH_BLOCK_SWEEP.json`` (also under ``chiprun_out/``).  The
-platform is whatever JAX brings up and every row names it: on a CPU the
-kernels run in interpret mode, so the sweep records a skip note and one tiny
-mechanism row instead of meaningless emulation timings.
+Artifact: ``FLASH_BLOCK_SWEEP.json``, and a copy in the output directory
+beside it: the rows timed now, each with its capture time, in place of the
+same shapes' rows of the record there, whose other rows are kept where it
+was taken on the same platform.  The platform is whatever JAX brings up and
+every row names it: on a CPU the kernels run in interpret mode, so the sweep
+records a skip note and one tiny mechanism row instead of meaningless
+emulation timings.
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -65,6 +72,30 @@ def time_grad(fn, args, reps):
     t0 = time.perf_counter()
     for _ in range(reps):
         outs = g(*args)
+    jax.block_until_ready(outs)
+    return round((time.perf_counter() - t0) / reps * 1e3, 4)
+
+
+def time_backward(qkv, bq, bk, reps):
+    """The backward alone at one tiling: the kernel and what XLA does
+    around it, from the forward's residuals and a cotangent of ones."""
+    import jax
+    import jax.numpy as jnp
+
+    from neural_networks_parallel_training_with_mpi_tpu.ops import (
+        pallas_kernels as pk,
+    )
+
+    q, k, v = qkv
+    out, lse = pk._flash_forward(q, k, v, True, bq, bk, None)
+    g = jnp.ones_like(out)
+    bwd = jax.jit(lambda *a: pk._flash_backward(*a, True, bq, bk, None))
+    args = (q, k, v, out, lse, g)
+    jax.block_until_ready(bwd(*args))         # compile
+    jax.block_until_ready(bwd(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        outs = bwd(*args)
     jax.block_until_ready(outs)
     return round((time.perf_counter() - t0) / reps * 1e3, 4)
 
@@ -128,7 +159,27 @@ def heads_major_rows(qkv, reps):
     return out
 
 
-def main() -> int:
+def merged(path, doc):
+    """``doc`` with the rows of the record at ``path`` that it did not
+    re-time, where that record was taken on the same platform."""
+    try:
+        with open(path) as f:
+            old = json.load(f)
+    except (OSError, ValueError):
+        return doc
+    if old.get("platform") != doc["platform"]:
+        return doc
+    timed = {row["shape"] for row in doc["rows"]}
+    kept = [row for row in old.get("rows", []) if row["shape"] not in timed]
+    return {**doc, "rows": kept + doc["rows"]}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="",
+                    help="comma-separated labels of SHAPES to time "
+                         "(default: all)")
+    args = ap.parse_args(argv)
     plat.select("auto", log=lambda m: print(m, file=sys.stderr))
     plat.compile_cache()
 
@@ -157,6 +208,14 @@ def main() -> int:
     on_chip = platform != "cpu"
     cd = jnp.bfloat16 if on_chip else jnp.float32
     shapes = SHAPES if on_chip else [("t128_h2_d32_cpu_mech", 1, 128, 2, 32)]
+    if args.shapes:
+        wanted = args.shapes.split(",")
+        unknown = set(wanted) - {label for label, *_ in shapes}
+        if unknown:
+            print(f"flash_block_sweep: no shape {sorted(unknown)}",
+                  file=sys.stderr)
+            return 2
+        shapes = [s for s in shapes if s[0] in wanted]
     blocks = BLOCKS if on_chip else [(64, 64), (128, 128)]
     if not on_chip:
         doc["skipped"] = ("cpu: pallas interpret-mode timings "
@@ -178,7 +237,8 @@ def main() -> int:
                                                causal=True)
                            .astype(jnp.float32))
 
-        row = {"shape": label, "batch": b, "seq": seq, "heads": h,
+        row = {"shape": label, "captured_iso": doc["captured_iso"],
+               "batch": b, "seq": seq, "heads": h,
                "head_dim": dh, "dtype": jnp.dtype(cd).name,
                "derived_block": flash_blocks(seq, dh, cd),
                "dense_ms": time_grad(dense_loss, qkv, reps)}
@@ -200,6 +260,8 @@ def main() -> int:
                 row[f"flash_{bq}x{bk}_error"] = str(e)[:200]
                 continue
             row[f"flash_{bq}x{bk}_ms"] = ms
+            row[f"flash_{bq}x{bk}_bwd_ms"] = time_backward(qkv, bq, bk,
+                                                           reps)
             if best[1] is None or ms < best[1]:
                 best = ((bq, bk), ms)
         if on_chip:
@@ -211,9 +273,10 @@ def main() -> int:
                                                3)
         print(f"[flash_sweep] {json.dumps(row)}", flush=True)
         doc["rows"].append(row)
+        record = merged(outs[0], doc)
         for path in outs:           # flush per shape: a mid-run failure
             with open(path, "w") as f:      # keeps completed rows
-                json.dump(doc, f, indent=2)
+                json.dump(record, f, indent=2)
 
     print(json.dumps({"metric": "flash_block_sweep_rows",
                       "value": len(doc["rows"]), "unit": "rows",

@@ -28,8 +28,8 @@ sys.path.insert(0, str(ROOT))
 # path components: what implements the attention, and the Pallas kernels'
 # own names (``pl.pallas_call(name=...)``) under it
 _IMPL = re.compile(r"(?:^|[/(])(attn_\w+?)(?=[)/]|$)")
-_KERNEL = re.compile(r"(?:^|[/(])(flash_fwd|flash_bwd_dq|flash_bwd_dkv|"
-                     r"paged_attention)(?=[)/]|$)")
+_KERNEL = re.compile(r"(?:^|[/(])(flash_fwd|flash_bwd|paged_attention)"
+                     r"(?=[)/]|$)")
 
 
 class _Trace:
