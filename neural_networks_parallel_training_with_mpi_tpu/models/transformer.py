@@ -55,6 +55,11 @@ def scaled(x: jax.Array, multiplier: float) -> jax.Array:
     return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
 
 
+def _whole(module, params, u: jax.Array):
+    """``module`` over a whole sequence from position 0: its ``apply``."""
+    return module.apply(params, u)
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
@@ -664,32 +669,9 @@ class Transformer(Module):
                                 matmul_dtype=self._mm("ff_out"),
                                 q_role="ff_out")
 
-    def scaled_qkv(self, mods, params, h: jax.Array, **qkw):
-        """The fused qkv projection of ``attention_in_multiplier * h``, split,
-        the keys times ``key_multiplier`` (before any norm or rotation);
-        shared by the training block and the paged server."""
-        c = self.cfg
-        qkv = mods["qkv"].apply(params["qkv"],
-                                scaled(h, c.attention_in_multiplier), **qkw)
-        q, k, v = split_qkv(c, qkv)
-        return q, scaled(k, c.key_multiplier), v
-
-    def mixer_half(self, mods, params, h: jax.Array, run):
-        """``ssm_out_multiplier * Mixer(ssm_in_multiplier * h)`` under scope
-        ``ssm``; ``run(mixer, its params, its input)`` picks the form (a
-        sequence, a prefill chunk, a decode tick) and returns (output, new
-        state or None).  Shared by the training block and the paged
-        server."""
-        c = self.cfg
-        with jax.named_scope("ssm"):
-            y, state = run(mods["ssm"], params["ssm"],
-                           scaled(h, c.ssm_in_multiplier))
-            return scaled(y, c.ssm_out_multiplier), state
-
-    def qk_normed(self, mods, params, q: jax.Array, k: jax.Array):
+    def _qk_normed(self, mods, params, q: jax.Array, k: jax.Array):
         """The norm of q and k (``qk_norm``: each head, or under
-        ``qk_norm_whole`` the whole projection), before any rotation; shared
-        by the training block and the paged server."""
+        ``qk_norm_whole`` the whole projection), before any rotation."""
         with jax.named_scope("qk_norm"):
             if not self.cfg.qk_norm_whole:
                 return (mods["q_norm"].apply(params["q_norm"], q),
@@ -698,31 +680,19 @@ class Transformer(Module):
                 params[n], x.reshape(*x.shape[:-2], -1)).reshape(x.shape)
             return whole("q_norm", q), whole("k_norm", k)
 
-    def norm_in(self, mods, params, name: str, x: jax.Array) -> jax.Array:
+    def _norm_in(self, mods, params, name: str, x: jax.Array) -> jax.Array:
         """A half's input: ``norm(x)`` (pre-norm), or ``x`` itself under
-        ``post_norm``; shared by the training block and the paged server."""
+        ``post_norm``."""
         if self.cfg.post_norm:
             return x
         return mods[name].apply(params[name], x)
 
-    def norm_out(self, mods, params, name: str, y: jax.Array) -> jax.Array:
+    def _norm_out(self, mods, params, name: str, y: jax.Array) -> jax.Array:
         """A half's output before the residual add: ``y`` itself
         (pre-norm), or ``norm(y)`` under ``post_norm``."""
         if not self.cfg.post_norm:
             return y
         return mods[name].apply(params[name], y)
-
-    def delta_half(self, mods, params, x: jax.Array, run):
-        """A 'D' layer's first half under scope ``gdn``: ``x +
-        norm(GDN(x))`` (or ``x + GDN(norm(x))`` pre-norm); ``run(mixer, its
-        params, its input)`` picks the form (a sequence, a prefill chunk, a
-        decode tick) and returns (output, new state or None).  Shared by the
-        training block and the paged server."""
-        with jax.named_scope("gdn"):
-            y, state = run(mods["gdn"], params["gdn"],
-                           self.norm_in(mods, params, "ln1", x))
-            return (x + self.norm_out(mods, params, "ln1", y).astype(x.dtype),
-                    state)
 
     def quant_roles(self):
         """fp8 delayed-scaling roles (ops.qmm): one activation amax
@@ -787,13 +757,35 @@ class Transformer(Module):
         return out
 
     def _block(self, params, x: jax.Array, qscales=None, collect=False,
-               layer: int = 0):
-        """Layer ``layer``'s pre-LN block: (params, x) -> (x, aux, qobs);
-        aux is the MoE
-        load-balance loss for this block (0.0 for a dense FFN), qobs the
-        fp8 calibration observations ({role: amax} when ``collect``, {}
+               layer: int = 0, *, attend=None, attend_latent=_whole,
+               run=_whole, live=None):
+        """Layer ``layer``'s block: (params, x) -> (x, aux, qobs); aux is
+        the MoE load-balance loss for this block (0.0 for a dense FFN), qobs
+        the fp8 calibration observations ({role: amax} when ``collect``, {}
         otherwise — ops.qmm delayed scaling; ``qscales`` is the delayed
-        amax each Linear reads)."""
+        amax each Linear reads).
+
+        The one statement of a block's halves, for a whole sequence and for
+        the paged server's prefill chunks and decode ticks alike.  A caller
+        passes only what reads or writes a cache; the defaults are a whole
+        causal sequence from position 0:
+
+        * ``attend(q, k, v, layer)``: the per-head attention core, from the
+          normed, projected queries (B, W, H, hd) and grouped keys and
+          values (B, W, KV, hd) to the attended values (B, W, H, hd)
+          (default :meth:`_attend`);
+        * ``attend_latent(attn, params, h)``: latent attention from its
+          normed input through its output projection (default
+          ``attn.apply``);
+        * ``run(mixer, params, u)``: the form a mixer beside the attention
+          or a 'D' layer's Gated DeltaNet runs in (default ``apply``, from
+          a zero state);
+        * ``live()``: under routing without drops, the (B, W) mask of the
+          tokens that are routed, taken inside the ``ffn`` scope; aux is
+          then the held experts' load (count,) int32.
+
+        A cache's core writes the cache itself: the block sees
+        activations only."""
         c = self.cfg
         mods = self._block_modules(layer)
         qobs = {} if collect else None
@@ -802,65 +794,87 @@ class Transformer(Module):
         # the named scopes are what the device trace is read by
         # (benchmark/reducers/scopes.py); each norm and residual add sits
         # in the scope of the matrix product it feeds or follows
-        if c.attention_kind == "mla":
-            x = self._latent_attention(mods, params, x)
-            return self._ffn_half(mods, params, x, qkw, qobs)
         if c.layer_is_delta(layer):
-            x, _ = self.delta_half(
-                mods, params, x, lambda mixer, p, u: (mixer.apply(p, u), None))
-            return self._ffn_half(mods, params, x, qkw, qobs)
+            # ``x + norm(GDN(x))`` (or ``x + GDN(norm(x))`` pre-norm)
+            with jax.named_scope("gdn"):
+                y = run(mods["gdn"], params["gdn"],
+                        self._norm_in(mods, params, "ln1", x))
+                x = x + self._norm_out(mods, params, "ln1",
+                                       y).astype(x.dtype)
+            return self._ffn_half(mods, params, x, qkw, qobs, live)
+        if c.attention_kind == "mla":
+            x = self._latent_attention(mods, params, x, attend_latent)
+            return self._ffn_half(mods, params, x, qkw, qobs, live)
         with jax.named_scope("attn_proj"):
-            h = self.norm_in(mods, params, "ln1", x)
-            q, k, v = self.scaled_qkv(mods, params, h, **qkw)
+            h = self._norm_in(mods, params, "ln1", x)
+            # the keys times ``key_multiplier`` before any norm or rotation
+            q, k, v = split_qkv(c, mods["qkv"].apply(
+                params["qkv"], scaled(h, c.attention_in_multiplier), **qkw))
+            k = scaled(k, c.key_multiplier)
             if c.qk_norm:
-                q, k = self.qk_normed(mods, params, q, k)
-            # GQA training path: repeat K/V to full query heads so every
-            # attention impl (dense/flash/ring/...) sees plain MHA — same
-            # math as grouped attention; the bandwidth win is decode-side
-            # (models.generate caches the UN-repeated kv_heads)
+                q, k = self._qk_normed(mods, params, q, k)
+        out = (attend or self._attend)(q, k, v, layer)
+        with jax.named_scope("attn_proj"):
+            out = out.reshape(*out.shape[:2], c.q_dim)
+            x = x + self._norm_out(mods, params, "ln1", scaled(
+                mods["attn_out"].apply(params["attn_out"], out, **qkw),
+                c.attention_out_multiplier))
+        if c.has_mixer:
+            # beside the attention, from the same normed input, added
+            with jax.named_scope("ssm"):
+                y = scaled(run(mods["ssm"], params["ssm"],
+                               scaled(h, c.ssm_in_multiplier)),
+                           c.ssm_out_multiplier)
+            x = x + y
+        return self._ffn_half(mods, params, x, qkw, qobs, live)
+
+    def _attend(self, q, k, v, layer: int) -> jax.Array:
+        """The attention core over a whole causal sequence, rotated and
+        windowed as layer ``layer`` is.  K/V are repeated to full query
+        heads so that every attention impl (dense/flash/ring/...) sees plain
+        MHA — same math as grouped attention; the bandwidth win is
+        decode-side, where the caches hold the un-repeated kv_heads."""
+        c = self.cfg
+        with jax.named_scope("attn_proj"):
             k, v = repeat_kv(c, k), repeat_kv(c, v)
         rot = c.layer_rotary(layer)
         with jax.named_scope("attention"):
-            out = sequence_sharded_attention(
+            return sequence_sharded_attention(
                 c.attention, q, k, v,
                 axis=c.seq_axis, causal=True, block_q=c.flash_block_q,
                 block_k=c.flash_block_k,
                 rope_theta=None if rot is None else rot.theta,
                 rope_scaling=None if rot is None else rot.scaling,
                 window=c.layer_window(layer))
-        with jax.named_scope("attn_proj"):
-            out = out.reshape(*out.shape[:2], c.q_dim)
-            x = x + self.norm_out(mods, params, "ln1", scaled(
-                mods["attn_out"].apply(params["attn_out"], out, **qkw),
-                c.attention_out_multiplier))
-        if c.has_mixer:
-            # beside the attention, from the same normed input, added
-            x = x + self.mixer_half(
-                mods, params, h,
-                lambda mixer, p, u: (mixer.apply(p, u), None))[0]
-        return self._ffn_half(mods, params, x, qkw, qobs)
 
-    def _ffn_half(self, mods, params, x: jax.Array, qkw, qobs):
+    def _ffn_half(self, mods, params, x: jax.Array, qkw, qobs, live=None):
         """``x + FFN(norm(x))``, the block's second half, whatever the
         first was: the two-matrix or gated feed-forward, the capacity
-        layer, or routing without drops."""
-        c = self.cfg
+        layer, or routing without drops (``live``: see :meth:`_block`)."""
         with jax.named_scope("ffn"):
-            h = self.norm_in(mods, params, "ln2", x)
-            if "moe" in mods:
-                ff, aux = mods["moe"].apply(params["moe"], h)
-            else:
+            h = self._norm_in(mods, params, "ln2", x)
+            if "moe" not in mods:
                 ff = self._ffn(mods, params, h, **qkw)
                 aux = jnp.zeros((), jnp.float32)
-            return (x + self.norm_out(mods, params, "ln2", ff).astype(x.dtype),
+            elif live is not None:
+                # pad columns and idle lanes reach no expert (and read
+                # none): the load counts what the traffic asked for
+                ff, _, aux = mods["moe"].apply(
+                    params["moe"], h, mask=live(), return_load=True)
+            else:
+                ff, aux = mods["moe"].apply(params["moe"], h)
+            return (x + self._norm_out(mods, params, "ln2",
+                                       ff).astype(x.dtype),
                     aux, (qobs or {}))
 
-    def _latent_attention(self, mods, params, x: jax.Array) -> jax.Array:
-        """``x + LatentAttn(norm(x))`` over a whole causal sequence from
-        position 0, in the expanded form (the training forward)."""
+    def _latent_attention(self, mods, params, x: jax.Array,
+                          attend=_whole) -> jax.Array:
+        """``x + LatentAttn(norm(x))``; ``attend`` as ``attend_latent`` of
+        :meth:`_block` (by default the whole causal sequence from position
+        0, in the expanded form)."""
         with jax.named_scope("attn_proj"):
             h = mods["ln1"].apply(params["ln1"], x)
-        out = mods["attn"].apply(params["attn"], h)
+        out = attend(mods["attn"], params["attn"], h)
         with jax.named_scope("attn_proj"):
             return x + out.astype(x.dtype)
 

@@ -1,4 +1,4 @@
-"""Paged KV cache: block-allocated pools + static-shape gathered attention.
+"""Paged KV cache: block-allocated pools, and the attention cores over them.
 
 The dense slot server (``models/serve.py``) reserves ``max_len`` cache
 positions per slot the moment a stream is admitted, so device memory is
@@ -73,6 +73,12 @@ blocks:
   block_size)`` — one position per row at decode, a chunk of positions
   at prefill (chunks may straddle block boundaries; each position
   resolves its own block).
+* **The block is the model's**: every layer runs ``Transformer._block``,
+  which holds the norms, projections, multipliers, residuals and the
+  feed-forward half; the programs pass it only what touches a cache: the
+  attention core of the layer's cache row (the rotation, the writes and
+  the dispatch above), the chunk or one-tick form of a recurrence (which
+  carries the stream's state row), and which tokens the experts route.
 
 Invariant the step relies on (mirrors the dense server's "dead lanes
 cost FLOPs, not recompiles" contract): every slot flows through the
@@ -153,7 +159,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import _quantize_kv, _sample
-from ..models.transformer import Transformer, scaled
+from ..models.transformer import Transformer
 from ..ops.pallas_kernels import paged_attention, paged_tiles
 from ..train import trace as trace_lib
 from ..utils import compile_ledger as ledger_lib
@@ -745,28 +751,20 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
         off = jnp.where(valid[None, :], positions % bs, 0)
         return blk, off
 
-    def dense_attention_half(mods, layer_params, pool, tables, starts, x,
-                             valid, lengths, layer=0):
-        """``x + Attn(LN(x))`` (and the normed input, for a mixer beside
-        it) with per-head K and V rows: the fused qkv
-        projection, K/V scattered into the pools, attention gathered or
-        fused.  Mirrors ``models.generate._block_chunk`` (the pinned dense
-        math) with the cache axis split into (block, offset).  ``layer``
-        says which kind of layer this is where a model has several: whether
-        q and k are rotated, and a window layer's lower bound (``tables``
-        is then the window kind's)."""
+    def per_head_core(q, k, v, layer, pool, tables, starts, valid, lengths,
+                      dtype):
+        """``attend`` of ``Transformer._block`` over per-head K and V rows:
+        q and k rotated at the streams' positions, K/V scattered into the
+        pools, attention gathered or fused.  ``layer`` says which kind of
+        layer this is where a model has several: whether q and k are
+        rotated, and a window layer's lower bound (``tables`` is then the
+        window kind's).  Returns (the attended values in ``dtype``, the new
+        pool)."""
         quant = "k_scale" in pool
         window = windows[layer]
-        # named scopes as in ``Transformer._block`` (the device trace is
-        # read by them): ``attention`` is the work, the inner scopes say
-        # what implements it here
-        with jax.named_scope("attn_proj"):
-            h = model.norm_in(mods, layer_params, "ln1", x)
-            # q: (B,W,H,hd); k/v: (B,W,KV,hd)
-            q, k, v = model.scaled_qkv(mods, layer_params, h)
-            b, w = q.shape[:2]
-            if c.qk_norm:
-                q, k = model.qk_normed(mods, layer_params, q, k)
+        b, w = q.shape[:2]
+        # ``attention`` is the work, the inner scopes say what implements
+        # it here
         with jax.named_scope("attention"):
             positions = starts[:, None] + jnp.arange(w)[None, :]  # (B, W)
             rot = c.layer_rotary(layer)
@@ -805,40 +803,34 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                         k_scale=new_ksp if quant else None,
                         v_scale=new_vsp if quant else None,
                         pages=pages, tile_cols=cols,
-                        window=window).astype(x.dtype)
+                        window=window).astype(dtype)
             else:
                 ledger_lib.note("attention", {"impl": "gathered",
                                               "keys": t_cap})
                 out = gathered_attention(
                     q, new_kp, new_vp, tables, positions,
                     new_ksp if quant else None,
-                    new_vsp if quant else None, window).astype(x.dtype)
-        with jax.named_scope("attn_proj"):
-            out = out.reshape(b, w, c.q_dim)
-            x = x + model.norm_out(mods, layer_params, "ln1", scaled(
-                mods["attn_out"].apply(layer_params["attn_out"], out),
-                c.attention_out_multiplier))
+                    new_vsp if quant else None, window).astype(dtype)
         new_pool = {"k": new_kp, "v": new_vp}
         if quant:
             new_pool.update(k_scale=new_ksp, v_scale=new_vsp)
-        return x, new_pool, h
+        return out, new_pool
 
-    def latent_attention_half(mods, layer_params, pool, tables, starts, x,
-                              valid, lengths, decode):
-        """``x + LatentAttn(norm(x))`` with the latent row: the chunk's rows
-        ``[c_kv | k_rope]`` (normed, rotated) scattered into the one pool and
-        attended in the expanded form (prefill: the stream's rows gathered
-        through its table and expanded through ``W_kvb``; ``lengths`` bounds
-        the keys a chunk walks) or the absorbed form (decode: the cache is
-        never expanded).  Under ``fused`` the decode reads the pool in place:
-        the paged kernel's shared-row mode walks each stream's pages up to
-        its length between the two ``mla_absorb`` products, over a pool whose
-        rows are stored padded to whole lane tiles (zero lanes, which a
-        zero-padded query adds nothing for); nothing of width ``T_cap`` is
-        gathered or reduced over."""
-        attn, ap = mods["attn"], layer_params["attn"]
-        b, w, _ = x.shape
-        positions = starts[:, None] + jnp.arange(w)[None, :]      # (B, W)
+    def latent_core(attn, ap, h, positions, pool, tables, starts, valid,
+                    lengths, decode):
+        """``attend_latent`` of ``Transformer._block`` over the latent row:
+        the chunk's rows ``[c_kv | k_rope]`` (normed, rotated) scattered
+        into the one pool and attended in the expanded form (prefill: the
+        stream's rows gathered through its table and expanded through
+        ``W_kvb``; ``lengths`` bounds the keys a chunk walks) or the absorbed
+        form (decode: the cache is never expanded).  Under ``fused`` the
+        decode reads the pool in place: the paged kernel's shared-row mode
+        walks each stream's pages up to its length between the two
+        ``mla_absorb`` products, over a pool whose rows are stored padded to
+        whole lane tiles (zero lanes, which a zero-padded query adds nothing
+        for); nothing of width ``T_cap`` is gathered or reduced over.
+        Returns (the output projection, the new pool)."""
+        b, w, _ = h.shape
         lanes = pool["latent"].shape[-1]        # as stored
         extra = lanes - attn.row_dim            # zero lanes of a stored row
 
@@ -848,7 +840,6 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 if extra else a
 
         with jax.named_scope("attn_proj"):
-            h = mods["ln1"].apply(layer_params["ln1"], x)
             q_nope, q_rope, rows = attn.project(ap, h, positions)
         with jax.named_scope("attention"):
             with jax.named_scope("paged_scatter"):
@@ -892,76 +883,64 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                                                    mask,
                                                    n_keys=lengths.max())
         with jax.named_scope("attn_proj"):
-            x = x + attn.output(ap, out).astype(x.dtype)
-        return x, {"latent": new_lp}
+            return attn.output(ap, out), {"latent": new_lp}
 
     def block_fwd(layer_params, pool, tables, starts, x, valid, lengths,
                   decode, layer=0, state=None):
         """Transformer block ``layer`` over a chunk ``x`` (B, W, D) whose rows
-        sit at per-row start positions: the attention half of the model's
-        kind writes the chunk's cache rows into the paged pool and reads
-        the streams' rows back through the block tables, then the
-        feed-forward half.  ``valid`` (W,) masks pad columns of a bucketed
-        prefill chunk: their writes divert to the sink block.  ``lengths``
-        (B,) is each row's attendable-key count (0 = inactive lane), traced
-        like the tables so length churn never recompiles.  ``decode``
-        (static) picks latent attention's absorbed form.  Returns (x, the
-        new pool, the held experts' load (count,) int32 under routing
-        without drops, else None, the new state rows).  Where the model
-        has window layers ``tables`` is the pair (full kind's, window
-        kind's), and the layer takes its own.  Where it has a mixer beside
-        the attention, ``state`` holds this layer's state rows of the
-        chunk's B streams: the mixer reads the attention's normed input,
-        its output is added to the same residual, and it writes to the
-        second store what the attention writes to its pages: a chunk carries
-        the state over its true columns, a tick over its decoding lanes
-        (``lengths > 0``), everything else holds.  A delta-rule layer ('D')
-        has no attention and no pool (``pool`` None): its Gated DeltaNet
-        mixes the tokens in the attention's place and carries ``state`` the
-        same way."""
-        mods = model._block_modules(layer)
+        sit at per-row start positions: ``Transformer._block`` with this
+        layer's cache cores, which write the chunk's rows into the paged
+        pool (``pool``; None for a 'D' layer, which has no attention) and
+        its recurrent state into ``state`` (this layer's rows of the chunk's
+        B streams), and read the streams' rows back.  ``valid`` (W,) masks
+        pad columns of a bucketed prefill chunk: their writes divert to the
+        sink block, and their state is not carried.  ``lengths`` (B,) is
+        each row's attendable-key count (0 = an idle lane, whose state
+        holds), traced like the tables so length churn never recompiles.
+        ``decode`` (static) picks the forms of a tick: latent attention's
+        absorbed form, the recurrences' one step.  Where the model has
+        window layers ``tables`` is the pair (full kind's, window kind's),
+        and the layer takes its own.  Returns (x, the new pool, the block's
+        aux: the held experts' load (count,) int32 under routing without
+        drops, the new state rows)."""
         if two_kinds:
             tables = tables[1 if windows[layer] else 0]
-        if recurrent:
-            if decode:
-                # the delta rule's tick takes its kernel on the fused path
-                fused = ({"fused": attn_impl == "fused"}
-                         if c.layer_is_delta(layer) else {})
-                run = lambda mixer, p, u: mixer.apply_step(     # noqa: E731
-                    p, u, state, lengths > 0, **fused)
-            else:
-                run = lambda mixer, p, u: mixer.apply_chunk(    # noqa: E731
-                    p, u, state, valid)
-        if c.layer_is_delta(layer):
-            new_pool = None
-            x, state = model.delta_half(mods, layer_params, x, run)
-        elif latent:
-            x, new_pool = latent_attention_half(
-                mods, layer_params, pool, tables, starts, x, valid, lengths,
-                decode)
-        else:
-            x, new_pool, h = dense_attention_half(
-                mods, layer_params, pool, tables, starts, x, valid, lengths,
-                layer)
-        if c.has_mixer:
-            mix, state = model.mixer_half(mods, layer_params, h, run)
-            x = x + mix
-        load = None
-        with jax.named_scope("ffn"):
-            h = model.norm_in(mods, layer_params, "ln2", x)
-            if "moe" not in mods:
-                ff = model._ffn(mods, layer_params, h)
-            elif c.moe_dropless:
-                # pad columns and idle lanes reach no expert (and read
-                # none): the load counts what the traffic asked for
-                live = valid[None, :] & (lengths > 0)[:, None]
-                ff, _, load = mods["moe"].apply(
-                    layer_params["moe"], h, mask=live, return_load=True)
-            else:
-                ff, _ = mods["moe"].apply(layer_params["moe"], h)
-            x = x + model.norm_out(mods, layer_params, "ln2",
-                                   ff).astype(x.dtype)
-        return x, new_pool, load, state
+        cache = (pool, tables, starts, valid, lengths)
+        wrote = {"pool": None, "state": state}      # by the cores, below
+
+        def attend(q, k, v, i):
+            out, wrote["pool"] = per_head_core(q, k, v, i, *cache, x.dtype)
+            return out
+
+        def run(mixer, p, u):
+            if not decode:
+                y, wrote["state"] = mixer.apply_chunk(p, u, state, valid)
+                return y
+            # the delta rule's tick takes its kernel on the fused path
+            fused = ({"fused": attn_impl == "fused"}
+                     if c.layer_is_delta(layer) else {})
+            y, wrote["state"] = mixer.apply_step(p, u, state, lengths > 0,
+                                                 **fused)
+            return y
+
+        kw = {}
+        if latent:
+            # (B, W), read by the latent core and taken before the block's
+            # first operation, where the latent programs' pinned text has
+            # them
+            positions = starts[:, None] + jnp.arange(x.shape[1])[None, :]
+
+            def attend_latent(attn, ap, h):
+                out, wrote["pool"] = latent_core(attn, ap, h, positions,
+                                                 *cache, decode)
+                return out
+
+            kw["attend_latent"] = attend_latent
+        if c.moe_dropless:
+            kw["live"] = lambda: valid[None, :] & (lengths > 0)[:, None]
+        x, load, _ = model._block(layer_params, x, layer=layer,
+                                  attend=attend, run=run, **kw)
+        return x, wrote["pool"], load, wrote["state"]
 
     def forward(params, pools, tables, starts, ids, valid, lengths,
                 decode, state=None):
@@ -993,20 +972,20 @@ def _paged_programs(model: Transformer, block_size: int, max_blocks: int,
                 new_pools.append(pool)
             if held is not None:
                 new_state.append(rows)
-            loads.append(load)
+            if c.layer_is_moe(i):
+                loads.append(load)
         # the last block's hidden state: each program applies the head to
         # the columns it reads
         return x, new_pools, loads, new_state
 
     def count_load(stats, loads, decode):
-        """Fold one program's expert loads (a (count,) int32 per layer)
-        into the cumulative counters the server carries on the device
-        (``EXPERT_COUNTERS``); ``stats`` is ``{}`` for a model without
-        routing without drops, and stays so."""
+        """Fold one program's expert loads (a (count,) int32 per layer with
+        experts) into the cumulative counters the server carries on the
+        device (``EXPERT_COUNTERS``); ``stats`` is ``{}`` for a model
+        without routing without drops, and stays so."""
         if "experts" not in stats:
             return stats
-        # (L, count); a leading dense layer of such a model has no load
-        load = jnp.stack([ld for ld in loads if ld is not None])
+        load = jnp.stack(loads)                         # (L, count)
         assigned, busiest = load.sum(), load.max(axis=1).sum()
         zero, one = jnp.zeros((), jnp.int32), jnp.ones((), jnp.int32)
         if decode:

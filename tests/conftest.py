@@ -205,6 +205,45 @@ def mesh1(devices):
     return make_mesh(MeshConfig(data=1), devices=devices[:1])
 
 
+@pytest.fixture(scope="session")
+def lowered_programs():
+    """``lower(net, **serve_config) -> {name: jax.stages.Lowered}``: the
+    programs a pin of lowered text or of named scopes reads.  ``train`` is
+    the value and gradient of the mean of ``net``'s logits over a (2, 32)
+    batch; ``decode`` is the tick of a ``PagedDecodeServer`` of
+    ``serve_config`` over all its slots (none active), ``prefill`` its chunk
+    of 8 columns, 5 of them true, at position 0 of slot 0.  Parameters are
+    ``net.init`` of key 0; only their shapes reach the text."""
+    import jax.numpy as jnp
+
+    from neural_networks_parallel_training_with_mpi_tpu.serve import (
+        PagedDecodeServer,
+    )
+    from neural_networks_parallel_training_with_mpi_tpu.utils import prng
+
+    def lower(net, **serve_config):
+        params = net.init(prng.init_key(0))
+        loss = lambda p, x: net.apply(p, x).astype(jnp.float32).mean()  # noqa: E731
+        srv = PagedDecodeServer(net, params, **serve_config)
+        # the second store and the chunk's slot in it: a model with
+        # recurrent state only
+        lead = (srv.params, srv.pools) + ((srv.state,) if srv.state else ())
+        slot = (jnp.asarray(0, jnp.int32),) if srv.state else ()
+        return {
+            "train": jax.jit(jax.value_and_grad(loss)).lower(
+                params, jax.ShapeDtypeStruct((2, 32), jnp.int32)),
+            "decode": srv._step_fn.lower(
+                *lead, srv.stats, srv.tokens,
+                srv._device_tables(slice(None), keep=srv.active), srv.pos,
+                jnp.asarray(srv.active), srv.key),
+            "prefill": srv._prefill_fn.lower(
+                *lead, srv.stats, srv._device_tables(slice(0, 1)), *slot,
+                jnp.zeros((1,), jnp.int32), jnp.zeros((1, 8), jnp.int32),
+                jnp.asarray(5, jnp.int32), jnp.asarray(True))}
+
+    return lower
+
+
 @pytest.fixture
 def staggered_batch():
     """``run(net, params, requests, callers, **serve_config)``: a closed loop

@@ -147,7 +147,7 @@ def test_the_per_head_norm(toy):
     q = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 8, 8)) * 3 + 1
     k = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 2, 8)) * 2 - 1
     mods = net._block_modules(1)
-    qn, kn = net.qk_normed(mods, blk, q, k)
+    qn, kn = net._qk_normed(mods, blk, q, k)
     assert close(qn, ref.rms_norm(q, p["q_norm.scale"], 1e-5))
     assert close(kn, ref.rms_norm(k, p["k_norm.scale"], 1e-5))
     # without it the block is another block

@@ -270,3 +270,37 @@ def test_the_counts(toy):
     want = 2 * full + 6 * linear + 8 * 2 * 3 * b * t * d * ff \
         + 2 * b * t * d * v
     assert net.fwd_flops((b, t)) == pytest.approx(want)
+
+
+# ---- the programs' lowered text --------------------------------------------
+
+# the toy's training step and paged programs as commit 61f07d9 lowered them
+# (StableHLO, no locations; the delta rule's tick kernel and the paged
+# attention kernel interpreted under ``fused``, so their bodies are in it),
+# before the paged programs ran the model's own block with the cache's cores
+# passed in: that move was to change no operation and no order of operations
+# (on the chip the programs' compile-cache keys then stay).  A change that
+# means to move them re-pins these.
+_TEXT_SHA256 = {
+    "train": "1b1d2a0c989615c9ad0631ea39bf4203164c27e79f6434fb9ca19c47401fbd47",
+    "decode": "653501948581e7e6d83f9c93df494d301f838422ac7c4fe768c87e6652431b4f",
+    "decode_fused":
+        "8a0f8364bbd0791598c2fc6df2b8a524a68c83a6616c08db56aa2fb053f1d22b",
+    "prefill": "61fc6828c2f0c2275d036a8616a66a7b0f2f79e4c37afd0b15fad8f0ab30f4b8",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered(toy, lowered_programs):
+    fused = lowered_programs(toy[0], **SERVE, attn_impl="fused")
+    return {**lowered_programs(toy[0], **SERVE),
+            "decode_fused": fused["decode"]}
+
+
+@pytest.mark.parametrize("program", sorted(_TEXT_SHA256))
+def test_the_programs_lower_to_the_parents_text(lowered, program):
+    import hashlib
+
+    text = lowered[program].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _TEXT_SHA256[program]

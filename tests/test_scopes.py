@@ -10,9 +10,13 @@ capture, and the compile ledger's steady-state call.
   ``nnpt:`` annotations, nested as the code nests them, with no
   ``Tracer`` installed;
 * with a ledger installed, a call whose signature is compiled makes no
-  call of ``_leaf_key``.
+  call of ``_leaf_key``;
+* the multiset of the operations' scope paths in the tiny train step and in
+  the paged programs of each family's toy is pinned.
 """
 
+import collections
+import hashlib
 import re
 
 import jax
@@ -63,7 +67,8 @@ def _has(names, scope: str) -> bool:
 
 # ---- (1) scopes in the compiled programs ------------------------------------
 
-def _train_names(devices, update_sharding):
+def _train_step(devices, update_sharding):
+    """The tiny data-parallel train step, lowered."""
     model = Transformer(TransformerConfig(
         vocab_size=V, max_seq_len=T, n_layers=2, d_model=16, n_heads=2,
         d_ff=32, ce_chunk=4))
@@ -87,7 +92,11 @@ def _train_names(devices, update_sharding):
                               update_plan=plan)
     rng = np.random.default_rng(0)
     batch = {k: rng.integers(0, V, (B, T)).astype(np.int32) for k in "xy"}
-    return _op_names(step.lower(state, batch).compile())
+    return step.lower(state, batch)
+
+
+def _train_names(devices, update_sharding):
+    return _op_names(_train_step(devices, update_sharding).compile())
 
 
 @pytest.fixture(scope="module")
@@ -125,15 +134,19 @@ def test_sharded_updates_name_exchange_and_update(devices, update_sharding):
     assert _has(names, "optimizer_update")
 
 
+def _paged_model(**kw):
+    return Transformer(TransformerConfig(
+        vocab_size=64, max_seq_len=64, n_layers=2, d_model=32, n_heads=4,
+        n_kv_heads=2, d_ff=64, pos_encoding="rope", **kw))
+
+
 @pytest.fixture(scope="module")
 def paged_names():
     from neural_networks_parallel_training_with_mpi_tpu.serve.paged_kv import (
         PagedDecodeServer,
     )
 
-    model = Transformer(TransformerConfig(
-        vocab_size=64, max_seq_len=64, n_layers=2, d_model=32, n_heads=4,
-        n_kv_heads=2, d_ff=64, pos_encoding="rope"))
+    model = _paged_model()
     srv = PagedDecodeServer(model, model.init(prng.init_key(0)), slots=2,
                             num_blocks=24, block_size=8)
     step = srv._step_fn.lower(
@@ -314,3 +327,119 @@ def test_failed_executable_is_not_retried(ledger):
         fn(x)
     assert fn._cache[key] is None and fn._expect == ((), {})
     assert float(fn(x)[0]) == 2.0 and len(ledger.events) == 1
+
+
+# ---- (4) where the operations' time is booked -------------------------------
+
+_NAME_LOC = re.compile(r'^loc\("([^"]*)"\(')
+
+
+def _scope_paths(lowered) -> str:
+    """The multiset of a lowered program's operations' name-stack paths
+    (scopes and primitive, no file or line; "" for an operation without
+    one): a ``count path`` line a path, sorted."""
+    paths = collections.Counter()
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    m = _NAME_LOC.match(str(inner.location))
+                    paths[m.group(1) if m else ""] += 1
+                    walk(inner)
+
+    walk(lowered.compiler_ir().operation)
+    return "".join(f"{n} {p}\n" for p, n in sorted(paths.items()))
+
+
+# the toys whose training step and paged programs are pinned: the model above
+# in bfloat16 (grouped K/V, one kind of layer; the casts a float32 toy lacks),
+# and each family's test toy (its module's MODEL): latent attention, window
+# and full layers with routing without drops, YaRN beside plain rotary with
+# routing without drops, a mixer beside the attention, and delta-rule layers
+_FAMILIES = {"dense_gqa": None, "latent": "mla_moe_model",
+             "window_moe": "gqa_window_moe_model",
+             "yarn_moe": "window_yarn_moe_model",
+             "mixer": "ssm_hybrid_model", "delta": "olmo_hybrid_model"}
+
+# the scope paths as commit 61f07d9 lowered them, before the paged programs
+# ran the model's own block with the cache's cores passed in: every
+# per-layer metric that reads device time by scope (the serving cells', and
+# the train step's ``attention_ms`` / ``fwd_ms`` / ``bwd_ms``) reads the same
+# operations under the same names as long as these hold.  A change that means
+# to move an operation to another scope re-pins what it moves.
+_SCOPES_SHA256 = {
+    "delta.decode":
+        "9c8d5728d2dbf3779257f3806c658e97ab84e479bfba92a895f501bc544f0fac",
+    "delta.prefill":
+        "ad2ea53602df83ad8d58834265eb7d0fc9dbcfa1fd6afa0e10dd88de0552340a",
+    "delta.train":
+        "7b9dd5858b89a34bf19cf75974943e406a8e2838dbaf0451b6d4421f34c6e780",
+    "dense_gqa.decode":
+        "4c76c697aec35ee26bbd7470e0ae657af07ac92038ed71214cbedfc5187060d4",
+    "dense_gqa.prefill":
+        "96cdcc70781edde3b989a02df723410a618f2e09c4dcb98487b0230a2aecdc2d",
+    "dense_gqa.train":
+        "666cc5a405b45a162aacfc2f4b7508a055e9453bb62afe0e4c598132ac08770d",
+    "latent.decode":
+        "8cb1ffe99472499521df6da327162f1b23f27304fce8dae6b41a57c50f1488a4",
+    "latent.prefill":
+        "2956dc1bfb7fbc1aa16499a8a4da136fb699bba5daf2364462ab3e29e1d19cdf",
+    "latent.train":
+        "435bde5b34630e8fc17c349faf17dcade2eea740496994a011f4a4884abffd14",
+    "mixer.decode":
+        "053e32d453f1c3ddaef6dbe6f8df5634f04fa512ad1f4358074a8847f86fc684",
+    "mixer.prefill":
+        "7d311f86a299f542338f16cdbea5a57017a7aae98d7867605ac555ed844fb3e2",
+    "mixer.train":
+        "efd9f54e66369f9fd4d6d8c65b655b8e6bcf9e79be5c76d437c09664bf9394cf",
+    "train":
+        "ae2cc0a3a813fd8d66ff8148d90e842e6a479a73e5c4e74bd75d24486f43bc14",
+    "window_moe.decode":
+        "a0555e681740303ab834038108c65ce55bf7f583da85d040b9bde996a6421ac0",
+    "window_moe.prefill":
+        "ec8fe0cfde37aa3218b520410a1a41f26493e0611fa18d47efe4da296293c03d",
+    "window_moe.train":
+        "c682f14be77f2244813b072cc349e238c83d43f5280ee0668aaf2ba9bb3981e3",
+    "yarn_moe.decode":
+        "19dc682a301ceeedf93f6f482fba7ec1d95c7d02dbc6223b802ac6058c586378",
+    "yarn_moe.prefill":
+        "7ab61f2d755ba3bcc6dd9d5a7314ee01ca5a56313486f5b2baa8ca014cd0ce2c",
+    "yarn_moe.train":
+        "d82495936721bc3aac644fca12345a82ed04e9315831d9453c73edcf85d1abc4",
+}
+
+
+@pytest.fixture(scope="module")
+def served(lowered_programs):
+    import importlib
+
+    got = {}
+
+    def programs(family):
+        if family not in got:
+            module = _FAMILIES[family]
+            if module is None:
+                got[family] = lowered_programs(
+                    _paged_model(compute_dtype=jnp.bfloat16), slots=2,
+                    num_blocks=24, block_size=8)
+            else:
+                model = importlib.import_module(f"tests.test_{module}").MODEL
+                got[family] = lowered_programs(
+                    model["family"].program_model(model), slots=2,
+                    num_blocks=48, block_size=4, max_len=64,
+                    prefill_chunk=8)
+        return got[family]
+
+    return programs
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPES_SHA256))
+def test_scope_paths_are_the_parents(program, devices, served):
+    if program == "train":
+        lowered = _train_step(devices, "replicated")
+    else:
+        family, name = program.split(".")
+        lowered = served(family)[name]
+    assert hashlib.sha256(_scope_paths(lowered).encode()).hexdigest() \
+        == _SCOPES_SHA256[program]

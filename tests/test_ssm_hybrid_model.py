@@ -265,3 +265,33 @@ def test_rows_landing_a_program_behind_serve_the_same_tokens(
     assert sched.ssm_counters == sched.server.ssm_counters
     assert sched.ssm_counters["ssm_prefill_tokens"] == 3 * sum(
         len(p) for p, _ in requests)
+
+
+# ---- the programs' lowered text --------------------------------------------
+
+# the toy's training step and paged programs as commit 61f07d9 lowered them
+# (StableHLO, no locations), before the paged programs ran the model's own
+# block with the cache's cores passed in: that move was to change no
+# operation and no order of operations (on the chip the programs'
+# compile-cache keys then stay).  A change that means to move them re-pins
+# these.
+_TEXT_SHA256 = {
+    "train": "54c1bcb13bb3ebe9b5492c443612539e4cde84e74bfa09c581f2540539082546",
+    "decode": "52ae5994494f882ebf805c0eeb6665189566bcbc2c12114f388098305c1cccef",
+    "prefill": "7425316cbbe8992c64d55ae43b13585e6b8b3a4adcd1d7dcda195f0005e9b62f",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered(toy, lowered_programs):
+    return lowered_programs(toy[0], slots=2, num_blocks=48, block_size=4,
+                            max_len=80, prefill_chunk=8)
+
+
+@pytest.mark.parametrize("program", sorted(_TEXT_SHA256))
+def test_the_programs_lower_to_the_parents_text(lowered, program):
+    import hashlib
+
+    text = lowered[program].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _TEXT_SHA256[program]

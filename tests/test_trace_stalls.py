@@ -90,13 +90,26 @@ def _cyclic_heap(pairs=150_000):
 # a bare loop: one planted stall of each kind
 # ---------------------------------------------------------------------------
 # What the process really did (slept, spun, collected) is read from the OS, so
-# those three run on the real clock, with a floor well above a loaded host's
-# jitter, and look their record up by its lap.  Where only the times matter
-# the clock is a fake one, and the loop is exact.
+# those three look their record up by its lap and really sleep, spin or
+# collect.  A sleep is timed on the real clock, with a floor well above a
+# loaded host's jitter; a spin and a collection on the thread's own CPU clock
+# (``cpu_clock``), so that a lap lasts what the thread ran and time the host
+# gave to other work is in no lap.  Where only the times matter the clock is a
+# fake one, and the loop is exact.
 
 def _planted(watch, n):
     (rec,) = [r for r in watch.records if r["n"] == n]
     return rec
+
+
+@pytest.fixture
+def cpu_clock(monkeypatch):
+    """The thread's CPU clock in place of the wall clock, for the laps, the
+    spans, the collections and ``_busy`` alike."""
+    monkeypatch.setattr(trace_lib.time, "perf_counter", time.thread_time)
+    trace_lib._CLOSED.clear()   # spans of an earlier test's clock
+    yield
+    trace_lib._CLOSED.clear()
 
 
 @pytest.fixture
@@ -160,7 +173,7 @@ def test_a_sleep_inside_a_span_is_waiting_under_that_span(tmp_path):
     assert watch.end() == []
 
 
-def test_a_busy_loop_is_python():
+def test_a_busy_loop_is_python(cpu_clock):
     watch = _watch()
     _loop(watch, 30, lambda i: _busy(0.5) if i == 15 else None)
     rec = _planted(watch, 15)
@@ -168,7 +181,7 @@ def test_a_busy_loop_is_python():
     assert rec["cpu_s"] >= 0.5 * rec["wall_s"]
 
 
-def test_a_collection_of_a_large_cyclic_heap_is_gc():
+def test_a_collection_of_a_large_cyclic_heap_is_gc(cpu_clock):
     watch = _watch(floor_s=0.02)
     gc.collect()
     gc.disable()    # the one collection is the planted one, of generation 2

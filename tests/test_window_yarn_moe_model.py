@@ -308,3 +308,32 @@ def test_paths_without_yarn_refuse_a_rotation_by_kind():
         generate.init_kv_cache(net, 1, 16)
     with pytest.raises(ValueError, match="pos_encoding must be 'rope'"):
         TransformerConfig(rope_kinds=(yarn, yarn))
+
+
+# ---- the programs' lowered text --------------------------------------------
+
+# the toy's training step and paged programs as commit 61f07d9 lowered them
+# (StableHLO, no locations), before the paged programs ran the model's own
+# block with the cache's cores passed in: that move was to change no
+# operation and no order of operations (on the chip the programs'
+# compile-cache keys then stay).  A change that means to move them re-pins
+# these.
+_TEXT_SHA256 = {
+    "train": "3bfdb51aa92665b829c2b23c63a2d016f68e5c1c7b5db195d5a87913ad3aa9f9",
+    "decode": "c11b28ef8e860506b08851c80e5e2eb51bda3354bc2c493153b44c2b6fe306e3",
+    "prefill": "385090e13eb6143969d981f40491dd8abf3c2bc0c03a95d89bbbdf986d6da870",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered(toy, lowered_programs):
+    return lowered_programs(toy[0], **SERVE)
+
+
+@pytest.mark.parametrize("program", sorted(_TEXT_SHA256))
+def test_the_programs_lower_to_the_parents_text(lowered, program):
+    import hashlib
+
+    text = lowered[program].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _TEXT_SHA256[program]
